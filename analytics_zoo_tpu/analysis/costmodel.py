@@ -122,23 +122,39 @@ PLATFORM_PEAKS: dict[str, PeakTable] = {
 }
 
 
+#: What a chip reports as ``device_kind`` where that is not its row's
+#: name: one v5e chip says "TPU v5 lite".
+_DEVICE_KIND_ROWS = {"tpu-v5-lite": "tpu-v5e"}
+
+
 def resolve_peaks(platform: str | None = None,
                   device_kind: str | None = None) -> PeakTable:
-    """The ceilings for this process: per-platform table entry (device
-    kind beats bare platform — "TPU v4" maps to the v4 row), then the
-    CPU-calibrated default, with ``ZOO_ORACLE_PEAKS`` (JSON object)
-    overriding individual fields last.  Unknown keys in the override
-    are rejected loudly — a typo'd ceiling must not silently leave the
-    default in place."""
+    """The ceilings for this process: the table row of the device kind
+    (or, without one, of the platform string) — "TPU v4" maps to the
+    v4 row, "TPU v5 lite" to the v5e row.  Called with neither, it asks
+    ``jax.devices()[0]``.  A TPU that has no row raises: a prediction
+    against another chip's ceilings is worse than none.  Anything that
+    is not a TPU gets the CPU-calibrated row.  ``ZOO_ORACLE_PEAKS``
+    (JSON object) overrides individual fields last; unknown keys in the
+    override are rejected loudly — a typo'd ceiling must not silently
+    leave the default in place."""
+    if platform is None and device_kind is None:
+        import jax
+
+        dev = jax.devices()[0]
+        platform, device_kind = dev.platform, dev.device_kind
+    kind = (device_kind or platform).lower().replace(" ", "-")
+    kind = _DEVICE_KIND_ROWS.get(kind, kind)
     table = PLATFORM_PEAKS["cpu"]
-    kind = (device_kind or platform or "cpu").lower().replace(" ", "-")
-    for key, peaks in PLATFORM_PEAKS.items():
-        if key != "cpu" and (key in kind or kind in key):
-            table = peaks
-            break
-    else:
-        if kind.startswith("tpu"):
-            table = PLATFORM_PEAKS["tpu-v4"]
+    if kind.startswith("tpu"):
+        rows = [peaks for key, peaks in PLATFORM_PEAKS.items()
+                if key != "cpu" and key in kind]
+        if not rows:
+            raise ValueError(
+                f"no peak table row for TPU device kind "
+                f"{device_kind or platform!r}; known rows: "
+                f"{sorted(k for k in PLATFORM_PEAKS if k != 'cpu')}")
+        table = rows[0]
     raw = os.environ.get("ZOO_ORACLE_PEAKS")
     if not raw:
         return table

@@ -166,22 +166,11 @@ class ConfigOracle:
     # ------------------------------------------------------------------
     @classmethod
     def from_env(cls, registry=None) -> "ConfigOracle":
-        """Platform-resolved peaks (device kind when jax is up,
-        ``ZOO_ORACLE_PEAKS`` override last) + a residual model fitted
-        from the accumulated report/tune-log history — analytic-only
-        when nothing has accumulated yet."""
-        platform = kind = None
-        try:
-            import jax
-
-            devices = jax.devices()
-            if devices:
-                platform = devices[0].platform
-                kind = devices[0].device_kind
-        except Exception:
-            pass
-        oracle = cls(peaks=resolve_peaks(platform, kind),
-                     registry=registry)
+        """Peaks of ``jax.devices()[0]`` (``ZOO_ORACLE_PEAKS`` override
+        last) + a residual model fitted from the accumulated
+        report/tune-log history — analytic-only when nothing has
+        accumulated yet."""
+        oracle = cls(registry=registry)
         oracle.refit()
         return oracle
 

@@ -28,36 +28,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 logger = logging.getLogger("analytics_zoo_tpu")
 
-if not hasattr(jax, "shard_map"):  # pragma: no branch
-    # Compat: this image ships jax 0.4.x, where shard_map lives in
-    # jax.experimental with `check_rep` instead of the later `check_vma`
-    # keyword.  The framework is written against the public jax.shard_map
-    # surface; adapt here ONCE (engine is imported before any parallel
-    # module) instead of forking every call site.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, mesh, in_specs, out_specs, check_vma=True,
-                          **kwargs):
-        kwargs.setdefault("check_rep", check_vma)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-    # marker for call sites that must fail LOUDLY where the 0.4.x
-    # semantics are known not to match (parallel/pipeline.py hetero+DP)
-    _compat_shard_map._zoo_compat_04x = True
-    jax.shard_map = _compat_shard_map
-
-try:
-    # Same 0.4.x-era rename: pallas-TPU CompilerParams was
-    # TPUCompilerParams (same dataclass fields).
-    from jax.experimental.pallas import tpu as _pltpu
-
-    if not hasattr(_pltpu, "CompilerParams") \
-            and hasattr(_pltpu, "TPUCompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover - pallas absent on some builds
-    pass
-
 # Canonical mesh-axis names, ordered outermost-first.  DCN-crossing axes
 # (multi-slice data parallelism) must come first so that XLA lays collectives
 # on ICI for the inner axes.
@@ -932,7 +902,9 @@ def init_zoo_context(
         ``init_nncontext("app name")``) of engine options: ``seed``,
         ``mesh_shape``, ``platform``.
       mesh_shape: e.g. ``{"data": 8}`` or ``{"data": 4, "model": 2}``; missing
-        axes get size 1 and leftover devices fold into ``data``.
+        axes get size 1 and leftover devices fold into ``data`` — also
+        when ``data`` is given: ``{"data": 4}`` on eight devices is an
+        eight-device mesh (ROADMAP D10).
       mesh_axes: axis names, outermost first.
       platform: force a jax platform ("cpu", "tpu"); tests use cpu meshes.
       dcn_shape: multi-slice extents, e.g. ``{"data": 2}`` for

@@ -169,8 +169,10 @@ def build_native(force: bool = False):
 def _compile(out_path: str) -> bool:
     # compile to a temp then rename: atomic for concurrent builders
     tmp = out_path + ".build"
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-march=native",
-           "-pthread", "-o", tmp, _SRC]
+    # no -march=native: the library is built from the committed source on
+    # whichever host runs it, but a copied tree must not carry a binary
+    # that only its build host's CPU can execute
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True)
         os.replace(tmp, out_path)

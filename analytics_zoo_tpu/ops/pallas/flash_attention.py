@@ -33,11 +33,11 @@ forward saves its softmax stats (m, l), so the backward needs no
 stats-recompute pass; on TPU the backward runs as two Pallas kernels
 (``_flash_bwd_pallas``: a dq kernel streaming K/V blocks past each q
 block, and a dk/dv/dbias kernel streaming q blocks past each K/V block)
-whose rematerialized score tiles never leave VMEM.  Elsewhere — CPU, a
-full (Lq, Lk) bias that needs its own O(Lq·Lk) gradient, or kernel
-failure — a blockwise lax.scan over key blocks serves as fallback and
-oracle (O(Lq·block_k) live memory).  Either way long-context training
-never materializes the (L, L) matrix.  On CPU (tests) the forward falls
+whose rematerialized score tiles never leave VMEM.  Elsewhere — CPU, or
+a full (Lq, Lk) bias that needs its own O(Lq·Lk) gradient — a blockwise
+lax.scan over key blocks serves as fallback and oracle (O(Lq·block_k)
+live memory).  Either way long-context training never materializes the
+(L, L) matrix.  On CPU (tests) the forward falls
 back to the jnp path automatically; set ``ZOO_FLASH_INTERPRET=1`` to
 force the actual Pallas kernels in interpret mode on CPU (CI routing +
 grad-oracle tests).
@@ -46,7 +46,6 @@ grad-oracle tests).
 from __future__ import annotations
 
 import functools
-import logging
 import math
 import os
 
@@ -191,19 +190,11 @@ def attention_stats(q, k, v, causal=False, scale=None, block_q=None,
     block_q, block_k = _resolve_blocks(block_q, block_k)
     if _pallas_available() and q.shape[-1] % 64 == 0 \
             and q.shape[2] >= 128 and k.shape[2] >= 128:
-        try:
-            out = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
-                                    block_k, interpret=_interpret_forced(),
-                                    return_stats=True)
-            invocation_counts["pallas"] += 1
-            return out
-        except Exception:
-            global _warned_fallback
-            if not _warned_fallback:
-                _warned_fallback = True
-                logging.getLogger("analytics_zoo_tpu").exception(
-                    "Pallas attention_stats kernel failed; jnp fallback. "
-                    "THIS IS A PERFORMANCE BUG.")
+        out = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
+                                block_k, interpret=_interpret_forced(),
+                                return_stats=True)
+        invocation_counts["pallas"] += 1
+        return out
     invocation_counts["fallback"] += 1
     return _attention_stats_reference(q, k, v, causal, scale)
 
@@ -795,9 +786,6 @@ def _pallas_available() -> bool:
             or _env_flag("ZOO_FLASH_FORCE_PALLAS"))
 
 
-_warned_fallback = False
-
-
 # ---------------------------------------------------------------------------
 # custom_vjp core: array args explicit so bias/segments/seed differentiate
 # (or get float0 cotangents) correctly.
@@ -811,32 +799,18 @@ def _flash_core(q, k, v, bias, q_seg, kv_seg, seed, causal, scale,
                          dropout_p, block_q, block_k)
 
 
-def _warn_fallback_once():
-    # Do NOT silently degrade to the O(L²) path on TPU: warn loudly
-    # (once) with the actual kernel error so a broken kernel is
-    # visible in logs and benchmarks.
-    global _warned_fallback
-    if not _warned_fallback:
-        _warned_fallback = True
-        logging.getLogger("analytics_zoo_tpu").exception(
-            "Pallas flash-attention kernel failed on TPU; falling "
-            "back to the O(L^2) jnp path. THIS IS A PERFORMANCE BUG."
-        )
-
-
 def _forward_impl(q, k, v, bias, q_seg, kv_seg, seed, causal, scale,
                   dropout_p, block_q, block_k, return_stats=False):
     if _pallas_available():
-        try:
-            res = _flash_fwd_pallas(
-                q, k, v, causal, scale, block_q, block_k,
-                interpret=_interpret_forced(), bias=bias, q_seg=q_seg,
-                kv_seg=kv_seg, dropout_p=dropout_p, seed=seed,
-                return_stats=return_stats)
-            invocation_counts["pallas"] += 1
-            return res
-        except Exception:
-            _warn_fallback_once()
+        # A kernel that fails to trace raises: on a TPU nothing degrades
+        # to the O(L^2) reference behind the caller's back.
+        res = _flash_fwd_pallas(
+            q, k, v, causal, scale, block_q, block_k,
+            interpret=_interpret_forced(), bias=bias, q_seg=q_seg,
+            kv_seg=kv_seg, dropout_p=dropout_p, seed=seed,
+            return_stats=return_stats)
+        invocation_counts["pallas"] += 1
+        return res
     invocation_counts["fallback"] += 1
     out = _attention_reference(q, k, v, causal, scale, bias=bias,
                                q_seg=q_seg, kv_seg=kv_seg,
@@ -857,8 +831,7 @@ def _fwd(q, k, v, bias, q_seg, kv_seg, seed, causal, scale, dropout_p,
 def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
     """Flash backward.  On TPU (stats saved by the Pallas forward):
     `_flash_bwd_pallas` — two streaming kernels whose score tiles never
-    leave VMEM.  Otherwise (CPU, full-(Lq,Lk)-bias grad, or kernel
-    failure): blockwise lax.scan over key blocks, recomputing each
+    leave VMEM.  Otherwise (CPU or full-(Lq,Lk)-bias grad): blockwise lax.scan over key blocks, recomputing each
     (lq, block_k) score tile from q/k (rematerialisation).  Live memory is
     O(lq·block_k + lk·d) either way; the (lq, lk) matrix is never
     materialized.  Dropout is re-derived from the same `_keep_bits` hash
@@ -881,15 +854,12 @@ def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
 
     full_bias = has_bias and bias.shape[2] > 1
     if m_s is not None and _pallas_available() and not full_bias:
-        try:
-            dq, dk, dv, dbias = _flash_bwd_pallas(
-                q, k, v, g, out, m_s, l_s, causal, scale_v,
-                block_q=block_q, block_k=block_k,
-                interpret=_interpret_forced(), bias=bias, q_seg=q_seg,
-                kv_seg=kv_seg, dropout_p=dropout_p, seed=seed)
-            return (dq, dk, dv, dbias, dseg_q, dseg_kv, dseed)
-        except Exception:
-            _warn_fallback_once()
+        dq, dk, dv, dbias = _flash_bwd_pallas(
+            q, k, v, g, out, m_s, l_s, causal, scale_v,
+            block_q=block_q, block_k=block_k,
+            interpret=_interpret_forced(), bias=bias, q_seg=q_seg,
+            kv_seg=kv_seg, dropout_p=dropout_p, seed=seed)
+        return (dq, dk, dv, dbias, dseg_q, dseg_kv, dseed)
     # The fallback scan keeps its own 256 cap: it materializes
     # (b, h, lq, bk) f32 score/grad tiles in HBM, so the forward kernel's
     # 1024 tuning would quadruple live memory and can OOM long-context

@@ -37,7 +37,6 @@ learning rate is evaluated at the PRE-increment count.
 
 from __future__ import annotations
 
-import logging
 import os
 
 import jax
@@ -68,18 +67,6 @@ def _pallas_available() -> bool:
     # this knob off-TPU will fail — lower, don't run.
     return (jax.default_backend() == "tpu" or _interpret_forced()
             or _env_flag("ZOO_KERNEL_FORCE_PALLAS"))
-
-
-_warned_fallback = False
-
-
-def _warn_fallback_once():
-    global _warned_fallback
-    if not _warned_fallback:
-        _warned_fallback = True
-        logging.getLogger("analytics_zoo_tpu").exception(
-            "Pallas fused-adam kernel failed on TPU; falling back to "
-            "the unfused optax chain. THIS IS A PERFORMANCE BUG.")
 
 
 def _adam_kernel(scal_ref, g_ref, mu_ref, nu_ref,
@@ -223,13 +210,8 @@ def fused_adam(learning_rate=0.001, b1: float = 0.9, b2: float = 0.999,
             # chain is the contract, never guess
             invocation_counts["fallback"] += 1
             return inner.update(updates, state, params)
-        try:
-            out = _fused_update(updates, state, b1, b2, eps, lr_fn)
-            invocation_counts["pallas"] += 1
-            return out
-        except Exception:
-            _warn_fallback_once()
-            invocation_counts["fallback"] += 1
-            return inner.update(updates, state, params)
+        out = _fused_update(updates, state, b1, b2, eps, lr_fn)
+        invocation_counts["pallas"] += 1
+        return out
 
     return optax.GradientTransformation(init_fn, update_fn)

@@ -31,7 +31,6 @@ predicts and the bench's cross-lowered HLO measurement checks.
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 import jax
@@ -58,18 +57,6 @@ def _interpret_forced() -> bool:
 def _pallas_available() -> bool:
     return (jax.default_backend() == "tpu" or _interpret_forced()
             or _env_flag("ZOO_KERNEL_FORCE_PALLAS"))
-
-
-_warned_fallback = False
-
-
-def _warn_fallback_once():
-    global _warned_fallback
-    if not _warned_fallback:
-        _warned_fallback = True
-        logging.getLogger("analytics_zoo_tpu").exception(
-            "Pallas fused softmax-xent kernel failed on TPU; falling "
-            "back to the unfused jnp path. THIS IS A PERFORMANCE BUG.")
 
 
 # ---------------------------------------------------------------------------
@@ -229,13 +216,9 @@ def _bwd_pallas(logits, labels, lse, g, interpret):
 
 def _fwd_impl(logits, labels):
     if _pallas_available():
-        try:
-            res = _fwd_pallas(logits, labels,
-                              interpret=_interpret_forced())
-            invocation_counts["pallas"] += 1
-            return res
-        except Exception:
-            _warn_fallback_once()
+        res = _fwd_pallas(logits, labels, interpret=_interpret_forced())
+        invocation_counts["pallas"] += 1
+        return res
     invocation_counts["fallback"] += 1
     return _reference_fwd(logits, labels)
 
@@ -258,16 +241,10 @@ def _vjp_fwd(logits, labels):
 def _vjp_bwd(res, g):
     logits, labels, lse = res
     if _pallas_available():
-        try:
-            dx = _bwd_pallas(logits, labels, lse, g,
-                             interpret=_interpret_forced())
-            invocation_counts["pallas"] += 1
-        except Exception:
-            _warn_fallback_once()
-            dx = None
+        dx = _bwd_pallas(logits, labels, lse, g,
+                         interpret=_interpret_forced())
+        invocation_counts["pallas"] += 1
     else:
-        dx = None
-    if dx is None:
         invocation_counts["fallback"] += 1
         dx = _reference_bwd(logits, labels, lse, g)
     dlabels = np.zeros(labels.shape, dtype=jax.dtypes.float0)

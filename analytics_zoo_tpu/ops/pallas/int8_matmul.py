@@ -23,7 +23,6 @@ tolerance ~1e-5 relative (accumulation order).  CPU runs the fallback,
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 import jax
@@ -47,18 +46,6 @@ def _interpret_forced() -> bool:
 def _pallas_available() -> bool:
     return (jax.default_backend() == "tpu" or _interpret_forced()
             or _env_flag("ZOO_KERNEL_FORCE_PALLAS"))
-
-
-_warned_fallback = False
-
-
-def _warn_fallback_once():
-    global _warned_fallback
-    if not _warned_fallback:
-        _warned_fallback = True
-        logging.getLogger("analytics_zoo_tpu").exception(
-            "Pallas int8-matmul kernel failed on TPU; falling back to "
-            "dequantize-then-dot. THIS IS A PERFORMANCE BUG.")
 
 
 def _reference(x, values, scale):
@@ -135,12 +122,9 @@ def int8_matmul(x, values, scale):
     through HBM and VMEM.  x (M, K) float, values (K, N) int8, scale
     (N,) f32 per-output-channel; returns (M, N) in x's dtype."""
     if _pallas_available():
-        try:
-            out = _matmul_pallas(x, values, scale,
-                                 interpret=_interpret_forced())
-            invocation_counts["pallas"] += 1
-            return out
-        except Exception:
-            _warn_fallback_once()
+        out = _matmul_pallas(x, values, scale,
+                             interpret=_interpret_forced())
+        invocation_counts["pallas"] += 1
+        return out
     invocation_counts["fallback"] += 1
     return _reference(x, values, scale)

@@ -386,18 +386,6 @@ def gpipe_hetero(stage_fns, edge_params, stacked_params, x, *,
       output (leading dim of every output leaf must be the microbatch row
       count).
     """
-    if batch_axis is not None and getattr(jax.shard_map,
-                                          "_zoo_compat_04x", False):
-        # fail loudly: under the jax-0.4.x shard_map shim this exact
-        # combination computes WRONG numbers (outputs scaled by the
-        # data-axis size — tests/test_pipeline_parallel.py
-        # TestGPipeHetero::test_full_lm_with_data_parallel), and a
-        # silently corrupted forward is worse than no forward
-        raise NotImplementedError(
-            "gpipe_hetero with a data-parallel batch_axis produces "
-            "incorrect results under the jax 0.4.x shard_map compat "
-            "shim; upgrade jax or drop batch_axis (run DP outside the "
-            "hetero pipeline)")
     mesh = mesh or get_zoo_context().mesh
     n_stages = dict(mesh.shape).get(axis_name, 1)
     if len(stage_fns) != n_stages:

@@ -435,8 +435,12 @@ class ShardingPlan:
         out = match_partition_rules(rules, tree,
                                     report_unused=report_unused)
         specs, unused = out if report_unused else (out, None)
+        # GSPMD computes the same values under any layout, so a leaf
+        # whose dim 0 the axis cannot divide may take the shard on a
+        # later dim; a shard_map plan's specs are its program's contract
         clamped = jax.tree_util.tree_map(
-            lambda leaf, spec: _clamp_spec(spec, np.shape(leaf), mesh),
+            lambda leaf, spec: _clamp_spec(spec, np.shape(leaf), mesh,
+                                           spill=self.mode == "jit"),
             tree, specs)
         return (clamped, unused) if report_unused else clamped
 
@@ -675,14 +679,27 @@ class ShardingPlan:
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
-def _clamp_spec(spec: P, shape: tuple, mesh) -> P:
+def _clamp_spec(spec: P, shape: tuple, mesh, spill: bool = False) -> P:
     """Clamp a rule's spec to what ``mesh`` can divide on this leaf:
     axes missing from the mesh drop to None, a dim the axis product does
     not divide evenly drops to None, entries beyond the leaf's rank are
     truncated.  A rule table written for ``{data: 8, model: 4}`` then
-    stays valid on ``{data: 2}`` — undividable dims just replicate."""
+    stays valid on ``{data: 2}`` — undividable dims just replicate.
+
+    ``spill=True`` keeps the one-entry spec ``P(axis)`` — the fsdp/ZeRO
+    idiom "shard this leaf over ``axis``" — from replicating a leaf only
+    because its dim 0 is small: the shard moves to the first later dim
+    the axis divides (a (3, 3, Cin, Cout) conv kernel shards Cin).
+    Leaves with no such dim, and specs that name more than one dim,
+    clamp as above."""
     if spec == P():
         return spec
+    if spill and len(spec) == 1 and spec[0] is not None:
+        for i in range(len(shape)):
+            moved = _clamp_spec(P(*([None] * i), spec[0]), shape, mesh)
+            if moved != P():
+                return moved
+        return P()
     entries = list(spec)[: len(shape)]
     out = []
     for dim, entry in zip(shape, entries):

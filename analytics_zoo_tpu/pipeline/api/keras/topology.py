@@ -253,9 +253,12 @@ class KerasNet(_ContainerBase):
                                 pad_to_batch=ctx.data_parallel_size):
             xb = ctx.shard_batch(batch["x"])
             out = fwd(self.params, self.state, xb)
-            outs.append([np.asarray(o) for o in out]
+            # EVERY batch the mesh does not divide is padded, not only
+            # the last: drop each one's padded rows before joining
+            valid = int(batch["n_valid"])
+            outs.append([np.asarray(o)[:valid] for o in out]
                         if isinstance(out, (list, tuple))
-                        else np.asarray(out))
+                        else np.asarray(out)[:valid])
         if isinstance(outs[0], list):  # multi-output graph
             return [np.concatenate([o[i] for o in outs], axis=0)[:n]
                     for i in range(len(outs[0]))]

@@ -28,7 +28,10 @@ def fresh_registry():
 
 
 @pytest.fixture(autouse=True)
-def cache_teardown():
+def cache_teardown(monkeypatch):
+    # these tests place the cache themselves: a directory given from
+    # outside would win over every path they name
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     try:
         yield
     finally:

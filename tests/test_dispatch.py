@@ -213,7 +213,8 @@ class TestPureStepProbe:
 
 
 class TestEstimatorWarmup:
-    def test_warmup_compiles_and_records_metrics(self, tmp_path):
+    def test_warmup_compiles_and_records_metrics(self, tmp_path,
+                                                 monkeypatch):
         """warmup() AOT-compiles the K=1 and scan-K steps through the
         compile plane; a second warmup at the same shapes is served from
         the persistent cache (hit counter moves, not the miss one)."""
@@ -224,6 +225,8 @@ class TestEstimatorWarmup:
             snapshot,
         )
 
+        # the cache this test places must be the one in force
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         reg = MetricsRegistry(enabled=True)
         prev = set_registry(reg)
         try:

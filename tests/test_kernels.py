@@ -346,6 +346,7 @@ def test_all_xla_table_trajectory_bit_identical():
 def _run_child(script, env_overrides=None, timeout=420):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     for k in ("ZOO_USE_PALLAS", "ZOO_SHARDING_PLAN", "ZOO_COMPILE_CACHE",
+              "JAX_COMPILATION_CACHE_DIR",
               "ZOO_KERNEL_INTERPRET", "ZOO_KERNEL_FORCE_PALLAS"):
         env.pop(k, None)
     env.update(env_overrides or {})

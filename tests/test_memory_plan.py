@@ -333,6 +333,7 @@ def _run_pipe_child(cache_dir):
     )
     env.pop("ZOO_SHARDING_PLAN", None)
     env.pop("ZOO_SHARD_OPTIMIZER", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     r = subprocess.run([sys.executable, "-c", _PIPE_CHILD], env=env,
                        cwd=REPO, capture_output=True, text=True,
                        timeout=420)

@@ -105,10 +105,14 @@ def test_normalize_features_aliases():
 
 def test_resolve_peaks_device_kind():
     assert resolve_peaks("tpu", "TPU v4").source == "tpu-v4"
-    assert resolve_peaks(None, "TPU v5 lite").source.startswith("tpu")
+    # the device_kind one v5e chip reports
+    assert resolve_peaks(None, "TPU v5 lite").source == "tpu-v5e"
     assert resolve_peaks("cpu", None).source == "cpu-default"
-    # unknown TPU generations fall to the v4 row, not the CPU row
-    assert resolve_peaks("tpu", "tpu-v99").source == "tpu-v4"
+    # the no-argument call asks jax.devices()[0]: the CPU here
+    assert resolve_peaks().source == "cpu-default"
+    # a TPU without a row is an error, never another chip's row
+    with pytest.raises(ValueError, match="tpu-v99"):
+        resolve_peaks("tpu", "tpu-v99")
 
 
 def test_peaks_env_override(monkeypatch):

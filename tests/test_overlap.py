@@ -252,6 +252,7 @@ def test_prefetch_compiles_own_label_and_warm_starts(tmp_path):
                    XLA_FLAGS="--xla_force_host_platform_device_count=8",
                    ZOO_COMPILE_CACHE=str(cache))
         env.pop("ZOO_SHARDING_PLAN", None)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         r = subprocess.run([sys.executable, "-c", _PREFETCH_CHILD],
                            env=env, cwd=REPO, capture_output=True,
                            text=True, timeout=420)

@@ -77,6 +77,33 @@ class TestShardingPlan:
         specs = tp.param_specs(params, mesh)  # mesh has no model axis
         assert specs["k"] == P()
 
+    @pytest.mark.parametrize("shape,want", [
+        ((3, 3, 64, 128), P(None, None, "data")),   # conv kernel: Cin
+        ((7, 7, 3, 64), P(None, None, None, "data")),  # stem: Cout
+        ((12, 32), P(None, "data")),
+        ((16, 4), P("data")),                       # dim 0 still first
+        ((3, 5), P()),                              # nothing divides
+    ])
+    def test_one_entry_spec_moves_to_a_dim_the_axis_divides(self, shape,
+                                                            want):
+        """``P(axis)`` under a GSPMD plan means "shard this leaf": a
+        small dim 0 must not leave a conv kernel replicated (a ResNet-50
+        under fsdp kept 94% of its state on every chip)."""
+        from analytics_zoo_tpu.parallel import plan as zp
+
+        mesh = zp.build_mesh({"data": 8})
+        params = {"w": np.zeros(shape, np.float32)}
+        assert zp.fsdp().param_specs(params, mesh)["w"] == want
+        assert zp.zero1().opt_specs(params, mesh)["w"] == want
+        # a spec that names its dims, and a shard_map plan's spec (the
+        # program's contract), stay where they were written
+        stays = P("data") if shape[0] % 8 == 0 else P()
+        tp = zp.tensor_parallel([(r"w", P("data", None))])
+        assert tp.param_specs(params, mesh)["w"] == stays
+        sm = zp.ShardingPlan(name="sm", mode="shard_map",
+                             param_rules=((r".*", P("data")),))
+        assert sm.param_specs(params, mesh)["w"] == stays
+
     def test_resolve_plan_precedence(self, monkeypatch):
         from analytics_zoo_tpu.common.engine import ZooConfig
         from analytics_zoo_tpu.parallel import plan as zp
@@ -392,6 +419,7 @@ def _run_child(cache_dir):
     )
     env.pop("ZOO_SHARDING_PLAN", None)
     env.pop("ZOO_SHARD_OPTIMIZER", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     r = subprocess.run([sys.executable, "-c", _CHILD], env=env, cwd=REPO,
                        capture_output=True, text=True, timeout=420)
     assert r.returncode == 0, r.stdout + "\n" + r.stderr

@@ -437,15 +437,6 @@ class TestGPipeHetero:
         toks_d = jax.device_put(jnp.asarray(toks),
                                 NamedSharding(mesh, P("data")))
 
-        if getattr(jax.shard_map, "_zoo_compat_04x", False):
-            # hetero+DP computes wrong numbers under the 0.4.x shard_map
-            # shim (outputs scaled by the data-axis size); the library
-            # must refuse loudly rather than return corrupted logits
-            with pytest.raises(NotImplementedError, match="batch_axis"):
-                jax.jit(lambda p, w, t: transformer_gpipe_lm(
-                    layer, p, w, head_b, t, n_microbatch=4,
-                    batch_axis="data"))(params, head_w, toks_d)
-            return
         out = jax.jit(lambda p, w, t: transformer_gpipe_lm(
             layer, p, w, head_b, t, n_microbatch=4,
             batch_axis="data"))(params, head_w, toks_d)

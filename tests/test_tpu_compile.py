@@ -1,0 +1,142 @@
+"""The Pallas kernels of the main path, compiled at real widths by the
+installed TPU compiler for a v5e chip that is described, not attached
+(section 2 of the on-chip-measurement guide).  Nothing runs: a compile that
+passes says the chip's compiler accepts the kernel at this shape, nothing
+about results or times.  ``chip_smoke.py`` runs the same kernels against
+their references on the chip.
+
+Keep these in ONE file: only one process may hold the TPU library, and the
+worker that gets this file is the one that loads it."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def real_kernels(monkeypatch):
+    """Route to the real (non-interpret) kernels off-TPU, and keep the
+    persistent cache out of it: an entry compiled for a described chip
+    cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setenv("ZOO_FLASH_FORCE_PALLAS", "1")
+    monkeypatch.setenv("ZOO_KERNEL_FORCE_PALLAS", "1")
+    monkeypatch.delenv("ZOO_FLASH_INTERPRET", raising=False)
+    monkeypatch.delenv("ZOO_KERNEL_INTERPRET", raising=False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _flash(shape, grad, padding_bias=False, segments=False, **kw):
+    """(fn, arg specs) for flash attention on (B, H, L, D) bf16."""
+    from analytics_zoo_tpu.ops.pallas.flash_attention import flash_attention
+
+    b, _h, l, _d = shape
+    args = [(shape, jnp.bfloat16)] * 3
+    if padding_bias:
+        args.append(((b, 1, 1, l), jnp.float32))  # the BERT mask
+    if segments:
+        args += [((b, l), jnp.int32)] * 2
+
+    def fwd(q, k, v, *rest):
+        rest = list(rest)
+        extra = {"bias": rest.pop(0)} if padding_bias else {}
+        if segments:
+            extra["q_segment_ids"], extra["kv_segment_ids"] = rest
+        return flash_attention(q, k, v, **kw, **extra)
+
+    if not grad:
+        return fwd, args
+
+    def loss(q, k, v, *rest):
+        return jnp.sum(fwd(q, k, v, *rest).astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2)), args
+
+
+def _xent(shape):
+    from analytics_zoo_tpu.ops.pallas.fused_softmax_xent import softmax_xent
+
+    def fn(logits, labels):
+        return jax.value_and_grad(
+            lambda x: jnp.sum(softmax_xent(x, labels)))(logits)
+
+    return fn, [(shape, jnp.float32), (shape[:1], jnp.int32)]
+
+
+def _int8(m, k, n):
+    from analytics_zoo_tpu.ops.pallas.int8_matmul import int8_matmul
+
+    return int8_matmul, [((m, k), jnp.float32), ((k, n), jnp.int8),
+                         ((n,), jnp.float32)]
+
+
+def _adam(shapes):
+    import optax
+
+    from analytics_zoo_tpu.ops.pallas.fused_adam import fused_adam
+
+    opt = fused_adam(1e-3)
+
+    def fn(*leaves):
+        params = dict(enumerate(leaves))
+        upd, _state = opt.update(params, opt.init(params), params)
+        return optax.apply_updates(params, upd)
+
+    return fn, [(s, jnp.float32) for s in shapes]
+
+
+CASES = {
+    "flash_fwd": lambda: _flash((8, 12, 2048, 64), False, causal=True),
+    "flash_fwd_bwd_causal":
+        lambda: _flash((4, 12, 2048, 64), True, causal=True),
+    "flash_fwd_bwd_dropout":
+        lambda: _flash((4, 32, 1024, 80), True, causal=True,
+                       dropout_p=0.1, dropout_seed=7),
+    "flash_fwd_bwd_padding_bias":
+        lambda: _flash((4, 20, 1024, 128), True, padding_bias=True),
+    "flash_fwd_bwd_segments_unaligned":
+        lambda: _flash((2, 12, 1000, 64), True, causal=True,
+                       segments=True),
+    "softmax_xent_fwd_bwd": lambda: _xent((4096, 50304)),
+    "int8_matmul": lambda: _int8(256, 2048, 1000),
+    "fused_adam":
+        lambda: _adam(((3, 3, 512, 512), (2048, 1000), (1000,))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, real_kernels):
+    fn, arg_specs = CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in arg_specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, f"{case}: no Mosaic kernel in:\n" \
+        + text[:2000]
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 16 << 30  # the chip's memory

@@ -505,9 +505,8 @@ class TestHloCostExtraction:
 
     def test_planted_f64_raises_finding(self):
         from analytics_zoo_tpu.analysis import analyze_hlo_text
-        from jax.experimental import enable_x64
 
-        with enable_x64():
+        with jax.enable_x64():
             text = jax.jit(lambda x: x.astype("float64") * 2.0).lower(
                 np.zeros((4,), np.float32)).as_text()
         rpt = analyze_hlo_text(text, "f64")
@@ -705,6 +704,7 @@ class TestFusedTrainStepAcceptance:
         from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
 
         reg, flight = fresh_telemetry
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setenv("ZOO_COMPILE_CACHE", str(tmp_path / "cc"))
         az.init_zoo_context(ZooConfig(seed=3, mesh_shape={"data": 8},
                                       steps_per_dispatch=2))

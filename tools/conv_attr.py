@@ -3,8 +3,8 @@
 Compiles the ResNet-50 train step, dumps optimized HLO to map
 convolution.N -> (operand shapes), then sums the profiled trace
 durations per conv name and prints the per-shape cost ranking.
-Usage: conv_attr.py [batch] [trace_dir]  (trace_dir default PROFILE_r03,
-or $ZOO_PROFILE_DIR).
+Usage: conv_attr.py [batch] [trace_dir]  (trace_dir default
+$ZOO_PROFILE_DIR).
 """
 
 import glob
@@ -54,8 +54,8 @@ def main():
             shapes = re.findall(r"(?:bf16|f32)\[[\d,]+\]", line)
             conv_lines[m.group(1)] = " ".join(shapes[:3])
 
-    trace_dir = sys.argv[2] if len(sys.argv) > 2 else os.environ.get(
-        "ZOO_PROFILE_DIR", "PROFILE_r03")
+    trace_dir = sys.argv[2] if len(sys.argv) > 2 \
+        else os.environ["ZOO_PROFILE_DIR"]
     files = glob.glob(f"{trace_dir}/**/*.trace.json.gz", recursive=True)
     if not files:
         sys.exit(f"no trace under {trace_dir}/ — run tools/profile_step.py "

@@ -16,7 +16,7 @@ relabeled after the fact (VERDICT r4 weak #6):
   ~2 bpc requires genuinely learned structure, not class priors);
 * the resumed run reproduces the uninterrupted loss curve.
 
-Merges its section into ACCURACY_r05.json (never clobbers other
+Merges its section into ACCURACY.json (never clobbers other
 sections).  Usage:
   python tools/transformer_convergence.py [--cpu] [--tiny] [--out FILE]
 """
@@ -170,7 +170,7 @@ def main():
         "seconds": round(time.time() - t0, 1),
     }
 
-    path = a.out or os.path.join(REPO, "ACCURACY_r05.json")
+    path = a.out or os.path.join(REPO, "ACCURACY.json")
     blob = {}
     if os.path.exists(path):
         try:
