@@ -945,37 +945,47 @@ def init_zoo_context(
     if compute_dtype is not None:
         cfg.compute_dtype = compute_dtype
 
-    devices = jax.devices(cfg.platform) if cfg.platform else jax.devices()
-    axes = tuple(cfg.mesh_axes)
-    if slice_groups is not None and not dcn_shape:
-        raise ValueError("slice_groups requires dcn_shape")
-    if dcn_shape:
-        # multi-slice: DCN-crossing axis outermost, per-slice ICI extents
-        # from mesh_shape (see parallel.multihost.hybrid_mesh).  The FULL
-        # axes tuple is kept — unlisted axes get size 1 exactly like the
-        # plain path, so PartitionSpecs naming them keep working.
-        from analytics_zoo_tpu.parallel.multihost import hybrid_mesh
+    # metrics/ stays off this module's import path (it imports nothing of
+    # common/, and the reverse holds at import time)
+    from analytics_zoo_tpu.metrics import get_registry, span
 
-        ici = dict(cfg.mesh_shape or {})
-        if not ici:
-            raise ValueError("dcn_shape requires an explicit mesh_shape "
-                             "(the per-slice ICI extents)")
-        mesh = hybrid_mesh(ici, dict(dcn_shape), axes=axes,
-                           devices=devices, slice_groups=slice_groups,
-                           allow_idle=allow_idle)
-        devices = list(mesh.devices.ravel())
-    else:
-        shape = _infer_mesh_shape(devices, axes, cfg.mesh_shape)
-        n_used = math.prod(shape.values())
-        dev_array = np.asarray(devices[:n_used]).reshape(
-            [shape[a] for a in axes])
-        mesh = Mesh(dev_array, axes)
-    ctx = ZooContext(
-        mesh=mesh, platform=devices[0].platform, seed=cfg.seed,
-        compute_dtype=_resolve_compute_dtype(
-            cfg.compute_dtype, devices[0].platform),
-        config=cfg,
-    )
+    # the first jax.devices() of a process starts the backend: on a TPU
+    # that is the seconds it takes to reach the chip
+    with span("zoo.context.init", observe=get_registry().gauge(
+            "zoo_context_init_seconds",
+            "the last init_zoo_context: backend start (the first call of "
+            "a process reaches the chip there) and mesh").set):
+        devices = jax.devices(cfg.platform) if cfg.platform else jax.devices()
+        axes = tuple(cfg.mesh_axes)
+        if slice_groups is not None and not dcn_shape:
+            raise ValueError("slice_groups requires dcn_shape")
+        if dcn_shape:
+            # multi-slice: DCN-crossing axis outermost, per-slice ICI extents
+            # from mesh_shape (see parallel.multihost.hybrid_mesh).  The FULL
+            # axes tuple is kept — unlisted axes get size 1 exactly like the
+            # plain path, so PartitionSpecs naming them keep working.
+            from analytics_zoo_tpu.parallel.multihost import hybrid_mesh
+
+            ici = dict(cfg.mesh_shape or {})
+            if not ici:
+                raise ValueError("dcn_shape requires an explicit mesh_shape "
+                                 "(the per-slice ICI extents)")
+            mesh = hybrid_mesh(ici, dict(dcn_shape), axes=axes,
+                               devices=devices, slice_groups=slice_groups,
+                               allow_idle=allow_idle)
+            devices = list(mesh.devices.ravel())
+        else:
+            shape = _infer_mesh_shape(devices, axes, cfg.mesh_shape)
+            n_used = math.prod(shape.values())
+            dev_array = np.asarray(devices[:n_used]).reshape(
+                [shape[a] for a in axes])
+            mesh = Mesh(dev_array, axes)
+        ctx = ZooContext(
+            mesh=mesh, platform=devices[0].platform, seed=cfg.seed,
+            compute_dtype=_resolve_compute_dtype(
+                cfg.compute_dtype, devices[0].platform),
+            config=cfg,
+        )
     with _LOCK:
         _CONTEXT = ctx
     logger.info(

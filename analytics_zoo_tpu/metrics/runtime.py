@@ -10,9 +10,14 @@ Processing Framework"): the data-wait vs. compute split must be measured
 - ``dispatch``    — handing the sharded batch to the jitted step
   (host-side async dispatch cost);
 - ``step``        — one full loop iteration wall time (data_wait +
-  dispatch + callback/trigger work; device compute overlaps it).
+  dispatch + callback/trigger work; device compute overlaps it);
+- ``epoch_sync``  — the epoch's closing ``float(loss)``: how far the
+  host's loop ran ahead of the device (near 0, the host sets the pace);
+- ``feed_host_batch`` / ``feed_shard`` — the infeed thread's own two
+  costs a queue item: the FeatureSet's host gather, then stack and
+  ``device_put``.  Their sum is what the input layer can produce at.
 
-All three are histograms, so the exporters carry p50/p95/p99 — tail
+All are histograms, so the exporters carry p50/p95/p99 — tail
 behavior (a stalling input pipeline shows up as a fat data_wait p99 long
 before it moves the mean).
 
@@ -48,8 +53,9 @@ class StepMetrics:
     """Fit-loop breakdown recorder.
 
     Children are resolved ONCE at construction, so the per-step cost is
-    three ``observe`` + two ``inc`` calls — and on a disabled registry
-    every one of those is the shared no-op singleton (no allocation)."""
+    three ``observe`` + two ``inc`` calls on the loop's thread and two
+    ``observe`` on the feeder's — and on a disabled registry every one
+    of those is the shared no-op singleton (no allocation)."""
 
     def __init__(self, registry: MetricsRegistry | None = None):
         reg = registry if registry is not None else get_registry()
@@ -64,6 +70,19 @@ class StepMetrics:
         self.step = reg.histogram(
             "zoo_train_step_seconds",
             "full loop-iteration wall time per step",
+            buckets=STEP_BUCKETS)
+        self.epoch_sync = reg.histogram(
+            "zoo_train_epoch_sync_seconds",
+            "the epoch-closing loss fetch: the host loop's lead over "
+            "the device", buckets=STEP_BUCKETS)
+        self.feed_host_batch = reg.histogram(
+            "zoo_feed_host_batch_seconds",
+            "infeed thread: one next() on the FeatureSet's batch stream "
+            "(host gather; the exhausted probe that ends an epoch counts)",
+            buckets=STEP_BUCKETS)
+        self.feed_shard = reg.histogram(
+            "zoo_feed_shard_seconds",
+            "infeed thread: stack and device_put of one queue item",
             buckets=STEP_BUCKETS)
         self.steps = reg.counter(
             "zoo_train_steps_total", "train steps dispatched")

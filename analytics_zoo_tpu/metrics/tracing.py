@@ -9,7 +9,13 @@ so the two timelines can be eyeballed side by side.
 
 Spans nest through a :mod:`contextvars` variable, so nesting is correct
 across threads (the serving loop thread and the infeed thread each get
-their own span stack) and each event records its parent span's name.
+their own span stack).  Each event carries an ``id`` unique in the
+process, its parent's ``parent_id`` (and name, ``args.parent``) and
+``fit``: the id of the outermost ``span(..., fit=True)`` open around it,
+which every span of one ``Estimator.train`` call shares.  A thread
+started under ``contextvars.copy_context().run`` (the estimator's infeed
+feeder) inherits both, so its spans join the call's tree from their own
+``tid``.
 
 Two optional device hooks, both gated on jax being importable so the
 module stays dependency-free:
@@ -28,6 +34,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
 import os
 import threading
@@ -35,9 +42,14 @@ import time
 
 __all__ = ["Tracer", "span", "get_tracer", "set_tracer"]
 
-# Innermost open span's name (per execution context / thread).
+# Innermost open span as (name, id), per execution context / thread.
 _current_span: contextvars.ContextVar = contextvars.ContextVar(
     "zoo_current_span", default=None)
+# Id of the span that opened the enclosing fit() call, None outside one.
+_current_fit: contextvars.ContextVar = contextvars.ContextVar(
+    "zoo.current_fit", default=None)
+# next() on a count is atomic under the interpreter lock
+_span_ids = itertools.count(1)
 
 
 def _block_until_ready(tree):
@@ -82,14 +94,16 @@ class Tracer:
         # process's trace clock alone is only self-consistent)
         self._t0 = time.perf_counter()
         self._t0_monotonic = time.monotonic()
-        self._t0_epoch = time.time()
+        self._t0_epoch_ns = time.time_ns()
+        self._t0_epoch = self._t0_epoch_ns / 1e9
 
     # -- recording ------------------------------------------------------
     def now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
 
     def add_event(self, name: str, ts_us: float, dur_us: float,
-                  args: dict | None = None):
+                  args: dict | None = None, *, id: int | None = None,
+                  parent_id: int | None = None, fit: int | None = None):
         if not self.enabled:
             return
         ev = {
@@ -100,6 +114,9 @@ class Tracer:
             "pid": os.getpid(),
             "tid": threading.get_ident(),
             "cat": "zoo",
+            "id": next(_span_ids) if id is None else id,
+            "parent_id": parent_id,
+            "fit": fit,
         }
         if args:
             ev["args"] = args
@@ -135,6 +152,14 @@ class Tracer:
                 "monotonic": self._t0_monotonic,
                 "pid": os.getpid()}
 
+    def device_clock_ns(self, event: dict) -> tuple[int, int]:
+        """``(start, end)`` of a recorded event in nanoseconds since the
+        Unix epoch: the clock ``jax.profiler`` stamps a device plane's
+        events with, so a span can be laid over the ``XLA Modules`` and
+        ``XLA Ops`` lines of a capture of the same process."""
+        start = self._t0_epoch_ns + int(event["ts"] * 1e3)
+        return start, start + int(event["dur"] * 1e3)
+
     def to_chrome_trace(self) -> dict:
         """The ``chrome://tracing`` JSON object format."""
         doc = {
@@ -162,26 +187,49 @@ class Tracer:
 
 @contextlib.contextmanager
 def span(name: str, sync=None, args: dict | None = None,
-         tracer: Tracer | None = None):
-    """Time a block as one trace event; nests via contextvars.
+         tracer: Tracer | None = None, observe=None, fit: bool = False):
+    """Time a block as one trace event; nests via contextvars.  Also a
+    decorator: ``@span("zoo.fit")`` times every call of the function.
 
     Args:
       name: event name (dotted convention: ``zoo.train.step``).
       sync: optional pytree passed to ``jax.block_until_ready`` before the
         span closes — makes the span cover device execution, not just the
         async dispatch.
-      args: extra key/values attached to the event.
+      args: extra key/values attached to the event.  A block that raises
+        still records its event, with the exception's type as
+        ``args.error``.
       tracer: override the process-global tracer (tests).
+      observe: called with the block's seconds (a histogram's
+        ``observe``, a gauge's ``set``), from this span's own two clock
+        reads and also with the tracer disabled, so the counter and the
+        span cannot disagree.
+      fit: this span is a ``fit()`` call's entry: unless a span around it
+        already is, every span opened under it carries its ``id`` as
+        ``fit``.
     """
     t = tracer if tracer is not None else get_tracer()
     if not t.enabled:
         # cheap disabled path: no contextvar churn, no event dict
-        yield
+        if observe is None:
+            yield
+        else:
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                observe(time.perf_counter() - t0)
         if sync is not None:
             _block_until_ready(sync)
         return
     parent = _current_span.get()
-    token = _current_span.set(name)
+    span_id = next(_span_ids)
+    token = _current_span.set((name, span_id))
+    fit_id = _current_fit.get()
+    fit_token = None
+    if fit and fit_id is None:
+        fit_id = span_id
+        fit_token = _current_fit.set(fit_id)
     annot = None
     if t.jax_bridge:
         try:
@@ -191,11 +239,15 @@ def span(name: str, sync=None, args: dict | None = None,
             annot.__enter__()
         except Exception:
             annot = None
+    error = None
     t0 = t.now_us()
     try:
         yield
         if sync is not None:
             _block_until_ready(sync)
+    except BaseException as e:
+        error = type(e).__name__
+        raise
     finally:
         dur = t.now_us() - t0
         if annot is not None:
@@ -204,10 +256,18 @@ def span(name: str, sync=None, args: dict | None = None,
             except Exception:
                 pass
         _current_span.reset(token)
+        if fit_token is not None:
+            _current_fit.reset(fit_token)
         ev_args = dict(args) if args else {}
         if parent is not None:
-            ev_args["parent"] = parent
-        t.add_event(name, t0, dur, ev_args or None)
+            ev_args["parent"] = parent[0]
+        if error is not None:
+            ev_args["error"] = error
+        t.add_event(name, t0, dur, ev_args or None, id=span_id,
+                    parent_id=parent[1] if parent is not None else None,
+                    fit=fit_id)
+        if observe is not None:
+            observe(dur / 1e6)
 
 
 # ---------------------------------------------------------------------------

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from analytics_zoo_tpu.common.engine import get_zoo_context
+from analytics_zoo_tpu.metrics import span
 from analytics_zoo_tpu.pipeline.api.keras.engine import (
     GraphFunction,
     InputLayer,
@@ -150,6 +151,7 @@ class KerasNet(_ContainerBase):
         )
         return est
 
+    @span("zoo.keras.fit", fit=True)
     def fit(self, x, y=None, batch_size=32, nb_epoch=10,
             validation_data=None, distributed=True, sample_weight=None,
             autotune=None, plan=None, elastic=None):

@@ -28,6 +28,7 @@ Also re-implemented with exact-state semantics instead of best-effort:
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import logging
 import os
@@ -58,7 +59,6 @@ from analytics_zoo_tpu.common.triggers import (
     TrainingState,
     ZooTrigger,
 )
-from analytics_zoo_tpu.common.utils import time_it
 from analytics_zoo_tpu.feature.dataset import FeatureSet
 from analytics_zoo_tpu.metrics import (
     StepMetrics,
@@ -193,29 +193,45 @@ class _DeviceFeeder:
     _END = object()
 
     def __init__(self, batches, shard_fn, depth: int = 2,
-                 heartbeat=None, on_exit=None):
+                 heartbeat=None, on_exit=None, metrics=None, context=None):
+        """``metrics``: the fit's :class:`StepMetrics`, whose two
+        ``feed_*`` histograms the thread observes.  ``context``: the
+        :class:`contextvars.Context` the thread runs under, by default a
+        copy of the caller's, so that the thread's ``zoo.feed.*`` spans
+        carry the caller's ``fit`` and open span as parent."""
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._err: BaseException | None = None
+        on_batch = metrics.feed_host_batch.observe if metrics else None
+        on_shard = metrics.feed_shard.observe if metrics else None
 
         def run():
             try:
-                for b in batches:
+                it = iter(batches)
+                while True:
+                    # a batch stream that dies mid-gather closes this
+                    # span on its way out, the exception's type in args
+                    with span("zoo.feed.batch", observe=on_batch):
+                        b = next(it, self._END)
+                    if b is self._END:
+                        break
                     if heartbeat is not None:
                         heartbeat()  # /healthz: the feeder is alive
-                    item = shard_fn(b)
-                    while not self._stop.is_set():
-                        try:
-                            self._q.put(item, timeout=0.1)
-                            break
-                        except queue.Full:
-                            # keep beating while blocked on a full
-                            # queue: waiting for the consumer (e.g.
-                            # through a multi-minute first-step compile)
-                            # is not being wedged
-                            if heartbeat is not None:
-                                heartbeat()
-                            continue
+                    with span("zoo.feed.shard", observe=on_shard):
+                        item = shard_fn(b)
+                    with span("zoo.feed.blocked"):
+                        while not self._stop.is_set():
+                            try:
+                                self._q.put(item, timeout=0.1)
+                                break
+                            except queue.Full:
+                                # keep beating while blocked on a full
+                                # queue: waiting for the consumer (e.g.
+                                # through a multi-minute first-step
+                                # compile) is not being wedged
+                                if heartbeat is not None:
+                                    heartbeat()
+                                continue
                     if self._stop.is_set():
                         return
             except BaseException as e:  # re-raised on the consumer side
@@ -239,8 +255,11 @@ class _DeviceFeeder:
                     except queue.Full:
                         continue
 
+        if context is None:
+            context = contextvars.copy_context()
         self._thread = threading.Thread(
-            target=run, daemon=True, name="zoo-infeed")
+            target=context.run, args=(run,), daemon=True,
+            name="zoo-infeed")
         self._thread.start()
 
     def __iter__(self):
@@ -1100,6 +1119,7 @@ class Estimator:
     # ------------------------------------------------------------------
     # train (InternalDistriOptimizer.train, Topology.scala:1076-1259)
     # ------------------------------------------------------------------
+    @span("zoo.fit", fit=True)
     def train(self, train_set: FeatureSet, batch_size: int = 32,
               nb_epoch: int | None = None,
               end_trigger: ZooTrigger | None = None,
@@ -1146,202 +1166,210 @@ class Estimator:
         new world size and resumes from LATEST through the
         partitioner's bit-exact resharding.  ``None`` (default) trains
         exactly as before.  See docs/elastic-training.md."""
-        ctx = self.ctx
-        dp = ctx.data_parallel_size
-        if batch_size % dp != 0:
-            # The TFDataset contract (tf_dataset.py:136-143): global batch
-            # must divide evenly across model replicas.
-            raise ValueError(
-                f"batch_size ({batch_size}) must be a multiple of the "
-                f"data-parallel size ({dp})"
-            )
-        if end_trigger is None:
-            # Keras semantics: each fit() call trains nb_epoch MORE epochs
-            # (relative to the in-process counter).  Checkpoint resume in a
-            # fresh process still continues to the absolute target, matching
-            # the reference's getFinishedEpoch continuation
-            # (Topology.scala:373-386).
-            end_trigger = MaxEpoch(
-                self.epoch - 1 + (nb_epoch if nb_epoch is not None else 10))
-        if checkpoint_trigger is None and self._ckpt is not None:
-            checkpoint_trigger = EveryEpoch()
-        if validation_set is not None and validation_trigger is None:
-            validation_trigger = EveryEpoch()
-        seed = ctx.seed if seed is None else seed
-        # Closed-loop autotuning (ZOO_AUTOTUNE / autotune=True): resolve
-        # the controller BEFORE the prefetch wrap so the pipeline starts
-        # at (and is resized from) the controller's state.  autotune
-        # unset/off ⇒ controller is None and every path below is the
-        # static-knob code, no new threads (the disabled-mode contract).
-        controller, own_controller, attached_set = None, False, None
-        auto = autotune if autotune is not None else ctx.config.autotune
-        if auto:
-            from analytics_zoo_tpu.feature.autotune import (
-                AutotuneController,
-            )
-            if isinstance(auto, AutotuneController):
-                controller = auto
-            else:
-                controller = AutotuneController.from_config(ctx.config)
-                own_controller = True
-        if ctx.config.prefetch_workers or controller is not None:
-            # Parallel host data plane (ZOO_PREFETCH_WORKERS): shard
-            # loading, host transforms and batch assembly move onto pool
-            # threads with ordered delivery, composing with the
-            # double-buffered device infeed below — the feeder consumes
-            # the prefetched stream instead of the serial generator, and
-            # the stream itself is byte-identical (resume included).
-            # Under autotune with prefetch off, start from the worst
-            # case (workers=1, depth=1) and let the controller grow it —
-            # but only when the set HAS host work to hide
-            # (worth_prefetching); a resident no-transform array set
-            # would pay queue handoffs for nothing, and an explicit
-            # ZOO_PREFETCH_WORKERS always wins over that heuristic.
-            from analytics_zoo_tpu.feature.prefetch import (
-                PrefetchFeatureSet,
-                worth_prefetching,
-            )
-            if isinstance(train_set, PrefetchFeatureSet):
-                if controller is not None \
-                        and train_set._controller is None:
-                    # attach for THIS fit only — detached in the finally
-                    # below, so a later train(autotune=False) on the same
-                    # FeatureSet cannot resurrect this fit's controller
-                    train_set._controller = controller
-                    attached_set = train_set
-            elif ctx.config.prefetch_workers or \
-                    worth_prefetching(train_set):
-                train_set = PrefetchFeatureSet(
-                    train_set,
-                    depth=(ctx.config.prefetch_depth
-                           if ctx.config.prefetch_workers else 1),
-                    workers=ctx.config.prefetch_workers or 1,
-                    controller=controller)
-
-        # Unified partitioner: resolve the plan ONCE per fit; placement,
-        # in-graph constraints, the batch sharding and the checkpoint's
-        # spec record all derive from it.  Params are built FIRST: a
-        # plan="auto" resolution needs their byte sizes to predict each
-        # candidate's per-chip footprint.
-        params, state = self.model.build_params()
-        plan = self._resolved_plan(plan, params=params)
-        # Keras continuation semantics: a second fit() on the same estimator
-        # keeps optimizer moments and the LR-schedule step count (they live
-        # in opt_state), not just the weights.
-        opt_state = (self._opt_state if self._opt_state is not None
-                     else self.optimizer.init(params))
-        repl = ctx.replicated()
-        state = jax.device_put(state, repl)
-        params = self._place_params(params, plan)
-        opt_state = self._place_opt_state(opt_state, plan)
-        # Close the MEMORY loop (zoo_mem_* family): measured per-chip
-        # param+opt bytes under this plan vs predict_chip_bytes, the
-        # way zoo_oracle rel_error closes steps/sec predictions.
-        self._publish_mem_gauges(plan, params, opt_state)
-        # Checkpoint spec record: the plan's clamped spec trees ride
-        # every snapshot, so a resume (any mesh size, any process) can
-        # see what layout the state was trained under and reshard
-        # through the partitioner — not a strategy-specific heuristic.
-        from analytics_zoo_tpu.parallel.plan import serialize_specs
-        # report_unused: the once-per-fit audit point — a typo'd rule
-        # that matched zero params surfaces as ONE warning here
-        param_specs, _ = plan.param_specs(params, ctx.mesh,
-                                          report_unused=True)
-        self._plan_record = {
-            "name": plan.name,
-            "mesh": dict(ctx.mesh.shape),
-            "param_specs": serialize_specs(param_specs),
-            "opt_specs": serialize_specs(
-                plan.opt_specs(opt_state, ctx.mesh)),
-            # precision contract ("" = no dtype rules): a resume under a
-            # DIFFERENT policy fails loudly below instead of silently
-            # mixing master widths
-            "dtype_policy": plan.dtype_policy_str(),
-        }
-        if self._auto_plan_record is not None:
-            # plan="auto": keep the oracle's per-candidate predictions
-            # next to the layout the fit actually ran under
-            self._plan_record["auto"] = self._auto_plan_record
-        dev_tf = getattr(train_set, "device_transform", None)
-        # Fused multi-step dispatch (ZOO_STEPS_PER_DISPATCH): K>1 runs K
-        # inner steps per jitted dispatch; the K=1 step is always built
-        # too — it serves partial tail chunks.  (K >= 1 is enforced by
-        # ZooConfig.__post_init__ — no silent clamping here.)
-        k = int(ctx.config.steps_per_dispatch or 1)
-        step_fn = self._train_step_for(dev_tf, 1, plan)
-        fused_fn = self._train_step_for(dev_tf, k, plan) if k > 1 else None
-        if controller is not None:
-            # name the K=1 program for the controller's oracle prior:
-            # its compile (first dispatch) caches the HLO features the
-            # predicted-K jump reads
-            tag = "" if plan.name == "dp" else f"_{plan.name}"
-            controller.set_feature_label(f"train_step{tag}")
-        # Persistent compile plane (ZOO_COMPILE_CACHE): enable before the
-        # first trace so this fit's compiles populate / hit the cache.
-        from analytics_zoo_tpu.common.compile_cache import (
-            maybe_enable_persistent_cache,
-        )
-        maybe_enable_persistent_cache(ctx.config.compile_cache)
-
-        start_epoch, start_batch = self.epoch, 0
-        # resume from checkpoint if present (Topology.scala:1220-1242)
-        resumed = self._ckpt.latest() if self._ckpt else None
-        if resumed is not None:
-            # Elastic resume through the partitioner: the checkpoint
-            # stores GLOBAL logical arrays, so resharding onto THIS
-            # mesh/plan (saved {data:8}, resuming {data:4}; saved fsdp,
-            # resuming dp; ...) is exactly the plan's placement
-            # device_put — no layout surgery.
-            saved_plan = resumed.get("plan")
-            saved_policy = (saved_plan or {}).get("dtype_policy")
-            if saved_policy is not None \
-                    and saved_policy != plan.dtype_policy_str():
-                # Precision contract guard: f32 masters saved under one
-                # policy must not be silently re-interpreted under
-                # another (pre-precision-plane checkpoints carry no
-                # policy key and skip the check).  ZOO_DTYPE_RESUME=cast
-                # opts into a DELIBERATE cast-on-resume.
-                if os.environ.get("ZOO_DTYPE_RESUME", "").strip().lower() \
-                        in ("cast", "force"):
-                    logger.warning(
-                        "resuming checkpoint trained under dtype policy "
-                        "%r into plan %r with policy %r "
-                        "(ZOO_DTYPE_RESUME): casting on resume",
-                        saved_policy, plan.name, plan.dtype_policy_str())
+        with span("zoo.fit.enter"):
+            ctx = self.ctx
+            dp = ctx.data_parallel_size
+            if batch_size % dp != 0:
+                # The TFDataset contract (tf_dataset.py:136-143): global batch
+                # must divide evenly across model replicas.
+                raise ValueError(
+                    f"batch_size ({batch_size}) must be a multiple of the "
+                    f"data-parallel size ({dp})"
+                )
+            if end_trigger is None:
+                # Keras semantics: each fit() call trains nb_epoch MORE epochs
+                # (relative to the in-process counter).  Checkpoint resume in a
+                # fresh process still continues to the absolute target, matching
+                # the reference's getFinishedEpoch continuation
+                # (Topology.scala:373-386).
+                end_trigger = MaxEpoch(
+                    self.epoch - 1 + (nb_epoch if nb_epoch is not None else 10))
+            if checkpoint_trigger is None and self._ckpt is not None:
+                checkpoint_trigger = EveryEpoch()
+            if validation_set is not None and validation_trigger is None:
+                validation_trigger = EveryEpoch()
+            seed = ctx.seed if seed is None else seed
+            # Closed-loop autotuning (ZOO_AUTOTUNE / autotune=True): resolve
+            # the controller BEFORE the prefetch wrap so the pipeline starts
+            # at (and is resized from) the controller's state.  autotune
+            # unset/off ⇒ controller is None and every path below is the
+            # static-knob code, no new threads (the disabled-mode contract).
+            controller, own_controller, attached_set = None, False, None
+            auto = autotune if autotune is not None else ctx.config.autotune
+            if auto:
+                from analytics_zoo_tpu.feature.autotune import (
+                    AutotuneController,
+                )
+                if isinstance(auto, AutotuneController):
+                    controller = auto
                 else:
-                    raise ValueError(
-                        f"checkpoint was trained under dtype policy "
-                        f"{saved_policy!r} but this fit's plan "
-                        f"{plan.name!r} declares "
-                        f"{plan.dtype_policy_str()!r}; resume with a "
-                        f"matching plan (mixed_precision(), "
-                        f"ZOO_DTYPE_POLICY) or set ZOO_DTYPE_RESUME=cast "
-                        f"to cast deliberately")
-            if saved_plan and (saved_plan.get("name") != plan.name
-                               or saved_plan.get("mesh")
-                               != dict(ctx.mesh.shape)):
-                logger.info(
-                    "resharding checkpoint (saved plan=%s mesh=%s) into "
-                    "plan=%s mesh=%s through the partitioner",
-                    saved_plan.get("name"), saved_plan.get("mesh"),
-                    plan.name, dict(ctx.mesh.shape))
-            params = self._place_params(resumed["params"], plan)
-            opt_state = jax.tree_util.tree_unflatten(
-                jax.tree_util.tree_structure(opt_state),
-                [jnp.asarray(x) for x in resumed["opt_flat"]],
-            )
-            opt_state = self._place_opt_state(opt_state, plan)
-            state = jax.device_put(resumed["state"], repl)
-            self.global_step = int(resumed["global_step"])
-            start_epoch = int(resumed["epoch"])
-            start_batch = int(resumed["next_batch"])
-            seed = int(resumed["seed"])
-            logger.info("resumed from checkpoint @ step %d (epoch %d.%d)",
-                        self.global_step, start_epoch, start_batch)
+                    controller = AutotuneController.from_config(ctx.config)
+                    own_controller = True
+            if ctx.config.prefetch_workers or controller is not None:
+                # Parallel host data plane (ZOO_PREFETCH_WORKERS): shard
+                # loading, host transforms and batch assembly move onto pool
+                # threads with ordered delivery, composing with the
+                # double-buffered device infeed below — the feeder consumes
+                # the prefetched stream instead of the serial generator, and
+                # the stream itself is byte-identical (resume included).
+                # Under autotune with prefetch off, start from the worst
+                # case (workers=1, depth=1) and let the controller grow it —
+                # but only when the set HAS host work to hide
+                # (worth_prefetching); a resident no-transform array set
+                # would pay queue handoffs for nothing, and an explicit
+                # ZOO_PREFETCH_WORKERS always wins over that heuristic.
+                from analytics_zoo_tpu.feature.prefetch import (
+                    PrefetchFeatureSet,
+                    worth_prefetching,
+                )
+                if isinstance(train_set, PrefetchFeatureSet):
+                    if controller is not None \
+                            and train_set._controller is None:
+                        # attach for THIS fit only — detached in the finally
+                        # below, so a later train(autotune=False) on the same
+                        # FeatureSet cannot resurrect this fit's controller
+                        train_set._controller = controller
+                        attached_set = train_set
+                elif ctx.config.prefetch_workers or \
+                        worth_prefetching(train_set):
+                    train_set = PrefetchFeatureSet(
+                        train_set,
+                        depth=(ctx.config.prefetch_depth
+                               if ctx.config.prefetch_workers else 1),
+                        workers=ctx.config.prefetch_workers or 1,
+                        controller=controller)
 
-        # ZooConfig env tier: ZOO_FAILURE_RETRY_TIMES (reference
-        # ``bigdl.failure.retryTimes`` sysprop, Topology.scala:1172)
-        retry_times = self.ctx.config.failure_retry_times
+            # Unified partitioner: resolve the plan ONCE per fit; placement,
+            # in-graph constraints, the batch sharding and the checkpoint's
+            # spec record all derive from it.  Params are built FIRST: a
+            # plan="auto" resolution needs their byte sizes to predict each
+            # candidate's per-chip footprint.
+            with span("zoo.fit.enter.build"):
+                params, state = self.model.build_params()
+                plan = self._resolved_plan(plan, params=params)
+                # Keras continuation semantics: a second fit() on the same estimator
+                # keeps optimizer moments and the LR-schedule step count (they live
+                # in opt_state), not just the weights.
+                opt_state = (self._opt_state if self._opt_state is not None
+                             else self.optimizer.init(params))
+            repl = ctx.replicated()
+            with span("zoo.fit.enter.place"):
+                state = jax.device_put(state, repl)
+                params = self._place_params(params, plan)
+                opt_state = self._place_opt_state(opt_state, plan)
+            # Close the MEMORY loop (zoo_mem_* family): measured per-chip
+            # param+opt bytes under this plan vs predict_chip_bytes, the
+            # way zoo_oracle rel_error closes steps/sec predictions.
+            with span("zoo.fit.enter.mem_gauges"):
+                self._publish_mem_gauges(plan, params, opt_state)
+            # Checkpoint spec record: the plan's clamped spec trees ride
+            # every snapshot, so a resume (any mesh size, any process) can
+            # see what layout the state was trained under and reshard
+            # through the partitioner — not a strategy-specific heuristic.
+            with span("zoo.fit.enter.spec_record"):
+                from analytics_zoo_tpu.parallel.plan import serialize_specs
+                # report_unused: the once-per-fit audit point — a typo'd rule
+                # that matched zero params surfaces as ONE warning here
+                param_specs, _ = plan.param_specs(params, ctx.mesh,
+                                                  report_unused=True)
+                self._plan_record = {
+                    "name": plan.name,
+                    "mesh": dict(ctx.mesh.shape),
+                    "param_specs": serialize_specs(param_specs),
+                    "opt_specs": serialize_specs(
+                        plan.opt_specs(opt_state, ctx.mesh)),
+                    # precision contract ("" = no dtype rules): a resume under a
+                    # DIFFERENT policy fails loudly below instead of silently
+                    # mixing master widths
+                    "dtype_policy": plan.dtype_policy_str(),
+                }
+                if self._auto_plan_record is not None:
+                    # plan="auto": keep the oracle's per-candidate predictions
+                    # next to the layout the fit actually ran under
+                    self._plan_record["auto"] = self._auto_plan_record
+            dev_tf = getattr(train_set, "device_transform", None)
+            # Fused multi-step dispatch (ZOO_STEPS_PER_DISPATCH): K>1 runs K
+            # inner steps per jitted dispatch; the K=1 step is always built
+            # too — it serves partial tail chunks.  (K >= 1 is enforced by
+            # ZooConfig.__post_init__ — no silent clamping here.)
+            k = int(ctx.config.steps_per_dispatch or 1)
+            with span("zoo.fit.enter.step_lookup"):
+                step_fn = self._train_step_for(dev_tf, 1, plan)
+                fused_fn = self._train_step_for(dev_tf, k, plan) \
+                    if k > 1 else None
+            if controller is not None:
+                # name the K=1 program for the controller's oracle prior:
+                # its compile (first dispatch) caches the HLO features the
+                # predicted-K jump reads
+                tag = "" if plan.name == "dp" else f"_{plan.name}"
+                controller.set_feature_label(f"train_step{tag}")
+            # Persistent compile plane (ZOO_COMPILE_CACHE): enable before the
+            # first trace so this fit's compiles populate / hit the cache.
+            from analytics_zoo_tpu.common.compile_cache import (
+                maybe_enable_persistent_cache,
+            )
+            maybe_enable_persistent_cache(ctx.config.compile_cache)
+
+            start_epoch, start_batch = self.epoch, 0
+            # resume from checkpoint if present (Topology.scala:1220-1242)
+            with span("zoo.fit.enter.resume"):
+                resumed = self._ckpt.latest() if self._ckpt else None
+                if resumed is not None:
+                    # Elastic resume through the partitioner: the checkpoint
+                    # stores GLOBAL logical arrays, so resharding onto THIS
+                    # mesh/plan (saved {data:8}, resuming {data:4}; saved fsdp,
+                    # resuming dp; ...) is exactly the plan's placement
+                    # device_put — no layout surgery.
+                    saved_plan = resumed.get("plan")
+                    saved_policy = (saved_plan or {}).get("dtype_policy")
+                    if saved_policy is not None \
+                            and saved_policy != plan.dtype_policy_str():
+                        # Precision contract guard: f32 masters saved under one
+                        # policy must not be silently re-interpreted under
+                        # another (pre-precision-plane checkpoints carry no
+                        # policy key and skip the check).  ZOO_DTYPE_RESUME=cast
+                        # opts into a DELIBERATE cast-on-resume.
+                        if os.environ.get("ZOO_DTYPE_RESUME", "").strip().lower() \
+                                in ("cast", "force"):
+                            logger.warning(
+                                "resuming checkpoint trained under dtype policy "
+                                "%r into plan %r with policy %r "
+                                "(ZOO_DTYPE_RESUME): casting on resume",
+                                saved_policy, plan.name, plan.dtype_policy_str())
+                        else:
+                            raise ValueError(
+                                f"checkpoint was trained under dtype policy "
+                                f"{saved_policy!r} but this fit's plan "
+                                f"{plan.name!r} declares "
+                                f"{plan.dtype_policy_str()!r}; resume with a "
+                                f"matching plan (mixed_precision(), "
+                                f"ZOO_DTYPE_POLICY) or set ZOO_DTYPE_RESUME=cast "
+                                f"to cast deliberately")
+                    if saved_plan and (saved_plan.get("name") != plan.name
+                                       or saved_plan.get("mesh")
+                                       != dict(ctx.mesh.shape)):
+                        logger.info(
+                            "resharding checkpoint (saved plan=%s mesh=%s) into "
+                            "plan=%s mesh=%s through the partitioner",
+                            saved_plan.get("name"), saved_plan.get("mesh"),
+                            plan.name, dict(ctx.mesh.shape))
+                    params = self._place_params(resumed["params"], plan)
+                    opt_state = jax.tree_util.tree_unflatten(
+                        jax.tree_util.tree_structure(opt_state),
+                        [jnp.asarray(x) for x in resumed["opt_flat"]],
+                    )
+                    opt_state = self._place_opt_state(opt_state, plan)
+                    state = jax.device_put(resumed["state"], repl)
+                    self.global_step = int(resumed["global_step"])
+                    start_epoch = int(resumed["epoch"])
+                    start_batch = int(resumed["next_batch"])
+                    seed = int(resumed["seed"])
+                    logger.info("resumed from checkpoint @ step %d (epoch %d.%d)",
+                                self.global_step, start_epoch, start_batch)
+
+            # ZooConfig env tier: ZOO_FAILURE_RETRY_TIMES (reference
+            # ``bigdl.failure.retryTimes`` sysprop, Topology.scala:1172)
+            retry_times = self.ctx.config.failure_retry_times
         try:
             params, opt_state, state = self._train_with_retries(
                 params, opt_state, state, step_fn, fused_fn, k, dev_tf,
@@ -1358,15 +1386,16 @@ class Estimator:
                 # provided controller keeps running (shared across fits)
                 controller.stop()
 
-        self.model.params = params
-        self.model.state = state
-        self._opt_state = opt_state
-        if self._ckpt is not None:
-            # Flush the in-flight async save before returning: the process
-            # may exit right after fit(), and a NEW estimator on the same
-            # dir must see the final snapshot (not a half-written .tmp).
-            # Also surfaces any deferred write error.
-            self._ckpt._wait()
+        with span("zoo.fit.exit"):
+            self.model.params = params
+            self.model.state = state
+            self._opt_state = opt_state
+            if self._ckpt is not None:
+                # Flush the in-flight async save before returning: the process
+                # may exit right after fit(), and a NEW estimator on the same
+                # dir must see the final snapshot (not a half-written .tmp).
+                # Also surfaces any deferred write error.
+                self._ckpt._wait()
         return self
 
     def _train_with_retries(self, params, opt_state, state, step_fn,
@@ -1467,230 +1496,246 @@ class Estimator:
         # would 503 a healthy process through every warmup.
         health.register("train_loop", stale_after=600.0)
         while not end_trigger(tstate):
-            epoch_t0 = time.perf_counter()
-            n_records = 0
-            batch_iter = train_set.batches(
-                batch_size, shuffle=True, seed=seed, epoch=epoch,
-                drop_last=True, start_batch=start_batch,
-                process_shard=_process_shard(),
-            )
-            loss_dev = None
-            bi = start_batch
-            # 60s budget: the feeder beats per batch AND while blocked
-            # on a full queue, so only a truly stalled input pipeline
-            # (the tf.data failure mode) exceeds it.  The feeder THREAD
-            # unregisters the component when it exits (on_exit), so the
-            # main thread never races a late beat.
-            health.register("infeed", stale_after=60.0)
-            # batch placement comes from the PLAN (its batch_axes — the
-            # data axis for every canned plan; ("dcn", "data") under a
-            # hybrid-mesh plan), not a hard-wired DATA_AXIS
-            baxes = plan.batch_axes
-            shard_single = partial(ctx.shard_batch, axes=baxes)
-            chunked = k > 1 or controller is not None
-            if chunked:
-                # Fused dispatch: the feeder consumes the CHUNKED stream.
-                # Full chunks are stacked into a [K, batch, ...]
-                # super-batch ON THE FEEDER THREAD (host work overlapping
-                # device compute, like every other shard_fn cost) and
-                # sharded with axis 1 on the data axis, so each inner
-                # scan step sees the same per-chip shards as K=1.
-                def shard_item(item, _stack=partial(
-                        ctx.shard_batch_stacked, axes=baxes),
-                               _single=shard_single):
-                    kind, payload = item
-                    if kind == "scan":
-                        stacked = jax.tree_util.tree_map(
-                            lambda *xs: np.stack(xs), *payload)
-                        return ("scan", _stack(stacked), len(payload))
-                    return ("single", _single(payload), 1)
-
-                # Autotune: chunk sizes follow the controller's K
-                # hill-climb, re-read at every chunk boundary; the batch
-                # sequence (and so the trajectory) is unchanged.
-                feed_src = (_chunk_batches_dynamic(
-                    batch_iter, controller.current_k)
-                    if controller is not None
-                    else _chunk_batches(batch_iter, k))
-                shard_fn = shard_item
-            else:
-                feed_src, shard_fn = batch_iter, shard_single
-            feeder = _DeviceFeeder(
-                feed_src, shard_fn, depth=cfg.infeed_depth,
-                heartbeat=lambda: health.heartbeat("infeed"),
-                on_exit=lambda: health.unregister("infeed"))
-            prof_active = False
-            try:
-                feeder_iter = iter(feeder)
-                while True:
-                    t_iter0 = time.perf_counter()
-                    with time_it("zoo.infeed"):
-                        sharded = next(feeder_iter, _SENTINEL)
-                    t_data = time.perf_counter()
-                    if sharded is _SENTINEL:
-                        break
-                    if prof_at is not None and not prof_active \
-                            and not self._profiled \
-                            and self.global_step >= prof_at:
-                        jax.profiler.start_trace(prof_dir)
-                        prof_active = True
-                        prof_at = self.global_step  # anchor the stop check
-                    # span covers HOST-side dispatch only (the jitted
-                    # step is async; device time shows in the
-                    # jax.profiler capture, not here) — named to match
-                    # zoo_train_step_dispatch_seconds
-                    losses = None
-                    # zoolint: disable=host-sync -- host int boxing of the step index, not a device fetch
-                    step_arr = np.asarray(self.global_step, np.int32)
-                    with time_it("zoo.step_dispatch"), \
-                            span("zoo.train.step_dispatch"):
-                        if chunked:
-                            kind, payload, nk = sharded
-                            if kind == "scan":
-                                # ONE dispatch advances nk inner steps;
-                                # losses come back as a [nk] device
-                                # array.  Under autotune nk follows the
-                                # hill-climb, so the fused program is
-                                # looked up per-chunk (a dict hit after
-                                # each K's first compile).
-                                fn = fused_fn if controller is None \
-                                    else self._train_step_for(
-                                        dev_tf, nk, plan)
-                                params, opt_state, state, losses = \
-                                    fn(
-                                        params, opt_state, state,
-                                        seed_arr, step_arr, payload)
-                                loss_dev = losses[nk - 1]
-                            else:  # partial tail chunk: K=1 fallback
-                                params, opt_state, state, loss_dev = \
-                                    step_fn(
-                                        params, opt_state, state,
-                                        seed_arr, step_arr, payload)
-                        else:
-                            nk = 1
-                            params, opt_state, state, loss_dev = step_fn(
-                                params, opt_state, state, seed_arr,
-                                step_arr, sharded
-                            )
-                    t_disp = time.perf_counter()
-                    self.global_step += nk
-                    if prof_active and self.global_step >= \
-                            prof_at + cfg.profile_steps:
-                        # zoolint: disable=host-sync -- intentional: the trace must close on a completed step
-                        jax.block_until_ready(loss_dev)
-                        jax.profiler.stop_trace()
-                        prof_active = False
-                        self._profiled = True
-                        logger.info("profiler trace written to %s", prof_dir)
-                    bi += nk
-                    n_records += batch_size * nk
-                    tstate.iteration = self.global_step
-                    tstate.epoch_finished = False
-                    if losses is not None and self._writers:
-                        # TB gets every inner step's loss, not just the
-                        # boundary one: ONE device slice for the first
-                        # nk-1 (the flush expands it; the last loss is
-                        # buffered as a scalar by _on_iteration) —
-                        # per-element indexing here would reintroduce
-                        # nk host dispatches per fused step
-                        base = self.global_step - nk
-                        if nk > 1:
-                            self._loss_buffer.append(
-                                (base + 1, losses[: nk - 1]))
-                    # Callbacks/triggers fire ONCE per dispatch, at the
-                    # K-step boundary (docs/performance.md caveat):
-                    # checkpoints, validation and loss flushes see
-                    # iteration counts in strides of nk.
-                    fired = self._on_iteration(
-                        tstate, loss_dev, params, opt_state, state,
-                        checkpoint_trigger, validation_set,
-                        validation_trigger, epoch, bi, seed, batch_size,
+            with span("zoo.train.epoch", args={"epoch": epoch}):
+                epoch_t0 = time.perf_counter()
+                n_records = 0
+                # the feeder thread inherits the epoch's span as parent (and
+                # the call's fit id), not the feeder_start span it outlives
+                feed_ctx = contextvars.copy_context()
+                with span("zoo.train.feeder_start"):
+                    batch_iter = train_set.batches(
+                        batch_size, shuffle=True, seed=seed, epoch=epoch,
+                        drop_last=True, start_batch=start_batch,
+                        process_shard=_process_shard(),
                     )
-                    params, opt_state, state = fired
-                    # step-time breakdown: data-wait (infeed the feeder
-                    # failed to hide) / dispatch / full iteration
-                    step_s = time.perf_counter() - t_iter0
-                    step_metrics.record_step(
-                        t_data - t_iter0, t_disp - t_data,
-                        step_s, batch_size * nk, steps=nk)
-                    if controller is not None:
-                        # one measured dispatch feeds the K hill-climb
-                        # (full loop-iteration wall time — the quantity
-                        # fusion amortizes)
-                        controller.observe_dispatch(nk, step_s)
-                    health.heartbeat("train_loop")
-                    # flight recorder: one structured record per step
-                    # (bounded ring — a postmortem shows the FINAL
-                    # steps), stragglers flagged against rolling p50
-                    flight.record(
-                        "step", loop="train", step=self.global_step,
-                        epoch=epoch, data_wait_s=round(t_data - t_iter0, 6),
-                        dispatch_s=round(t_disp - t_data, 6),
-                        step_s=round(step_s, 6),
-                        **({"fused_steps": nk} if nk > 1 else {}))
-                    # straggler detection on PER-STEP time: a K-step
-                    # fused dispatch is ~K x a tail single dispatch by
-                    # construction, so comparing raw dispatch times
-                    # against one rolling p50 would flag every fused
-                    # dispatch in epochs that end with a tail
-                    if straggler.observe(step_s / nk):
-                        step_metrics.stragglers.inc()
-                        flight.record(
-                            "straggler", loop="train",
-                            step=self.global_step,
-                            step_s=round(step_s, 6),
-                            per_step_s=round(step_s / nk, 6),
-                            rolling_p50_s=round(
-                                straggler.rolling_p50(), 6))
-                    if elastic is not None:
-                        # The STEP BARRIER (ISSUE 16): the membership
-                        # ledger's (generation, world, members) doc is
-                        # the single source of truth, read once per
-                        # dispatch; a generation change snapshots at
-                        # this exact boundary and yields the fit.
-                        newdoc = elastic.poll()
-                        if newdoc is not None:
-                            self._elastic_yield(
-                                newdoc, params, opt_state, state,
-                                tstate, epoch, bi, seed, flight)
-            finally:
-                feeder.stop()
-                if prof_active:
-                    # epoch ended (or failed) mid-capture: close the trace
-                    jax.profiler.stop_trace()
-                    self._profiled = True
-                    prof_at = None
-            # epoch boundary (the only unconditional host sync per epoch)
-            dt = time.perf_counter() - epoch_t0
-            if loss_dev is not None:
-                # zoolint: disable=host-sync -- deliberate once-per-epoch sync (the comment above is the contract)
-                tstate.loss = float(loss_dev)
-            self._flush_loss_buffer()
-            throughput = n_records / max(dt, 1e-9)
-            logger.info(
-                "epoch %d done: loss=%.4f, %.1f records/s, step=%d",
-                epoch, tstate.loss if tstate.loss is not None else float("nan"),
-                throughput, self.global_step,
-            )
-            self.history.append(
-                {"epoch": epoch, "loss": tstate.loss,
-                 "throughput": throughput}
-            )
-            if self._writers:
-                self._writers[0].add_scalar(
-                    "Throughput", throughput, self.global_step
-                )
-            step_metrics.record_epoch(epoch, throughput)
-            record_device_memory()  # HBM gauges (no-op on CPU backends)
-            tstate.epoch_finished = True
-            epoch += 1
-            tstate.epoch = epoch
-            start_batch = 0
-            params, opt_state, state = self._on_iteration(
-                tstate, loss_dev, params, opt_state, state,
-                checkpoint_trigger, validation_set, validation_trigger,
-                epoch, 0, seed, batch_size,
-            )
+                    loss_dev = None
+                    bi = start_batch
+                    # 60s budget: the feeder beats per batch AND while blocked
+                    # on a full queue, so only a truly stalled input pipeline
+                    # (the tf.data failure mode) exceeds it.  The feeder THREAD
+                    # unregisters the component when it exits (on_exit), so the
+                    # main thread never races a late beat.
+                    health.register("infeed", stale_after=60.0)
+                    # batch placement comes from the PLAN (its batch_axes — the
+                    # data axis for every canned plan; ("dcn", "data") under a
+                    # hybrid-mesh plan), not a hard-wired DATA_AXIS
+                    baxes = plan.batch_axes
+                    shard_single = partial(ctx.shard_batch, axes=baxes)
+                    chunked = k > 1 or controller is not None
+                    if chunked:
+                        # Fused dispatch: the feeder consumes the CHUNKED stream.
+                        # Full chunks are stacked into a [K, batch, ...]
+                        # super-batch ON THE FEEDER THREAD (host work overlapping
+                        # device compute, like every other shard_fn cost) and
+                        # sharded with axis 1 on the data axis, so each inner
+                        # scan step sees the same per-chip shards as K=1.
+                        def shard_item(item, _stack=partial(
+                                ctx.shard_batch_stacked, axes=baxes),
+                                       _single=shard_single):
+                            kind, payload = item
+                            if kind == "scan":
+                                stacked = jax.tree_util.tree_map(
+                                    lambda *xs: np.stack(xs), *payload)
+                                return ("scan", _stack(stacked), len(payload))
+                            return ("single", _single(payload), 1)
+
+                        # Autotune: chunk sizes follow the controller's K
+                        # hill-climb, re-read at every chunk boundary; the batch
+                        # sequence (and so the trajectory) is unchanged.
+                        feed_src = (_chunk_batches_dynamic(
+                            batch_iter, controller.current_k)
+                            if controller is not None
+                            else _chunk_batches(batch_iter, k))
+                        shard_fn = shard_item
+                    else:
+                        feed_src, shard_fn = batch_iter, shard_single
+                    feeder = _DeviceFeeder(
+                        feed_src, shard_fn, depth=cfg.infeed_depth,
+                        heartbeat=lambda: health.heartbeat("infeed"),
+                        on_exit=lambda: health.unregister("infeed"),
+                        metrics=step_metrics, context=feed_ctx)
+                prof_active = False
+                try:
+                    feeder_iter = iter(feeder)
+                    first = {"first": True}
+                    while True:
+                        t_iter0 = time.perf_counter()
+                        with span("zoo.train.data_wait", args=first):
+                            sharded = next(feeder_iter, _SENTINEL)
+                        first = None
+                        t_data = time.perf_counter()
+                        if sharded is _SENTINEL:
+                            break
+                        if prof_at is not None and not prof_active \
+                                and not self._profiled \
+                                and self.global_step >= prof_at:
+                            jax.profiler.start_trace(prof_dir)
+                            prof_active = True
+                            prof_at = self.global_step  # anchor the stop check
+                        # span covers HOST-side dispatch only (the jitted
+                        # step is async; device time shows in the
+                        # jax.profiler capture, not here) — named to match
+                        # zoo_train_step_dispatch_seconds
+                        losses = None
+                        # zoolint: disable=host-sync -- host int boxing of the step index, not a device fetch
+                        step_arr = np.asarray(self.global_step, np.int32)
+                        with span("zoo.train.step_dispatch",
+                                  args={"step": self.global_step}):
+                            if chunked:
+                                kind, payload, nk = sharded
+                                if kind == "scan":
+                                    # ONE dispatch advances nk inner steps;
+                                    # losses come back as a [nk] device
+                                    # array.  Under autotune nk follows the
+                                    # hill-climb, so the fused program is
+                                    # looked up per-chunk (a dict hit after
+                                    # each K's first compile).
+                                    fn = fused_fn if controller is None \
+                                        else self._train_step_for(
+                                            dev_tf, nk, plan)
+                                    params, opt_state, state, losses = \
+                                        fn(
+                                            params, opt_state, state,
+                                            seed_arr, step_arr, payload)
+                                    loss_dev = losses[nk - 1]
+                                else:  # partial tail chunk: K=1 fallback
+                                    params, opt_state, state, loss_dev = \
+                                        step_fn(
+                                            params, opt_state, state,
+                                            seed_arr, step_arr, payload)
+                            else:
+                                nk = 1
+                                params, opt_state, state, loss_dev = step_fn(
+                                    params, opt_state, state, seed_arr,
+                                    step_arr, sharded
+                                )
+                        t_disp = time.perf_counter()
+                        with span("zoo.train.on_iteration"):
+                            self.global_step += nk
+                            if prof_active and self.global_step >= \
+                                    prof_at + cfg.profile_steps:
+                                # zoolint: disable=host-sync -- intentional: the trace must close on a completed step
+                                jax.block_until_ready(loss_dev)
+                                jax.profiler.stop_trace()
+                                prof_active = False
+                                self._profiled = True
+                                logger.info("profiler trace written to %s", prof_dir)
+                            bi += nk
+                            n_records += batch_size * nk
+                            tstate.iteration = self.global_step
+                            tstate.epoch_finished = False
+                            if losses is not None and self._writers:
+                                # TB gets every inner step's loss, not just the
+                                # boundary one: ONE device slice for the first
+                                # nk-1 (the flush expands it; the last loss is
+                                # buffered as a scalar by _on_iteration) —
+                                # per-element indexing here would reintroduce
+                                # nk host dispatches per fused step
+                                base = self.global_step - nk
+                                if nk > 1:
+                                    self._loss_buffer.append(
+                                        (base + 1, losses[: nk - 1]))
+                            # Callbacks/triggers fire ONCE per dispatch, at the
+                            # K-step boundary (docs/performance.md caveat):
+                            # checkpoints, validation and loss flushes see
+                            # iteration counts in strides of nk.
+                            fired = self._on_iteration(
+                                tstate, loss_dev, params, opt_state, state,
+                                checkpoint_trigger, validation_set,
+                                validation_trigger, epoch, bi, seed, batch_size,
+                            )
+                            params, opt_state, state = fired
+                            # step-time breakdown: data-wait (infeed the feeder
+                            # failed to hide) / dispatch / full iteration
+                            step_s = time.perf_counter() - t_iter0
+                            step_metrics.record_step(
+                                t_data - t_iter0, t_disp - t_data,
+                                step_s, batch_size * nk, steps=nk)
+                            if controller is not None:
+                                # one measured dispatch feeds the K hill-climb
+                                # (full loop-iteration wall time — the quantity
+                                # fusion amortizes)
+                                controller.observe_dispatch(nk, step_s)
+                            health.heartbeat("train_loop")
+                            # flight recorder: one structured record per step
+                            # (bounded ring — a postmortem shows the FINAL
+                            # steps), stragglers flagged against rolling p50
+                            flight.record(
+                                "step", loop="train", step=self.global_step,
+                                epoch=epoch, data_wait_s=round(t_data - t_iter0, 6),
+                                dispatch_s=round(t_disp - t_data, 6),
+                                step_s=round(step_s, 6),
+                                **({"fused_steps": nk} if nk > 1 else {}))
+                            # straggler detection on PER-STEP time: a K-step
+                            # fused dispatch is ~K x a tail single dispatch by
+                            # construction, so comparing raw dispatch times
+                            # against one rolling p50 would flag every fused
+                            # dispatch in epochs that end with a tail
+                            if straggler.observe(step_s / nk):
+                                step_metrics.stragglers.inc()
+                                flight.record(
+                                    "straggler", loop="train",
+                                    step=self.global_step,
+                                    step_s=round(step_s, 6),
+                                    per_step_s=round(step_s / nk, 6),
+                                    rolling_p50_s=round(
+                                        straggler.rolling_p50(), 6))
+                            if elastic is not None:
+                                # The STEP BARRIER (ISSUE 16): the membership
+                                # ledger's (generation, world, members) doc is
+                                # the single source of truth, read once per
+                                # dispatch; a generation change snapshots at
+                                # this exact boundary and yields the fit.
+                                newdoc = elastic.poll()
+                                if newdoc is not None:
+                                    self._elastic_yield(
+                                        newdoc, params, opt_state, state,
+                                        tstate, epoch, bi, seed, flight)
+                finally:
+                    feeder.stop()
+                    if prof_active:
+                        # epoch ended (or failed) mid-capture: close the trace
+                        jax.profiler.stop_trace()
+                        self._profiled = True
+                        prof_at = None
+                # epoch boundary (the only unconditional host sync per epoch)
+                if loss_dev is not None:
+                    # the span times the fetch and nothing else: it is how
+                    # far this loop ran ahead of the device
+                    with span("zoo.train.epoch_sync",
+                              observe=step_metrics.epoch_sync.observe):
+                        # zoolint: disable=host-sync -- deliberate once-per-epoch sync (the comment above is the contract)
+                        tstate.loss = float(loss_dev)
+                # read after the sync: before it the dispatches are only
+                # queued, and the rate would be the host loop's
+                dt = time.perf_counter() - epoch_t0
+                with span("zoo.train.epoch_close"):
+                    self._flush_loss_buffer()
+                    throughput = n_records / max(dt, 1e-9)
+                    logger.info(
+                        "epoch %d done: loss=%.4f, %.1f records/s, step=%d",
+                        epoch, tstate.loss if tstate.loss is not None else float("nan"),
+                        throughput, self.global_step,
+                    )
+                    self.history.append(
+                        {"epoch": epoch, "loss": tstate.loss,
+                         "throughput": throughput}
+                    )
+                    if self._writers:
+                        self._writers[0].add_scalar(
+                            "Throughput", throughput, self.global_step
+                        )
+                    step_metrics.record_epoch(epoch, throughput)
+                    record_device_memory()  # HBM gauges (no-op on CPU backends)
+                    tstate.epoch_finished = True
+                    epoch += 1
+                    tstate.epoch = epoch
+                    start_batch = 0
+                    params, opt_state, state = self._on_iteration(
+                        tstate, loss_dev, params, opt_state, state,
+                        checkpoint_trigger, validation_set, validation_trigger,
+                        epoch, 0, seed, batch_size,
+                    )
         self.epoch = epoch
         health.unregister("train_loop")  # finished on purpose, not wedged
         return params, opt_state, state

@@ -53,12 +53,22 @@ def test_profiler_knob_writes_trace(tmp_path):
 
 
 def test_time_it_records_infeed_and_step():
+    """The loop's two intervals are spans now (``time_it`` left the loop):
+    eight steps, and one more wait that finds the feeder exhausted."""
+    from analytics_zoo_tpu.metrics import Tracer, set_tracer
+
     init_zoo_context(seed=0)
     reset_timings()
-    _fit_tiny()
-    t = get_timings()
-    assert "zoo.infeed" in t and "zoo.step_dispatch" in t
-    assert t["zoo.step_dispatch"]["count"] == 8  # 64/8 batches
+    tracer = Tracer(jax_bridge=False)
+    prev = set_tracer(tracer)
+    try:
+        _fit_tiny()
+    finally:
+        set_tracer(prev)
+    names = [e["name"] for e in tracer.events()]
+    assert names.count("zoo.train.step_dispatch") == 8  # 64/8 batches
+    assert names.count("zoo.train.data_wait") == 9
+    assert "zoo.infeed" not in get_timings()
 
 
 def test_explicit_value_beats_env(monkeypatch):

@@ -451,7 +451,7 @@ kernel_step("int8_matmul", im._reference)(x, w, s)
 
 out = {"hits": {}, "misses": {}}
 for smp in snapshot(get_registry())["samples"]:
-    lab = smp["labels"].get("label", "")
+    lab = smp.get("labels", {}).get("label", "")
     if not lab.startswith("kernel_"):
         continue
     if smp["name"] == "zoo_compile_cache_hits_total":
