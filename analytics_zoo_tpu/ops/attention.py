@@ -42,8 +42,7 @@ def flash_eligible(q_shape, mask_shape, mask_ndim, dropout_p, has_rng,
     if use_flash == False:  # noqa: E712
         return False
     b, h_, lq, d = q_shape[-4], q_shape[-3], q_shape[-2], q_shape[-1]
-    # d % 64: the kernel sustains 76.7 TFLOP/s at head_dim 64
-    # (FLASH_r03.json), which covers BERT-base/GPT-base head sizes
+    # d % 64 covers BERT-base/GPT-base head sizes
     if lq < 256 or d % 64 != 0:
         return False
     if dropout_p > 0.0 and not has_rng:

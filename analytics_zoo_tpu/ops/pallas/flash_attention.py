@@ -5,9 +5,9 @@
 
 The hot op behind TransformerLayer/BERT (reference materializes the full
 (L, L) score matrix per head, TransformerLayer.scala:137).  This kernel
-tiles Q over the grid and streams K/V blocks through VMEM with the
+tiles Q over the grid and walks K/V tiles through VMEM with the
 numerically-stable online-softmax accumulation, so HBM traffic is O(L·D)
-per head instead of O(L²), and the score block lives only in VMEM where the
+per head instead of O(L²), and the score tile lives only in VMEM where the
 MXU consumes it.
 
 Training-path features (so real TransformerLayer/BERT training — dropout
@@ -31,20 +31,23 @@ convention where q is the tail of the key sequence.
 Gradient support: ``flash_attention`` is wrapped in jax.custom_vjp.  The
 forward saves its softmax stats (m, l), so the backward needs no
 stats-recompute pass; on TPU the backward runs as two Pallas kernels
-(``_flash_bwd_pallas``: a dq kernel streaming K/V blocks past each q
-block, and a dk/dv/dbias kernel streaming q blocks past each K/V block)
-whose rematerialized score tiles never leave VMEM.  Elsewhere — CPU, or
-a full (Lq, Lk) bias that needs its own O(Lq·Lk) gradient — a blockwise
-lax.scan over key blocks serves as fallback and oracle (O(Lq·block_k)
-live memory).  Either way long-context training never materializes the
-(L, L) matrix.  On CPU (tests) the forward falls
-back to the jnp path automatically; set ``ZOO_FLASH_INTERPRET=1`` to
-force the actual Pallas kernels in interpret mode on CPU (CI routing +
-grad-oracle tests).
+(``_flash_bwd_pallas``: a dq kernel walking K/V tiles past each q tile,
+and a dk/dv/dbias kernel walking q tiles past each K/V tile) whose
+rematerialized score tiles never leave VMEM.  Which tiles the three
+kernels visit and what a visited tile computes is the tile schedule
+below (``tile_schedules`` records it for each traced call).  Elsewhere —
+CPU, or a full (Lq, Lk) bias that needs its own O(Lq·Lk) gradient — a
+blockwise lax.scan over key blocks serves as fallback and oracle
+(O(Lq·block_k) live memory).  Either way long-context training never
+materializes the (L, L) matrix.  On CPU (tests) the forward falls back to
+the jnp path automatically; set ``ZOO_FLASH_INTERPRET=1`` to force the
+actual Pallas kernels in interpret mode on CPU (CI routing + grad-oracle
+tests).
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import os
@@ -187,9 +190,10 @@ def attention_stats(q, k, v, causal=False, scale=None, block_q=None,
     elsewhere.  NOT differentiable on the TPU path — callers (ring
     attention) wrap it in their own custom_vjp."""
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
-    block_q, block_k = _resolve_blocks(block_q, block_k)
     if _pallas_available() and q.shape[-1] % 64 == 0 \
             and q.shape[2] >= 128 and k.shape[2] >= 128:
+        block_q, block_k = _resolve_blocks(block_q, block_k, q.shape[2],
+                                           k.shape[2], causal=causal)
         out = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
                                 block_k, interpret=_interpret_forced(),
                                 return_stats=True)
@@ -200,21 +204,239 @@ def attention_stats(q, k, v, causal=False, scale=None, block_q=None,
 
 
 # ---------------------------------------------------------------------------
+# Tile schedule: which (q tile, k tile) pairs a call visits and what a
+# visited tile computes, decided at trace time from the call's static
+# arguments (lq, lk, causal, bias, segment ids, dropout, input dtype).
+#
+# A tile is (block_k, block_q) scores, KEYS ON SUBLANES AND QUERIES ON
+# LANES (S^T = K Q^T): what the softmax keeps per query (m, l, lse, D) is
+# then a lane-dense (1, block_q) row that broadcasts along sublanes, the
+# reductions over keys are elementwise across vregs, and the stats travel
+# through HBM as (b, h, 1, lq).  With queries on sublanes each of them is
+# a (block_q, 1) column: a cross-lane reduction or broadcast for every
+# eight queries of every tile, and an array padded 128-fold in HBM.
+#
+# Above the causal diagonal a tile is SKIPPED.  Wholly below it, in a call
+# with no bias, segment ids, dropout or ragged edge, it is PLAIN: no iota,
+# compare or select.  Otherwise it is MASKED.  The matrix products take
+# their operands in the inputs' dtype (P and dS are rounded to it at the
+# product) and accumulate in float32; softmax statistics, exp and D stay
+# float32.
+#
+# One grid step keeps up to _RESIDENT_ROWS rows of each side in VMEM and
+# walks its tiles in loops inside the kernel, bounded by the diagonal, so
+# a skipped tile costs neither a grid step (0.35 us, more than a
+# 256 x 256 tile's products) nor a DMA.  A step that owns the whole
+# sequence in at most _UNROLLED_TILES tiles knows every bound as a Python
+# number and spells the visits out, which lets the scheduler run one
+# tile's products under another's softmax.  Bias and segment ids stream
+# one tile a grid step.
+# ---------------------------------------------------------------------------
+
+_RESIDENT_ROWS = 1024
+
+#: Trace-time record of each kernel's schedule, newest last: ``kernel``
+#: ("forward", "dq", "dkv"), ``shape`` (b, h, lq, lk, d), ``blocks``
+#: (block_q, block_k) as resolved, ``operand_dtype`` of the matrix
+#: products, and the tiles of one (batch, head) by class: ``skipped``,
+#: ``plain``, ``masked``.  Plain arithmetic on static shapes, like
+#: ``invocation_counts``: jit traces once, so it counts compilations.
+tile_schedules: collections.deque = collections.deque(maxlen=64)
+
+
+def _static(*xs) -> bool:
+    return all(isinstance(x, (int, np.integer)) for x in xs)
+
+
+def _k_tile_range(q0, block_q, block_k, offset, n_k, causal, all_masked):
+    """For the q tile whose first row is ``q0``: key tiles [0, plain) are
+    plain, [plain, need) masked, [need, n_k) skipped.  Python numbers
+    where ``q0`` is one (the trace-time record, and a kernel whose grid
+    step owns the whole sequence), traced scalars otherwise."""
+    xp = np if _static(q0) else jnp
+    need = plain = n_k
+    if causal:
+        need = xp.minimum(
+            xp.maximum(q0 + block_q + offset + block_k - 1, 0) // block_k,
+            n_k)
+        plain = xp.minimum(xp.maximum(q0 + offset + 1, 0) // block_k, n_k)
+    return (0 if all_masked else plain), need
+
+
+def _q_tile_range(k0, block_q, block_k, offset, n_q, causal, all_masked):
+    """For the key tile whose first column is ``k0``: q tiles [0, first)
+    are skipped, [first, plain) masked, [plain, n_q) plain."""
+    xp = np if _static(k0) else jnp
+    first = plain = 0
+    if causal:
+        first = xp.minimum(xp.maximum(k0 - offset, 0) // block_q, n_q)
+        plain = xp.minimum(
+            xp.maximum(k0 + block_k - 1 - offset + block_q - 1, 0)
+            // block_q, n_q)
+    return first, (n_q if all_masked else plain)
+
+
+def _tiles(block_q, block_k, lq, lk):
+    """The tile a kernel runs: the resolved blocks cut to the call, the q
+    side to whole lanes where the sequence has them."""
+    block_q, block_k = min(block_q, lq), min(block_k, lk)
+    if block_q > 128:
+        block_q -= block_q % 128
+    return block_q, block_k
+
+
+#: the most tiles of a sequence that the one grid step which owns it all
+#: spells out one by one
+_UNROLLED_TILES = 16
+
+
+def _grouping(l_outer, t_outer, l_inner, t_inner, streamed):
+    """(outer tiles a grid step owns, inner tiles it keeps resident,
+    whether one grid step owns them all and few enough to unroll)."""
+    if streamed:
+        return 1, 1, False
+    group = 1
+    if l_outer % t_outer == 0:
+        n_outer = l_outer // t_outer
+        group = max(g for g in range(1, n_outer + 1) if n_outer % g == 0
+                    and g * t_outer <= max(_RESIDENT_ROWS, t_outer))
+    n_inner = -(-l_inner // t_inner)
+    resident = max(1, min(n_inner, _RESIDENT_ROWS // t_inner))
+    whole = (group * t_outer == l_outer and resident == n_inner
+             and group * resident <= _UNROLLED_TILES)
+    return group, resident, whole
+
+
+def _tile_rows(j, lo, block, resident):
+    """The rows that tile ``j`` holds of the resident chunk that starts at
+    tile ``lo``."""
+    from jax.experimental import pallas as pl
+
+    if resident == 1:
+        return pl.ds(0, block)
+    start = (j - lo) * block
+    return pl.ds(start if _static(start)
+                 else pl.multiple_of(start, block), block)
+
+
+def _record_schedule(kernel, q, lk, block_q, block_k, causal, all_masked):
+    b, h, lq, d = q.shape
+    n_q, n_k = -(-lq // block_q), -(-lk // block_k)
+    plain = masked = 0
+    for i in range(n_q):
+        p, need = _k_tile_range(i * block_q, block_q, block_k, lk - lq,
+                                n_k, causal, all_masked)
+        plain += int(p)
+        masked += int(need) - int(p)
+    tile_schedules.append({
+        "kernel": kernel, "shape": (b, h, lq, lk, d),
+        "blocks": (block_q, block_k), "operand_dtype": str(q.dtype),
+        "skipped": n_q * n_k - plain - masked, "plain": plain,
+        "masked": masked})
+
+
+def _zero_rows(x, live):
+    """Zero the rows of a ragged edge: a block read past the array is
+    unspecified, and a NaN there would poison a product through 0 * NaN.
+    In float32, since a select on packed rows needs a packed mask."""
+    return jnp.where(live, x.astype(jnp.float32), 0.0).astype(x.dtype)
+
+
+def _scaled(x, scale):
+    """The score scale folded into a (rows, d) operand tile, (rows, d)
+    multiplies where the score tile would take (block_k, block_q); in
+    float32, as the v5e's vector unit has no bf16."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _live(q0, k0, block_q, block_k, lq, lk, offset, causal, pad_q, pad_k,
+          segs):
+    """The (block_k, block_q) mask of a masked tile, None where the call
+    masks nothing, and the key rows inside a ragged last tile."""
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_q), 1)
+    live = k_rows = None
+    if pad_k:
+        live = k_rows = k_pos < lk
+    if pad_q:
+        q_live = q_pos < lq
+        live = q_live if live is None else live & q_live
+    if causal:
+        tri = q_pos + offset >= k_pos
+        live = tri if live is None else live & tri
+    if segs is not None:
+        # q_seg rides as (B, 8, Lq) and kv_seg as (B, Lk, 8): a bare
+        # (B, L) operand would need a block (1, block) whose
+        # second-to-last dim breaks Mosaic's (8, 128)-or-full-dim rule
+        qseg_ref, kseg_ref = segs
+        seg = kseg_ref[0][:, :1] == qseg_ref[0][:1, :]
+        live = seg if live is None else live & seg
+    return live, k_rows, q_pos, k_pos
+
+
+def _seg_operands(q_seg, kv_seg, b, lq, lk):
+    return (jnp.broadcast_to(q_seg.astype(jnp.int32)[:, None, :], (b, 8, lq)),
+            jnp.broadcast_to(kv_seg.astype(jnp.int32)[:, :, None],
+                             (b, lk, 8)))
+
+
+def _walk_tiles(lo, resident, masked, plain, tile, carry):
+    """Run ``tile(j, carry, masked=...)`` over the share that the resident
+    chunk [lo, lo + resident) holds of the ``plain`` and of the ``masked``
+    range of tiles, each (first, past the last).  A range that the call's
+    static arguments leave empty (no plain tile in a call that masks them
+    all, no masked one in a clean call that is not causal) emits no loop."""
+    def span(bounds, is_masked, carry):
+        if _static(*bounds) and bounds[0] >= bounds[1]:
+            return carry
+        if _static(lo, *bounds):
+            # one grid step owns the whole sequence: every visit spelled
+            # out, so that the scheduler can run one tile's products under
+            # another's softmax
+            for j in range(max(lo, bounds[0]),
+                           min(lo + resident, bounds[1])):
+                carry = tile(j, carry, masked=is_masked)
+            return carry
+        return jax.lax.fori_loop(
+            jnp.maximum(lo, bounds[0]), jnp.minimum(lo + resident, bounds[1]),
+            functools.partial(tile, masked=is_masked), carry)
+
+    return span(masked, True, span(plain, False, carry))
+
+
+# ---------------------------------------------------------------------------
 # Pallas forward
 # ---------------------------------------------------------------------------
 
 
+# The kernels are jitted on their own: a model's layers call them with the
+# same shapes and static arguments, and an inner jit is traced once and
+# then found in JAX's cache, where a bare pallas_call traces its kernel
+# anew at every call site (half a second a layer's backward at 1024
+# tokens, all of it set-up time).
+_STATIC = ("causal", "scale", "block_q", "block_k", "interpret",
+           "dropout_p")
+
+
+# zoolint: disable=raw-jit -- an inner jit, inlined into the caller's program (the step that compile_step compiles): it is there for JAX's trace cache, not as a compile site
+@functools.partial(jax.jit, static_argnames=_STATIC + ("return_stats",))
 def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                       interpret=False, bias=None, q_seg=None, kv_seg=None,
                       dropout_p=0.0, seed=None, return_stats=False):
-    """Streaming forward: K/V blocks are a GRID dimension.
+    """Forward: grid (b, h, q groups, k chunks), key chunks innermost.
 
-    grid = (b, h, n_q, n_k) with the key-block index innermost; Pallas's
-    pipeline DMAs exactly one (block_k, d) K and V tile into VMEM per grid
-    step (double-buffered against compute), so VMEM holds O(block_q·d +
-    block_k·d) — never the whole (lk, d) K/V — and max sequence length is
-    bounded by HBM, not VMEM.  Softmax running stats (m, l) and the output
-    accumulator persist across the ki steps in VMEM scratch.
+    A grid step owns ``group`` q tiles and sees one chunk of K and V
+    (the whole of them up to _RESIDENT_ROWS rows); for each q tile it
+    walks the chunk's key tiles up to the diagonal, the plain ones in one
+    loop and the masked ones in another.  Softmax running stats (m, l)
+    and the output accumulator, (d, block_q) like the tile, persist
+    across chunks in VMEM scratch, so VMEM holds O(group·block_q·d +
+    chunk·d) and the sequence length is bounded by HBM, not VMEM.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -222,25 +444,29 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     b, h, lq, d = q.shape
     lk = k.shape[2]
     offset = lk - lq  # end-aligned causal diagonal
-    block_q = min(block_q, lq)
-    block_k = min(block_k, lk)
-    n_q = pl.cdiv(lq, block_q)
+    block_q, block_k = _tiles(block_q, block_k, lq, lk)
     n_k = pl.cdiv(lk, block_k)
     has_bias = bias is not None
     has_seg = q_seg is not None
     has_drop = dropout_p > 0.0
-    if has_bias:
-        bb, bh, bq, _ = bias.shape
-        bq_blk = block_q if bq > 1 else 1
+    pad_k = lk % block_k != 0
+    all_masked = (has_bias or has_seg or has_drop or pad_k
+                  or lq % block_q != 0)
+    group, resident, whole = _grouping(lq, block_q, lk, block_k,
+                                       has_bias or has_seg)
+    rows_q, chunk = group * block_q, resident * block_k
+    n_c = pl.cdiv(n_k, resident)
+    _record_schedule("forward", q, lk, block_q, block_k, causal, all_masked)
 
     def kernel(*refs):
         i = 3
         q_ref, k_ref, v_ref = refs[:3]
+        bias_ref = segs = None
         if has_bias:
             bias_ref = refs[i]
             i += 1
         if has_seg:
-            qseg_ref, kseg_ref = refs[i:i + 2]
+            segs = refs[i:i + 2]
             i += 2
         if has_drop:
             seed_ref = refs[i]
@@ -253,150 +479,132 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
             i += 1
         m_ref, l_ref, acc_ref = refs[i:i + 3]
 
+        # program ids are read OUTSIDE any loop or pl.when branch (inside
+        # one they cannot lower in interpret mode)
         bi = pl.program_id(0)
         hi = pl.program_id(1)
-        qi = pl.program_id(2)
-        ki = pl.program_id(3)
+        gi = 0 if whole else pl.program_id(2)
+        ci = 0 if whole else pl.program_id(3)
 
-        @pl.when(ki == 0)
+        @pl.when(ci == 0)
         def _init():
             m_ref[...] = jnp.full_like(m_ref, _NEG)
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        q_start = qi * block_q
-        k_start = ki * block_k
-
-        def compute():
-            qb = q_ref[0, 0].astype(jnp.float32)
-            kb = k_ref[0, 0].astype(jnp.float32)
-            vb = v_ref[0, 0].astype(jnp.float32)
-            # Zero padded key rows (lk % block_k != 0): OOB block reads are
-            # unspecified, and a NaN there would poison p @ v even with
-            # p == 0 at those columns (0 * NaN = NaN).
-            k_live = (
-                k_start + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_k, 1), 0) < lk
-            )
-            kb = jnp.where(k_live, kb, 0.0)
-            vb = jnp.where(k_live, vb, 0.0)
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
+        def tile(j, carry, qs, q0, masked):
+            m, l, acc = carry  # (1, block_q) twice, (d, block_q)
+            at = _tile_rows(j, ci * resident, block_k, resident)
+            kb, vb = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
+            live = None
+            if masked:
+                # a ragged q tile needs no mask here: its columns past lq
+                # are dropped on the way out
+                live, k_rows, q_pos, k_pos = _live(
+                    q0, j * block_k, block_q, block_k, lq, lk, offset,
+                    causal, False, pad_k, segs)
+                if pad_k:
+                    kb, vb = _zero_rows(kb, k_rows), _zero_rows(vb, k_rows)
+            s = _dot(kb, qs, (1, 1))  # (block_k, block_q)
             if has_bias:
                 s = s + bias_ref[0, 0].astype(jnp.float32)
-            # mask padded key rows (lk % block_k != 0), if causal the
-            # end-aligned upper triangle, and cross-segment pairs
-            live = k_pos < lk
-            if causal:
-                live = live & (q_pos + offset >= k_pos)
-            if has_seg:
-                # q_seg rides as (B, Lq, 8) and kv_seg as (B, 8, Lk): a bare
-                # (B, L) operand would need block (1, block) whose
-                # second-to-last dim violates Mosaic's (8, 128)-or-full-dim
-                # block rule on real TPU (interpret mode does not check).
-                sq = qseg_ref[0][:, :1]            # (block_q, 1)
-                sk = kseg_ref[0][:1, :]            # (1, block_k)
-                live = live & (sq == sk)
-            s = jnp.where(live, s, _NEG)
-            m, l = m_ref[...], l_ref[...]
-            new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            if live is not None:
+                s = jnp.where(live, s, _NEG)
+            new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             alpha = jnp.exp(m - new_m)
-            p = jnp.where(live, jnp.exp(s - new_m), 0.0)
-            m_ref[...] = new_m
+            p = jnp.exp(s - new_m)
+            if live is not None:
+                # a query with no live key yet has new_m == _NEG == s
+                p = jnp.where(live, p, 0.0)
             # l is the full softmax denominator (pre-dropout), so the final
             # acc / l division reproduces dropout-after-softmax semantics
-            l_ref[...] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
             if has_drop:
                 bits = _keep_bits(seed_ref[0], seed_ref[1], bi, hi,
                                   q_pos, k_pos)
                 p = jnp.where(bits >= _drop_threshold(dropout_p),
                               p * (1.0 / (1.0 - dropout_p)), 0.0)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            acc = acc * alpha + _dot(vb, p.astype(vb.dtype), (0, 0))
+            return new_m, l, acc
 
-        if causal:
-            # Skip compute for key blocks fully above this query block's
-            # diagonal (their DMA is still pipelined, but no MXU work).
-            pl.when(k_start <= q_start + block_q - 1 + offset)(compute)
-        else:
-            compute()
+        lo = ci * resident
+        for r in range(group):
+            rows = pl.ds(r * block_q, block_q)
+            q0 = (gi * group + r) * block_q
+            qs = _scaled(q_ref[0, 0, rows, :], scale)
+            plain, need = _k_tile_range(q0, block_q, block_k, offset, n_k,
+                                        causal, all_masked)
+            m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows] = _walk_tiles(
+                lo, resident, (plain, need), (0, plain),
+                functools.partial(tile, qs=qs, q0=q0),
+                (m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows]))
 
-        @pl.when(ki == n_k - 1)
+        @pl.when(ci == n_c - 1)
         def _emit():
             o_ref[0, 0] = (
                 acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
-            ).astype(o_ref.dtype)
+            ).T.astype(o_ref.dtype)
             if return_stats:
                 m_out_ref[0, 0] = m_ref[...]
                 l_out_ref[0, 0] = l_ref[...]
 
+    def q_side(bi, hi, gi, ci):
+        return (bi, hi, gi, 0)
+
+    def k_side(bi, hi, gi, ci):
+        return (bi, hi, ci, 0)
+
     in_specs = [
-        pl.BlockSpec((1, 1, block_q, d),
-                     lambda bi, hi, qi, ki: (bi, hi, qi, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda bi, hi, qi, ki: (bi, hi, ki, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda bi, hi, qi, ki: (bi, hi, ki, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, rows_q, d), q_side, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, chunk, d), k_side, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, chunk, d), k_side, memory_space=pltpu.VMEM),
     ]
     args = [q, k, v]
     if has_bias:
+        # keys on sublanes: a (…, 1, Lk) bias rides as a column, a full
+        # (…, Lq, Lk) one transposed
+        bb, bh, bq, _ = bias.shape
         in_specs.append(pl.BlockSpec(
-            (1, 1, bq_blk, block_k),
-            lambda bi, hi, qi, ki, _bb=bb, _bh=bh, _bq=bq: (
-                bi if _bb > 1 else 0, hi if _bh > 1 else 0,
-                qi if _bq > 1 else 0, ki),
+            (1, 1, block_k, block_q if bq > 1 else 1),
+            lambda bi, hi, gi, ci: (
+                bi if bb > 1 else 0, hi if bh > 1 else 0, ci,
+                gi if bq > 1 else 0),
             memory_space=pltpu.VMEM))
-        args.append(bias.astype(jnp.float32))
+        args.append(jnp.swapaxes(bias.astype(jnp.float32), 2, 3))
     if has_seg:
         in_specs.append(pl.BlockSpec(
-            (1, block_q, 8), lambda bi, hi, qi, ki: (bi, qi, 0),
+            (1, 8, block_q), lambda bi, hi, gi, ci: (bi, 0, gi),
             memory_space=pltpu.VMEM))
         in_specs.append(pl.BlockSpec(
-            (1, 8, block_k), lambda bi, hi, qi, ki: (bi, 0, ki),
+            (1, block_k, 8), lambda bi, hi, gi, ci: (bi, ci, 0),
             memory_space=pltpu.VMEM))
-        args.append(jnp.broadcast_to(
-            q_seg.astype(jnp.int32)[:, :, None], (b, lq, 8)))
-        args.append(jnp.broadcast_to(
-            kv_seg.astype(jnp.int32)[:, None, :], (b, 8, lk)))
+        args += _seg_operands(q_seg, kv_seg, b, lq, lk)
     if has_drop:
         in_specs.append(pl.BlockSpec(
-            (2,), lambda bi, hi, qi, ki: (0,),
+            (2,), lambda bi, hi, gi, ci: (0,),
             memory_space=pltpu.SMEM))
         args.append(seed.astype(jnp.int32))
 
-    grid = (b, h, n_q, n_k)
-    out_specs = pl.BlockSpec((1, 1, block_q, d),
-                             lambda bi, hi, qi, ki: (bi, hi, qi, 0),
+    out_specs = pl.BlockSpec((1, 1, rows_q, d), q_side,
                              memory_space=pltpu.VMEM)
     out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     if return_stats:
-        stat_spec = pl.BlockSpec((1, 1, block_q, 1),
-                                 lambda bi, hi, qi, ki: (bi, hi, qi, 0),
+        stat_spec = pl.BlockSpec((1, 1, 1, rows_q),
+                                 lambda bi, hi, gi, ci: (bi, hi, 0, gi),
                                  memory_space=pltpu.VMEM)
-        stat_shape = jax.ShapeDtypeStruct((b, h, lq, 1), jnp.float32)
+        stat_shape = jax.ShapeDtypeStruct((b, h, 1, lq), jnp.float32)
         out_specs = [out_specs, stat_spec, stat_spec]
         out_shape = [out_shape, stat_shape, stat_shape]
     res = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, h, pl.cdiv(lq, rows_q), n_c),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((1, rows_q), jnp.float32),
+            pltpu.VMEM((1, rows_q), jnp.float32),
+            pltpu.VMEM((d, rows_q), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -406,44 +614,60 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     )(*args)
     if return_stats:
         out, m, l = res
-        return out, m[..., 0], l[..., 0]
+        return out, m[:, :, 0], l[:, :, 0]
     return res
 
 
-def _resolve_blocks(block_q, block_k,
+def _causal_tile(length: int) -> int:
+    """Half of the sequence, so that a causal call has tiles to skip (at
+    two tiles a side, one of four), and not under 256: a visited tile costs
+    about a third of a microsecond whatever its size (the products'
+    latency, in a chain through the running max), which at 256 x 256 is
+    more than the tile's own work."""
+    return max(256, 1 << max((length // 2).bit_length() - 1, 0))
+
+
+def _resolve_blocks(block_q, block_k, lq, lk, *, causal=False,
                     full_bias: bool = False,
                     dropout: bool = False) -> tuple[int, int]:
-    """Block defaults sized against the v5e ~16 MB scoped-VMEM budget.
+    """Tile defaults: upper bounds sized against the v5e ~16 MB scoped-VMEM
+    budget, brought down by the call's shape where that lets tiles go.
 
-    The dominant live buffers are the (block_q, block_k) f32 score and
+    The dominant live buffers are the (block_k, block_q) f32 score and
     prob tiles; in-kernel dropout adds a PRNG-bits tile of the same shape
-    and a full (…, Lq, Lk) bias streams an extra f32 tile.  The r03-tuned
-    2048-row blocks left <1% headroom and went over once those operands
-    landed (measured: 16.09M/16M clean @4k d=64, 22.73M/16M dropout @2k
-    d=128 — both hard compile failures on the chip), so: 1024x1024 clean
-    (~10 MB live), block_k 512 under dropout/full-bias (~8 MB live).
-    Explicit block_q/block_k arguments always win."""
-    if full_bias:
-        return block_q or 512, block_k or 512
-    if block_q is None:
-        block_q = 1024
-    if block_k is None:
-        block_k = 512 if dropout else 1024
-    return block_q, block_k
+    and a full (…, Lq, Lk) bias streams an extra f32 tile.  2048-row
+    blocks went over the budget once those operands landed (measured:
+    16.09M/16M clean @4k d=64, 22.73M/16M dropout @2k d=128 — both hard
+    compile failures on the chip), so at most 1024x1024 clean (~10 MB
+    live), block_k 512 under dropout (its PRNG tile) and 512x512 with a
+    full bias (~8 MB live).  A causal call takes `_causal_tile` of its
+    shorter side where that is less: 512x512 at 1024 tokens, the caps
+    from 2048 on.  Explicit block_q/block_k arguments always win."""
+    cap_q, cap_k = (512, 512) if full_bias else \
+        (1024, 512 if dropout else 1024)
+    if causal:
+        tile = _causal_tile(min(lq, lk))
+        cap_q, cap_k = min(cap_q, tile), min(cap_k, tile)
+    return block_q or cap_q, block_k or cap_k
 
 
-def _resolve_bwd_blocks(block_q, block_k, lq, lk) -> tuple[int, int]:
-    """Backward blocks: 512x512 keeps both kernels' live VMEM ~7 MB at
-    d=128 with dropout (f32 q/g/k/v casts + up to four (bq, bk) f32
-    score/prob/grad tiles + the PRNG-bits tile + (bq|bk, d) accumulators),
-    well under the measured ~16 MB scoped budget that burned the 1024-row
-    forward tuning (see _resolve_blocks).  A caller's SMALLER explicit
-    blocks are honored (the VMEM-pressure escape hatch); anything larger —
-    including the forward's resolved 1024 defaults flowing through
-    _flash_core — is capped at 512 because the backward holds roughly
-    twice the forward's live tiles per step."""
-    return (min(block_q or 512, 512, lq),
-            min(block_k or 512, 512, lk))
+def _resolve_bwd_blocks(block_q, block_k, lq, lk, *, causal=False,
+                        kernel="dq") -> tuple[int, int]:
+    """Backward tiles: the forward's (`_resolve_blocks`), at most 512x512,
+    and for the dk/dv kernel of a causal call half of that a side (256x256
+    at 1024 tokens): its four products a tile make the share of tiles
+    visited worth more than the visits.  The backward holds roughly twice
+    the forward's live tiles a step (up to four (bk, bq) f32
+    score/prob/grad tiles, the PRNG-bits tile, two (bk, d) accumulators),
+    and 512x512 keeps both kernels ~7 MB at d=128 with dropout, well under
+    the ~16 MB scoped budget.  A caller's SMALLER explicit blocks are
+    honored (the VMEM-pressure escape hatch)."""
+    cap = 512
+    if causal and kernel == "dkv":
+        cap = min(cap, max(256, _causal_tile(min(lq, lk)) // 2))
+    block_q, block_k = _resolve_blocks(block_q, block_k, lq, lk,
+                                       causal=causal)
+    return min(block_q, cap, lq), min(block_k, cap, lk)
 
 
 def _flash_bwd_pallas(q, k, v, g, out, m, l, causal, scale,
@@ -452,13 +676,16 @@ def _flash_bwd_pallas(q, k, v, g, out, m, l, causal, scale,
                       seed=None):
     """Pallas flash backward: two kernels, both O(block²) VMEM.
 
-    dq kernel: grid (b, h, n_q, n_k) — a q block accumulates dq across
-    streamed K/V blocks.  dk/dv kernel: grid (b, h, n_k, n_q) — a K/V
-    block accumulates dk/dv (and its bias-grad tile) across streamed q
-    blocks.  Score tiles are rematerialized from q/k in VMEM (standard
-    flash strategy) using the forward's saved softmax stats (m, l), so
-    no stats-recompute pass exists and nothing O(Lq·Lk) ever reaches
-    HBM.  Dropout re-derives the forward's exact keep mask from the
+    dq kernel: grid (b, h, q groups, k chunks) — each q tile accumulates
+    dq across the key tiles of the chunks it sees.  dk/dv kernel: grid
+    (b, h, k groups, q chunks) — each K/V tile accumulates dk/dv (and its
+    bias-grad tile) across the q tiles.  Both walk their tiles in loops
+    inside the kernel, bounded by the diagonal, as the forward does.
+    Score tiles are rematerialized from q/k in VMEM (standard flash
+    strategy) as ``exp(s - lse)`` from the forward's saved softmax stats,
+    ``lse = m + log(l)`` made once in XLA beside ``D``, so no
+    stats-recompute pass exists and nothing O(Lq·Lk) ever reaches HBM.
+    Dropout re-derives the forward's exact keep mask from the
     `_keep_bits` position hash.
 
     Bias gradients are emitted per (b, h) as (b, h, 1, lk) partials and
@@ -466,150 +693,164 @@ def _flash_bwd_pallas(q, k, v, g, out, m, l, causal, scale,
     biases are NOT handled here (their db is itself O(Lq·Lk) — callers
     fall back to the jnp blockwise path).
     """
+    invocation_counts["pallas"] += 1
+    if bias is not None and bias.shape[2] > 1:
+        raise ValueError("full (Lq, Lk) bias backward not supported "
+                         "in the Pallas path")
+    gf = g.astype(jnp.float32)
+    # D_i = dO_i · O_i (flash-bwd identity; holds under dropout because
+    # O already contains the dropped probabilities)
+    D4 = jnp.sum(gf * out.astype(jnp.float32), axis=-1)[:, :, None]
+    # p = exp(s - lse) needs no divide; a query with no live key has
+    # m == _NEG, which absorbs the log
+    lse4 = (m.astype(jnp.float32) + jnp.log(
+        jnp.maximum(l.astype(jnp.float32), 1e-20)))[:, :, None]
+    rest = ((q, k, v, gf.astype(q.dtype), lse4, D4), causal, scale, block_q,
+            block_k, interpret, bias, q_seg, kv_seg, dropout_p, seed)
+    (dq,) = _flash_bwd_kernel("dq", *rest)
+    dk, dv, *db = _flash_bwd_kernel("dkv", *rest)
+    if bias is None:
+        return dq, dk, dv, None
+    # (b, h, 1, lk) per-(b, h) partials
+    db = jnp.swapaxes(db[0][:, :, :k.shape[2]], 2, 3)
+    if bias.shape[0] == 1:
+        db = jnp.sum(db, axis=0, keepdims=True)
+    if bias.shape[1] == 1:
+        db = jnp.sum(db, axis=1, keepdims=True)
+    return dq, dk, dv, db.astype(bias.dtype)
+
+
+# zoolint: disable=raw-jit -- an inner jit, inlined into the caller's program (the step that compile_step compiles): it is there for JAX's trace cache, not as a compile site
+@functools.partial(jax.jit, static_argnames=_STATIC + ("kernel",))
+def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
+                      interpret, bias, q_seg, kv_seg, dropout_p, seed):
+    """One of the two backward kernels, "dq" or "dkv", on its own tiles:
+    its results as a list."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    q, k, v = operands[:3]
     b, h, lq, d = q.shape
     lk = k.shape[2]
     offset = lk - lq
-    invocation_counts["pallas"] += 1
-    bq, bk = _resolve_bwd_blocks(block_q, block_k, lq, lk)
+    bq, bk = _tiles(*_resolve_bwd_blocks(block_q, block_k, lq, lk,
+                                         causal=causal, kernel=kernel),
+                    lq, lk)
     n_q = pl.cdiv(lq, bq)
     n_k = pl.cdiv(lk, bk)
     has_bias = bias is not None
     has_seg = q_seg is not None
     has_drop = dropout_p > 0.0
+    pad_q = lq % bq != 0
+    pad_k = lk % bk != 0
+    all_masked = has_bias or has_seg or has_drop or pad_q or pad_k
+    streamed = has_bias or has_seg
     if has_bias:
-        bb, bh, bq_dim, _ = bias.shape
-        if bq_dim > 1:
-            raise ValueError("full (Lq, Lk) bias backward not supported "
-                             "in the Pallas path")
-
-    gf = g.astype(jnp.float32)
-    # D_i = dO_i · O_i (flash-bwd identity; holds under dropout because
-    # O already contains the dropped probabilities)
-    D = jnp.sum(gf * out.astype(jnp.float32), axis=-1)  # (b, h, lq)
-    m4 = m.astype(jnp.float32)[..., None]               # (b, h, lq, 1)
-    l4 = jnp.maximum(l.astype(jnp.float32), 1e-20)[..., None]
-    D4 = D[..., None]
+        bb, bh = bias.shape[:2]
+    _record_schedule(kernel, q, lk, bq, bk, causal, all_masked)
 
     thr = _drop_threshold(dropout_p) if has_drop else None
     inv_keep = 1.0 / (1.0 - dropout_p) if has_drop else None
 
-    def tiles(q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref, bias_ref,
-              qseg_ref, kseg_ref, seed_ref, bi, hi, qi, ki):
-        """Shared per-(q block, k block) recompute: returns
-        (p_t, ds_raw, ds, qb, kb, gb) — all f32 tiles.  bi/hi/qi/ki are
-        program ids read OUTSIDE any pl.when branch (program_id inside a
-        cond branch cannot lower in interpret mode)."""
-        q_start = qi * bq
-        k_start = ki * bk
-        qb = q_ref[0, 0].astype(jnp.float32)
-        kb = k_ref[0, 0].astype(jnp.float32)
-        vb = v_ref[0, 0].astype(jnp.float32)
-        gb = g_ref[0, 0].astype(jnp.float32)
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        q_live = q_pos < lq
-        k_live = k_pos < lk
-        # zero padded rows: OOB block reads are unspecified and a NaN
-        # would poison the accumulations through 0 * NaN
-        qb = jnp.where(q_live, qb, 0.0)
-        gb = jnp.where(q_live, gb, 0.0)
-        # column-oriented mask built directly from iota: reshaping the
-        # (1, bk) i1 vector is a Mosaic "insert minor dim" op that only
-        # lowers for 32-bit types on real TPU
-        k_live_col = (k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (bk, 1), 0)) < lk
-        kb = jnp.where(k_live_col, kb, 0.0)
-        vb = jnp.where(k_live_col, vb, 0.0)
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    def recompute(qs, kb, vb, gb, lse, dd, q0, k0, masked, opt, bi, hi):
+        """One tile's (p_t, ds), (bk, bq) in float32, from the scaled q
+        tile, the k, v and dO tiles and the queries' stats, (1, bq) rows;
+        and the operands, with the rows of a ragged edge zeroed."""
+        bias_ref, segs, seed_ref = opt
+        live = None
+        if masked:
+            live, k_rows, q_pos, k_pos = _live(
+                q0, k0, bq, bk, lq, lk, offset, causal, pad_q, pad_k, segs)
+            if pad_q:
+                q_rows = q0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (bq, 1), 0) < lq
+                qs, gb = _zero_rows(qs, q_rows), _zero_rows(gb, q_rows)
+            if pad_k:
+                kb, vb = _zero_rows(kb, k_rows), _zero_rows(vb, k_rows)
+        s = _dot(kb, qs, (1, 1))
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)
-        live = q_live & k_live
-        if causal:
-            live = live & (q_pos + offset >= k_pos)
-        if has_seg:
-            live = live & (qseg_ref[0][:, :1] == kseg_ref[0][:1, :])
-        mb = m_ref[0, 0]  # (bq, 1) f32
-        lb = l_ref[0, 0]
-        db_row = d_ref[0, 0]
-        # division and D-subtraction INSIDE the where: padded q rows read
-        # OOB stats (NaN/0 in interpret mode, unspecified on hardware) and
-        # the dk/dv kernel CONTRACTS over q rows — a NaN there would
-        # poison every output element, so masked entries must be exact 0s
-        p = jnp.where(live, jnp.exp(s - mb) / lb, 0.0)
-        dp = jax.lax.dot_general(
-            gb, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        p = jnp.exp(s - lse)
+        if live is not None:
+            p = jnp.where(live, p, 0.0)
+        dp = _dot(vb, gb, (1, 1))
         if has_drop:
             bits = _keep_bits(seed_ref[0], seed_ref[1], bi, hi,
                               q_pos, k_pos)
             t = jnp.where(bits >= thr, inv_keep, 0.0)
             p_t = p * t
-            ds_raw = jnp.where(live, p * (t * dp - db_row), 0.0)
+            ds = p * (t * dp - dd)
         else:
             p_t = p
-            ds_raw = jnp.where(live, p * (dp - db_row), 0.0)
-        return p_t, ds_raw, ds_raw * scale, qb, kb, gb
+            ds = p * (dp - dd)
+        if live is not None and (pad_q or pad_k):
+            # padded queries read OOB stats (unspecified) and the dk/dv
+            # kernel CONTRACTS over queries, so masked entries must be
+            # exact 0s, not 0 * NaN
+            ds = jnp.where(live, ds, 0.0)
+        return p_t, ds, qs, kb, gb
 
-    # ---- dq kernel: grid (b, h, n_q, n_k), key blocks innermost --------
-    def dq_kernel(*refs):
-        i = 7
-        q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref = refs[:7]
-        bias_ref = qseg_ref = kseg_ref = seed_ref = None
+    def optional_refs(refs, i):
+        bias_ref = segs = seed_ref = None
         if has_bias:
             bias_ref = refs[i]
             i += 1
         if has_seg:
-            qseg_ref, kseg_ref = refs[i:i + 2]
+            segs = refs[i:i + 2]
             i += 2
         if has_drop:
             seed_ref = refs[i]
             i += 1
+        return (bias_ref, segs, seed_ref), i
+
+    # ---- dq kernel: grid (b, h, q groups, k chunks) --------------------
+    group_q, resident_k, whole_dq = _grouping(lq, bq, lk, bk, streamed)
+
+    def dq_kernel(*refs):
+        q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref = refs[:6]
+        opt, i = optional_refs(refs, 6)
         dq_ref, acc_ref = refs[i], refs[i + 1]
         bi = pl.program_id(0)
         hi = pl.program_id(1)
-        qi = pl.program_id(2)
-        ki = pl.program_id(3)
+        gi = 0 if whole_dq else pl.program_id(2)
+        ci = 0 if whole_dq else pl.program_id(3)
 
-        @pl.when(ki == 0)
+        @pl.when(ci == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        def compute():
-            _, _, ds, _, kb, _ = tiles(
-                q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
-                bias_ref, qseg_ref, kseg_ref, seed_ref, bi, hi, qi, ki)
-            acc_ref[...] += jax.lax.dot_general(
-                ds, kb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        lo = ci * resident_k
+        for r in range(group_q):
+            rows = pl.ds(r * bq, bq)
+            q0 = (gi * group_q + r) * bq
+            qs = _scaled(q_ref[0, 0, rows, :], scale)
+            gb = g_ref[0, 0, rows, :]
+            lse, dd = lse_ref[0, 0, :, rows], d_ref[0, 0, :, rows]
 
-        if causal:
-            pl.when(ki * bk <= qi * bq + bq - 1 + offset)(compute)
-        else:
-            compute()
+            def tile(j, acc, masked, qs=qs, gb=gb, lse=lse, dd=dd, q0=q0):
+                at = _tile_rows(j, lo, bk, resident_k)
+                _, ds, _, kb, _ = recompute(
+                    qs, k_ref[0, 0, at, :], v_ref[0, 0, at, :], gb, lse,
+                    dd, q0, j * bk, masked, opt, bi, hi)
+                return acc + _dot(kb, ds.astype(kb.dtype), (0, 0))
 
-        @pl.when(ki == n_k - 1)
+            plain, need = _k_tile_range(q0, bq, bk, offset, n_k, causal,
+                                        all_masked)
+            acc_ref[:, rows] = _walk_tiles(
+                lo, resident_k, (plain, need), (0, plain), tile,
+                acc_ref[:, rows])
+
+        @pl.when(ci == pl.cdiv(n_k, resident_k) - 1)
         def _emit():
-            dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
+            # the scale that q carried into s, now on its way out
+            dq_ref[0, 0] = (acc_ref[...] * scale).T.astype(dq_ref.dtype)
 
-    # ---- dk/dv kernel: grid (b, h, n_k, n_q), q blocks innermost -------
+    # ---- dk/dv kernel: grid (b, h, k groups, q chunks) -----------------
+    group_k, resident_q, whole_dkv = _grouping(lk, bk, lq, bq, streamed)
+
     def dkv_kernel(*refs):
-        i = 7
-        q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref = refs[:7]
-        bias_ref = qseg_ref = kseg_ref = seed_ref = None
-        if has_bias:
-            bias_ref = refs[i]
-            i += 1
-        if has_seg:
-            qseg_ref, kseg_ref = refs[i:i + 2]
-            i += 2
-        if has_drop:
-            seed_ref = refs[i]
-            i += 1
+        q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref = refs[:6]
+        opt, i = optional_refs(refs, 6)
         if has_bias:
             dk_ref, dv_ref, db_ref = refs[i:i + 3]
             dk_acc, dv_acc, db_acc = refs[i + 3:i + 6]
@@ -619,84 +860,96 @@ def _flash_bwd_pallas(q, k, v, g, out, m, l, causal, scale,
             db_ref = db_acc = None
         bi = pl.program_id(0)
         hi = pl.program_id(1)
-        ki = pl.program_id(2)
-        qi = pl.program_id(3)
+        gi = 0 if whole_dkv else pl.program_id(2)
+        ci = 0 if whole_dkv else pl.program_id(3)
 
-        @pl.when(qi == 0)
+        @pl.when(ci == 0)
         def _init():
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
             if has_bias:
                 db_acc[...] = jnp.zeros_like(db_acc)
 
-        def compute():
-            p_t, ds_raw, ds, qb, _, gb = tiles(
-                q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, d_ref,
-                bias_ref, qseg_ref, kseg_ref, seed_ref, bi, hi, qi, ki)
-            dk_acc[...] += jax.lax.dot_general(
-                ds, qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dv_acc[...] += jax.lax.dot_general(
-                p_t, gb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        lo = ci * resident_q
+        for r in range(group_k):
+            rows = pl.ds(r * bk, bk)
+            k0 = (gi * group_k + r) * bk
+            kb, vb = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
+
+            def tile(j, carry, masked, kb=kb, vb=vb, k0=k0):
+                at = _tile_rows(j, lo, bq, resident_q)
+                p_t, ds, qs, _, gb = recompute(
+                    _scaled(q_ref[0, 0, at, :], scale), kb, vb,
+                    g_ref[0, 0, at, :], lse_ref[0, 0, :, at],
+                    d_ref[0, 0, :, at], j * bq, k0, masked, opt, bi, hi)
+                # dk through the scaled q: the scale of s, for nothing
+                dk = carry[0] + _dot(ds.astype(qs.dtype), qs, (1, 0))
+                dv = carry[1] + _dot(p_t.astype(gb.dtype), gb, (1, 0))
+                if has_bias:
+                    return dk, dv, carry[2] + jnp.sum(ds, axis=1,
+                                                      keepdims=True)
+                return dk, dv
+
+            first, plain = _q_tile_range(k0, bq, bk, offset, n_q, causal,
+                                         all_masked)
+            carry = (dk_acc[rows, :], dv_acc[rows, :])
             if has_bias:
-                db_acc[...] += jnp.sum(ds_raw, axis=0, keepdims=True)
+                carry += (db_acc[...],)
+            carry = _walk_tiles(lo, resident_q, (first, plain),
+                                (plain, n_q), tile, carry)
+            dk_acc[rows, :], dv_acc[rows, :] = carry[:2]
+            if has_bias:
+                db_acc[...] = carry[2]
 
-        if causal:
-            pl.when(ki * bk <= qi * bq + bq - 1 + offset)(compute)
-        else:
-            compute()
-
-        @pl.when(qi == n_q - 1)
+        @pl.when(ci == pl.cdiv(n_q, resident_q) - 1)
         def _emit():
             dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
             if has_bias:
                 db_ref[0, 0] = db_acc[...]
 
-    def common_specs(order):
-        """In-specs for q/g/m/l/D + k/v + optionals; ``order`` maps grid
-        ids -> (qi, ki) for the kernel's grid layout."""
+    def common_specs(rows_q, rows_k, order):
+        """In-specs for q/g/lse/D + k/v + optionals; ``order`` maps grid
+        ids -> (q block, k block) for the kernel's grid layout."""
         def im_q(bi, hi, g2, g3):
             return (bi, hi, order(g2, g3)[0], 0)
 
         def im_k(bi, hi, g2, g3):
             return (bi, hi, order(g2, g3)[1], 0)
 
-        def im_row(bi, hi, g2, g3):
-            return (bi, hi, order(g2, g3)[0], 0)
+        def im_stat(bi, hi, g2, g3):
+            return (bi, hi, 0, order(g2, g3)[0])
 
         specs = [
-            pl.BlockSpec((1, 1, bq, d), im_q, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk, d), im_k, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk, d), im_k, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bq, d), im_q, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bq, 1), im_row, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bq, 1), im_row, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bq, 1), im_row, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, rows_q, d), im_q, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, rows_k, d), im_k, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, rows_k, d), im_k, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, rows_q, d), im_q, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, 1, rows_q), im_stat,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, 1, rows_q), im_stat,
+                         memory_space=pltpu.VMEM),
         ]
-        args = [q, k, v, gf.astype(q.dtype), m4, l4, D4]
+        args = list(operands)
         if has_bias:
+            # a column, keys on sublanes like the tile
             specs.append(pl.BlockSpec(
-                (1, 1, 1, bk),
-                lambda bi, hi, g2, g3, _bb=bb, _bh=bh: (
-                    bi if _bb > 1 else 0, hi if _bh > 1 else 0, 0,
-                    order(g2, g3)[1]),
+                (1, 1, bk, 1),
+                lambda bi, hi, g2, g3: (
+                    bi if bb > 1 else 0, hi if bh > 1 else 0,
+                    order(g2, g3)[1], 0),
                 memory_space=pltpu.VMEM))
-            args.append(bias.astype(jnp.float32))
+            args.append(jnp.swapaxes(bias.astype(jnp.float32), 2, 3))
         if has_seg:
             specs.append(pl.BlockSpec(
-                (1, bq, 8),
-                lambda bi, hi, g2, g3: (bi, order(g2, g3)[0], 0),
+                (1, 8, bq),
+                lambda bi, hi, g2, g3: (bi, 0, order(g2, g3)[0]),
                 memory_space=pltpu.VMEM))
             specs.append(pl.BlockSpec(
-                (1, 8, bk),
-                lambda bi, hi, g2, g3: (bi, 0, order(g2, g3)[1]),
+                (1, bk, 8),
+                lambda bi, hi, g2, g3: (bi, order(g2, g3)[1], 0),
                 memory_space=pltpu.VMEM))
-            args.append(jnp.broadcast_to(
-                q_seg.astype(jnp.int32)[:, :, None], (b, lq, 8)))
-            args.append(jnp.broadcast_to(
-                kv_seg.astype(jnp.int32)[:, None, :], (b, 8, lk)))
+            args += _seg_operands(q_seg, kv_seg, b, lq, lk)
         if has_drop:
             specs.append(pl.BlockSpec(
                 (2,), lambda bi, hi, g2, g3: (0,),
@@ -708,43 +961,47 @@ def _flash_bwd_pallas(q, k, v, g, out, m, l, causal, scale,
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
 
-    dq_specs, dq_args = common_specs(lambda g2, g3: (g2, g3))
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b, h, n_q, n_k),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda bi, hi, qi, ki: (bi, hi, qi, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=params,
-        interpret=interpret,
-    )(*dq_args)
+    if kernel == "dq":
+        rows_q, rows_k = group_q * bq, resident_k * bk
+        dq_specs, dq_args = common_specs(rows_q, rows_k,
+                                         lambda g2, g3: (g2, g3))
+        return [pl.pallas_call(
+            dq_kernel,
+            grid=(b, h, pl.cdiv(lq, rows_q), pl.cdiv(n_k, resident_k)),
+            in_specs=dq_specs,
+            out_specs=pl.BlockSpec((1, 1, rows_q, d),
+                                   lambda bi, hi, gi, ci: (bi, hi, gi, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((d, rows_q), jnp.float32)],
+            compiler_params=params,
+            interpret=interpret,
+        )(*dq_args)]
 
-    kv_specs, kv_args = common_specs(lambda g2, g3: (g3, g2))
+    rows_q, rows_k = resident_q * bq, group_k * bk
+    kv_specs, kv_args = common_specs(rows_q, rows_k, lambda g2, g3: (g3, g2))
     kv_out_specs = [
-        pl.BlockSpec((1, 1, bk, d),
-                     lambda bi, hi, ki, qi: (bi, hi, ki, 0),
+        pl.BlockSpec((1, 1, rows_k, d),
+                     lambda bi, hi, gi, ci: (bi, hi, gi, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, bk, d),
-                     lambda bi, hi, ki, qi: (bi, hi, ki, 0),
+        pl.BlockSpec((1, 1, rows_k, d),
+                     lambda bi, hi, gi, ci: (bi, hi, gi, 0),
                      memory_space=pltpu.VMEM),
     ]
     kv_out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
                     jax.ShapeDtypeStruct(v.shape, v.dtype)]
-    kv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
-                  pltpu.VMEM((bk, d), jnp.float32)]
+    kv_scratch = [pltpu.VMEM((rows_k, d), jnp.float32),
+                  pltpu.VMEM((rows_k, d), jnp.float32)]
     if has_bias:
         kv_out_specs.append(pl.BlockSpec(
-            (1, 1, 1, bk), lambda bi, hi, ki, qi: (bi, hi, 0, ki),
+            (1, 1, bk, 1), lambda bi, hi, gi, ci: (bi, hi, gi, 0),
             memory_space=pltpu.VMEM))
         kv_out_shape.append(
-            jax.ShapeDtypeStruct((b, h, 1, n_k * bk), jnp.float32))
-        kv_scratch.append(pltpu.VMEM((1, bk), jnp.float32))
-    res = pl.pallas_call(
+            jax.ShapeDtypeStruct((b, h, n_k * bk, 1), jnp.float32))
+        kv_scratch.append(pltpu.VMEM((bk, 1), jnp.float32))
+    return pl.pallas_call(
         dkv_kernel,
-        grid=(b, h, n_k, n_q),
+        grid=(b, h, pl.cdiv(lk, rows_k), pl.cdiv(n_q, resident_q)),
         in_specs=kv_specs,
         out_specs=kv_out_specs,
         out_shape=kv_out_shape,
@@ -752,18 +1009,6 @@ def _flash_bwd_pallas(q, k, v, g, out, m, l, causal, scale,
         compiler_params=params,
         interpret=interpret,
     )(*kv_args)
-    if has_bias:
-        dk, dv, db_part = res
-        db = db_part[..., :lk]  # (b, h, 1, lk) per-(b,h) partials
-        if bb == 1:
-            db = jnp.sum(db, axis=0, keepdims=True)
-        if bh == 1:
-            db = jnp.sum(db, axis=1, keepdims=True)
-        dbias = db.astype(bias.dtype)
-    else:
-        dk, dv = res
-        dbias = None
-    return dq, dk, dv, dbias
 
 
 def _env_flag(name: str) -> bool:
@@ -802,6 +1047,10 @@ def _flash_core(q, k, v, bias, q_seg, kv_seg, seed, causal, scale,
 def _forward_impl(q, k, v, bias, q_seg, kv_seg, seed, causal, scale,
                   dropout_p, block_q, block_k, return_stats=False):
     if _pallas_available():
+        block_q, block_k = _resolve_blocks(
+            block_q, block_k, q.shape[2], k.shape[2], causal=causal,
+            full_bias=bias is not None and bias.shape[2] > 1,
+            dropout=dropout_p > 0.0)
         # A kernel that fails to trace raises: on a TPU nothing degrades
         # to the O(L^2) reference behind the caller's back.
         res = _flash_fwd_pallas(
@@ -862,7 +1111,7 @@ def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
         return (dq, dk, dv, dbias, dseg_q, dseg_kv, dseed)
     # The fallback scan keeps its own 256 cap: it materializes
     # (b, h, lq, bk) f32 score/grad tiles in HBM, so the forward kernel's
-    # 1024 tuning would quadruple live memory and can OOM long-context
+    # tiles would multiply live memory and can OOM long-context
     # training.  A caller's SMALLER explicit block_k is honored.
     bk = min(block_k or 256, 256, lk)
     n_k = -(-lk // bk)
@@ -1000,10 +1249,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
       dropout_p: attention-prob dropout; requires ``dropout_seed`` (int,
         PRNG key, or (2,) int array).  The mask is hash-derived in-kernel.
 
-    Default blocks come from ``_resolve_blocks``: 1024x1024 (clean),
-    1024x512 (dropout), 512x512 (full (Lq, Lk) bias), sized against the
-    v5e ~16 MB scoped-VMEM budget — see that function's docstring for the
-    measured limits that set them."""
+    ``block_q``/``block_k`` are the tile, (block_k, block_q) scores; on a
+    TPU ``block_q`` is a multiple of 128 (queries lie on lanes).  Default
+    tiles come from ``_resolve_blocks`` and ``_resolve_bwd_blocks``: at
+    most 1024x1024 (clean), 1024x512 (dropout), 512x512 (full (Lq, Lk)
+    bias, and every backward), sized against the v5e ~16 MB scoped-VMEM
+    budget, and for a causal call half of the sequence a side where that
+    is less (a quarter in the dk/dv kernel), so that tiles above the
+    diagonal go — see those functions' docstrings for the measured limits
+    that set them."""
     b, h, lq, d = q.shape
     lk = k.shape[2]
     scale = 1.0 / math.sqrt(d) if scale is None else scale
@@ -1021,9 +1275,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if dropout_p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
     seed = _normalize_seed(dropout_seed) if dropout_p > 0.0 else None
-    full_bias = bias is not None and bias.shape[2] > 1
-    block_q, block_k = _resolve_blocks(block_q, block_k, full_bias,
-                                       dropout=dropout_p > 0.0)
     return _flash_core(q, k, v, bias, q_segment_ids, kv_segment_ids, seed,
                        causal, scale, float(dropout_p), block_q, block_k)
 

@@ -459,11 +459,11 @@ def test_mosaic_tpu_lowering_all_variants():
     with _mosaic_module_spy():
         for name, kw in variants.items():
             b = kw.get("bias")
+            causal = kw.pop("causal", False)
             bq, bk = _resolve_blocks(
-                None, None,
+                None, None, L, L, causal=causal,
                 full_bias=b is not None and b.shape[-2] > 1,
                 dropout=kw.get("dropout_p", 0) > 0)
-            causal = kw.pop("causal", False)
 
             def fn(q, kw=kw, causal=causal, bq=bq, bk=bk):
                 return _flash_fwd_pallas(q, q, q, causal, 0.125, bq, bk, **kw)
@@ -565,6 +565,193 @@ def test_mosaic_tpu_lowering_backward(D):
                                                    **kw) ** 2)
 
                 jax.jit(jax.grad(fn)).trace(q).lower(
+                    lowering_platforms=("tpu",))
+    finally:
+        os.environ.pop("ZOO_FLASH_FORCE_PALLAS", None)
+
+
+# ---------------------------------------------------------------------------
+# The tile schedule (ROADMAP S4): operands in the inputs' dtype, causal
+# tiles skipped, masks only on the diagonal
+# ---------------------------------------------------------------------------
+
+#: (rel_l2, rel_max) against the dense float32 oracle on the same inputs.
+#: float32: the order of the sums alone differs.  bf16: the output and
+#: the gradients are rounded to bf16 (2^-9 an element), and so are P and
+#: dS at their products; what chip_smoke.py holds the kernels to on a TPU.
+SCHEDULE_TOL = {"float32": (1e-5, 1e-4), "bfloat16": (1e-2, 4e-2)}
+
+
+def _fresh_schedules():
+    """The kernels are jitted, so a call that an earlier test traced at
+    the same shapes records nothing: drop their traces and the record."""
+    import analytics_zoo_tpu.ops.pallas.flash_attention as fa
+
+    fa._flash_fwd_pallas.clear_cache()
+    fa._flash_bwd_kernel.clear_cache()
+    fa.tile_schedules.clear()
+    return fa.tile_schedules
+
+
+def _rel(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    diff = got - want
+    return (float(np.linalg.norm(diff) / np.linalg.norm(want)),
+            float(np.abs(diff).max() / np.abs(want).max()))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("length,block", [(512, 128), (1536, 256)],
+                         ids=["unrolled", "looped"])
+def test_tile_schedule_matches_reference(length, block, dtype, monkeypatch):
+    """Forward and all three gradients at a block-aligned causal shape
+    that has all three classes of tile, through the real custom_vjp route
+    in interpret mode: 4 x 4 tiles that one grid step spells out, and
+    6 x 6 that it walks in loops over two resident chunks."""
+    monkeypatch.setenv("ZOO_FLASH_INTERPRET", "1")
+    shape = (1, 2, length, 16)
+    q, k, v, g = (_rand(shape, 70 + i).astype(dtype) for i in range(4))
+    scale = 0.25
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32) * g.astype(jnp.float32))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, scale, block, block)
+
+    def dense(q, k, v):
+        return _attention_reference(q, k, v, True, scale)
+
+    wide = [x.astype(jnp.float32) for x in (q, k, v)]
+    schedules = _fresh_schedules()
+    got = [flash(q, k, v), *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)]
+    want = [dense(*wide), *jax.grad(loss(dense), argnums=(0, 1, 2))(*wide)]
+    tol_l2, tol_max = SCHEDULE_TOL[dtype]
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.dtype(dtype), name
+        rel_l2, rel_max = _rel(a, b)
+        assert rel_l2 <= tol_l2 and rel_max <= tol_max, (
+            f"{name} {dtype}: rel_l2 {rel_l2:.3g} (<= {tol_l2}), "
+            f"rel_max {rel_max:.3g} (<= {tol_max})")
+    n = length // block
+    for record in schedules:
+        assert record["operand_dtype"] == dtype
+        assert (record["skipped"], record["plain"], record["masked"]) == (
+            n * (n - 1) // 2, n * (n - 1) // 2, n), record
+    assert {r["kernel"] for r in schedules} == {"forward", "dq", "dkv"}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_tile_schedule_record_at_the_cell_shape(dtype, monkeypatch):
+    """The trace-time record of `gpt2-small-fit`'s call, (8, 12, 1024, 64)
+    causal: tiles skipped in all three kernels, masks on the diagonal's
+    tiles only, operands in the inputs' dtype.  Traced, not run."""
+    monkeypatch.setenv("ZOO_FLASH_FORCE_PALLAS", "1")
+    q = jax.ShapeDtypeStruct((8, 12, 1024, 64), jnp.dtype(dtype))
+    schedules = _fresh_schedules()
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2)),
+        q, q, q)
+    records = {r["kernel"]: r for r in schedules}
+    assert set(records) == {"forward", "dq", "dkv"}
+    for record in records.values():
+        assert record["shape"] == (8, 12, 1024, 1024, 64)
+        assert record["operand_dtype"] == dtype
+        bq, bk = record["blocks"]
+        assert bq == bk and 1024 % bq == 0
+        diagonal = 1024 // bq
+        assert record["masked"] == diagonal
+        assert record["skipped"] == diagonal * (diagonal - 1) // 2 > 0
+        assert record["plain"] == record["skipped"]
+
+
+def test_tile_schedule_masks_every_tile_of_a_call_that_needs_it(monkeypatch):
+    """Bias, segment ids, dropout or a ragged edge: no plain tile."""
+    monkeypatch.setenv("ZOO_FLASH_FORCE_PALLAS", "1")
+    q = jax.ShapeDtypeStruct((2, 2, 1024, 64), jnp.bfloat16)
+    ragged = jax.ShapeDtypeStruct((2, 2, 1000, 64), jnp.bfloat16)
+    segs = jax.ShapeDtypeStruct((2, 1024), jnp.int32)
+    bias = jax.ShapeDtypeStruct((2, 1, 1, 1024), jnp.float32)
+    calls = {
+        "dropout": lambda: jax.eval_shape(
+            lambda q: flash_attention(q, q, q, causal=True, dropout_p=0.1,
+                                      dropout_seed=3), q),
+        "segments": lambda: jax.eval_shape(
+            lambda q, s: flash_attention(q, q, q, causal=True,
+                                         q_segment_ids=s,
+                                         kv_segment_ids=s), q, segs),
+        "bias": lambda: jax.eval_shape(
+            lambda q, b: flash_attention(q, q, q, causal=True, bias=b),
+            q, bias),
+        "ragged": lambda: jax.eval_shape(
+            lambda q: flash_attention(q, q, q, causal=True), ragged),
+    }
+    for name, call in calls.items():
+        schedules = _fresh_schedules()
+        call()
+        (record,) = schedules
+        assert record["plain"] == 0 and record["masked"] > 0, (name, record)
+        assert record["skipped"] > 0, (name, record)
+
+
+@pytest.mark.parametrize("lq,lk,bq,bk", [
+    (1024, 1024, 256, 256), (1024, 1024, 512, 256), (1024, 1024, 128, 512),
+    (600, 700, 128, 256), (700, 600, 256, 128), (256, 1024, 128, 128),
+])
+@pytest.mark.parametrize("all_masked", [False, True])
+def test_tile_ranges_agree_with_the_positions(lq, lk, bq, bk, all_masked):
+    """`_k_tile_range` (forward, dq) and `_q_tile_range` (dk/dv) put every
+    tile in the class that its positions give it, end-aligned diagonal
+    and ragged edges included."""
+    from analytics_zoo_tpu.ops.pallas.flash_attention import (
+        _k_tile_range,
+        _q_tile_range,
+    )
+
+    offset = lk - lq
+    n_q, n_k = -(-lq // bq), -(-lk // bk)
+    # over the tiles' whole extent: a ragged edge is not the diagonal's
+    # business (a call that has one masks every tile it visits)
+    live = (np.arange(n_q * bq)[:, None] + offset
+            >= np.arange(n_k * bk)[None, :])
+    want = {}
+    for i in range(n_q):
+        for j in range(n_k):
+            tile = live[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+            want[i, j] = ("skipped" if not tile.any() else "masked"
+                          if all_masked or not tile.all() else "plain")
+
+    def name(j, plain, need):
+        return "plain" if j < plain else "masked" if j < need else "skipped"
+
+    by_q = {(i, j): name(j, *_k_tile_range(i * bq, bq, bk, offset, n_k,
+                                           True, all_masked))
+            for i in range(n_q) for j in range(n_k)}
+    by_k = {}
+    for j in range(n_k):
+        first, plain = _q_tile_range(j * bk, bq, bk, offset, n_q, True,
+                                     all_masked)
+        for i in range(n_q):
+            by_k[i, j] = ("skipped" if i < first else "masked"
+                          if i < plain else "plain")
+    assert by_q == want
+    assert by_k == want
+
+
+def test_mosaic_tpu_lowering_gpt2_cell():
+    """`gpt2-small-fit`'s own call, (8, 12, 1024, 64) causal bf16,
+    forward and backward, cross-lowered for the TPU backend."""
+    import os
+
+    q = jnp.zeros((8, 12, 1024, 64), jnp.bfloat16)
+    os.environ["ZOO_FLASH_FORCE_PALLAS"] = "1"
+    try:
+        with _mosaic_module_spy():
+            jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, causal=True).astype(jnp.float32)),
+                argnums=(0, 1, 2))).trace(q, q, q).lower(
                     lowering_platforms=("tpu",))
     finally:
         os.environ.pop("ZOO_FLASH_FORCE_PALLAS", None)

@@ -114,6 +114,13 @@ CASES = {
     "flash_fwd": lambda: _flash((8, 12, 2048, 64), False, causal=True),
     "flash_fwd_bwd_causal":
         lambda: _flash((4, 12, 2048, 64), True, causal=True),
+    # `gpt2-small-fit`'s own call
+    "flash_fwd_bwd_gpt2_cell":
+        lambda: _flash((8, 12, 1024, 64), True, causal=True),
+    # the shape `_resolve_blocks` sizes its VMEM caps against
+    "flash_fwd_bwd_vmem_caps":
+        lambda: _flash((1, 2, 4096, 128), True, causal=True,
+                       dropout_p=0.1, dropout_seed=7),
     "flash_fwd_bwd_dropout":
         lambda: _flash((4, 32, 1024, 80), True, causal=True,
                        dropout_p=0.1, dropout_seed=7),
