@@ -34,17 +34,23 @@ import jax
 import jax.numpy as jnp
 
 
+#: state leaves that join the training loss: an MoE stack's pre-weighted
+#: load-balancing loss, a looped decoder's exit-gate loss
+COST_LEAVES = ("moe_aux_cost", "loop_exit_cost")
+
+
 def collect_aux_cost(state):
-    """Sum every ``moe_aux_cost`` leaf in a model state tree: the
-    pre-weighted auxiliary losses MoE stacks report through the layer
-    state channel (keras/layers/self_attention.py ``_moe_state``).  Every
-    train-step builder that computes a loss from ``model.forward`` must
-    add this to the task loss, or a collapsed router trains unpenalized."""
+    """Sum every ``COST_LEAVES`` leaf in a model state tree: the costs
+    layers report through the layer state channel (keras/layers/
+    self_attention.py: ``_moe_state``'s pre-weighted auxiliary loss, a
+    ``LoopedDecoder``'s own training loss).  Every train-step builder that
+    computes a loss from ``model.forward`` must add this to the task loss,
+    or a collapsed router trains unpenalized."""
     total = jnp.zeros((), jnp.float32)
     for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
         last = path[-1]
         key = getattr(last, "key", getattr(last, "name", None))
-        if key == "moe_aux_cost":
+        if key in COST_LEAVES:
             total = total + leaf.astype(jnp.float32)
     return total
 

@@ -1204,6 +1204,17 @@ def build_mesh(mesh_shape: Mapping[str, int] | None = None,
 # ---------------------------------------------------------------------------
 
 
+def _lower_seconds(label: str):
+    from analytics_zoo_tpu.common.compile_cache import COMPILE_BUCKETS
+    from analytics_zoo_tpu.metrics import get_registry
+
+    return get_registry().histogram(
+        "zoo_lower_seconds",
+        "wall time of jit(...).lower(): tracing the step and lowering it, "
+        "before zoo_compile_seconds' compile",
+        ("label",), buckets=COMPILE_BUCKETS).labels(label=label)
+
+
 class PlannedStep:
     """A step function compiled through the choke point.
 
@@ -1260,12 +1271,17 @@ class PlannedStep:
 
     def __call__(self, *args):
         from analytics_zoo_tpu.common.compile_cache import timed_compile
+        from analytics_zoo_tpu.metrics import span
 
         key = self._sig(args)
         exe = self._exes.get(key)
         if exe is None:
-            exe = timed_compile(self._jitted.lower(*args), self.label,
-                                meta=self.meta)
+            # tracing and lowering, apart from the compile that follows:
+            # where a model's depth (a loop traced a pass at a time) shows
+            with span("zoo.compile.lower",
+                      observe=_lower_seconds(self.label).observe):
+                lowered = self._jitted.lower(*args)
+            exe = timed_compile(lowered, self.label, meta=self.meta)
             while len(self._exes) >= self._MAX_EXES:
                 self._exes.pop(next(iter(self._exes)))
             self._exes[key] = exe

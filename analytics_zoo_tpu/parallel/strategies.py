@@ -48,6 +48,7 @@ def make_shard_map_train_step(model, loss_fn, optimizer, mesh=None,
         _normalize_grad_clip,
     )
 
+    _refuse_in_model_loss(loss_fn)
     grad_clip = _normalize_grad_clip(grad_clip)
     mesh = mesh or get_zoo_context().mesh
 
@@ -87,6 +88,14 @@ def make_shard_map_train_step(model, loss_fn, optimizer, mesh=None,
         in_specs=(repl, repl, repl, repl, batch_spec),
         out_specs=(repl, repl, repl, repl),
         donate_argnums=(0, 1, 2), label="shard_map_step")
+
+
+def _refuse_in_model_loss(loss_fn) -> None:
+    if getattr(loss_fn, "in_model", False):
+        raise NotImplementedError(
+            f"loss {loss_fn.name!r} is taken inside the model from the "
+            "step's targets, which only the estimator's GSPMD train step "
+            "hands over")
 
 
 def _ring_reduce_scatter(flat, n, axis_name=DATA_AXIS):
@@ -165,6 +174,7 @@ def make_zero1_train_step(model, loss_fn, optimizer, mesh=None,
     )
 
     # same grad_clip contract as make_shard_map_train_step / the Estimator
+    _refuse_in_model_loss(loss_fn)
     _clip = _normalize_grad_clip(grad_clip)
     mesh = mesh or get_zoo_context().mesh
     n = mesh.shape[DATA_AXIS]

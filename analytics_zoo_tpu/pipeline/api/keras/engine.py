@@ -23,6 +23,7 @@ user-facing ``input_shape`` excludes the batch dim; internal full shapes carry
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 from typing import Any, Callable, Sequence
 
@@ -220,6 +221,35 @@ def Input(shape=None, name: str | None = None) -> Variable:
     node = Node(layer, [], [var])
     var.node = node
     return var
+
+
+# ---------------------------------------------------------------------------
+# The step's targets, for a loss that a layer takes itself
+# ---------------------------------------------------------------------------
+
+# entered by a train-step builder for the duration of TRACING the forward
+# pass, innermost last (the idiom of parallel/plan.py's active plans)
+_TRAINING_TARGETS: list = []
+
+
+@contextlib.contextmanager
+def training_targets(y):
+    """Hand the step's targets to the layers of the forward pass traced
+    inside the block.  A loss over several sets of logits (a looped
+    decoder's passes) is taken where each set is made, one at a time, so
+    that they are never all live; such a layer reads the targets with
+    :func:`current_targets` and reports its loss under a ``*_cost`` leaf
+    of its state (``objectives.InModelLoss``).  ``None`` hands nothing."""
+    _TRAINING_TARGETS.append(y)
+    try:
+        yield
+    finally:
+        _TRAINING_TARGETS.pop()
+
+
+def current_targets():
+    """The targets of the train step being traced, or None."""
+    return _TRAINING_TARGETS[-1] if _TRAINING_TARGETS else None
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,8 @@ sharded) variants swap in without touching this layer.  Long-context support
 
 from __future__ import annotations
 
+import collections
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -26,6 +28,7 @@ from analytics_zoo_tpu.ops.attention import (
 )
 from analytics_zoo_tpu.pipeline.api.keras.engine import (
     Layer,
+    current_targets,
     get_initializer,
 )
 
@@ -41,7 +44,9 @@ class _TransformerCore(Layer):
                  hidden_drop=0.1, attn_drop=0.1, initializer_range=0.02,
                  bidirectional=False, activation="gelu", remat=False,
                  moe_experts=0, moe_top_k=2, moe_capacity_factor=1.25,
-                 moe_aux_weight=0.01, input_shape=None, name=None, **kwargs):
+                 moe_aux_weight=0.01, norm="layer", norm_placement="after",
+                 norm_eps=1e-5, rotary_theta=None, gated_ffn=False,
+                 use_bias=True, input_shape=None, name=None, **kwargs):
         super().__init__(input_shape=input_shape, name=name, **kwargs)
         self.n_block = int(n_block)
         self.n_head = int(n_head)
@@ -51,6 +56,34 @@ class _TransformerCore(Layer):
         self.attn_drop = float(attn_drop)
         self.initializer_range = float(initializer_range)
         self.bidirectional = bool(bidirectional)
+        # The block is assembled from what the configuration says; the
+        # defaults are the source system's block (GPT-1's), so
+        # TransformerLayer and BERT keep their parameter trees.
+        #   norm: "layer" (gamma and beta) or "rms" (gamma, in float32);
+        #   norm_placement: "after" h = norm(h + branch(h)), "before"
+        #     h = h + branch(norm(h)), "around" h = h + norm(branch(norm(h)))
+        #     (four norms a block: ln1, ln2 about attention, ln3, ln4
+        #     about the feed-forward);
+        #   rotary_theta: rotary positions on q and k (rotate-half over the
+        #     whole head) where a number; None leaves positions to the
+        #     embedding;
+        #   gated_ffn: (act(u Wgate) * (u Wfc)) Wout in place of
+        #     act(u Wfc) Wout;  use_bias: no ``*_bias`` leaf when False.
+        if norm not in ("layer", "rms"):
+            raise ValueError(f"norm must be 'layer' or 'rms'; got {norm!r}")
+        if norm_placement not in ("after", "before", "around"):
+            raise ValueError("norm_placement must be 'after', 'before' or "
+                             f"'around'; got {norm_placement!r}")
+        self.norm = norm
+        self.norm_placement = norm_placement
+        self.norm_eps = float(norm_eps)
+        self.rotary_theta = None if rotary_theta is None \
+            else float(rotary_theta)
+        self.gated_ffn = bool(gated_ffn)
+        self.use_bias = bool(use_bias)
+        if moe_experts and (self.gated_ffn or not self.use_bias):
+            raise ValueError("the routed feed-forward is the plain one "
+                             "with biases: no gated_ffn, no use_bias=False")
         # moe_experts > 0 swaps every block's dense feed-forward for a
         # routed mixture of experts (ops.moe.routed_ffn: GShard top-k +
         # capacity, dense-dispatch so the GSPMD train step shards the
@@ -91,18 +124,23 @@ class _TransformerCore(Layer):
         self.act = get_activation(activation)
 
     # -- param construction (nested; overrides the flat-spec default) ------
+    @property
+    def _n_norms(self):
+        return 4 if self.norm_placement == "around" else 2
+
     def _block_params(self, rng):
         d, m = self.hidden_size, self.intermediate_size
         std = self.initializer_range
         ks = jax.random.split(rng, 6)
         p = {
             "qkv_kernel": _dense_init(ks[0], (d, 3 * d), std),
-            "qkv_bias": jnp.zeros((3 * d,)),
             "proj_kernel": _dense_init(ks[1], (d, d), std),
-            "proj_bias": jnp.zeros((d,)),
-            "ln1_gamma": jnp.ones((d,)), "ln1_beta": jnp.zeros((d,)),
-            "ln2_gamma": jnp.ones((d,)), "ln2_beta": jnp.zeros((d,)),
         }
+        bias = {"qkv_bias": 3 * d, "proj_bias": d}
+        for i in range(1, self._n_norms + 1):
+            p[f"ln{i}_gamma"] = jnp.ones((d,))
+            if self.norm == "layer":
+                p[f"ln{i}_beta"] = jnp.zeros((d,))
         if self.moe_experts:
             e = self.moe_experts
             p.update({
@@ -115,10 +153,14 @@ class _TransformerCore(Layer):
         else:
             p.update({
                 "fc_kernel": _dense_init(ks[2], (d, m), std),
-                "fc_bias": jnp.zeros((m,)),
                 "out_kernel": _dense_init(ks[3], (m, d), std),
-                "out_bias": jnp.zeros((d,)),
             })
+            bias.update(fc_bias=m, out_bias=d)
+            if self.gated_ffn:
+                p["gate_kernel"] = _dense_init(ks[4], (d, m), std)
+                bias["gate_bias"] = m
+        if self.use_bias:
+            p.update({k: jnp.zeros((n,)) for k, n in bias.items()})
         return p
 
     @property
@@ -142,11 +184,14 @@ class _TransformerCore(Layer):
 
     def _per_block_param_count(self):
         d, m = self.hidden_size, self.intermediate_size
-        attn = 3 * d * d + 3 * d + d * d + d + 4 * d  # qkv + proj + 2 LN
+        b = 1 if self.use_bias else 0
+        norms = self._n_norms * d * (2 if self.norm == "layer" else 1)
+        attn = 3 * d * d + d * d + b * 4 * d + norms    # qkv + proj + norms
         if self.moe_experts:
             e = self.moe_experts
             return attn + d * e + e * (2 * d * m + m) + d
-        return attn + 2 * d * m + m + d
+        n_in = 2 if self.gated_ffn else 1
+        return attn + (n_in + 1) * d * m + b * (n_in * m + d)
 
     @staticmethod
     def _ln(x, gamma, beta, eps=1e-5):
@@ -202,41 +247,102 @@ class _TransformerCore(Layer):
         # (parallel/pipeline.py), which carry dense blocks only
         return self._block_forward_aux(bp, h, mask, training, brng)[0]
 
-    def _block_forward_aux(self, bp, h, mask, training, brng):
-        qkv = h @ bp["qkv_kernel"] + bp["qkv_bias"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = split_heads(q, self.n_head)
-        k = split_heads(k, self.n_head)
-        v = split_heads(v, self.n_head)
-        a = dot_product_attention(
-            q, k, v, mask=mask,
-            dropout_p=self.attn_drop if training else 0.0,
-            rng=(jax.random.fold_in(brng, 3)
-                 if brng is not None else None),
-            causal=not self.bidirectional,
-        )
-        a = checkpoint_name(a, "attn_context")
-        a = merge_heads(a) @ bp["proj_kernel"] + bp["proj_bias"]
-        a = self._drop(a, self.hidden_drop, training, brng, 1)
-        h = self._ln(h + a, bp["ln1_gamma"], bp["ln1_beta"])
-        aux = jnp.zeros((), jnp.float32)
-        drop = jnp.zeros((), jnp.float32)
-        if "moe_gate" in bp:
-            from analytics_zoo_tpu.ops.moe import routed_ffn
+    def _norm(self, x, bp, i):
+        """The block's ``i``-th norm (1-based) of ``x``."""
+        gamma = bp[f"ln{i}_gamma"]
+        if self.norm == "layer":
+            return self._ln(x, gamma, bp[f"ln{i}_beta"], self.norm_eps)
+        return _rms_norm(x, gamma, self.norm_eps)
 
-            # routed FFN behind the residual: an over-capacity token's
-            # zero expert output degrades to identity, never to a zeroed
-            # activation (tests/test_moe_layer.py pins this)
-            f, aux, drop = routed_ffn(
-                h, bp["moe_gate"], bp["moe_w1"], bp["moe_b1"],
-                bp["moe_w2"], bp["moe_b2"], top_k=self.moe_top_k,
-                capacity_factor=self.moe_capacity_factor,
-                activation=self.act)
-        else:
-            f = self.act(h @ bp["fc_kernel"] + bp["fc_bias"])
-            f = f @ bp["out_kernel"] + bp["out_bias"]
-        f = self._drop(f, self.hidden_drop, training, brng, 2)
-        return self._ln(h + f, bp["ln2_gamma"], bp["ln2_beta"]), aux, drop
+    def _branch(self, branch, h, bp, first, training, brng, salt):
+        """One residual branch under the configured norm placement; its
+        norms are ``first`` and, around a branch, ``first + 1``."""
+        place = self.norm_placement
+        out = branch(self._norm(h, bp, first) if place != "after" else h)
+        out = self._drop(out, self.hidden_drop, training, brng, salt)
+        if place == "after":
+            return self._norm(h + out, bp, first)
+        if place == "around":
+            out = self._norm(out, bp, first + 1)
+        return h + out
+
+    def _block_forward_aux(self, bp, h, mask, training, brng):
+        def dense(x, name):
+            y = x @ bp[name + "_kernel"]
+            return y + bp[name + "_bias"] if self.use_bias else y
+
+        def attention(u):
+            q, k, v = jnp.split(dense(u, "qkv"), 3, axis=-1)
+            q = split_heads(q, self.n_head)
+            k = split_heads(k, self.n_head)
+            v = split_heads(v, self.n_head)
+            if self.rotary_theta is not None:
+                q, k = _rotary(q, k, self.rotary_theta)
+            a = dot_product_attention(
+                q, k, v, mask=mask,
+                dropout_p=self.attn_drop if training else 0.0,
+                rng=(jax.random.fold_in(brng, 3)
+                     if brng is not None else None),
+                causal=not self.bidirectional,
+            )
+            a = checkpoint_name(a, "attn_context")
+            return dense(merge_heads(a), "proj")
+
+        aux = drop = jnp.zeros((), jnp.float32)
+
+        def feed_forward(u):
+            nonlocal aux, drop
+            if "moe_gate" in bp:
+                from analytics_zoo_tpu.ops.moe import routed_ffn
+
+                # routed FFN behind the residual: an over-capacity
+                # token's zero expert output degrades to identity, never
+                # to a zeroed activation (tests/test_moe_layer.py pins it)
+                f, aux, drop = routed_ffn(
+                    u, bp["moe_gate"], bp["moe_w1"], bp["moe_b1"],
+                    bp["moe_w2"], bp["moe_b2"], top_k=self.moe_top_k,
+                    capacity_factor=self.moe_capacity_factor,
+                    activation=self.act)
+                return f
+            f = self.act(dense(u, "gate" if self.gated_ffn else "fc"))
+            if self.gated_ffn:
+                f = f * dense(u, "fc")
+            return dense(f, "out")
+
+        n = self._n_norms // 2
+        h = self._branch(attention, h, bp, 1, training, brng, 1)
+        h = self._branch(feed_forward, h, bp, 1 + n, training, brng, 2)
+        return h, aux, drop
+
+
+def _rms_norm(x, gamma, eps):
+    """x * rsqrt(mean(x^2) + eps) * gamma, taken in float32 whatever the
+    compute dtype (a bf16 mean of squares over the width loses the
+    statistic), handed on in ``x``'s dtype."""
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+                          + eps)
+    return (x32 * scale * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rotary(q, k, theta):
+    """Rotary positions 0..L-1 on (B, H, L, hd) queries and keys, the
+    rotate-half form over the whole head: x cos + rotate_half(x) sin with
+    the angle of pair i at position p being p * theta^(-2i/hd).  The
+    tables are float32; the result keeps the inputs' dtype."""
+    l, hd = q.shape[-2], q.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    angles = jnp.arange(l, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+
+    def turn(x):
+        x32 = x.astype(jnp.float32)
+        x1, x2 = jnp.split(x32, 2, axis=-1)
+        half = jnp.concatenate([-x2, x1], axis=-1)
+        return (x32 * cos + half * sin).astype(x.dtype)
+
+    return turn(q), turn(k)
 
 
 class TransformerLayer(_TransformerCore):
@@ -386,3 +492,194 @@ class BERT(_TransformerCore):
             else input_shape
         b, l = shape[0], shape[1]
         return [(b, l, self.hidden_size), (b, self.hidden_size)]
+
+
+#: Trace-time record of each looped stack traced, newest last (plain
+#: arithmetic on static structure, like ``flash_attention.tile_schedules``:
+#: jit traces once, so it counts compilations): ``layer``, ``training``,
+#: ``passes``, ``layers``, ``layer_applications`` (passes x layers, over one
+#: set of weights), ``head_evaluations`` (one a pass where the layer takes
+#: the exit-gate loss itself, one where only the last pass's logits are
+#: made), ``loop`` (how the passes are traced: unrolled), ``remat`` (the policy
+#: resolved for a layer application) and ``loss_blocks`` (token blocks a
+#: pass's head and cross-entropy are taken in; 0 without the loss).
+loop_records: collections.deque = collections.deque(maxlen=16)
+
+
+class LoopedDecoder(_TransformerCore):
+    """Looped language model (Ouro's shape): token embedding, then ONE
+    stack of ``n_block`` layers run ``passes`` times over the same weights,
+    the final RMSNorm after every pass, an untied head and an exit gate.
+
+    A layer is RMSNorm around both branches (four norms), rotary positions
+    on q and k, causal attention, a gated SiLU feed-forward, no bias.  For
+    tokens x: h = E[x]; for pass t = 1..T: h = s_t = RMSNorm(stack(h));
+    logits_t = s_t W_head; lambda_t = sigmoid(s_t . w_exit + b_exit).
+    Input (B, L) token ids, output logits_T (B, L, vocab).
+
+    Trained with ``loss="looped_exit_cross_entropy"`` the layer takes the
+    loss itself, a pass at a time in blocks of ``loss_block`` tokens,
+    recomputed in the backward pass, so that only one block's logits are
+    ever live: with exit distribution p_t = lambda_t prod_{j<t}
+    (1 - lambda_j), p_T = prod_{j<T} (1 - lambda_j), a token costs
+    sum_t p_t CE(logits_t, y) - exit_beta H(p).  It reports the mean under
+    ``loop_exit_cost`` of its state, which the train step adds to the
+    loss, with ``loop_exit_mass`` (mean p_t) and ``loop_pass_loss`` (mean
+    CE_t), a number a pass.  With any other loss only logits_T is made.
+    """
+
+    def __init__(self, vocab, n_block, n_head, hidden_size,
+                 intermediate_size, passes=4, rotary_theta=1e6,
+                 norm_eps=1e-6, exit_beta=0.05, loss_block=2048,
+                 remat="full", **kwargs):
+        super().__init__(
+            n_block=n_block, n_head=n_head, hidden_size=hidden_size,
+            intermediate_size=intermediate_size, hidden_drop=0.0,
+            attn_drop=0.0, activation="silu", remat=remat, norm="rms",
+            norm_placement="around", norm_eps=norm_eps,
+            rotary_theta=rotary_theta, gated_ffn=True, use_bias=False,
+            **kwargs)
+        self.vocab = int(vocab)
+        self.passes = int(passes)
+        self.exit_beta = float(exit_beta)
+        self.loss_block = int(loss_block)
+        if self.passes < 1:
+            raise ValueError(f"passes={passes} < 1")
+
+    def build(self, input_shape):
+        pass  # params are nested; built in init_params
+
+    def init_params(self, rng):
+        std, d = self.initializer_range, self.hidden_size
+        ks = jax.random.split(rng, 3 + self.n_block)
+        return {
+            "tok_embed": _dense_init(ks[0], (self.vocab, d), std),
+            "blocks": [self._block_params(ks[3 + i])
+                       for i in range(self.n_block)],
+            "final_gamma": jnp.ones((d,)),
+            "head_kernel": _dense_init(ks[1], (d, self.vocab), std),
+            "exit_kernel": _dense_init(ks[2], (d, 1), std),
+            "exit_bias": jnp.zeros((1,)),
+        }
+
+    def param_count(self):
+        d = self.hidden_size
+        return (2 * self.vocab * d + 2 * d + 1
+                + self.n_block * self._per_block_param_count())
+
+    @property
+    def stateful(self):
+        return True
+
+    def init_state(self):
+        per_pass = jnp.zeros((self.passes,), jnp.float32)
+        return {"loop_exit_cost": jnp.zeros((), jnp.float32),
+                "loop_exit_mass": per_pass, "loop_pass_loss": per_pass}
+
+    def compute_output_shape(self, input_shape):
+        if isinstance(input_shape, list):
+            input_shape = input_shape[0]
+        return tuple(input_shape) + (self.vocab,)
+
+    # -- one pass's head, gate and loss ---------------------------------
+    def _loss_blocks(self, batch, length):
+        """How many blocks of positions a pass's cross-entropy is taken
+        in: the fewest that divide ``length`` and keep a block of the
+        whole batch at ``loss_block`` tokens or under."""
+        return next((n for n in range(1, length + 1)
+                     if length % n == 0
+                     and batch * (length // n) <= self.loss_block), length)
+
+    def _token_ce(self, kernel, s, targets):
+        """CE(s W_head, y) a token, float32 (B, L): a block of positions
+        at a time, each block's logits made again in the backward pass."""
+        from analytics_zoo_tpu.parallel.plan import apply_remat
+
+        b, l, d = s.shape
+        n = self._loss_blocks(b, l)
+
+        def blocked(x):     # (B, L, ...) -> (n, B, L / n, ...)
+            return jnp.moveaxis(
+                x.reshape((b, n, l // n) + x.shape[2:]), 1, 0)
+
+        def block_ce(args):
+            s_blk, y_blk = args
+            logits = (s_blk @ kernel).astype(jnp.float32)
+            picked = jnp.take_along_axis(logits, y_blk[..., None],
+                                         axis=-1)[..., 0]
+            return jax.nn.logsumexp(logits, axis=-1) - picked
+
+        ce = jax.lax.map(apply_remat(block_ce, "full"),
+                         (blocked(s), blocked(targets.astype(jnp.int32))))
+        return jnp.moveaxis(ce, 0, 1).reshape(b, l)
+
+    def _exit_tail(self, params, s, targets, log_survive, last):
+        """After one pass: its cost to the loss, mean exit mass and mean
+        CE, and log prod_{j<=t} (1 - lambda_j) for the next.  ``last``:
+        the final pass takes what is left, whatever its gate says."""
+        ce = self._token_ce(params["head_kernel"], s, targets)
+        z = (s.astype(jnp.float32)
+             @ params["exit_kernel"].astype(jnp.float32))[..., 0] \
+            + params["exit_bias"].astype(jnp.float32)
+        log_p = log_survive if last \
+            else jax.nn.log_sigmoid(z) + log_survive
+        p = jnp.exp(log_p)
+        # sum_t p_t CE_t - beta H(p) = sum_t p_t (CE_t + beta log p_t)
+        cost = jnp.mean(p * (ce + self.exit_beta * log_p))
+        return (cost, jnp.mean(p), jnp.mean(ce),
+                log_survive + jax.nn.log_sigmoid(-z))
+
+    def call(self, params, inputs, state=None, training=False, rng=None):
+        from analytics_zoo_tpu.parallel.plan import (
+            apply_remat,
+            resolve_remat,
+        )
+
+        tokens = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        targets = current_targets() if training else None
+        h = jnp.take(params["tok_embed"], tokens.astype(jnp.int32), axis=0)
+
+        # The final norm keeps its input alone for the backward pass, as a
+        # layer application does under its policy.  The tail is NOT under
+        # jax.checkpoint (its cross-entropy blocks are): recomputed on the
+        # chip in the backward pass its float32 arithmetic lost the gate's
+        # gradient, which is a small difference between the passes' large
+        # shares (PERF.md, PR 27); it keeps a few (B, L) arrays a pass.
+        final = apply_remat(
+            lambda gamma, h: _rms_norm(h, gamma, self.norm_eps), "full")
+
+        def one_pass(h):
+            h = self._run_blocks(params["blocks"], h, None, training, None)
+            return final(params["final_gamma"], h)
+
+        takes_loss = targets is not None
+        loop_records.append({
+            "layer": self.name, "training": bool(training),
+            "passes": self.passes, "layers": self.n_block,
+            "layer_applications": self.passes * self.n_block,
+            "head_evaluations": self.passes if takes_loss else 1,
+            "loop": "unrolled",
+            "remat": resolve_remat(self.name or "blocks",
+                                   default=self.remat),
+            "loss_blocks": self._loss_blocks(*tokens.shape)
+            if takes_loss else 0})
+        # The passes are unrolled: on the chip a step is 1.8% shorter than
+        # with them in a lax.scan (PERF.md, PR 27), for a longer compile.
+        if not takes_loss:
+            for _ in range(self.passes):
+                h = one_pass(h)
+            # training under another loss: nothing of the gate's to report
+            return h @ params["head_kernel"], \
+                self.init_state() if training or state is None else state
+        log_survive = jnp.zeros(tokens.shape, jnp.float32)
+        costs, mass, pass_loss = [], [], []
+        for t in range(self.passes):
+            h = one_pass(h)
+            cost, p_mean, ce_mean, log_survive = self._exit_tail(
+                params, h, targets, log_survive, t == self.passes - 1)
+            costs.append(cost)
+            mass.append(p_mean)
+            pass_loss.append(ce_mean)
+        return h @ params["head_kernel"], {
+            "loop_exit_cost": sum(costs), "loop_exit_mass": jnp.stack(mass),
+            "loop_pass_loss": jnp.stack(pass_loss)}

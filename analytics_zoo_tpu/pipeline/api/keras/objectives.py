@@ -24,6 +24,10 @@ _EPS = 1e-7
 class LossFunction:
     """Callable loss with a name; subclass or wrap a function."""
 
+    #: True where a layer of the model takes the training loss itself
+    #: (:class:`InModelLoss`)
+    in_model = False
+
     def __init__(self, fn, name):
         self.fn = fn
         self.name = name
@@ -248,6 +252,30 @@ def SparseCategoricalCrossEntropy():
                         "sparse_categorical_crossentropy")
 
 
+class InModelLoss(LossFunction):
+    """A training loss that a layer of the model takes itself, where its
+    logits are made, because it needs the targets beside several sets of
+    logits that must not all be live at once (``LoopedDecoder``: every
+    pass's logits, weighted by an exit distribution).
+
+    The train step hands the batch's targets to the forward pass
+    (``engine.training_targets``), calls no ``mean`` and minimises what
+    the layers report under the ``*_cost`` leaves of their state
+    (``ops.moe.collect_aux_cost``).  ``fn`` is the loss of the model's
+    OUTPUT alone and serves ``evaluate``: for the looped decoder the last
+    pass's cross-entropy, the pass inference reads.  Sample weights are
+    not supported."""
+
+    in_model = True
+
+
+def LoopedExitCrossEntropy():
+    """sum_t p_t CE(logits_t, y) - beta H(p) over a looped decoder's
+    passes (``layers.LoopedDecoder`` takes it and holds beta)."""
+    return InModelLoss(sparse_categorical_crossentropy_from_logits,
+                       "looped_exit_cross_entropy")
+
+
 class RankHinge(LossFunction):
     """Pairwise ranking hinge (reference RankHinge.scala)."""
 
@@ -267,6 +295,8 @@ def get_loss(identifier) -> LossFunction:
                             getattr(identifier, "__name__", "custom"))
     if isinstance(identifier, str):
         key = identifier.lower()
+        if key == "looped_exit_cross_entropy":
+            return LoopedExitCrossEntropy()
         if key in _LOSSES:
             return LossFunction(_LOSSES[key], key)
     raise ValueError(f"unknown loss {identifier!r}")

@@ -91,6 +91,30 @@ def _process_shard() -> tuple[int, int] | None:
 
 
 from analytics_zoo_tpu.ops.moe import collect_aux_cost as _collect_aux_cost
+from analytics_zoo_tpu.pipeline.api.keras.engine import training_targets
+
+
+def _publish_loop_gauges(state) -> None:
+    """A looped decoder's per-pass numbers of the epoch's last step, from
+    the layer-state channel into ``zoo_loop_exit_mass{label=<pass>}`` and
+    ``zoo_loop_pass_loss{label=<pass>}``.  Called after the epoch's closing
+    sync and at no other time: the state is then computed, so the fetch
+    waits for nothing."""
+    if not isinstance(state, dict):
+        return
+    for key, family, text in (
+            ("loop_exit_mass", "zoo_loop_exit_mass",
+             "mean exit probability of a looped decoder's pass over the "
+             "last step's tokens"),
+            ("loop_pass_loss", "zoo_loop_pass_loss",
+             "mean cross-entropy of a looped decoder's pass over the last "
+             "step's tokens")):
+        if key in state:
+            gauge = get_registry().gauge(family, text, ("label",))
+            for t, value in enumerate(np.asarray(state[key]), start=1):
+                gauge.labels(label=str(t)).set(float(value))
+    for child in state.values():
+        _publish_loop_gauges(child)
 
 
 def _normalize_grad_clip(grad_clip):
@@ -977,15 +1001,28 @@ class Estimator:
                 else:
                     pc = cast_floats(p, compute_dtype)
                     xc = cast_floats(batch["x"], compute_dtype)
-                preds, new_state = model.forward(
-                    pc, xc, state=state, training=True, rng=rng
-                )
-                preds = cast_floats(preds, jnp.float32)
-                l = loss_fn.mean(batch.get("y"), preds, batch.get("w"))
-                # Auxiliary losses reported through the layer-state channel
-                # (MoE load balancing: each stack stores its pre-weighted
-                # contribution under `moe_aux_cost`) join the training
-                # loss; eval loss stays the task loss alone.
+                # A loss that a layer takes itself (InModelLoss: a looped
+                # decoder's exit-gate loss) gets the targets where its
+                # logits are made and comes back under a `*_cost` leaf of
+                # the state; `preds` is then dead code in this program.
+                in_model = getattr(loss_fn, "in_model", False)
+                if in_model and batch.get("w") is not None:
+                    raise ValueError(f"loss {loss_fn.name!r} is taken "
+                                     "inside the model: no sample weights")
+                with training_targets(batch.get("y") if in_model else None):
+                    preds, new_state = model.forward(
+                        pc, xc, state=state, training=True, rng=rng
+                    )
+                if in_model:
+                    l = jnp.zeros((), jnp.float32)
+                else:
+                    preds = cast_floats(preds, jnp.float32)
+                    l = loss_fn.mean(batch.get("y"), preds, batch.get("w"))
+                # Costs reported through the layer-state channel (MoE load
+                # balancing: each stack stores its pre-weighted
+                # contribution under `moe_aux_cost`; an in-model loss under
+                # `loop_exit_cost`) join the training loss; eval loss
+                # stays the task loss alone.
                 l = l + _collect_aux_cost(new_state)
                 return l, new_state
 
@@ -1726,6 +1763,7 @@ class Estimator:
                             "Throughput", throughput, self.global_step
                         )
                     step_metrics.record_epoch(epoch, throughput)
+                    _publish_loop_gauges(state)
                     record_device_memory()  # HBM gauges (no-op on CPU backends)
                     tstate.epoch_finished = True
                     epoch += 1

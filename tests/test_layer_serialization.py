@@ -243,6 +243,11 @@ def _specs():
                                    n_head=2, hidden_size=8,
                                    input_shape=(6,)), (6,), ints=17)
 
+    seq("LoopedDecoder",
+        lambda: L.LoopedDecoder(vocab=17, n_block=1, n_head=2,
+                                hidden_size=8, intermediate_size=12,
+                                passes=2, input_shape=(6,)), (6,), ints=17)
+
     # ---- multi-input / multi-output graphs -----------------------------
     def merge_spec():
         a, b = Input(shape=(4,)), Input(shape=(4,))

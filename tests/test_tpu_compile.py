@@ -117,6 +117,9 @@ CASES = {
     # `gpt2-small-fit`'s own call
     "flash_fwd_bwd_gpt2_cell":
         lambda: _flash((8, 12, 1024, 64), True, causal=True),
+    # `ouro-2.6b-fit`'s own call: heads of 128, four 1024-row chunks
+    "flash_fwd_bwd_ouro_cell":
+        lambda: _flash((2, 16, 4096, 128), True, causal=True),
     # the shape `_resolve_blocks` sizes its VMEM caps against
     "flash_fwd_bwd_vmem_caps":
         lambda: _flash((1, 2, 4096, 128), True, causal=True,
