@@ -19,6 +19,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 def _flash_backend_ok() -> bool:
@@ -122,7 +123,10 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, rng=None,
     if dropout_p > 0.0 and rng is not None:
         keep = jax.random.bernoulli(rng, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    # the context under the name the flash path gives its own (the "attn"
+    # recomputation policy of parallel/plan.py keeps it; inert elsewhere)
+    return checkpoint_name(jnp.einsum("bhqk,bhkd->bhqd", probs, v),
+                           "attn_context")
 
 
 def split_heads(x, n_heads):

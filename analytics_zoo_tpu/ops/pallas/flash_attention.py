@@ -55,6 +55,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG = -1e30
 
@@ -1074,6 +1075,13 @@ def _fwd(q, k, v, bias, q_seg, kv_seg, seed, causal, scale, dropout_p,
     out, m, l = _forward_impl(q, k, v, bias, q_seg, kv_seg, seed, causal,
                               scale, dropout_p, block_q, block_k,
                               return_stats=True)
+    # Named HERE, on the arrays `_bwd` reads: under a jax.checkpoint whose
+    # policy saves "attn_context" (apply_remat's "attn") these three are
+    # kept and the forward kernel is not run again for them; a name on the
+    # caller's copy of `out` keeps an array the backward never reads.
+    # Outside such a checkpoint the name is the identity.
+    out, m, l = (None if x is None else checkpoint_name(x, "attn_context")
+                 for x in (out, m, l))
     return out, (q, k, v, bias, q_seg, kv_seg, seed, out, m, l)
 
 

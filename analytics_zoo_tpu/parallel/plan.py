@@ -70,7 +70,7 @@ __all__ = [
     "with_kernels", "resolve_kernel", "KERNEL_NAMES",
     "DEFAULT_KERNEL_RULES",
     "resolve_plan", "build_mesh", "compile_step", "PlannedStep",
-    "apply_remat", "resolve_remat", "REMAT_POLICIES",
+    "apply_remat", "resolve_remat", "REMAT_POLICIES", "REMAT_KEPT_NAMES",
     "per_chip_bytes", "live_bytes", "record_mem_gauges",
     "record_dtype_gauges", "record_kernel_gauges",
     "serialize_specs", "deserialize_specs",
@@ -87,10 +87,21 @@ PLAN_NAMES = ("dp", "data_parallel", "none", "fsdp", "zero1", "zero2",
 #: remat policy names a plan's ``remat_rules`` may map a path to —
 #: ``"full"`` recomputes everything in the matched scope, ``"dots"``
 #: keeps contraction outputs (``dots_with_no_batch_dims_saveable``),
-#: ``"attn"`` keeps only tensors tagged ``checkpoint_name(
-#: "attn_context")``; any other string resolves as an attribute of
-#: ``jax.checkpoint_policies``
+#: ``"attn"`` keeps only the arrays named in :data:`REMAT_KEPT_NAMES`;
+#: any other string resolves as an attribute of ``jax.checkpoint_policies``
 REMAT_POLICIES = ("full", "dots", "attn")
+
+#: what a policy keeps by ``checkpoint_name``.  ``"attn_context"`` is
+#: given by the attention ops to what their backward pass reads besides
+#: q, k and v: the flash kernels' output and both softmax statistics
+#: (inside the ``custom_vjp``'s forward rule, ``ops/pallas/
+#: flash_attention.py::_fwd``), the context on the dense path
+#: (``ops/attention.py``).  ``"ffn_out"`` is the transformer block's
+#: feed-forward output, which the norm after or around that branch reads.
+#: So under ``"attn"`` the backward pass makes the norms, q, k, v and the
+#: other products of the scope again, but runs no attention forward and
+#: no down-projection a second time (measured: ``PERF.md``, PR 29).
+REMAT_KEPT_NAMES = {"attn": ("attn_context", "ffn_out")}
 
 #: dtype ROLES a plan's ``dtype_rules`` may map a path to.  A role is
 #: not a raw dtype: it names the leaf's job in the precision plane.
@@ -779,18 +790,22 @@ def apply_remat(fn, policy: str | None, *, static_argnums=()):
     ``None`` returns ``fn`` unchanged; ``"full"`` recomputes the whole
     scope in the backward pass (max memory saving, ~1/3 extra FLOPs);
     ``"dots"`` keeps contraction outputs
-    (``dots_with_no_batch_dims_saveable``); ``"attn"`` keeps only
-    tensors tagged ``checkpoint_name(..., "attn_context")``; any other
-    name resolves as an attribute of ``jax.checkpoint_policies``."""
+    (``dots_with_no_batch_dims_saveable``); ``"attn"`` keeps the arrays
+    named in :data:`REMAT_KEPT_NAMES` (the flash kernels' output and
+    softmax statistics, which are what their backward kernels read, and
+    a transformer block's feed-forward output), so the scope is
+    recomputed but for the attention forward and the down-projection;
+    any other name resolves as an attribute of
+    ``jax.checkpoint_policies``."""
     if policy in (None, "", "none"):
         return fn
     if policy == "full":
         return jax.checkpoint(fn, static_argnums=static_argnums)
     if policy == "dots":
         ckpt_policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    elif policy == "attn":
+    elif policy in REMAT_KEPT_NAMES:
         ckpt_policy = jax.checkpoint_policies.save_only_these_names(
-            "attn_context")
+            *REMAT_KEPT_NAMES[policy])
     else:
         try:
             ckpt_policy = getattr(jax.checkpoint_policies, policy)

@@ -105,10 +105,13 @@ class _TransformerCore(Layer):
         # training deep stacks near the HBM limit.  Accepts True/"full"
         # (recompute everything), "dots" (save matmul outputs —
         # checkpoint_dots_with_no_batch_dims: less recompute, more
-        # memory), or "attn" (save only the per-block attention context
-        # via checkpoint_name — the backward re-derives the cheap
-        # projections but not the flash-attention forward).  The best
-        # point is hardware-dependent; the transformer bench sweeps it.
+        # memory), or "attn" (keep what parallel/plan.py's
+        # REMAT_KEPT_NAMES lists: the flash kernels' output and softmax
+        # statistics, exactly what their backward kernels read, and the
+        # feed-forward's output — the backward re-derives the rest but
+        # runs no flash-attention forward and no down product a second
+        # time).  The best point is hardware-dependent; the transformer
+        # bench sweeps it.
         if remat in (False, None):
             self.remat = None
         elif remat in (True, "full"):
@@ -285,7 +288,6 @@ class _TransformerCore(Layer):
                      if brng is not None else None),
                 causal=not self.bidirectional,
             )
-            a = checkpoint_name(a, "attn_context")
             return dense(merge_heads(a), "proj")
 
         aux = drop = jnp.zeros((), jnp.float32)
@@ -307,7 +309,10 @@ class _TransformerCore(Layer):
             f = self.act(dense(u, "gate" if self.gated_ffn else "fc"))
             if self.gated_ffn:
                 f = f * dense(u, "fc")
-            return dense(f, "out")
+            # named for the "attn" policy: the norm after (or around) the
+            # branch reads this in the backward pass, and keeping it
+            # spares the down product's second run (PERF.md, PR 29)
+            return checkpoint_name(dense(f, "out"), "ffn_out")
 
         n = self._n_norms // 2
         h = self._branch(attention, h, bp, 1, training, brng, 1)
@@ -501,8 +506,10 @@ class BERT(_TransformerCore):
 #: set of weights), ``head_evaluations`` (one a pass where the layer takes
 #: the exit-gate loss itself, one where only the last pass's logits are
 #: made), ``loop`` (how the passes are traced: unrolled), ``remat`` (the policy
-#: resolved for a layer application) and ``loss_blocks`` (token blocks a
-#: pass's head and cross-entropy are taken in; 0 without the loss).
+#: resolved for a layer application), ``kept`` (the ``checkpoint_name``s that
+#: policy keeps for the backward pass; none under ``"full"``) and
+#: ``loss_blocks`` (token blocks a pass's head and cross-entropy are taken in;
+#: 0 without the loss).
 loop_records: collections.deque = collections.deque(maxlen=16)
 
 
@@ -526,12 +533,25 @@ class LoopedDecoder(_TransformerCore):
     ``loop_exit_cost`` of its state, which the train step adds to the
     loss, with ``loop_exit_mass`` (mean p_t) and ``loop_pass_loss`` (mean
     CE_t), a number a pass.  With any other loss only logits_T is made.
+
+    Every layer application is one ``jax.checkpoint`` under the ``"attn"``
+    policy (``remat=``; a plan's ``remat_rules`` override it).  Besides its
+    input it keeps the attention's output with the two rows of softmax
+    statistics, which is all the flash backward kernels read apart from q,
+    k and v, and the feed-forward's output, which the norm around that
+    branch reads: the backward pass makes the norms, q, k, v, the
+    projections, gate and up again, but runs no attention forward and no
+    down product twice.  Kept an application: 2 x B x L x hidden in the
+    compute dtype and 2 x B x heads x L float32, 68.1 MB at (2, 4096)
+    tokens, 16 heads of 128, bf16; over 4 passes of 6 layers the step's
+    peak on a v5e grew by 2.46 GB for a step 5.7% shorter (PERF.md, PR 29).
+    With less room, ``remat="full"`` keeps the input alone.
     """
 
     def __init__(self, vocab, n_block, n_head, hidden_size,
                  intermediate_size, passes=4, rotary_theta=1e6,
                  norm_eps=1e-6, exit_beta=0.05, loss_block=2048,
-                 remat="full", **kwargs):
+                 remat="attn", **kwargs):
         super().__init__(
             n_block=n_block, n_head=n_head, hidden_size=hidden_size,
             intermediate_size=intermediate_size, hidden_drop=0.0,
@@ -631,6 +651,7 @@ class LoopedDecoder(_TransformerCore):
 
     def call(self, params, inputs, state=None, training=False, rng=None):
         from analytics_zoo_tpu.parallel.plan import (
+            REMAT_KEPT_NAMES,
             apply_remat,
             resolve_remat,
         )
@@ -653,14 +674,14 @@ class LoopedDecoder(_TransformerCore):
             return final(params["final_gamma"], h)
 
         takes_loss = targets is not None
+        policy = resolve_remat(self.name or "blocks", default=self.remat)
         loop_records.append({
             "layer": self.name, "training": bool(training),
             "passes": self.passes, "layers": self.n_block,
             "layer_applications": self.passes * self.n_block,
             "head_evaluations": self.passes if takes_loss else 1,
-            "loop": "unrolled",
-            "remat": resolve_remat(self.name or "blocks",
-                                   default=self.remat),
+            "loop": "unrolled", "remat": policy,
+            "kept": list(REMAT_KEPT_NAMES.get(policy, ())),
             "loss_blocks": self._loss_blocks(*tokens.shape)
             if takes_loss else 0})
         # The passes are unrolled: on the chip a step is 1.8% shorter than

@@ -243,7 +243,81 @@ def test_no_array_of_passes_x_tokens_x_vocabulary_in_the_step(cfg, weights,
     record = self_attention.loop_records[-1]
     assert record["loss_blocks"] == 4 and record["head_evaluations"] == 3
     assert record["layer_applications"] == 6 and record["loop"] == "unrolled"
-    assert record["remat"] == "full"
+    assert record["remat"] == "attn"
+    assert record["kept"] == ["attn_context", "ffn_out"]
+
+
+@pytest.mark.parametrize("remat", ["full", "dots", None])
+def test_the_default_policy_is_a_trade_of_memory_alone(
+        remat, cfg, reference, weights, batches, program_first):
+    """A layer application keeps the attention's context under the layer's
+    own default; loss, state and every leaf's gradient are those of the
+    other policies and of no checkpoint at all, and the record of what was
+    traced names the policy and what it keeps."""
+    assert _layer(cfg).remat == "attn"
+    layer = _layer(cfg, remat=remat)
+    (loss, state), grad = jax.jit(jax.value_and_grad(
+        lambda core, x, y: _program_loss(layer, core, x, y), has_aux=True))(
+            weights[reference.CORE], batches[0][0], batches[1][0])
+    record = self_attention.loop_records[-1]
+    assert (record["remat"], record["kept"]) == (remat, [])
+    (loss_default, state_default), grad_default = program_first
+    np.testing.assert_allclose(float(loss_default), float(loss), rtol=1e-6)
+    _close(state_default, state, 1e-6, "state")
+    _close(grad_default, grad, 1e-6, "gradient")
+
+
+def test_an_application_keeps_its_context_and_feed_forward_output(cfg, capsys):
+    """What the default policy keeps of one layer application besides its
+    arguments: the attention's context (B, heads, L, head) and the
+    feed-forward's output (B, L, hidden), each from the op that names it;
+    so the gradient has two products fewer than under ``"full"`` (PV and
+    the down-projection; on the dense path q k^T is still made again)."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from analytics_zoo_tpu.parallel.plan import apply_remat
+
+    layer = _layer(cfg)
+    bp = layer._block_params(jax.random.PRNGKey(0))
+    h = jnp.ones((BATCH, 32, 64), jnp.float32)
+
+    def application(bp, h):
+        return layer._block_forward_aux(bp, h, None, True, None)[0]
+
+    capsys.readouterr()
+    print_saved_residuals(apply_remat(application, layer.remat), bp, h)
+    kept = [ln for ln in capsys.readouterr().out.splitlines()
+            if " from the argument " not in ln and "constant" not in ln]
+    assert len(kept) == 2, kept
+    assert any(ln.startswith("f32[8,2,32,32]")
+               and "dot_product_attention" in ln for ln in kept), kept
+    assert any(ln.startswith("f32[8,32,64]") and "feed_forward" in ln
+               for ln in kept), kept
+
+    def products(policy):
+        fn = apply_remat(application, policy)
+        return str(jax.make_jaxpr(jax.grad(
+            lambda bp: jnp.sum(fn(bp, h) ** 2)))(bp)).count("dot_general")
+
+    assert products("full") - products("attn") == 2
+    # made again: qkv, q k^T, the attention's projection, gate, up
+    assert products("attn") - products(None) == 5
+
+
+def test_a_plan_rule_overrides_the_layers_policy(cfg, reference, weights,
+                                                 batches):
+    """``remat_rules`` of the plan being compiled say ``"full"`` to a layer
+    built with the default: the way a user with less room asks for it."""
+    from analytics_zoo_tpu.parallel import plan as plan_mod
+
+    layer = _layer(cfg)
+    rules = plan_mod.with_remat(plan_mod.data_parallel(), "full", r"ouro")
+    with plan_mod._active_plan(rules):
+        jax.make_jaxpr(lambda core: _program_loss(
+            layer, core, batches[0][0], batches[1][0])[0])(
+                weights[reference.CORE])
+    record = self_attention.loop_records[-1]
+    assert (record["remat"], record["kept"]) == ("full", [])
 
 
 # -- the block before it became configurable ------------------------------

@@ -150,3 +150,42 @@ def test_kernel_compiles_for_v5e(case, one_chip, real_kernels):
         + text[:2000]
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 16 << 30  # the chip's memory
+
+
+@pytest.mark.parametrize("policy, forward_calls",
+                         [({}, 1), ({"remat": "full"}, 2)],
+                         ids=["the_layers_own", "full"])
+def test_a_layer_application_runs_the_forward_kernel_once(
+        policy, forward_calls, one_chip, real_kernels):
+    """The gradient of one checkpointed layer application of
+    `ouro-2.6b-fit` (2 x 4,096 tokens, 16 heads of 128, bf16): under the
+    looped decoder's own policy the compiled program calls the Mosaic
+    forward kernel, the one that returns three arrays, once; under
+    ``"full"`` the backward pass calls it again.  dq and dk/dv once each.
+    The kernels are told apart as the benchmark's readers tell them."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import LoopedDecoder
+    from benchmark import xplane
+
+    layer = LoopedDecoder(vocab=49152, n_block=1, n_head=16,
+                          hidden_size=2048, intermediate_size=5632, **policy)
+
+    def loss(blocks, h):
+        # the cotangent reads the output, as the next layer's would, so
+        # the application's forward pass is no dead code
+        out = layer._run_blocks(blocks, h, None, True, None)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    blocks = jax.eval_shape(
+        lambda: [layer._block_params(jax.random.PRNGKey(0))])
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        (blocks, jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16)))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *args).compile().as_text()
+    marked = f"/{xplane.KERNEL_TARGET}/"
+    returned = sorted(
+        int(name.rpartition(marked)[2])
+        for name in map(xplane.short_name, text.splitlines())
+        if marked in name)
+    assert returned == [1, 2] + [3] * forward_calls, returned
