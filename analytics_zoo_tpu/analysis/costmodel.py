@@ -11,7 +11,8 @@ search.  This module is both halves:
   vector: per-step time = max(flops/peak_flops, bytes/peak_bw) +
   collective_bytes/link_bw + dispatch_overhead/K.  The K term is the
   fused-dispatch amortization the autotuner otherwise discovers by
-  measurement (~53 dispatches, BENCH_AUTOTUNE_r08); the ceilings come
+  measurement (about 53 dispatches on a one-core CPU host in PR 8; no
+  chip number); the ceilings come
   from a small per-platform :class:`PeakTable` with a CPU-calibrated
   default, any field overridable via ``ZOO_ORACLE_PEAKS`` (a JSON
   object, e.g. ``{"dispatch_overhead_s": 4e-4}``).
@@ -24,8 +25,7 @@ search.  This module is both halves:
   normal equations are solved by Gaussian elimination, no
   sklearn/numpy.linalg).  Trained from accumulated
   ``ZOO_HLO_REPORT_DIR`` reports (:func:`load_report_rows`, schema v1
-  accepted with nulls) joined with BENCH_*.json rows
-  (:func:`load_bench_rows`) and the autotuner's persisted decision
+  accepted with nulls) joined with the autotuner's persisted decision
   history (:func:`load_tune_log_rows`, ``ZOO_TUNE_LOG_DIR``).  Below
   :data:`MIN_FIT_SAMPLES` joined samples the model reports
   ``ready == False`` and callers fall back to the analytic prediction
@@ -54,7 +54,7 @@ __all__ = [
     "DTYPE_PEAK_FACTORS", "plan_dtype", "dtype_peaks",
     "histogram_compute_dtype",
     "KERNEL_BYTE_MODELS", "kernel_bytes", "choose_kernel",
-    "ResidualModel", "load_report_rows", "load_bench_rows",
+    "ResidualModel", "load_report_rows",
     "load_tune_log_rows", "training_rows",
     "predict_serving_seconds", "serving_bucket_label",
     "load_serving_rows", "SERVING_LABEL_PREFIX",
@@ -90,14 +90,14 @@ class PeakTable:
 
 
 #: Per-platform ceilings.  The CPU row is CALIBRATED, not theoretical:
-#: dispatch_overhead_s comes from BENCH_AUTOTUNE_r08's measured
-#: per-step cost curve (cost(K) = compute + overhead/K over
-#: K∈{1..16} gives overhead ≈ 5e-4 s on this harness's host), and the
+#: dispatch_overhead_s comes from a per-step cost curve measured on a
+#: one-core CPU host in PR 8 (cost(K) = compute + overhead/K over
+#: K∈{1..16} gave overhead ≈ 5e-4 s there; no chip number), and the
 #: flops/bandwidth rows are order-of-magnitude host numbers — for the
 #: dispatch-bound programs the CPU backend exists to exercise, the
 #: overhead term dominates and ranking is insensitive to them.  TPU
-#: rows use published per-chip peaks (see also TPU_PEAK_FLOPS in
-#: bench.py for MFU accounting).
+#: rows use published per-chip peaks (the benchmark keeps its own table,
+#: ``benchmark/peaks.json``).
 PLATFORM_PEAKS: dict[str, PeakTable] = {
     "cpu": PeakTable(
         flops=5.0e10, hbm_bytes_per_s=2.0e10, link_bytes_per_s=1.0e10,
@@ -222,8 +222,9 @@ def normalize_features(features: Mapping) -> dict:
 #: the pre-overlap additive roofline exactly.  Bucketed "+overlap"
 #: plans hide all but the tail: the last gradient bucket's
 #: reduce-scatter has no backward segment left to hide behind, and the
-#: first prefetch gather precedes any compute — validated against the
-#: measured serial/bucketed legs in BENCH_OVERLAP_r13.json.
+#: first prefetch gather precedes any compute.  The 0.25 was set against
+#: serial and bucketed legs timed on a one-core CPU host in PR 14; no
+#: chip number.
 EXPOSED_FRACTIONS = {"serial": 1.0, "overlap": 0.25}
 
 
@@ -459,8 +460,8 @@ def load_serving_rows(report_dir: str) -> list[dict]:
 #: zero3/fsdp shard both, pipeline splits the stage-stacked tree over
 #: the pipe axis, tp shards params + opt over the model axis
 #: (rule-table dependent; 1/n is the intended steady state).  Matches
-#: the live-array measurements in BENCH_PARTITION_r10.json (fsdp ≈
-#: 0.125x on 8 devices) and BENCH_MEMORY_r12.json (zero3 ≈ 0.125x).
+#: the bytes of the placed arrays on 8 devices (fsdp and zero3 ≈ 0.125x:
+#: ``tests/test_memory_plan.py``, ``tests/test_oracle.py``).
 PLAN_MEMORY_FACTORS = {
     "dp": (1.0, 1.0),
     "zero1": (1.0, None),   # None -> 1/n
@@ -854,39 +855,6 @@ def load_report_rows(report_dir: str) -> list[dict]:
     return rows
 
 
-def load_bench_rows(bench_dir: str) -> list[dict]:
-    """Measured (features, K, steps/sec) rows from accumulated
-    BENCH_*.json artifacts.  Only self-contained rows are harvested —
-    today the partition bench's per-plan legs, which carry their own
-    ``zoo_hlo_*`` feature block next to the measured steps/sec."""
-    rows = []
-    try:
-        names = sorted(os.listdir(bench_dir))
-    except OSError:
-        return rows
-    for name in names:
-        if not (name.startswith("BENCH_") and name.endswith(".json")):
-            continue
-        try:
-            with open(os.path.join(bench_dir, name)) as f:
-                doc = json.load(f)
-        except (OSError, json.JSONDecodeError):
-            continue
-        for leg in (doc.get("legs") or {}).values():
-            hlo = leg.get("hlo") or {}
-            sps = leg.get("steps_per_sec")
-            if not hlo or not sps:
-                continue
-            rows.append({
-                "label": f"{name}:{leg.get('plan')}",
-                "features": normalize_features(hlo),
-                "k": 1,
-                "plan": leg.get("plan"),
-                "measured_steps_per_sec": float(sps),
-            })
-    return rows
-
-
 def load_tune_log_rows(tune_log_dir: str) -> list[dict]:
     """Measured per-K rows from the autotuner's persisted decision
     history (``ZOO_TUNE_LOG_DIR`` JSONL, feature/autotune.py): each
@@ -929,17 +897,15 @@ def load_tune_log_rows(tune_log_dir: str) -> list[dict]:
 
 
 def training_rows(report_dir: str | None = None,
-                  bench_dir: str | None = None,
                   tune_log_dir: str | None = None) -> list[dict]:
-    """The residual model's joined training set.  Bench legs are
-    self-contained; tune-log rows (measurement, no features) join with
-    the latest report row of the same compile label (features, no
-    measurement).  Unjoinable rows drop silently — with nothing
-    accumulated yet the result is [] and the caller's fit stays
-    analytic."""
+    """The residual model's joined training set: tune-log rows
+    (measurement, no features) join with the latest report row of the
+    same compile label (features, no measurement).  Unjoinable rows
+    drop silently — with nothing accumulated yet the result is [] and
+    the caller's fit stays analytic."""
     report_dir = report_dir or os.environ.get("ZOO_HLO_REPORT_DIR")
     tune_log_dir = tune_log_dir or os.environ.get("ZOO_TUNE_LOG_DIR")
-    rows = list(load_bench_rows(bench_dir)) if bench_dir else []
+    rows = []
     reports = load_report_rows(report_dir) if report_dir else []
     by_label: dict[str, dict] = {}
     for rpt in reports:  # later files win: freshest features per label

@@ -8,7 +8,8 @@ to search blind:
   :meth:`ConfigOracle.predict_k` after the first compiled dispatch and
   jumps straight to the predicted ``steps_per_dispatch``, demoting the
   ladder sweep to a ±1-neighbor validation pass — ≤8 dispatches to
-  settle instead of ~53 (BENCH_AUTOTUNE_r08), trajectory still
+  settle (``tests/test_oracle.py``) where the blind climb took about 53
+  on a one-core CPU host in PR 8, trajectory still
   bitwise-equal because per-inner-step RNG folds on the global step
   index regardless of the K schedule;
 - ``estimator.fit(plan="auto")`` calls :meth:`ConfigOracle.choose_plan`

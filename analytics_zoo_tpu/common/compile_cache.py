@@ -43,37 +43,6 @@ logger = logging.getLogger("analytics_zoo_tpu")
 _LOCK = threading.Lock()
 _ENABLED_DIR: str | None = None  # guarded-by: _LOCK
 
-# XLA flags the bench's probe-subprocess path validated and adopted for
-# this process (latency-hiding scheduler set, sweep winners).  Purely a
-# provenance registry: the flags were already applied via XLA_FLAGS /
-# jax config by the adopter — recording them here stamps every
-# subsequent compile's hlo report (meta["xla_flags"]) so a cost-model
-# training row says WHICH scheduler produced its graph.
-_ADOPTED_FLAGS: tuple = ()  # guarded-by: _LOCK
-
-
-def record_adopted_flags(flags) -> tuple:
-    """Register XLA flags adopted for this process (idempotent,
-    order-preserving union); returns the full adopted set.  Called by
-    the bench's probe-validated adoption paths — see
-    ``bench.adopt_sweep_flags`` / ``bench.adopt_latency_hiding_flags``.
-    """
-    global _ADOPTED_FLAGS
-    with _LOCK:
-        merged = list(_ADOPTED_FLAGS)
-        for f in flags:
-            f = str(f)
-            if f not in merged:
-                merged.append(f)
-        _ADOPTED_FLAGS = tuple(merged)
-        return _ADOPTED_FLAGS
-
-
-def adopted_flags() -> tuple:
-    """The XLA flags recorded via :func:`record_adopted_flags` (empty
-    tuple when none were adopted)."""
-    return _ADOPTED_FLAGS
-
 # Histogram bounds shaped for compile times: sub-second CPU toys through
 # multi-minute TPU programs.
 COMPILE_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
@@ -223,9 +192,7 @@ def timed_compile(lowered, label: str, meta: dict | None = None):
     ``zoo-hlo-report/2`` row carries the measured compile
     wall-seconds.  ``meta`` is the compile context the lowered text
     cannot show (``plan`` / ``mesh_shape`` / ``steps_per_dispatch``),
-    stamped into the report for the cost model's training join; any
-    flags registered via :func:`record_adopted_flags` are stamped in
-    as ``xla_flags`` automatically.
+    stamped into the report for the cost model's training join.
     Disable with ``ZOO_HLO_LINT=0``; lint errors never propagate into
     the compile.
     """
@@ -234,9 +201,6 @@ def timed_compile(lowered, label: str, meta: dict | None = None):
         maybe_write_report,
     )
 
-    if _ADOPTED_FLAGS:
-        meta = dict(meta or {})
-        meta.setdefault("xla_flags", _ADOPTED_FLAGS)
     rpt = maybe_lint_lowered(lowered, label, meta=meta,
                              defer_report=True)
     hist, hits, misses = _metrics(label)

@@ -1,53 +1,6 @@
-"""Small shared utilities: named timers, shape helpers, pytree helpers.
-
-``time_it`` mirrors the reference's lightweight tracing
-(``Utils.timeIt`` zoo/.../common/Utils.scala:40, used around TF session calls
-at TFNet.scala:176) — elapsed time per named block, logged.
-"""
+"""Small shared utilities: shape helpers."""
 
 from __future__ import annotations
-
-import contextlib
-import logging
-import time
-from collections import defaultdict
-
-logger = logging.getLogger("analytics_zoo_tpu")
-
-def _new_agg() -> dict:
-    return {"count": 0, "total": 0.0, "min": float("inf"), "max": 0.0}
-
-
-# Per-name AGGREGATES (count/total/min/max), not per-call lists: time_it
-# wraps every train-step infeed+dispatch, so lists would grow without bound
-# over multi-day jobs.
-_TIMINGS: dict[str, dict] = defaultdict(_new_agg)
-
-
-@contextlib.contextmanager
-def time_it(name: str, log: bool = False):
-    """Time a block; accumulate under ``name`` (Utils.scala:40 equivalent)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        agg = _TIMINGS[name]
-        agg["count"] += 1
-        agg["total"] += dt
-        agg["min"] = min(agg["min"], dt)
-        agg["max"] = max(agg["max"], dt)
-        if log:
-            logger.info("[%s] %.3f ms", name, dt * 1e3)
-
-
-def get_timings() -> dict[str, dict]:
-    """name -> {count, total, min, max} (seconds)."""
-    return {k: dict(v) for k, v in _TIMINGS.items()}
-
-
-def reset_timings() -> None:
-    _TIMINGS.clear()
 
 
 def to_tuple_shape(shape) -> tuple:

@@ -30,8 +30,9 @@ Every signal needed is already exported — this module closes the loop:
   K=1: after the first compiled dispatch the controller reads the
   program's HLO features, jumps to the oracle's predicted K, and
   demotes the ladder sweep to a ±1-neighbor validation pass — ≤8
-  dispatches to settle instead of ~53 (BENCH_ORACLE_r11 vs
-  BENCH_AUTOTUNE_r08), same bitwise trajectory.  The settle outcome
+  dispatches to settle (``tests/test_oracle.py``) where the blind climb
+  took about 53 on a one-core CPU host in PR 8, same bitwise
+  trajectory.  The settle outcome
   feeds back to the oracle (predicted-vs-measured), closing the loop.
 
 Every decision is recorded three ways so a bad tune is diagnosable
@@ -72,8 +73,8 @@ __all__ = ["AutotuneController", "K_CANDIDATES", "DEFAULT_RAM_BUDGET",
            "varz_doc"]
 
 # The fused-dispatch search space: beyond K=16 the per-dispatch overhead
-# is already amortized to noise (BENCH_DISPATCH_r07: K=16 = 6.3x K=1)
-# while checkpoint/validation cadence coarsens linearly.
+# is already amortized to noise (on a one-core CPU host in PR 7; no chip
+# number) while checkpoint/validation cadence coarsens linearly.
 K_CANDIDATES = (1, 2, 4, 8, 16)
 
 # Default host-RAM budget for the prefetch window (batches in the queue +

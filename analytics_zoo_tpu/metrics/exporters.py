@@ -89,8 +89,8 @@ def unique_exposition_names(names) -> dict:
 def sample_key(sample: dict) -> str:
     """Canonical flat key for one :func:`snapshot` sample —
     ``name`` or ``name{label=value,...}`` — shared by every consumer
-    that needs a dict key per labeled series (``tools/metrics_dump.py``,
-    ``tools/serving_bench.py``), so the two JSON outputs agree."""
+    that needs a dict key per labeled series (``tools/metrics_dump.py``),
+    so their JSON outputs agree."""
     labels = sample.get("labels")
     if not labels:
         return sample["name"]
@@ -171,7 +171,7 @@ def prometheus_text(registry: MetricsRegistry | None = None) -> str:
 def snapshot(registry: MetricsRegistry | None = None,
              step: int | None = None) -> dict:
     """One registry snapshot as a plain JSON-able dict — the JSONL line
-    shape (also what ``bench.py`` embeds in its result line)."""
+    shape."""
     reg = registry if registry is not None else get_registry()
     samples = []
     for fam in reg.collect():

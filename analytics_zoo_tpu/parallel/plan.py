@@ -34,7 +34,7 @@ were bespoke programs.  Here they are CONFIGURATIONS:
 Loss trajectories are placement-invariant: a plan changes WHERE bytes
 live and which collectives XLA inserts, never the math — the fsdp plan
 trains bit-identically to replicated DP (pinned by
-``tests/test_partitioner.py`` and ``bench.py --partition``).
+``tests/test_partitioner.py``).
 """
 
 from __future__ import annotations
@@ -336,8 +336,9 @@ class ShardingPlan:
     reduction collective is pinned (via an ``optimization_barrier``
     chain) to issue as soon as that group's backward segment completes,
     instead of all collectives queuing behind the full backward.
-    Identity on values — the trajectory stays bitwise equal to the
-    unbucketed plan.  ``prefetch`` adds the fsdp gather-on-use
+    Identity on the traced values — the trajectory is the unbucketed
+    plan's to float32 rounding (see :meth:`constrain_grads`).
+    ``prefetch`` adds the fsdp gather-on-use
     schedule: sharded params are explicitly gathered bucket-by-bucket
     ahead of use (double-buffered order pin via
     :func:`_sched_barrier`), so layer k+1's all-gather can overlap
@@ -622,8 +623,10 @@ class ShardingPlan:
         ``optimization_barrier`` chain (:func:`_chain_buckets`): each
         bucket's reduce-scatter/all-reduce is issued as its backward
         segment completes instead of queueing behind the full backward.
-        Values are untouched — the trajectory is bitwise equal to the
-        unbucketed plan (the per-leaf reduction grouping is unchanged).
+        The traced values are untouched (the per-leaf reduction grouping
+        is unchanged); the two compiled programs may still sum in
+        different orders, so the trajectory is the unbucketed plan's to
+        float32 rounding (``tests/test_overlap.py``).
         """
         if self.grad_rules is not None:
             specs = self._specs(self.grad_rules, grads, mesh)
@@ -849,7 +852,7 @@ def zero1(axis: str = DATA_AXIS, overlap=False) -> ShardingPlan:
     (ZeRO-1: 1/n moment memory + update compute per chip).  Subsumes the
     old ``ZOO_SHARD_OPTIMIZER`` GSPMD path.  ``overlap`` turns on
     bucketed gradient overlap (``True`` = default bucket size, an int =
-    that many bytes per bucket; trajectory stays bitwise)."""
+    that many bytes per bucket; the trajectory to float32 rounding)."""
     extra = _overlap_fields(overlap)
     return ShardingPlan(
         name="zero1+overlap" if extra else "zero1",
@@ -887,7 +890,7 @@ def zero2(axis: str = DATA_AXIS, overlap=False) -> ShardingPlan:
     update).  Same math as DP — per-chip persistent state matches
     zero1, and the transient gradient buffer drops to 1/n.  ``overlap``
     buckets the reduce-scatters into backward-completion-order groups
-    (bitwise trajectory)."""
+    (the trajectory to float32 rounding)."""
     shard = ((r".*", P(axis)),)
     extra = _overlap_fields(overlap)
     return ShardingPlan(
@@ -907,7 +910,7 @@ def zero3(axis: str = DATA_AXIS, overlap=False) -> ShardingPlan:
     shard, so per-chip param+opt state is ~1/n (the fsdp layout with
     the gradient scatter pinned explicitly).  ``overlap`` buckets the
     gradient reduce-scatters and prefetch-gathers the params
-    (bitwise trajectory)."""
+    (the trajectory to float32 rounding)."""
     shard = ((r".*", P(axis)),)
     extra = _overlap_fields(overlap)
     return ShardingPlan(

@@ -322,8 +322,7 @@ def _gather_for_save(tree):
 def _async_checkpoint_enabled() -> bool:
     """``ZOO_ASYNC_CHECKPOINT`` env gate, default ON.  ``0`` forces the
     serialization+rename back onto the caller's thread (the pre-overlap
-    behavior) — the conservative fallback, and the baseline leg of
-    ``bench.py --overlap``'s checkpoint-stall comparison."""
+    behavior) — the conservative fallback."""
     raw = os.environ.get("ZOO_ASYNC_CHECKPOINT")
     if raw is None:
         return True
@@ -1175,11 +1174,10 @@ class Estimator:
         zero2 reduce-scatters grads at zero1's resident state) and
         which collectives XLA inserts, never the math: fsdp/zero3 train
         BIT-identically to dp; zero1/zero2's differently-grouped
-        gradient reduction matches to float tolerance (ulp-level —
-        BENCH_PARTITION_r10.json / BENCH_MEMORY_r12.json record the
-        max |Δ|).  ``"auto"`` asks the config oracle to sweep the
-        (plan × remat) space against the HBM budget.  See
-        docs/parallelism.md.
+        gradient reduction matches to float tolerance (ulp-level:
+        ``tests/test_partitioner.py`` holds rtol 1e-5).  ``"auto"`` asks
+        the config oracle to sweep the (plan × remat) space against the
+        HBM budget.  See docs/parallelism.md.
 
         ``autotune``: ``True`` (or ``ZOO_AUTOTUNE=1`` via the config
         tier, which ``None`` defers to) turns on the closed-loop tuner

@@ -7,7 +7,8 @@ module moves the shedding to the FRONT DOOR: an
 :class:`AdmissionController` watches broker pressure, per-stream
 backlog, and the SLO burn headroom (the
 :class:`~analytics_zoo_tpu.metrics.slo.SloEngine` multi-window signal
-that fires BEFORE the hard violation — BENCH_FED_r15), and publishes a
+that fires BEFORE the hard violation — ``tests/test_zoowatch.py``
+holds that order on a process-mode fleet), and publishes a
 per-stream verdict hash (``admission:<stream>``) on the broker.
 Clients read the verdict at enqueue and raise the typed
 :class:`~analytics_zoo_tpu.serving.client.ServingRejected` (with the

@@ -4,8 +4,7 @@ Reference: zoo/.../examples/resnet/TrainImageNet.scala:36-120 (warmup +
 epoch-decay SGD) and the vnni Perf harness
 (examples/vnni/bigdl/Perf.scala:53-66) that prints images/sec.
 
-`bench.py` at the repo root invokes :func:`run` — this example IS the
-benchmark.  With --data-dir it trains on ``.npz`` image shards (uint8 HWC
+With --data-dir it trains on ``.npz`` image shards (uint8 HWC
 images + int labels); without, synthetic data measures training throughput.
 
 The input pipeline is TPU-shaped: the host ships **uint8** images (4× less

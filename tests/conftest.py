@@ -60,19 +60,19 @@ QUICK_FILES = {
     "test_layer_oracle_enforcement.py", "test_api_docs.py",
     "test_textset.py", "test_image3d.py", "test_transfer_learning.py",
     "test_layer_serialization.py", "test_metrics.py",
-    "test_prefetch.py",  # host data plane + --data-pipeline bench guard
-    "test_dispatch.py",  # fused scan-K dispatch + --dispatch bench guard
-    "test_autotune.py",  # closed-loop autotune + --autotune bench guard
+    "test_prefetch.py",  # host data plane + overlap of sleep-bound work
+    "test_dispatch.py",  # fused scan-K dispatch + its count of dispatches
+    "test_autotune.py",  # closed-loop autotune, resizing byte-identical
     "test_compile_cache.py",  # persistent compile plane
-    "test_partitioner.py",  # unified partitioner + --partition guard
+    "test_partitioner.py",  # unified partitioner, plans at two sizes
     "test_partition_rules.py",  # rule matching + path rendering
     "test_zoolint.py",  # static analysis + package-clean CI gate
     "test_zoosan.py",  # whole-program pass + runtime sanitizer
     "test_telemetry.py",  # ~9s incl. two actor spawns
     "test_fleet.py",  # serving fleet: claim protocol, autoscaler, kill -9
-    "test_overlap.py",  # latency-hiding plane + --overlap bench guard
+    "test_overlap.py",  # latency-hiding plane + bucketed-vs-two-phase
     "test_elastic.py",  # elastic runtime: membership, chaos, supervisor
-    "test_zoowatch.py",  # federation plane: scrape/SLO + two e2e guards
+    "test_zoowatch.py",  # federation plane: scrape/SLO + two e2e runs
     # test_actors.py left OUT since the spawn switch: interpreter
     # startup per actor puts the file at ~5 min — nightly tier
 }

@@ -88,3 +88,32 @@ def test_committed_pages_match_generator():
                 stale.append(modname)
     assert not stale, (
         f"stale pages {stale[:5]} — rerun tools/make_api_docs.py")
+
+
+def test_cited_records_and_tools_exist():
+    """A root-level record (``NAME_r<n>.json``), the root's old harness
+    script (second pattern) or a ``tools/*.py`` that the README, a guide
+    under docs/ or the package's sources name is a file of the tree: a
+    deleted record cannot be cited as what holds a number."""
+    import glob
+    import re
+
+    sources = [os.path.join(REPO, "README.md")]
+    sources += glob.glob(os.path.join(REPO, "docs", "*.md"))
+    sources += glob.glob(os.path.join(REPO, "analytics_zoo_tpu", "**",
+                                      "*.py"), recursive=True)
+    cited = {
+        r"\b[A-Z][A-Z_]*_r\d+\.json\b": REPO,
+        r"(?<![\w/])bench\.py\b": REPO,
+        r"\btools/(\w+\.py)\b": os.path.join(REPO, "tools"),
+    }
+    dangling = set()
+    for path in sources:
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        for pattern, where in cited.items():
+            for m in re.finditer(pattern, text):
+                name = m.group(m.lastindex or 0)
+                if not os.path.exists(os.path.join(where, name)):
+                    dangling.add((os.path.relpath(path, REPO), name))
+    assert not dangling, sorted(dangling)

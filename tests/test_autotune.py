@@ -23,8 +23,6 @@ from analytics_zoo_tpu.feature.prefetch import (
     worth_prefetching,
 )
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
 
 def _sleepy_sharded(n_shards=4, records=32, load_sleep=0.01,
                     transform_sleep=0.001):
@@ -176,14 +174,22 @@ def test_read_ahead_count_knob(shard_paths=None, tmp_path=None):
 # controller: data plane
 # ---------------------------------------------------------------------------
 
-def test_controller_grows_pipeline_and_stays_byte_identical():
-    fs = _sleepy_sharded()
+@pytest.mark.parametrize("load_sleep, epochs, interval", [
+    (0.01, 4, 0.03),
+    (0.015, 5, 0.04),  # a slower disk, a slower controller
+], ids=["10ms-shards", "15ms-shards"])
+def test_controller_grows_pipeline_and_stays_byte_identical(
+        load_sleep, epochs, interval):
+    """From the worst-case start (one worker, depth one) the controller
+    ends above both, and resizing a live pipeline changes no byte of the
+    stream."""
+    fs = _sleepy_sharded(load_sleep=load_sleep)
     serial = [list(fs.batches(8, shuffle=True, seed=7, epoch=e))
-              for e in range(4)]
-    ctrl = AutotuneController(interval=0.03, min_window=4)
+              for e in range(epochs)]
+    ctrl = AutotuneController(interval=interval, min_window=4)
     pre = PrefetchFeatureSet(fs, depth=1, workers=1, controller=ctrl)
     try:
-        for e in range(4):
+        for e in range(epochs):
             got = list(pre.batches(8, shuffle=True, seed=7, epoch=e))
             assert _streams_equal(serial[e], got)
     finally:
@@ -192,7 +198,7 @@ def test_controller_grows_pipeline_and_stays_byte_identical():
     assert any(d["knob"] == "workers" and d["new"] > d["old"]
                for d in log), log
     cur = ctrl.current()
-    assert cur["workers"] > 1
+    assert cur["workers"] > 1 and cur["depth"] > 1, cur
     # every decision also landed in the flight ring
     from analytics_zoo_tpu.metrics import get_flight_recorder
     flight_autotune = get_flight_recorder().events(kind="autotune")
@@ -273,8 +279,7 @@ def test_k_hill_climb_policy_on_synthetic_costs():
 def test_k_hill_climb_explores_and_trajectory_is_bitwise_identical():
     """The online contract: exploring K during a REAL fit leaves the
     loss trajectory bit-for-bit unchanged (which K it settles on is
-    timing-dependent — the convergence quality itself is pinned by
-    bench --autotune / BENCH_AUTOTUNE_r08.json)."""
+    timing-dependent, and no test holds it)."""
     l1 = _fit_tiny(autotune=False, epochs=2, n=2048)
     ctrl = AutotuneController(k_samples=3, k_warm_skip=2)
     try:
@@ -536,23 +541,3 @@ def test_zoo_autotune_metrics_family_exported():
             "zoo_autotune_read_ahead", "zoo_autotune_k",
             "zoo_autotune_ram_budget_bytes",
             "zoo_autotune_decisions_total"} <= names, sorted(names)
-
-
-# ---------------------------------------------------------------------------
-# bench quick-tier guard (the acceptance pins)
-# ---------------------------------------------------------------------------
-
-def test_autotune_bench_quick_tier(tmp_path):
-    """CI guard: from worst-case (workers=1, depth=1) the controller
-    must reach at least the untuned-default throughput on the
-    sleep-bound synthetic with the stream byte-identical under
-    resizing.  (The full --autotune bench additionally pins >= 0.9x the
-    best hand-tuned config on BOTH synthetics —
-    BENCH_AUTOTUNE_r08.json.)"""
-    import bench
-
-    doc = bench.autotune_data_plane_bench(quick=True)
-    assert doc["deterministic_under_resizing"], doc
-    assert doc["autotuned_final_batches_per_sec"] >= \
-        doc["untuned_default_batches_per_sec"], doc
-    assert doc["decisions"], doc

@@ -1,7 +1,7 @@
 """Observability subsystem (ISSUE 1): registry semantics, exporters,
 span tracing, serving/estimator telemetry wiring — plus regression tests
-for the satellite fixes that rode the same PR (actor-worker auth, bench
-flag-probe validation, ZeRO-1 reshard exact matching)."""
+for the satellite fixes that rode the same PR (actor-worker auth,
+ZeRO-1 reshard exact matching)."""
 
 import json
 import math
@@ -29,8 +29,8 @@ from analytics_zoo_tpu.metrics import (
 
 # The `metrics` marker selects the observability-subsystem tests; the
 # satellite-regression classes at the bottom of this file ride the same
-# PR but are deliberately NOT tagged (they test actor auth / bench /
-# reshard, not telemetry).
+# PR but are deliberately NOT tagged (they test actor auth / reshard,
+# not telemetry).
 metrics_mark = pytest.mark.metrics
 
 
@@ -686,65 +686,6 @@ class TestActorWorkerAuth:
             c.close()
         finally:
             srv.close()
-
-
-class TestBenchFlagAdoption:
-    """ADVICE r05 low (bench.py:136): sweep flags must be validated in a
-    probe subprocess WITH the flags applied before being adopted."""
-
-    @pytest.fixture()
-    def bench(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "zoo_bench", os.path.join(os.path.dirname(__file__), "..",
-                                      "bench.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    @pytest.fixture()
-    def sweep_file(self, tmp_path):
-        path = str(tmp_path / "FLAGSWEEP.json")
-        with open(path, "w") as f:
-            json.dump({"best": "combo", "gain_pct": 2.0,
-                       "results": {"combo": {
-                           "flags": "--xla_tpu_fake_flag=1"}}}, f)
-        return path
-
-    def test_flags_probed_before_adoption(self, bench, sweep_file,
-                                          monkeypatch):
-        monkeypatch.delenv("XLA_FLAGS", raising=False)
-        seen = {}
-
-        def fake_probe(timeout, env=None):
-            seen["env"] = env
-            return True, "tpu 4"
-
-        adopted = bench.adopt_sweep_flags(probe=fake_probe,
-                                          path=sweep_file)
-        assert adopted == "combo (+2.0%)"
-        # probe child saw the candidate flags...
-        assert "--xla_tpu_fake_flag=1" in seen["env"]["XLA_FLAGS"]
-        # ...and only then were they committed to this process
-        assert os.environ["XLA_FLAGS"] == "--xla_tpu_fake_flag=1"
-
-    def test_failed_probe_skips_adoption(self, bench, sweep_file,
-                                         monkeypatch):
-        monkeypatch.delenv("XLA_FLAGS", raising=False)
-        adopted = bench.adopt_sweep_flags(
-            probe=lambda t, env=None: (False, "Unknown flag"),
-            path=sweep_file)
-        assert adopted is None
-        assert "XLA_FLAGS" not in os.environ
-
-    def test_cpu_fallback_probe_skips_adoption(self, bench, sweep_file,
-                                               monkeypatch):
-        monkeypatch.delenv("XLA_FLAGS", raising=False)
-        adopted = bench.adopt_sweep_flags(
-            probe=lambda t, env=None: (True, "cpu 1"), path=sweep_file)
-        assert adopted is None
-        assert "XLA_FLAGS" not in os.environ
 
 
 class TestReshardZero1:

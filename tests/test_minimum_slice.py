@@ -171,7 +171,7 @@ def test_consecutive_fits_both_train(zoo_ctx):
     """Each fit() call must train nb_epoch MORE epochs (Keras semantics).
     Regression: MaxEpoch was absolute, so a second fit(nb_epoch=1) trained
     zero steps — which would have silently voided warm-up + timed benchmark
-    patterns (bench.py)."""
+    patterns."""
     import numpy as np
 
     from analytics_zoo_tpu.pipeline.api.keras import Sequential

@@ -33,15 +33,23 @@ def _data(n=256, feat=8, classes=4, seed=0):
     return x, y
 
 
-def _model():
+def _model(feat=8, width=64, depth=1, classes=4):
     from analytics_zoo_tpu.pipeline.api.keras import Sequential
     from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
 
     m = Sequential()
-    m.add(Dense(64, activation="relu", input_shape=(8,)))
-    m.add(Dense(4, activation="softmax"))
+    m.add(Dense(width, activation="relu", input_shape=(feat,)))
+    for _ in range(depth - 1):
+        m.add(Dense(width, activation="relu"))
+    m.add(Dense(classes, activation="softmax"))
     m.compile(optimizer="adam", loss="sparse_categorical_crossentropy")
     return m
+
+
+#: the estimator's plans train both: two layers, and three of four times
+#: the width
+SIZES = {"narrow": dict(feat=8, width=64, depth=1, classes=4),
+         "wide": dict(feat=32, width=256, depth=2, classes=10)}
 
 
 # ---------------------------------------------------------------------------
@@ -248,13 +256,14 @@ class TestCompileStep:
 # ---------------------------------------------------------------------------
 
 
-def _fit_under(plan, nb_epoch=3, **fit_kw):
+def _fit_under(plan, nb_epoch=3, size="narrow", **fit_kw):
     import analytics_zoo_tpu as zoo
     from analytics_zoo_tpu.parallel.plan import per_chip_bytes
 
     zoo.init_zoo_context(seed=3, mesh_shape={"data": 8})
-    x, y = _data()
-    m = _model()
+    shape = SIZES[size]
+    x, y = _data(feat=shape["feat"], classes=shape["classes"])
+    m = _model(**shape)
     m.fit(x, y, batch_size=32, nb_epoch=nb_epoch, plan=plan, **fit_kw)
     est = m._estimator
     return {
@@ -266,22 +275,27 @@ def _fit_under(plan, nb_epoch=3, **fit_kw):
 
 
 class TestEstimatorPlans:
-    def test_fsdp_bitwise_trajectory_and_memory(self):
+    @pytest.mark.parametrize("size", list(SIZES))
+    def test_fsdp_bitwise_trajectory_and_memory(self, size):
         """The headline contract: fsdp trains bit-identically to
         replicated DP while holding <= 0.6x (measured ~0.13x) the
         per-chip param+opt bytes."""
-        dp = _fit_under(None)
-        fs = _fit_under("fsdp")
+        dp = _fit_under(None, size=size)
+        fs = _fit_under("fsdp", size=size)
         assert fs["losses"] == dp["losses"]  # BITWISE
         assert fs["spec0"] == P("data")
         assert dp["spec0"] == P()
         assert fs["bytes"] <= 0.6 * dp["bytes"], (fs["bytes"], dp["bytes"])
 
-    def test_zero1_plan_shards_opt_only(self):
-        dp = _fit_under(None)
-        z1 = _fit_under("zero1")
+    @pytest.mark.parametrize("size", list(SIZES))
+    def test_zero1_plan_shards_opt_only(self, size):
+        """zero1's program groups the gradient's reduction differently
+        (reduce-scatter into the moments, all-gather of the updates), so
+        its losses are dp's to an ulp, not to the bit."""
+        dp = _fit_under(None, size=size)
+        z1 = _fit_under("zero1", size=size)
         assert z1["spec0"] == P()  # params pinned replicated
-        assert z1["bytes"] < dp["bytes"]
+        assert z1["bytes"] <= 0.6 * dp["bytes"], (z1["bytes"], dp["bytes"])
         np.testing.assert_allclose(z1["losses"], dp["losses"],
                                    rtol=1e-5, atol=1e-6)
 
@@ -451,24 +465,3 @@ def test_every_plan_compiles_through_choke_point_and_warm_starts(tmp_path):
     assert warm["misses"] == 0, warm
     assert warm["hits"] == len(ALL_PLAN_LABELS)
     assert ALL_PLAN_LABELS <= set(warm["hlo_flops"])
-
-
-# ---------------------------------------------------------------------------
-# Quick-tier bench guard (bench.py --partition)
-# ---------------------------------------------------------------------------
-
-
-def test_partition_bench_quick_tier(tmp_path):
-    """CI guard on the bench itself: fsdp per-chip param+opt bytes <=
-    0.6x replicated at a bitwise-equal loss trajectory."""
-    sys.path.insert(0, REPO)
-    try:
-        from bench import partition_bench
-    finally:
-        sys.path.remove(REPO)
-    doc = partition_bench(quick=True,
-                          out_path=str(tmp_path / "bench.json"))
-    assert doc["trajectory_bitwise_equal"] is True
-    assert doc["value"] <= 0.6, doc["value"]
-    assert doc["zero1_ratio"] <= 0.6, doc["zero1_ratio"]
-    assert doc["zero1_trajectory_max_abs_diff"] < 1e-5

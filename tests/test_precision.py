@@ -3,7 +3,7 @@ on :class:`ShardingPlan` — bf16 compute + f32 masters/accumulation
 (``mixed_precision()``), the int8 weight-only serving role, the dtype-
 aware cost-model ceilings behind ``plan="auto"``, the generalized
 ``hlo-dtype-policy`` lint, the checkpoint's dtype-policy contract, and
-the ``bench.py --precision`` artifact's invariants.
+what the lowered step and the served weights show of the policy.
 
 The core claims pinned here:
 
@@ -18,16 +18,11 @@ The core claims pinned here:
   never collides with its f32 twin in the compiled-step cache.
 """
 
-import os
-import sys
-
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -436,35 +431,70 @@ class TestInt8Serving:
 
 
 # ---------------------------------------------------------------------------
-# Bench quick tier (the acceptance guard on bench.py --precision)
+# What the lowered programs and the served weights show of the policy
 # ---------------------------------------------------------------------------
 
 
-def test_precision_bench_quick_tier(tmp_path):
-    """CI guard on the bench itself: bf16 trajectory within tolerance
-    of f32, a measured bf16 histogram shift, the predicted 2/3 fsdp
-    collective-bytes ratio, and the int8 serving bytes/parity numbers.
-    CPU tier: throughput wins recorded, not required."""
-    sys.path.insert(0, REPO)
-    try:
-        from bench import precision_bench
-    finally:
-        sys.path.remove(REPO)
-    doc = precision_bench(quick=True, out_path=str(tmp_path / "b.json"))
-    assert doc["value"] <= 0.05, doc["value"]
-    shift = doc["bf16_hlo_shift"]
-    assert shift["f32_leg_bf16_ops"] == 0
-    assert shift["bf16_leg_bf16_ops"] > 0
-    assert doc["predicted_fsdp_collective_bytes"]["ratio"] < 1.0
-    assert doc["int8_serving_bytes_ratio"] < 0.5
-    assert doc["legs"]["int8_serving"]["predict_max_abs_diff"] < 0.05
-    legs = doc["legs"]
-    assert legs["bf16"]["plan"] == "dp+bf16"
-    assert legs["bf16"]["dtype_policy"] == ".*=bf16"
-    # the compile plane saw both programs (per-plan labels, distinct
-    # cache keys): each leg carries its own feature block, and the
-    # bf16 leg moves fewer bytes through the lowered program
-    assert legs["bf16"]["hlo"]["zoo_hlo_bytes_accessed"] \
-        < legs["f32"]["hlo"]["zoo_hlo_bytes_accessed"]
-    # a bench row is load_bench_rows-harvestable (steps_per_sec + hlo)
-    assert legs["f32"]["steps_per_sec"] > 0
+def _wide_fit(plan, report_dir, monkeypatch):
+    """Two epochs of a 32 -> 256 -> 256 -> 10 net under ``plan`` on the
+    8-device mesh; returns the model, its losses, the compile plane's
+    features of the step and the report's dtype histogram of it."""
+    import analytics_zoo_tpu as zoo
+    from analytics_zoo_tpu.analysis.costmodel import load_report_rows
+    from analytics_zoo_tpu.analysis.hlo import last_features
+    from analytics_zoo_tpu.parallel.plan import resolve_plan
+    from analytics_zoo_tpu.pipeline.api.keras import Sequential
+    from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
+
+    monkeypatch.setenv("ZOO_HLO_REPORT_DIR", report_dir)
+    zoo.init_zoo_context(seed=11, mesh_shape={"data": 8}, platform="cpu")
+    plan = resolve_plan(plan)
+    rng = np.random.default_rng(7)
+    x = rng.normal(size=(512, 32)).astype(np.float32)
+    y = np.argmax(x @ rng.normal(size=(32, 10)), axis=1).astype(np.int32)
+    m = Sequential()
+    m.add(Dense(256, activation="relu", input_shape=(32,)))
+    m.add(Dense(256, activation="relu"))
+    m.add(Dense(10, activation="softmax"))
+    m.compile(optimizer="adam", loss="sparse_categorical_crossentropy")
+    m.fit(x, y, batch_size=64, nb_epoch=2, plan=plan)
+    label = "train_step" if plan.name == "dp" \
+        else f"train_step_{plan.name}"
+    row = next(r for r in load_report_rows(report_dir)
+               if r["label"] == label)
+    return {"model": m, "x": x, "hlo": last_features(label),
+            "losses": [h["loss"] for h in m._estimator.history],
+            "dtype_histogram": row["dtype_histogram"] or {}}
+
+
+def test_bf16_shift_shows_in_the_lowered_step_and_int8_in_served_bytes(
+        tmp_path, monkeypatch):
+    """f32 against ``mixed_precision()``: the trajectory within
+    tolerance, bf16 ops in the bf16 step's report and none in the f32
+    one's, fewer bytes through the lowered program.  Then the f32 leg's
+    trained weights quantized under ``int8_serving()``: under half the
+    bytes, and ``predict`` within 0.05 of the f32 answers."""
+    from analytics_zoo_tpu.parallel.plan import int8_serving, mixed_precision
+    from analytics_zoo_tpu.pipeline.inference.quantize import (
+        dequantize_params,
+        quantize_params_for_plan,
+        quantized_bytes_ratio,
+    )
+
+    f32 = _wide_fit("dp", str(tmp_path / "f32"), monkeypatch)
+    bf16 = _wide_fit(mixed_precision(), str(tmp_path / "bf16"), monkeypatch)
+    assert max(abs(a - b) / max(abs(a), 1e-9)
+               for a, b in zip(f32["losses"], bf16["losses"])) <= 0.05
+    assert f32["dtype_histogram"].get("bf16", 0) == 0
+    assert bf16["dtype_histogram"].get("bf16", 0) > 0
+    # per-plan labels, distinct cache keys: each leg has its own features
+    assert bf16["hlo"]["bytes_accessed"] < f32["hlo"]["bytes_accessed"]
+
+    m, x = f32["model"], f32["x"]
+    params = m.params
+    qparams = quantize_params_for_plan(params, int8_serving())
+    assert quantized_bytes_ratio(params, qparams) < 0.5
+    base = np.asarray(m.predict(x[:64]))
+    m._estimator.model.params = dequantize_params(qparams)
+    served = np.asarray(m.predict(x[:64]))
+    assert float(np.max(np.abs(base - served))) < 0.05

@@ -1,9 +1,7 @@
 """Parallel host data plane (feature/prefetch.py): ordered deterministic
 delivery, worker-exception propagation, clean shutdown, shard read-ahead,
-estimator composition, and the --data-pipeline bench quick tier."""
+estimator composition, and the overlap of sleep-bound host work."""
 
-import os
-import sys
 import threading
 import time
 
@@ -16,8 +14,6 @@ from analytics_zoo_tpu.feature.prefetch import (
     PrefetchFeatureSet,
     PrefetchPipeline,
 )
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def assert_streams_identical(a_batches, b_batches):
@@ -236,22 +232,45 @@ def test_pipeline_rejects_bad_knobs(bad):
         PrefetchPipeline(iter([]), **bad)
 
 
-def test_data_pipeline_bench_quick_tier(tmp_path):
-    """CI guard: the quick-sized --data-pipeline bench must show the
-    acceptance speedup (>= 2x with 4 workers on a sleep-bound loader)
-    and a byte-identical stream, so pipeline regressions fail loudly."""
-    import json
+def test_prefetched_host_work_overlaps_and_stream_is_identical():
+    """A loader that sleeps a shard and a transform that sleeps a record
+    (IO-shaped: both release the GIL) count their calls in flight.
+    Serially there is one at a time; four workers hold at least two at
+    once, and deliver the serial stream byte for byte."""
+    lock = threading.Lock()
+    in_flight = {"now": 0, "peak": 0}
 
-    import bench
+    def sleeping(seconds):
+        with lock:
+            in_flight["now"] += 1
+            in_flight["peak"] = max(in_flight["peak"], in_flight["now"])
+        time.sleep(seconds)
+        with lock:
+            in_flight["now"] -= 1
 
-    out = str(tmp_path / "BENCH_DATA_quick.json")
-    doc = bench.data_pipeline_bench(
-        n_shards=4, shard_records=32, batch_size=8,
-        load_sleep_ms=15.0, transform_sleep_ms=1.0, out_path=out)
-    assert doc["deterministic"], doc
-    assert doc["speedup"] >= 2.0, doc
-    with open(out) as f:
-        artifact = json.load(f)
-    assert artifact["prefetched_batches_per_sec"] > \
-        artifact["serial_batches_per_sec"]
-    assert "consumer_wait_s" in artifact
+    def load(path):
+        sleeping(0.015)
+        rng = np.random.default_rng(1234 + int(path.rsplit("-", 1)[-1]))
+        return {"x": rng.standard_normal((32, 16)).astype("float32"),
+                "y": rng.integers(0, 10, size=(32,)).astype("int32")}
+
+    def slow_identity(record):
+        sleeping(0.001)
+        return record
+
+    fs = ShardedFeatureSet(
+        [f"synth://shard-{i}" for i in range(4)], n_slices=4,
+        loader=load, sizer=lambda p: 32,
+    ).transform(FnPreprocessing(slow_identity))
+
+    def drain(feature_set):
+        in_flight["peak"] = 0
+        out = list(feature_set.batches(8, shuffle=True, seed=7, epoch=0))
+        return out, in_flight["peak"]
+
+    serial, serial_peak = drain(fs)
+    prefetched, prefetched_peak = drain(
+        PrefetchFeatureSet(fs, depth=8, workers=4))
+    assert_streams_identical(serial, prefetched)
+    assert serial_peak == 1
+    assert prefetched_peak >= 2

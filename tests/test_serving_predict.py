@@ -3,11 +3,9 @@ bucket-stamped report join, the choose_serving verdict contract with
 its logged prediction->outcome pairs, the oracle-seeded scaler prior,
 admission accept/shed hysteresis with the typed client reject, the
 two-model router, ZOO_SERVING_MODELS parsing, the ZooConfig knobs, and
-the --serving-predict bench quick-tier guard."""
+the verdict priming a fleet end to end."""
 
 import json
-import os
-import sys
 import time
 
 import numpy as np
@@ -37,8 +35,6 @@ from analytics_zoo_tpu.serving.modelspec import (
     parse_model_specs,
 )
 from analytics_zoo_tpu.serving.scaler import FleetSignals, SloScaler
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -389,23 +385,54 @@ def test_zooconfig_serving_knobs_validate_eagerly(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# bench quick-tier guard
+# verdict -> scaler prior -> fleet, end to end
 # ---------------------------------------------------------------------------
 
-def test_serving_predict_bench_quick_tier():
-    """CI guard (the --serving-predict bench's priming half): the
-    oracle-primed fleet takes the 10x load step with no more hard
-    SLO-violation windows than the reactive baseline, and the logged
-    per-bucket predictions close within 50% of measured."""
-    sys.path.insert(0, REPO_ROOT)
+def test_oracle_verdict_primes_the_fleet_before_any_traffic(tmp_path):
+    """``choose_serving`` predicts the replica target for an offered 80
+    records/s from the per-bucket serving cost model (20 ms a record: one
+    replica saturates near 50), logging a prediction for every bucket;
+    the target seeds the scaler, and the fleet STARTS there: its first
+    decision is the prior's, with no record enqueued yet.  The primed
+    fleet then answers a burst."""
+    from analytics_zoo_tpu.serving import ClusterServingHelper
+    from analytics_zoo_tpu.serving.fleet import (
+        FleetController,
+        _SyntheticModel,
+    )
+
+    service_ms, buckets = 20.0, (8, 16)
+    oracle = ConfigOracle(peaks=_cpu_peaks())
+    verdict = oracle.choose_serving(
+        {b: _bucket_feats(b, service_ms) for b in buckets},
+        slo_p99_ms=400.0, offered_rate=80.0, model="step")
+    assert {f"serving:step:b{b}" for b in buckets} \
+        <= {row["config"] for row in oracle.prediction_log()}
+    assert 1 < verdict["replicas"] <= 3, verdict
+
+    broker = InMemoryBroker()
+    ctrl = FleetController(
+        ClusterServingHelper(
+            model_path=None, batch_size=8, batch_budget_ms=5.0,
+            lease_ms=5_000, log_dir=str(tmp_path)),
+        broker, model_factory=lambda: _SyntheticModel(service_ms),
+        scaler=SloScaler(slo_p99_ms=400.0, min_replicas=1, max_replicas=3,
+                         up_windows=2, down_windows=10_000,
+                         prior_target=verdict["replicas"]),
+        interval=0.25)
+    ctrl.start()
     try:
-        from bench import serving_predict_primed_bench
+        first = ctrl.decision_log()[0]
+        assert (first["reason"], first["new"]) \
+            == ("oracle_prior", verdict["replicas"])
+        assert ctrl.replica_count() == verdict["replicas"]
+        inq, outq = InputQueue(broker=broker), OutputQueue(broker=broker)
+        for i in range(40):
+            inq.enqueue(f"q{i}", np.zeros((8,), np.float32))
+        served, deadline = {}, time.time() + 60
+        while len(served) < 40 and time.time() < deadline:
+            served.update(outq.dequeue())
+            time.sleep(0.01)
     finally:
-        sys.path.pop(0)
-    out = serving_predict_primed_bench(quick=True)
-    assert out["primed"]["violation_windows"] \
-        <= out["reactive"]["violation_windows"], out
-    assert out["primed"]["decisions"][0]["reason"] == "oracle_prior"
-    assert out["predict_rel_error_by_bucket"], out
-    for config, err in out["predict_rel_error_by_bucket"].items():
-        assert err <= 0.5, (config, err)
+        ctrl.stop()
+    assert sorted(served) == sorted(f"q{i}" for i in range(40))

@@ -1,11 +1,9 @@
 """zoowatch federation plane (ISSUE 17): time-series windows, SLO
 burn-rate engine, cross-host scraping, federated scaling signals, the
 supervisor's heartbeat SLO, flight-dump merging, and the metrics-docs
-drift gate — plus the two acceptance bench guards.
-
-Alphabetically this file sorts AFTER the tier-1 timeout horizon, so the
-heavy e2e guards at the bottom run in the quick tier (conftest
-QUICK_FILES) and nightly, like test_fleet.py's scaling guard."""
+drift gate — plus the two acceptance runs at the bottom (heavy e2e: a
+process-mode fleet behind a scraper, and a chaos run's merged flight
+dumps)."""
 
 import json
 import math
@@ -862,42 +860,209 @@ class TestMetricsDocsDrift:
 
 
 # ---------------------------------------------------------------------------
-# acceptance bench guards (heavy e2e — quick tier + nightly)
+# the two acceptance runs (heavy e2e — quick tier + nightly)
 # ---------------------------------------------------------------------------
 
 
 class TestFederatedAcceptance:
-    def _bench(self):
-        if REPO not in sys.path:
-            sys.path.insert(0, REPO)
-        import bench
-
-        return bench
-
-    def test_federated_scaler_bench_quick_tier(self):
+    def test_burn_alert_fires_before_the_hard_slo_violation(self, tmp_path):
         """A process-mode fleet's per-replica /varz is scraped; the
         scaler runs ONLY on the federated view through a 10x load step;
         the burn alert fires at /alertz before the first hard SLO
-        violation window (the ISSUE 17 acceptance)."""
-        res = self._bench().federated_scaler_bench(quick=True)
-        assert res["federated"] is True
-        assert res["scrape_targets_final"] >= 1
-        assert res["scaled_up"] and res["max_replicas_seen"] >= 2
-        assert res["alert_t_s"] is not None
-        assert res["alert_before_hard_violation"] is True
-        assert max(res["hosts_seen"]) >= 1
-        assert res["served"] == res["enqueued"]
+        violation window (the ISSUE 17 acceptance).  The SLO spec's
+        threshold is the per-dispatch latency budget (batches filling
+        up is the leading indicator of saturation), so the multi-window
+        burn crosses while the client-visible p99 is still inside the
+        SLO."""
+        import numpy as np
 
-    def test_chaos_explainability_bench_quick_tier(self, tmp_path):
+        from analytics_zoo_tpu.metrics import (
+            MetricsServer, VarzScraper, fleet_varz_targets)
+        from analytics_zoo_tpu.serving import (
+            ClusterServingHelper, InputQueue, OutputQueue)
+        from analytics_zoo_tpu.serving.broker import connect_broker
+        from analytics_zoo_tpu.serving.fleet import FleetController
+        from analytics_zoo_tpu.serving.scaler import (
+            FederatedSignalSource, SloScaler)
+
+        service_ms = 20.0          # one replica saturates at ~50 rec/s
+        slo_p99_ms = 400.0         # the HARD serving SLO (sojourn estimate)
+        dispatch_budget_s = 0.08   # SLO-spec threshold: per-dispatch budget
+        phases = ((8.0, 3.0), (80.0, 10.0))  # (records/s, seconds): 10x
+
+        broker_spec = "dir:" + str(tmp_path / "spool")
+        db = connect_broker(broker_spec)
+        store = TimeSeriesStore(capacity=1024)
+        engine = SloEngine(store, [SloSpec(
+            "predict_latency", "zoo_serving_predict_seconds",
+            threshold=dispatch_budget_s, objective=0.95,
+            short_window=1.5, long_window=6.0, burn_threshold=1.0)])
+        scraper = VarzScraper(
+            store=store, engine=engine, interval=0.2, timeout=5.0,
+            discover=fleet_varz_targets(db))
+        srv = MetricsServer(port=0).start()  # the /alertz polled below
+        ctrl = FleetController(
+            ClusterServingHelper(
+                model_path=None, batch_size=8, batch_budget_ms=10.0,
+                lease_ms=5_000, log_dir=str(tmp_path / "logs")),
+            broker_spec,
+            scaler=SloScaler(slo_p99_ms=slo_p99_ms, min_replicas=1,
+                             max_replicas=3, up_windows=2,
+                             down_windows=10_000),
+            interval=0.4, mode="process",
+            signal_source=FederatedSignalSource(
+                store, db, "image_stream", scraper=scraper),
+            replica_metrics=True,
+            replica_extra_args=("--synthetic-sleep-ms", str(service_ms)))
+
+        marks = {"alert": None, "hard_violation": None}
+        replicas_seen, hosts_seen = [1], set()
+        stop = threading.Event()
+
+        def sampler():
+            while not stop.is_set():
+                now = time.time()
+                cur = ctrl.current()
+                win = cur["window"]
+                # the sojourn estimate the scaler acts on, recomputed from
+                # the federated window: predict p99 + backlog drain time
+                est_ms = win["predict_p99_ms"]
+                if win["queue_depth"]:
+                    est_ms = est_ms + (
+                        win["queue_depth"] / win["service_rate"] * 1e3
+                        if win["service_rate"] > 0 else float("inf"))
+                if marks["hard_violation"] is None and est_ms > slo_p99_ms:
+                    marks["hard_violation"] = now
+                if marks["alert"] is None:
+                    try:
+                        with urllib.request.urlopen(
+                                srv.url + "/alertz", timeout=2) as r:
+                            if json.load(r).get("firing"):
+                                marks["alert"] = now
+                    except (OSError, ValueError):
+                        pass
+                replicas_seen.append(cur["replicas"])
+                if cur["hosts"] is not None:
+                    hosts_seen.add(cur["hosts"])
+                time.sleep(0.1)
+
+        served = {}
+        outq = OutputQueue(broker=db)
+
+        def collector():
+            while not stop.is_set():
+                served.update(outq.dequeue())
+                time.sleep(0.01)
+
+        scraper.start()
+        ctrl.start()
+        seq = 0
+        try:
+            # wait for discovery: the scraper must see the first replica's
+            # /telemetryz before load starts (the federated view is the
+            # ONLY view the scaler has)
+            deadline = time.time() + 120
+            while time.time() < deadline:
+                hz = scraper.healthz()
+                if hz["healthy"] and hz["targets"]:
+                    break
+                time.sleep(0.1)
+            else:
+                pytest.fail("scraper never discovered a replica: %r"
+                            % scraper.healthz())
+            threading.Thread(target=sampler, daemon=True).start()
+            threading.Thread(target=collector, daemon=True).start()
+            inq = InputQueue(broker=db)
+            rec = np.zeros((8,), np.float32)
+            for rate, duration in phases:
+                t_phase = time.perf_counter()
+                while time.perf_counter() - t_phase < duration:
+                    inq.enqueue(f"q{seq}", rec)
+                    seq += 1
+                    time.sleep(1.0 / rate)
+            deadline = time.time() + 240
+            while len(served) < seq and time.time() < deadline:
+                time.sleep(0.1)
+        finally:
+            stop.set()
+            ctrl.stop()
+            scraper.stop()
+            srv.stop()
+
+        assert ctrl.current()["federated"] is True
+        assert len(scraper.healthz()["targets"]) >= 1
+        assert any(d["action"] == "up" for d in ctrl.decision_log())
+        assert max(replicas_seen) >= 2
+        alert, hard = marks["alert"], marks["hard_violation"]
+        assert alert is not None
+        assert hard is None or alert <= hard  # an order of events
+        assert max(hosts_seen) >= 1
+        assert len(served) == seq
+
+    def test_every_rejoin_and_respawn_has_its_cause_on_one_timeline(
+            self, tmp_path):
         """A ChaosSchedule elastic run's per-process flight dumps merge
         into ONE timeline where every generation change, takeover and
         respawn has its cause event within clock-skew tolerance."""
-        res = self._bench().chaos_explainability_bench(
-            quick=True, keep_artifacts_in=str(tmp_path))
-        assert res["flight_dumps_merged"] >= 3
-        assert res["chaos_events_seen"] >= 1
-        assert res["generation_changes"] >= 2
-        assert res["skew_beyond_tolerance"] == []
-        assert res["all_effects_have_causes"] is True
-        assert all(e["cause"] for e in res["explained"])
-        assert os.path.exists(res["merged_trace_artifact"])
+        from analytics_zoo_tpu.elastic import ChaosSchedule, TrainSupervisor
+        from analytics_zoo_tpu.metrics import get_flight_recorder
+
+        _tools()
+        import flight_merge
+
+        flight_dir = str(tmp_path / "flight")
+        spec = dict(ckpt_dir=str(tmp_path / "ckpt"), nb_epoch=3,
+                    plan="dp", k=1, throttle_s=0.08)
+        total_steps = (256 // 32) * spec["nb_epoch"]
+        chaos = ChaosSchedule.parse(f"kill@{total_steps // 2}:w1")
+        sup = TrainSupervisor(
+            "dir:" + str(tmp_path / "spool"), spec, workers=3,
+            lease_ms=800, min_workers=1, interval=0.1, chaos=chaos,
+            worker_env={"ZOO_FLIGHT_DIR": flight_dir})
+        run_start = time.time()
+        res = sup.run(timeout_s=420)
+        assert res is not None, sup.decision_log()
+        # the supervisor's own ring is the third process-perspective
+        # (workers dumped theirs on exit/SIGTERM; the SIGKILLed
+        # incarnation could not — its death is explained by the
+        # supervisor's chaos event instead).  Written directly so the
+        # global recorder's dump-dir/once-per-reason state is untouched.
+        os.makedirs(flight_dir, exist_ok=True)
+        sup_doc = get_flight_recorder().to_doc("supervisor")
+        # the process-global ring may hold elastic events from earlier
+        # tests in this interpreter whose worker dumps are not in this
+        # run's flight_dir — they would show up as uncaused effects
+        sup_doc["events"] = [e for e in sup_doc["events"]
+                             if e.get("ts", 0.0) >= run_start]
+        with open(os.path.join(
+                flight_dir, f"flight-{os.getpid()}-supervisor.json"),
+                "w") as f:
+            json.dump(sup_doc, f)
+
+        merged = flight_merge.merge_flight_docs(
+            flight_merge.load_inputs([flight_dir]))
+        out_trace = str(tmp_path / "chaos_trace.json")
+        flight_merge.write_outputs(merged, out=out_trace)
+
+        elastic = [e for e in merged["timeline"]
+                   if e.get("kind") == "elastic"]
+        rejoins = [e for e in elastic if e.get("event") == "rejoin"]
+        respawns = [e for e in elastic if e.get("event") == "respawn"]
+
+        def cause_of(effect):
+            """Nearest earlier event that explains `effect` — the
+            chaos kill, a worker leave/join, or a respawn."""
+            causes = [e for e in elastic
+                      if e["t"] <= effect["t"] and e is not effect
+                      and e.get("event") in ("chaos", "leave", "join",
+                                             "respawn")]
+            return causes[-1] if causes else None
+
+        assert merged["sources"] >= 3
+        assert any(e.get("event") == "chaos" for e in elastic)
+        assert len(rejoins) >= 2
+        assert [s for s, v in merged["skew"].items()
+                if v["beyond_tolerance"]] == []
+        for effect in rejoins + respawns:
+            assert cause_of(effect) is not None, effect
+        assert os.path.exists(out_trace)

@@ -29,7 +29,7 @@ Usage::
         [--trace trace.json ...] [--out merged_trace.json]
         [--narrative narrative.txt] [--skew-tolerance-s 0.25]
 
-Library surface (used by tests and bench.py): :func:`load_inputs`,
+Library surface (used by tests): :func:`load_inputs`,
 :func:`merge_flight_docs`, :func:`write_outputs`.
 """
 

@@ -2,8 +2,7 @@
 latency/throughput summary table.
 
 Reads the output of ``analytics_zoo_tpu.metrics.exporters.write_jsonl``
-(one registry snapshot per line — e.g. what ``bench.py`` appends when
-``ZOO_METRICS_JSONL`` is set), or scrapes one snapshot from a running
+(one registry snapshot per line), or scrapes one snapshot from a running
 process's ``/varz`` endpoint (``MetricsServer``, ZOO_METRICS_PORT), and
 prints, for the LATEST snapshot:
 

@@ -106,12 +106,11 @@ def run(seq=1024, batch=8, blocks=12, hidden=768, heads=12, vocab=32768,
         "train_flops_per_step": train_flops,
     }
     if d.platform == "tpu":
-        from bench import peak_flops_for
+        from analytics_zoo_tpu.analysis.costmodel import resolve_peaks
 
-        peak = peak_flops_for(d.device_kind)
-        if peak:
-            out["mfu"] = round(train_flops / dt / peak, 4)
-            out["peak_flops_assumed"] = peak
+        peak = resolve_peaks(d.platform, d.device_kind).flops
+        out["mfu"] = round(train_flops / dt / peak, 4)
+        out["peak_flops_assumed"] = peak
     return out
 
 
