@@ -16,6 +16,7 @@ sharded) variants swap in without touching this layer.  Long-context support
 from __future__ import annotations
 
 import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -499,13 +500,86 @@ class BERT(_TransformerCore):
         return [(b, l, self.hidden_size), (b, self.hidden_size)]
 
 
+# -- the looped decoder's head: loss and gradient in one walk ---------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _weighted_ce(n, kernel, s, targets, w, head_grad):
+    """(sum_tok w CE(s kernel, targets), CE a token float32 (B, L),
+    ``head_grad`` + d sum / d kernel), walked in ``n`` blocks of positions.
+    The weights ``w`` are an input, so a block's gradient is made while its
+    logits are live and the backward pass only scales what was kept.
+
+    Private to ``LoopedDecoder``: the third result is the head's gradient at
+    cotangent one, summed over the passes it is threaded through, and is
+    right only where the caller adds the first results with weight one and
+    hands the sum with the last ``head_grad`` to ``_deliver``; ``kernel``
+    and ``head_grad`` get no cotangent here, and none flows through CE."""
+    return _weighted_ce_fwd(n, kernel, s, targets, w, head_grad)[0]
+
+
+def _weighted_ce_fwd(n, kernel, s, targets, w, head_grad):
+    b, l = targets.shape
+
+    def blocked(x):     # (B, L, ...) -> (n, B, L / n, ...)
+        return jnp.moveaxis(
+            x.reshape((b, n, l // n) + x.shape[2:]), 1, 0)
+
+    def whole(x):       # and back
+        return jnp.moveaxis(x, 0, 1).reshape((b, l) + x.shape[3:])
+
+    def block(head_grad, args):
+        s_blk, y_blk, w_blk = args
+        logits = (s_blk @ kernel).astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        onehot = y_blk[..., None] == jnp.arange(logits.shape[-1])
+        picked = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
+        # rounded where the transpose of the logits' astype would round it
+        dlogits = ((jnp.exp(logits - lse[..., None]) - onehot)
+                   * w_blk[..., None]).astype(s.dtype)
+        head_grad = head_grad + jnp.einsum(
+            "bld,blv->dv", s_blk, dlogits,
+            preferred_element_type=jnp.float32)
+        return head_grad, (lse - picked, dlogits @ kernel.T)
+
+    head_grad, (ce, ds) = jax.lax.scan(
+        block, head_grad, (blocked(s), blocked(targets), blocked(w)))
+    ce = whole(ce)
+    return (jnp.sum(w * ce), ce, head_grad), (ce, whole(ds))
+
+
+def _weighted_ce_bwd(_n, kept, cotangents):
+    ce, ds = kept
+    g = cotangents[0]
+    return None, (g * ds).astype(ds.dtype), None, g * ce, None
+
+
+_weighted_ce.defvjp(_weighted_ce_fwd, _weighted_ce_bwd)
+
+
+@jax.custom_vjp
+def _deliver(total, kernel, head_grad):
+    """``total``, whose cotangent g reaches ``kernel`` as g x ``head_grad``:
+    where the sum that ``_weighted_ce`` made over the passes is handed to
+    the head, once.  ``kernel`` is a float32 view of the head, so that the
+    view's own transpose rounds the sum to the head's dtype."""
+    return total
+
+
+_deliver.defvjp(lambda total, kernel, head_grad: (total, head_grad),
+                lambda head_grad, g: (g, g * head_grad, None))
+
+
 #: Trace-time record of each looped stack traced, newest last (plain
 #: arithmetic on static structure, like ``flash_attention.tile_schedules``:
 #: jit traces once, so it counts compilations): ``layer``, ``training``,
 #: ``passes``, ``layers``, ``layer_applications`` (passes x layers, over one
 #: set of weights), ``head_evaluations`` (one a pass where the layer takes
 #: the exit-gate loss itself, one where only the last pass's logits are
-#: made), ``loop`` (how the passes are traced: unrolled), ``remat`` (the policy
+#: made), ``head_products`` (products over the vocabulary that the layer
+#: traces: 3 x passes x ``loss_blocks`` under its own loss, a block's logits
+#: and both products of its gradient, all in the forward pass, and none in
+#: the backward pass; 1 otherwise, logits_T, to which differentiation adds
+#: its two), ``loop`` (how the passes are traced: unrolled), ``remat`` (the policy
 #: resolved for a layer application), ``kept`` (the ``checkpoint_name``s that
 #: policy keeps for the backward pass; none under ``"full"``) and
 #: ``loss_blocks`` (token blocks a pass's head and cross-entropy are taken in;
@@ -525,14 +599,28 @@ class LoopedDecoder(_TransformerCore):
     Input (B, L) token ids, output logits_T (B, L, vocab).
 
     Trained with ``loss="looped_exit_cross_entropy"`` the layer takes the
-    loss itself, a pass at a time in blocks of ``loss_block`` tokens,
-    recomputed in the backward pass, so that only one block's logits are
-    ever live: with exit distribution p_t = lambda_t prod_{j<t}
-    (1 - lambda_j), p_T = prod_{j<T} (1 - lambda_j), a token costs
-    sum_t p_t CE(logits_t, y) - exit_beta H(p).  It reports the mean under
-    ``loop_exit_cost`` of its state, which the train step adds to the
-    loss, with ``loop_exit_mass`` (mean p_t) and ``loop_pass_loss`` (mean
-    CE_t), a number a pass.  With any other loss only logits_T is made.
+    loss itself, a pass at a time in blocks of ``loss_block`` tokens, so
+    that only one block's logits are ever live: with exit distribution
+    p_t = lambda_t prod_{j<t} (1 - lambda_j), p_T = prod_{j<T}
+    (1 - lambda_j), a token costs sum_t p_t CE(logits_t, y) - exit_beta
+    H(p).  It reports the mean under ``loop_exit_cost`` of its state,
+    which the train step adds to the loss, with ``loop_exit_mass`` (mean
+    p_t) and ``loop_pass_loss`` (mean CE_t), a number a pass.  With any
+    other loss only logits_T is made.
+
+    The head's gradients are made where its logits are made: p_t comes
+    from the gates, not from CE_t, so a token's weight p_t / N is known in
+    the forward pass, and while a block's logits are live the walk also
+    makes ``dlogits = (softmax - onehot) x weight``, ``dlogits @ W_head^T``
+    and ``s^T @ dlogits``.  The backward pass of the head scales what was
+    kept by the cotangent and runs no product over the vocabulary.  Kept:
+    for the layer ONE float32 sum of the head's gradient over blocks and
+    passes (hidden x vocab x 4 bytes, 403 MB at 2048 x 49152), handed to
+    the head once; a pass its ``ds`` in the compute dtype (33.5 MB at
+    (2, 4096, 2048) bf16, 134 MB over four passes) and CE_t (B, L) float32
+    (32 KB).  On a v5e the step of 4 passes over 6 layers is 4.2% shorter
+    than with the logits made again and its peak 0.63 GB lower (PERF.md,
+    PR 31).
 
     Every layer application is one ``jax.checkpoint`` under the ``"attn"``
     policy (``remat=``; a plan's ``remat_rules`` override it).  Besides its
@@ -610,44 +698,52 @@ class LoopedDecoder(_TransformerCore):
                      if length % n == 0
                      and batch * (length // n) <= self.loss_block), length)
 
-    def _token_ce(self, kernel, s, targets):
-        """CE(s W_head, y) a token, float32 (B, L): a block of positions
-        at a time, each block's logits made again in the backward pass."""
-        from analytics_zoo_tpu.parallel.plan import apply_remat
-
-        b, l, d = s.shape
-        n = self._loss_blocks(b, l)
-
-        def blocked(x):     # (B, L, ...) -> (n, B, L / n, ...)
-            return jnp.moveaxis(
-                x.reshape((b, n, l // n) + x.shape[2:]), 1, 0)
-
-        def block_ce(args):
-            s_blk, y_blk = args
-            logits = (s_blk @ kernel).astype(jnp.float32)
-            picked = jnp.take_along_axis(logits, y_blk[..., None],
-                                         axis=-1)[..., 0]
-            return jax.nn.logsumexp(logits, axis=-1) - picked
-
-        ce = jax.lax.map(apply_remat(block_ce, "full"),
-                         (blocked(s), blocked(targets.astype(jnp.int32))))
-        return jnp.moveaxis(ce, 0, 1).reshape(b, l)
-
     def _exit_tail(self, params, s, targets, log_survive, last):
         """After one pass: its cost to the loss, mean exit mass and mean
         CE, and log prod_{j<=t} (1 - lambda_j) for the next.  ``last``:
-        the final pass takes what is left, whatever its gate says."""
-        ce = self._token_ce(params["head_kernel"], s, targets)
+        the final pass takes what is left, whatever its gate says.
+        ``params["head_grad"]``, the running sum of the head's gradient,
+        is replaced by the sum with this pass's in it (``_weighted_ce``)."""
         z = (s.astype(jnp.float32)
              @ params["exit_kernel"].astype(jnp.float32))[..., 0] \
             + params["exit_bias"].astype(jnp.float32)
         log_p = log_survive if last \
             else jax.nn.log_sigmoid(z) + log_survive
         p = jnp.exp(log_p)
-        # sum_t p_t CE_t - beta H(p) = sum_t p_t (CE_t + beta log p_t)
-        cost = jnp.mean(p * (ce + self.exit_beta * log_p))
+        # sum_t p_t CE_t - beta H(p) = sum_t p_t (CE_t + beta log p_t);
+        # the first term a token at weight p / N through the head
+        weighted, ce, params["head_grad"] = _weighted_ce(
+            self._loss_blocks(*targets.shape), params["head_kernel"], s,
+            targets.astype(jnp.int32), p / p.size, params["head_grad"])
+        cost = weighted + self.exit_beta * jnp.mean(p * log_p)
         return (cost, jnp.mean(p), jnp.mean(ce),
                 log_survive + jax.nn.log_sigmoid(-z))
+
+    def _exit_loss(self, params, states, targets):
+        """The layer's state under its own loss, from the passes' states,
+        which are taken one at a time (``call`` makes each as it is asked
+        for, so a pass's tail is traced right after the pass)."""
+        log_survive = jnp.zeros(targets.shape, jnp.float32)
+        # one dict for every pass's tail, each of which replaces its
+        # "head_grad": the sum travels beside the weights because
+        # ``_exit_tail`` keeps its arguments and results (the benchmark's
+        # tests plant their fault by replacing it)
+        tail_params = {**params, "head_grad": jnp.zeros(
+            params["head_kernel"].shape, jnp.float32)}
+        costs, mass, pass_loss = [], [], []
+        for t, s in enumerate(states):
+            cost, p_mean, ce_mean, log_survive = self._exit_tail(
+                tail_params, s, targets, log_survive, t == self.passes - 1)
+            costs.append(cost)
+            mass.append(p_mean)
+            pass_loss.append(ce_mean)
+        # the costs are added here with weight one, which is what makes the
+        # sum over the passes the head's gradient (``_weighted_ce``)
+        total = _deliver(
+            sum(costs), params["head_kernel"].astype(jnp.float32),
+            tail_params["head_grad"])
+        return {"loop_exit_cost": total, "loop_exit_mass": jnp.stack(mass),
+                "loop_pass_loss": jnp.stack(pass_loss)}
 
     def call(self, params, inputs, state=None, training=False, rng=None):
         from analytics_zoo_tpu.parallel.plan import (
@@ -662,10 +758,10 @@ class LoopedDecoder(_TransformerCore):
 
         # The final norm keeps its input alone for the backward pass, as a
         # layer application does under its policy.  The tail is NOT under
-        # jax.checkpoint (its cross-entropy blocks are): recomputed on the
-        # chip in the backward pass its float32 arithmetic lost the gate's
-        # gradient, which is a small difference between the passes' large
-        # shares (PERF.md, PR 27); it keeps a few (B, L) arrays a pass.
+        # jax.checkpoint: recomputed on the chip in the backward pass its
+        # float32 arithmetic lost the gate's gradient, which is a small
+        # difference between the passes' large shares (PERF.md, PR 27); it
+        # keeps a few (B, L) arrays a pass.
         final = apply_remat(
             lambda gamma, h: _rms_norm(h, gamma, self.norm_eps), "full")
 
@@ -675,15 +771,17 @@ class LoopedDecoder(_TransformerCore):
 
         takes_loss = targets is not None
         policy = resolve_remat(self.name or "blocks", default=self.remat)
+        loss_blocks = self._loss_blocks(*tokens.shape) if takes_loss else 0
         loop_records.append({
             "layer": self.name, "training": bool(training),
             "passes": self.passes, "layers": self.n_block,
             "layer_applications": self.passes * self.n_block,
             "head_evaluations": self.passes if takes_loss else 1,
+            "head_products": 3 * self.passes * loss_blocks
+            if takes_loss else 1,
             "loop": "unrolled", "remat": policy,
             "kept": list(REMAT_KEPT_NAMES.get(policy, ())),
-            "loss_blocks": self._loss_blocks(*tokens.shape)
-            if takes_loss else 0})
+            "loss_blocks": loss_blocks})
         # The passes are unrolled: on the chip a step is 1.8% shorter than
         # with them in a lax.scan (PERF.md, PR 27), for a longer compile.
         if not takes_loss:
@@ -692,15 +790,12 @@ class LoopedDecoder(_TransformerCore):
             # training under another loss: nothing of the gate's to report
             return h @ params["head_kernel"], \
                 self.init_state() if training or state is None else state
-        log_survive = jnp.zeros(tokens.shape, jnp.float32)
-        costs, mass, pass_loss = [], [], []
-        for t in range(self.passes):
-            h = one_pass(h)
-            cost, p_mean, ce_mean, log_survive = self._exit_tail(
-                params, h, targets, log_survive, t == self.passes - 1)
-            costs.append(cost)
-            mass.append(p_mean)
-            pass_loss.append(ce_mean)
-        return h @ params["head_kernel"], {
-            "loop_exit_cost": sum(costs), "loop_exit_mass": jnp.stack(mass),
-            "loop_pass_loss": jnp.stack(pass_loss)}
+
+        def states():   # a pass is made when its tail asks for its state
+            nonlocal h
+            for _ in range(self.passes):
+                h = one_pass(h)
+                yield h
+
+        loss_state = self._exit_loss(params, states(), targets)
+        return h @ params["head_kernel"], loss_state

@@ -141,6 +141,56 @@ def test_training_loss_and_first_gradient_of_every_leaf(
     assert float(jnp.std(p.mean(axis=(1, 2)))) > 0.05   # no uniform exit
 
 
+@pytest.mark.parametrize("compute, loss_rtol, grad_rtol", [
+    ("float32", 2e-6, 2e-5), ("bfloat16", 1e-4, 6e-2)])
+def test_the_head_keeps_its_gradient_in_either_compute_dtype(
+        compute, loss_rtol, grad_rtol, cfg, reference, weights, batches,
+        reference_first):
+    """Float32 master weights cast down on use, as the train step casts
+    them: the loss and every leaf's gradient, by the leaf's length, against
+    the float32 reference.  The head's gradient is the float32 sum that
+    ``_deliver`` hands over, rounded once to the compute dtype.  bfloat16
+    reads 2e-5 on the loss, 0.012 on the head and 0.037 on the widest leaf
+    (the gate's bias), the same as with the logits made again."""
+    layer = _layer(cfg)
+    x, y = batches[0][0], batches[1][0]
+
+    def loss(core):
+        core = jax.tree_util.tree_map(lambda a: a.astype(compute), core)
+        return _program_loss(layer, core, x, y)[0]
+
+    got, grads = jax.jit(jax.value_and_grad(loss))(weights[reference.CORE])
+    want, want_grads = reference_first
+    assert abs(float(got) - float(want)) < loss_rtol * float(want)
+    assert all(g.dtype == jnp.float32
+               for g in jax.tree_util.tree_leaves(grads))
+    _close(grads, want_grads[reference.CORE], grad_rtol,
+           f"gradient under {compute}", norm=jnp.linalg.norm)
+
+
+def test_a_cotangent_other_than_one_scales_every_leaf(cfg, reference,
+                                                      weights, batches,
+                                                      program_first):
+    """The backward pass of the head only scales what the forward pass
+    kept: 2.5 on ``loop_exit_cost`` gives every leaf 2.5 times its
+    gradient, the head's (which ``_deliver`` hands over) and the exit
+    gate's (through the weights p / N) included."""
+    layer = _layer(cfg)
+    x, y = batches[0][0], batches[1][0]
+    cost, pull = jax.vjp(
+        lambda core: _program_loss(layer, core, x, y)[0],
+        weights[reference.CORE])
+    (loss, _), grads = program_first
+    np.testing.assert_allclose(float(cost), float(loss), rtol=1e-6)
+    got, = jax.jit(pull)(jnp.float32(2.5))
+    for leaf in ("head_kernel", "exit_kernel", "exit_bias"):
+        assert float(jnp.max(jnp.abs(grads[leaf]))) > 0, leaf
+    # another compiled program sums in another order (2e-6); a leaf that
+    # missed the factor reads 0.6
+    _close(got, jax.tree_util.tree_map(lambda g: 2.5 * g, grads), 1e-5,
+           "gradient at cotangent 2.5")
+
+
 def test_three_adam_steps_through_fit(cfg, reference, weights, batches):
     """``compile``/``fit`` on the normal path: three one-batch calls
     against three steps of the reference's written-out Adam.  Adam divides
@@ -245,6 +295,49 @@ def test_no_array_of_passes_x_tokens_x_vocabulary_in_the_step(cfg, weights,
     assert record["layer_applications"] == 6 and record["loop"] == "unrolled"
     assert record["remat"] == "attn"
     assert record["kept"] == ["attn_context", "ffn_out"]
+
+
+def _products_over(jaxpr, size, in_scan=False):
+    """(inside a scan?, output shape) of every ``dot_general`` of the
+    jaxpr that has ``size`` among an operand's or its output's dims, in
+    program order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and any(
+                size in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+            found.append((in_scan, eqn.outvars[0].aval.shape))
+        for sub in eqn.params.values():
+            for j in sub if isinstance(sub, (list, tuple)) else [sub]:
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    found += _products_over(
+                        inner, size, in_scan or eqn.primitive.name == "scan")
+    return found
+
+
+def test_no_product_over_the_vocabulary_in_the_backward_pass(cfg, weights,
+                                                             reference):
+    """A block's logits, ``dlogits @ W^T`` and ``s^T @ dlogits`` are made
+    in one scan body a pass, in the forward pass; the only other product
+    over the vocabulary in the step is the output logits_T (dead code under
+    the in-model loss), the forward pass's last, and nothing follows it:
+    the backward pass scales what was kept.  128, the toy vocabulary, is no
+    other size of the toy."""
+    layer = _layer(cfg)
+    x = jnp.zeros((BATCH, 32), jnp.int32)
+    step = jax.make_jaxpr(jax.grad(
+        lambda core: _program_loss(layer, core, x, x)[0]))(
+            weights[reference.CORE])
+    passes, vocab = 3, 128
+    found = _products_over(step.jaxpr, vocab)
+    assert [scanned for scanned, _ in found] == [True] * 3 * passes + [False]
+    assert found[-1][1] == (BATCH, 32, vocab)
+    assert sorted(shape for _, shape in found[:3]) == sorted(
+        [(BATCH, 64 // BATCH, vocab), (BATCH, 64 // BATCH, 64), (64, vocab)])
+    record = self_attention.loop_records[-1]
+    assert record["head_products"] == 3 * passes * record["loss_blocks"] \
+        == 36
+    assert record["head_evaluations"] == passes
 
 
 @pytest.mark.parametrize("remat", ["full", "dots", None])
@@ -494,7 +587,8 @@ def test_another_loss_trains_the_last_pass_alone(cfg, reference, weights,
                             state=layer.init_state(), training=True)
     assert out.shape == (BATCH, 32, 128)
     assert float(state["loop_exit_cost"]) == 0.0
-    assert self_attention.loop_records[-1]["head_evaluations"] == 1
+    record = self_attention.loop_records[-1]
+    assert (record["head_evaluations"], record["head_products"]) == (1, 1)
 
 
 def test_fit_publishes_the_loop_gauges_and_the_lowering_seconds(cfg,

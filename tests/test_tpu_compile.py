@@ -8,6 +8,8 @@ their references on the chip.
 Keep these in ONE file: only one process may hold the TPU library, and the
 worker that gets this file is the one that loads it."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -189,3 +191,53 @@ def test_a_layer_application_runs_the_forward_kernel_once(
         for name in map(xplane.short_name, text.splitlines())
         if marked in name)
     assert returned == [1, 2] + [3] * forward_calls, returned
+
+
+def _convolutions(computations, name):
+    """Convolutions in the HLO computation ``name`` and in what it calls."""
+    text = computations[name]
+    return text.count(" convolution(") + sum(
+        _convolutions(computations, called)
+        for called in re.findall(r"calls=%([\w.\-]+)", text))
+
+
+def test_the_looped_head_makes_its_gradient_in_its_forward_loops(
+        one_chip, real_kernels):
+    """The gradient of `ouro-2.6b-fit`'s four-pass tail (head, exit gate
+    and loss after each pass: (2, 4096, 2048) bf16 states, a (2048, 49152)
+    head, blocks of 2,048 tokens): four ``while`` loops over the token
+    blocks, each with the block's logits and both products of its gradient,
+    and no product over the vocabulary beside them (with the logits made
+    again in the backward pass it was eight loops and sixteen products).
+    The temporaries are the float32 sum of the head's gradient, 403 MB, and
+    what a pass keeps; the bf16 logits of a block live in the output's
+    buffer: 0.47 GB here."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import LoopedDecoder
+
+    passes, vocab = 4, 49152
+    layer = LoopedDecoder(vocab=vocab, n_block=1, n_head=16,
+                          hidden_size=2048, intermediate_size=5632,
+                          passes=passes)
+
+    def tail(params, states, targets):
+        return layer._exit_loss(params, states, targets)["loop_exit_cost"]
+
+    def described(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"head_kernel": described((2048, vocab)),
+              "exit_kernel": described((2048, 1)),
+              "exit_bias": described((1,))}
+    compiled = jax.jit(jax.grad(tail, argnums=(0, 1))).lower(
+        params, [described((2, 4096, 2048))] * passes,
+        described((2, 4096), jnp.int32)).compile()
+    text = compiled.as_text()
+    computations = {
+        block.split(" (", 1)[0].rpartition("%")[2]: block
+        for block in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)}
+    loops = re.findall(r" while\(.*body=%([\w.\-]+)", text)
+    assert len(loops) == passes, loops
+    assert [_convolutions(computations, body) for body in loops] \
+        == [3] * passes
+    assert text.count(" convolution(") == 3 * passes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
