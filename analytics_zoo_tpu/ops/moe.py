@@ -28,6 +28,7 @@ means are global (jit sees global shapes), so no pmean is needed.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -35,8 +36,9 @@ import jax.numpy as jnp
 
 
 #: state leaves that join the training loss: an MoE stack's pre-weighted
-#: load-balancing loss, a looped decoder's exit-gate loss
-COST_LEAVES = ("moe_aux_cost", "loop_exit_cost")
+#: load-balancing loss, a looped decoder's exit-gate loss, a decoder's own
+#: next-token cross-entropy
+COST_LEAVES = ("moe_aux_cost", "loop_exit_cost", "lm_loss_cost")
 
 
 def collect_aux_cost(state):
@@ -155,3 +157,121 @@ def routed_ffn(h, gate_w, w1, b1, w2, b2, *, top_k=2, capacity_factor=1.25,
     aux = e * jnp.sum(me * ce)
     drop_fraction = 1.0 - kept / float(top_k * b * s)
     return y, aux, drop_fraction
+
+
+# ---------------------------------------------------------------------------
+# Routed experts without dropped tokens, for a worker that holds some of them
+# ---------------------------------------------------------------------------
+
+#: the ``checkpoint_name`` of what the route decides by integers alone: the
+#: picked experts, the sort's permutation and its inverse, the group sizes.
+#: The ``"attn"`` recomputation policy keeps them (``parallel/plan.py``): a
+#: few hundred KB that cost a top-k and two sorts to make again.
+ROUTE_NAME = "moe_route"
+
+
+# The two below are each other's transpose, and both are gathers: the
+# assignments are a permutation of (token, choice) pairs, so the scatter-add
+# that autodiff would make of either gather is the other gather through the
+# inverse permutation.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def dispatch(k, x, perm, inv):
+    """``x`` (T, D) -> (k T, D): row a is the token of the a-th assignment
+    in sorted order (``perm`` (k T,) of indices into the (T, k) assignments
+    laid out row by row, ``inv`` its inverse)."""
+    return jnp.take(x, perm // k, axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def combine(k, y, perm, inv):
+    """``y`` (k T, D) in sorted order -> (T, D): the sum of a token's k
+    rows, in float32."""
+    rows = jnp.take(y, inv, axis=0).astype(jnp.float32)
+    return jnp.sum(rows.reshape(-1, k, y.shape[-1]), axis=1).astype(y.dtype)
+
+
+dispatch.defvjp(
+    lambda k, x, perm, inv: (dispatch(k, x, perm, inv), (perm, inv)),
+    lambda k, kept, g: (combine(k, g, *kept), None, None))
+combine.defvjp(
+    lambda k, y, perm, inv: (combine(k, y, perm, inv), (perm, inv)),
+    lambda k, kept, g: (dispatch(k, g, *kept), None, None))
+
+
+def sigmoid_route(u, router, score_bias, *, top_k, routed_scale):
+    """DeepSeek-V3's router without groups (``n_group`` 1): scores
+    s = sigmoid(u router) in float32 over ALL the router's experts; a
+    token's experts are the top-k of s + ``score_bias`` (the
+    ``e_score_correction_bias``, which picks and takes no gradient); their
+    weights are s itself at those k, over their sum + 1e-20, times
+    ``routed_scale``.  Returns (experts (T, k) int32, weights (T, k)
+    float32)."""
+    s = jax.nn.sigmoid(u.astype(jnp.float32) @ router.astype(jnp.float32))
+    _, experts = jax.lax.top_k(
+        jax.lax.stop_gradient(s + score_bias.astype(jnp.float32)), top_k)
+    picked = jnp.take_along_axis(s, experts, axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * routed_scale
+
+
+def held_experts_ffn(u, router, score_bias, w_gate, w_up, w_down, *,
+                     first_held, top_k, routed_scale,
+                     activation=jax.nn.silu):
+    """The part that the experts held here give of a routed feed-forward,
+    for (T, D) tokens: sum over a token's top-k experts e that are held of
+    w_e (act(u Wgate_e) * (u Wup_e)) Wdown_e.
+
+    One expert-parallel worker's share: ``router`` (D, E) and
+    ``score_bias`` (E,) are the whole router's, ``w_gate``/``w_up``
+    (H, D, F) and ``w_down`` (H, F, D) the H experts held, which are
+    ``first_held`` .. ``first_held + H - 1``.  Every token is routed over
+    all E experts (``sigmoid_route``); the k T assignments are sorted so
+    that the held experts' rows come first, expert by expert, and only
+    those rows are multiplied, in groups, by their expert
+    (``ops/pallas/grouped_matmul.py``).  There is no capacity: the buffer
+    has all k T rows, so no assignment is dropped at any skew.  What the
+    experts held elsewhere would add is left out, and no code stands in
+    for the exchange that would bring it.
+
+    Returns ``(y (T, D), stats)`` with ``stats`` float32 scalars:
+    ``held_assignments`` (rows multiplied), ``load_max_over_mean`` (the
+    fullest held expert's rows over the mean) and ``dropped_assignments``
+    (held assignments that were not multiplied: 0 by construction)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from analytics_zoo_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    t, held = u.shape[0], w_gate.shape[0]
+    experts, weights = sigmoid_route(u, router, score_bias, top_k=top_k,
+                                     routed_scale=routed_scale)
+    # sorted by (held expert, then the rest), stably: rows of one expert
+    # keep the tokens' order
+    local = experts.reshape(-1) - first_held
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    perm = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(perm).astype(jnp.int32)
+    group_sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
+                          axis=0, dtype=jnp.int32)
+    perm, inv, group_sizes = (checkpoint_name(x, ROUTE_NAME)
+                              for x in (perm, inv, group_sizes))
+    n_held = jnp.sum(group_sizes)
+    live = (jnp.arange(top_k * t) < n_held)[:, None]
+
+    # rows past the held ones are never multiplied and never written: the
+    # select on the way in zeroes what the kernel leaves in their gradient,
+    # the one on the way out what it leaves in the result
+    x = jnp.where(live, dispatch(top_k, u, perm, inv), 0)
+    f = activation(grouped_matmul(x, w_gate, group_sizes)) \
+        * grouped_matmul(x, w_up, group_sizes)
+    y = grouped_matmul(f.astype(u.dtype), w_down, group_sizes)
+    w_sorted = jnp.take(weights.reshape(-1), perm)[:, None]
+    # masked before it meets its weight: the weight's gradient reads y
+    y = jnp.where(live, y, 0) * w_sorted.astype(y.dtype)
+    multiplied = jnp.minimum(n_held, top_k * t)
+    stats = {
+        "held_assignments": multiplied.astype(jnp.float32),
+        "load_max_over_mean": jnp.max(group_sizes).astype(jnp.float32)
+        * held / jnp.maximum(n_held, 1).astype(jnp.float32),
+        "dropped_assignments": (n_held - multiplied).astype(jnp.float32)}
+    return combine(top_k, y, perm, inv), stats

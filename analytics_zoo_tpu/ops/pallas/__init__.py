@@ -35,6 +35,7 @@ _KERNEL_MODULES = {
     "fused_softmax_xent":
         "analytics_zoo_tpu.ops.pallas.fused_softmax_xent",
     "int8_matmul": "analytics_zoo_tpu.ops.pallas.int8_matmul",
+    "grouped_matmul": "analytics_zoo_tpu.ops.pallas.grouped_matmul",
 }
 
 _PLANNED_STEPS: dict = {}

@@ -237,8 +237,9 @@ def attention_stats(q, k, v, causal=False, scale=None, block_q=None,
 _RESIDENT_ROWS = 1024
 
 #: Trace-time record of each kernel's schedule, newest last: ``kernel``
-#: ("forward", "dq", "dkv"), ``shape`` (b, h, lq, lk, d), ``blocks``
-#: (block_q, block_k) as resolved, ``operand_dtype`` of the matrix
+#: ("forward", "dq", "dkv"), ``shape`` (b, h, lq, lk, d) with d the width
+#: of q and k, ``value_width`` (that of v and of the output, which latent
+#: attention makes unlike d), ``blocks`` (block_q, block_k) as resolved, ``operand_dtype`` of the matrix
 #: products, and the tiles of one (batch, head) by class: ``skipped``,
 #: ``plain``, ``masked``.  Plain arithmetic on static shapes, like
 #: ``invocation_counts``: jit traces once, so it counts compilations.
@@ -320,8 +321,9 @@ def _tile_rows(j, lo, block, resident):
                  else pl.multiple_of(start, block), block)
 
 
-def _record_schedule(kernel, q, lk, block_q, block_k, causal, all_masked):
+def _record_schedule(kernel, q, v, block_q, block_k, causal, all_masked):
     b, h, lq, d = q.shape
+    lk = v.shape[2]
     n_q, n_k = -(-lq // block_q), -(-lk // block_k)
     plain = masked = 0
     for i in range(n_q):
@@ -331,7 +333,7 @@ def _record_schedule(kernel, q, lk, block_q, block_k, causal, all_masked):
         masked += int(need) - int(p)
     tile_schedules.append({
         "kernel": kernel, "shape": (b, h, lq, lk, d),
-        "blocks": (block_q, block_k), "operand_dtype": str(q.dtype),
+        "value_width": v.shape[3], "blocks": (block_q, block_k), "operand_dtype": str(q.dtype),
         "skipped": n_q * n_k - plain - masked, "plain": plain,
         "masked": masked})
 
@@ -435,7 +437,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     (the whole of them up to _RESIDENT_ROWS rows); for each q tile it
     walks the chunk's key tiles up to the diagonal, the plain ones in one
     loop and the masked ones in another.  Softmax running stats (m, l)
-    and the output accumulator, (d, block_q) like the tile, persist
+    and the output accumulator, (dv, block_q) like the tile, persist
     across chunks in VMEM scratch, so VMEM holds O(group·block_q·d +
     chunk·d) and the sequence length is bounded by HBM, not VMEM.
     """
@@ -443,7 +445,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    lk, dv = k.shape[2], v.shape[3]   # v and the output have their own width
     offset = lk - lq  # end-aligned causal diagonal
     block_q, block_k = _tiles(block_q, block_k, lq, lk)
     n_k = pl.cdiv(lk, block_k)
@@ -457,7 +459,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                                        has_bias or has_seg)
     rows_q, chunk = group * block_q, resident * block_k
     n_c = pl.cdiv(n_k, resident)
-    _record_schedule("forward", q, lk, block_q, block_k, causal, all_masked)
+    _record_schedule("forward", q, v, block_q, block_k, causal, all_masked)
 
     def kernel(*refs):
         i = 3
@@ -494,7 +496,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         def tile(j, carry, qs, q0, masked):
-            m, l, acc = carry  # (1, block_q) twice, (d, block_q)
+            m, l, acc = carry  # (1, block_q) twice, (dv, block_q)
             at = _tile_rows(j, ci * resident, block_k, resident)
             kb, vb = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
             live = None
@@ -558,7 +560,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     in_specs = [
         pl.BlockSpec((1, 1, rows_q, d), q_side, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, 1, chunk, d), k_side, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, chunk, d), k_side, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, chunk, dv), k_side, memory_space=pltpu.VMEM),
     ]
     args = [q, k, v]
     if has_bias:
@@ -586,9 +588,9 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
             memory_space=pltpu.SMEM))
         args.append(seed.astype(jnp.int32))
 
-    out_specs = pl.BlockSpec((1, 1, rows_q, d), q_side,
+    out_specs = pl.BlockSpec((1, 1, rows_q, dv), q_side,
                              memory_space=pltpu.VMEM)
-    out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    out_shape = jax.ShapeDtypeStruct((b, h, lq, dv), q.dtype)
     if return_stats:
         stat_spec = pl.BlockSpec((1, 1, 1, rows_q),
                                  lambda bi, hi, gi, ci: (bi, hi, 0, gi),
@@ -605,7 +607,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         scratch_shapes=[
             pltpu.VMEM((1, rows_q), jnp.float32),
             pltpu.VMEM((1, rows_q), jnp.float32),
-            pltpu.VMEM((d, rows_q), jnp.float32),
+            pltpu.VMEM((dv, rows_q), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -732,7 +734,7 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
 
     q, k, v = operands[:3]
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    lk, dv = k.shape[2], v.shape[3]   # v and dO have their own width
     offset = lk - lq
     bq, bk = _tiles(*_resolve_bwd_blocks(block_q, block_k, lq, lk,
                                          causal=causal, kernel=kernel),
@@ -748,7 +750,7 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
     streamed = has_bias or has_seg
     if has_bias:
         bb, bh = bias.shape[:2]
-    _record_schedule(kernel, q, lk, bq, bk, causal, all_masked)
+    _record_schedule(kernel, q, v, bq, bk, causal, all_masked)
 
     thr = _drop_threshold(dropout_p) if has_drop else None
     inv_keep = 1.0 / (1.0 - dropout_p) if has_drop else None
@@ -924,8 +926,8 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
         specs = [
             pl.BlockSpec((1, 1, rows_q, d), im_q, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, rows_k, d), im_k, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, rows_k, d), im_k, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, rows_q, d), im_q, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, rows_k, dv), im_k, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, rows_q, dv), im_q, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, 1, rows_q), im_stat,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, 1, rows_q), im_stat,
@@ -985,14 +987,14 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
         pl.BlockSpec((1, 1, rows_k, d),
                      lambda bi, hi, gi, ci: (bi, hi, gi, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, rows_k, d),
+        pl.BlockSpec((1, 1, rows_k, dv),
                      lambda bi, hi, gi, ci: (bi, hi, gi, 0),
                      memory_space=pltpu.VMEM),
     ]
     kv_out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
                     jax.ShapeDtypeStruct(v.shape, v.dtype)]
     kv_scratch = [pltpu.VMEM((rows_k, d), jnp.float32),
-                  pltpu.VMEM((rows_k, d), jnp.float32)]
+                  pltpu.VMEM((rows_k, dv), jnp.float32)]
     if has_bias:
         kv_out_specs.append(pl.BlockSpec(
             (1, 1, bk, 1), lambda bi, hi, gi, ci: (bi, hi, gi, 0),
@@ -1095,7 +1097,7 @@ def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
     the forward used, so no mask is stored."""
     q, k, v, bias, q_seg, kv_seg, seed, out, m_s, l_s = res
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    lk, dv = k.shape[2], v.shape[3]
     scale_v = 1.0 / math.sqrt(d) if scale is None else scale
     offset = lk - lq
     has_bias = bias is not None
@@ -1131,7 +1133,7 @@ def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0))).astype(jnp.float32)
     # (n_k, b, h, bk, d) so scan iterates key blocks
     kb_s = jnp.moveaxis(kp.reshape(b, h, n_k, bk, d), 2, 0)
-    vb_s = jnp.moveaxis(vp.reshape(b, h, n_k, bk, d), 2, 0)
+    vb_s = jnp.moveaxis(vp.reshape(b, h, n_k, bk, dv), 2, 0)
     kpos_s = jnp.arange(n_k * bk, dtype=jnp.int32).reshape(n_k, bk)
     q_pos = jnp.arange(lq, dtype=jnp.int32)
     if has_bias:
@@ -1230,7 +1232,7 @@ def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
     dq, (dk_s, dv_s, db_s) = jax.lax.scan(
         grad_step, dq0, (kb_s, vb_s, kpos_s, bias_s, kseg_s))
     dk = jnp.moveaxis(dk_s, 0, 2).reshape(b, h, n_k * bk, d)[:, :, :lk]
-    dv = jnp.moveaxis(dv_s, 0, 2).reshape(b, h, n_k * bk, d)[:, :, :lk]
+    dv = jnp.moveaxis(dv_s, 0, 2).reshape(b, h, n_k * bk, dv)[:, :, :lk]
     if has_bias:
         dbias = jnp.moveaxis(db_s, 0, 3).reshape(
             bb, bh, bq, n_k * bk)[..., :lk].astype(bias.dtype)
@@ -1249,7 +1251,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     """Fused attention: Pallas kernel on TPU, jnp fallback elsewhere.
 
     Args:
-      q, k, v: (B, H, L, D).
+      q, k, v: (B, H, L, D); v may have a last dimension of its own, Dv
+        (latent attention: q and k at 192, v at 128).  The output is then
+        (B, H, Lq, Dv), the default scale stays 1/sqrt(D), and all three
+        kernels read both widths from the operands' shapes.
       bias: optional additive f32 mask/bias, shape (B|1, H|1, Lq|1, Lk) —
         the BERT (B, 1, 1, L) padding mask streams as (1, block_k) tiles.
       q_segment_ids / kv_segment_ids: optional (B, Lq)/(B, Lk) int arrays;

@@ -98,10 +98,14 @@ REMAT_POLICIES = ("full", "dots", "attn")
 #: flash_attention.py::_fwd``), the context on the dense path
 #: (``ops/attention.py``).  ``"ffn_out"`` is the transformer block's
 #: feed-forward output, which the norm after or around that branch reads.
+#: ``"moe_route"`` is what a routed feed-forward without dropped tokens
+#: decides by integers (``ops/moe.py::held_experts_ffn``: the sort's
+#: permutation, its inverse and the group sizes).
 #: So under ``"attn"`` the backward pass makes the norms, q, k, v and the
-#: other products of the scope again, but runs no attention forward and
-#: no down-projection a second time (measured: ``PERF.md``, PR 29).
-REMAT_KEPT_NAMES = {"attn": ("attn_context", "ffn_out")}
+#: other products of the scope again, but runs no attention forward, no
+#: down-projection and no sort a second time (measured: ``PERF.md``,
+#: PR 29).
+REMAT_KEPT_NAMES = {"attn": ("attn_context", "ffn_out", "moe_route")}
 
 #: dtype ROLES a plan's ``dtype_rules`` may map a path to.  A role is
 #: not a raw dtype: it names the leaf's job in the precision plane.

@@ -82,6 +82,7 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.recurrent import (  # noqa: F40
 )
 from analytics_zoo_tpu.pipeline.api.keras.layers.self_attention import (  # noqa: F401
     BERT,
+    LatentMoEDecoder,
     LoopedDecoder,
     TransformerLayer,
 )

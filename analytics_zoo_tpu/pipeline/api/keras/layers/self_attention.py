@@ -47,7 +47,12 @@ class _TransformerCore(Layer):
                  moe_experts=0, moe_top_k=2, moe_capacity_factor=1.25,
                  moe_aux_weight=0.01, norm="layer", norm_placement="after",
                  norm_eps=1e-5, rotary_theta=None, gated_ffn=False,
-                 use_bias=True, input_shape=None, name=None, **kwargs):
+                 use_bias=True, attention="full", kv_latent_rank=None,
+                 qk_nope_dim=None, qk_rope_dim=None, v_head_dim=None,
+                 routed_experts=0, experts_held=None, experts_held_from=0,
+                 experts_per_token=None, expert_size=None, shared_experts=0,
+                 routed_scale=1.0, leading_dense=0, input_shape=None,
+                 name=None, **kwargs):
         super().__init__(input_shape=input_shape, name=name, **kwargs)
         self.n_block = int(n_block)
         self.n_head = int(n_head)
@@ -69,7 +74,24 @@ class _TransformerCore(Layer):
         #     whole head) where a number; None leaves positions to the
         #     embedding;
         #   gated_ffn: (act(u Wgate) * (u Wfc)) Wout in place of
-        #     act(u Wfc) Wout;  use_bias: no ``*_bias`` leaf when False.
+        #     act(u Wfc) Wout;  use_bias: no ``*_bias`` leaf when False;
+        #   attention: "full" (one fused qkv kernel, heads of hidden_size /
+        #     n_head) or "latent" (DeepSeek's MLA without query compression:
+        #     q = u Wq in heads of qk_nope_dim + qk_rope_dim; [c, k_r] =
+        #     u Wkva with c kv_latent_rank wide and ONE rope key k_r for all
+        #     heads; [k_n, v] = RMSNorm(c) Wkvb in heads of qk_nope_dim +
+        #     v_head_dim; rotary on q_r and k_r in adjacent pairs; scores
+        #     over [q_n, q_r] . [k_n, k_r] / sqrt(qk_nope_dim + qk_rope_dim),
+        #     values and output v_head_dim a head);
+        #   routed_experts: E > 0 makes the feed-forward of every block from
+        #     ``leading_dense`` on a routed one (ops.moe.held_experts_ffn):
+        #     a router over all E experts, sigmoid scores, the bias-corrected
+        #     top ``experts_per_token``, no capacity and no dropped token; of
+        #     the E experts this worker holds ``experts_held`` (all by
+        #     default), from ``experts_held_from``, each a gated feed-forward
+        #     ``expert_size`` wide; beside them ``shared_experts`` x
+        #     ``expert_size`` of gated feed-forward on every token.  The
+        #     blocks before ``leading_dense`` keep the dense feed-forward.
         if norm not in ("layer", "rms"):
             raise ValueError(f"norm must be 'layer' or 'rms'; got {norm!r}")
         if norm_placement not in ("after", "before", "around"):
@@ -85,6 +107,38 @@ class _TransformerCore(Layer):
         if moe_experts and (self.gated_ffn or not self.use_bias):
             raise ValueError("the routed feed-forward is the plain one "
                              "with biases: no gated_ffn, no use_bias=False")
+        if attention not in ("full", "latent"):
+            raise ValueError("attention must be 'full' or 'latent'; got "
+                             f"{attention!r}")
+        self.attention = attention
+        self.latent = None
+        if attention == "latent":
+            self.latent = tuple(int(x) for x in (
+                kv_latent_rank, qk_nope_dim, qk_rope_dim, v_head_dim))
+            if self.use_bias or self.rotary_theta is None \
+                    or self.latent[2] % 2:
+                raise ValueError("latent attention has no bias, rotary "
+                                 "positions and an even qk_rope_dim")
+        self.routed_experts = int(routed_experts)
+        self.leading_dense = int(leading_dense)
+        if self.routed_experts:
+            if moe_experts or not self.gated_ffn or self.use_bias:
+                raise ValueError("routed_experts are gated feed-forwards "
+                                 "without bias, and not moe_experts'")
+            self.experts_held = int(experts_held or routed_experts)
+            self.experts_held_from = int(experts_held_from)
+            self.experts_per_token = int(experts_per_token)
+            self.expert_size = int(expert_size)
+            self.shared_experts = int(shared_experts)
+            self.routed_scale = float(routed_scale)
+            if self.experts_per_token > self.routed_experts or not (
+                    0 <= self.experts_held_from and self.experts_held_from
+                    + self.experts_held <= self.routed_experts):
+                raise ValueError(
+                    f"experts {self.experts_held_from}.."
+                    f"{self.experts_held_from + self.experts_held - 1} "
+                    f"held, top-{self.experts_per_token}, of "
+                    f"{self.routed_experts}")
         # moe_experts > 0 swaps every block's dense feed-forward for a
         # routed mixture of experts (ops.moe.routed_ffn: GShard top-k +
         # capacity, dense-dispatch so the GSPMD train step shards the
@@ -132,20 +186,65 @@ class _TransformerCore(Layer):
     def _n_norms(self):
         return 4 if self.norm_placement == "around" else 2
 
-    def _block_params(self, rng):
+    def _is_routed(self, index):
+        return bool(self.routed_experts) and index is not None \
+            and index >= self.leading_dense
+
+    def _block_params(self, rng, index=None):
+        """One block's leaves; ``index`` tells a routed block from the
+        leading dense ones where the feed-forward differs by layer."""
         d, m = self.hidden_size, self.intermediate_size
         std = self.initializer_range
         ks = jax.random.split(rng, 6)
-        p = {
-            "qkv_kernel": _dense_init(ks[0], (d, 3 * d), std),
-            "proj_kernel": _dense_init(ks[1], (d, d), std),
-        }
-        bias = {"qkv_bias": 3 * d, "proj_bias": d}
+        # the keys of what the options above add, apart from the six that
+        # the older blocks draw from
+        more = iter(jax.random.split(jax.random.fold_in(rng, 1), 11))
+        bias = {}
+        if self.attention == "latent":
+            rank, nope, rope, vd = self.latent
+            h = self.n_head
+            p = {
+                "q_kernel": _dense_init(next(more), (d, h * (nope + rope)),
+                                        std),
+                "kv_a_kernel": _dense_init(next(more), (d, rank + rope), std),
+                "kv_a_norm": jnp.ones((rank,)),
+                "kv_b_kernel": _dense_init(next(more),
+                                           (rank, h * (nope + vd)), std),
+                "o_kernel": _dense_init(next(more), (h * vd, d), std),
+            }
+        else:
+            p = {
+                "qkv_kernel": _dense_init(ks[0], (d, 3 * d), std),
+                "proj_kernel": _dense_init(ks[1], (d, d), std),
+            }
+            bias = {"qkv_bias": 3 * d, "proj_bias": d}
         for i in range(1, self._n_norms + 1):
             p[f"ln{i}_gamma"] = jnp.ones((d,))
             if self.norm == "layer":
                 p[f"ln{i}_beta"] = jnp.zeros((d,))
-        if self.moe_experts:
+        if self._is_routed(index):
+            e, held, f = self.routed_experts, self.experts_held, \
+                self.expert_size
+            p.update({
+                "router_kernel": _dense_init(next(more), (d, e), std),
+                # DeepSeek-V3's e_score_correction_bias: it picks, takes no
+                # gradient, and is a load balancer's to set; 0 picks by
+                # the scores alone
+                "router_bias": jnp.zeros((e,)),
+                "experts_gate": _dense_init(next(more), (held, d, f), std),
+                "experts_up": _dense_init(next(more), (held, d, f), std),
+                "experts_down": _dense_init(next(more), (held, f, d), std),
+            })
+            if self.shared_experts:
+                sf = self.shared_experts * f
+                p.update({
+                    "shared_gate_kernel": _dense_init(next(more), (d, sf),
+                                                      std),
+                    "shared_fc_kernel": _dense_init(next(more), (d, sf), std),
+                    "shared_out_kernel": _dense_init(next(more), (sf, d),
+                                                     std),
+                })
+        elif self.moe_experts:
             e = self.moe_experts
             p.update({
                 "moe_gate": _dense_init(ks[2], (d, e), std),
@@ -186,11 +285,20 @@ class _TransformerCore(Layer):
                 "moe_aux_cost": self.moe_aux_weight * aux,
                 "moe_drop_fraction": drop}
 
-    def _per_block_param_count(self):
+    def _per_block_param_count(self, index=None):
         d, m = self.hidden_size, self.intermediate_size
         b = 1 if self.use_bias else 0
         norms = self._n_norms * d * (2 if self.norm == "layer" else 1)
         attn = 3 * d * d + d * d + b * 4 * d + norms    # qkv + proj + norms
+        if self.attention == "latent":
+            rank, nope, rope, vd = self.latent
+            h = self.n_head
+            attn = d * h * (nope + rope) + d * (rank + rope) + rank \
+                + rank * h * (nope + vd) + h * vd * d + norms
+        if self._is_routed(index):
+            f = self.expert_size
+            return attn + d * self.routed_experts + self.routed_experts \
+                + 3 * d * f * (self.experts_held + self.shared_experts)
         if self.moe_experts:
             e = self.moe_experts
             return attn + d * e + e * (2 * d * m + m) + d
@@ -275,7 +383,30 @@ class _TransformerCore(Layer):
             y = x @ bp[name + "_kernel"]
             return y + bp[name + "_bias"] if self.use_bias else y
 
+        def latent_attention(u):
+            rank, nope, rope, vd = self.latent
+            b, l, _ = u.shape
+            q = split_heads(u @ bp["q_kernel"], self.n_head)
+            c, k_r = jnp.split(u @ bp["kv_a_kernel"], [rank], axis=-1)
+            kv = split_heads(
+                _rms_norm(c, bp["kv_a_norm"], self.norm_eps)
+                @ bp["kv_b_kernel"], self.n_head)
+            # adjacent pairs: the halves side by side (on q and k alike, so
+            # the scores are the pairs' own), then the rotate-half form
+            q_r, k_r = _rotary(_pairs_to_halves(q[..., nope:]),
+                               _pairs_to_halves(k_r[:, None]),
+                               self.rotary_theta)
+            q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_r, (b, self.n_head, l, rope))], axis=-1)
+            a = dot_product_attention(q, k, kv[..., nope:],
+                                      causal=not self.bidirectional)
+            return merge_heads(a) @ bp["o_kernel"]
+
         def attention(u):
+            if self.attention == "latent":
+                return latent_attention(u)
             q, k, v = jnp.split(dense(u, "qkv"), 3, axis=-1)
             q = split_heads(q, self.n_head)
             k = split_heads(k, self.n_head)
@@ -293,8 +424,28 @@ class _TransformerCore(Layer):
 
         aux = drop = jnp.zeros((), jnp.float32)
 
+        def gated(u, prefix):
+            f = self.act(u @ bp[prefix + "gate_kernel"]) \
+                * (u @ bp[prefix + "fc_kernel"])
+            return f @ bp[prefix + "out_kernel"]
+
         def feed_forward(u):
             nonlocal aux, drop
+            if "router_kernel" in bp:
+                from analytics_zoo_tpu.ops.moe import held_experts_ffn
+
+                b, l, d = u.shape
+                # ``aux`` of a routed block is the route's counts
+                f, aux = held_experts_ffn(
+                    u.reshape(b * l, d), bp["router_kernel"],
+                    bp["router_bias"], bp["experts_gate"], bp["experts_up"],
+                    bp["experts_down"], first_held=self.experts_held_from,
+                    top_k=self.experts_per_token,
+                    routed_scale=self.routed_scale, activation=self.act)
+                f = f.reshape(b, l, d)
+                if "shared_gate_kernel" in bp:
+                    f = f + gated(u, "shared_")
+                return checkpoint_name(f, "ffn_out")
             if "moe_gate" in bp:
                 from analytics_zoo_tpu.ops.moe import routed_ffn
 
@@ -349,6 +500,13 @@ def _rotary(q, k, theta):
         return (x32 * cos + half * sin).astype(x.dtype)
 
     return turn(q), turn(k)
+
+
+def _pairs_to_halves(x):
+    """(..., 2n) adjacent pairs (x0, x1), (x2, x3), ... -> the pairs' first
+    members, then their second: ``_rotary``'s rotate-half form on the
+    result turns the published pairs (``rope_interleave``)."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
 class TransformerLayer(_TransformerCore):
@@ -509,7 +667,8 @@ def _weighted_ce(n, kernel, s, targets, w, head_grad):
     The weights ``w`` are an input, so a block's gradient is made while its
     logits are live and the backward pass only scales what was kept.
 
-    Private to ``LoopedDecoder``: the third result is the head's gradient at
+    Private to this file's decoders (``LoopedDecoder`` a pass,
+    ``LatentMoEDecoder`` once): the third result is the head's gradient at
     cotangent one, summed over the passes it is threaded through, and is
     right only where the caller adds the first results with weight one and
     hands the sum with the last ``head_grad`` to ``_deliver``; ``kernel``
@@ -799,3 +958,171 @@ class LoopedDecoder(_TransformerCore):
 
         loss_state = self._exit_loss(params, states(), targets)
         return h @ params["head_kernel"], loss_state
+
+
+#: Trace-time record of each ``LatentMoEDecoder`` traced, newest last (as
+#: ``loop_records``): ``layer``, ``training``, ``dense_layers`` and
+#: ``routed_layers``, ``router_width`` (experts the router scores),
+#: ``experts_held`` and ``experts_held_from``, ``experts_per_token``,
+#: ``capacity_factor`` (None: the routed layer has none and drops nothing),
+#: ``attention`` with ``qk_width`` and ``value_width`` of a head, ``remat``
+#: and ``kept`` (the policy of a layer application and the
+#: ``checkpoint_name``s it keeps), ``loss_blocks`` (token blocks the head's
+#: cross-entropy is taken in under the layer's own loss; 0 without it).
+decoder_records: collections.deque = collections.deque(maxlen=16)
+
+
+class LatentMoEDecoder(_TransformerCore):
+    """Decoder-only language model of DeepSeek-V3's shape, one
+    expert-parallel worker's share: token embedding, ``n_block`` blocks of
+    RMSNorm before each branch, latent attention (MLA, no query
+    compression) and a gated SiLU feed-forward that is dense in the first
+    ``leading_dense`` blocks and routed after them (sigmoid router over
+    ``routed_experts``, bias-corrected top ``experts_per_token``, no
+    capacity and no dropped token, ``experts_held`` of the experts here,
+    ``shared_experts`` beside them), a final RMSNorm and an untied head.
+    Input (B, L) token ids, output logits (B, L, vocab).  ``vocab`` may be
+    this worker's slice of the vocabulary: ids and targets then lie in it.
+
+    Trained with ``loss="next_token_cross_entropy"`` the layer takes the
+    mean cross-entropy itself in blocks of ``loss_block`` tokens, the
+    head's gradient made while a block's logits are live (as
+    ``LoopedDecoder`` does, one pass), and reports it under
+    ``lm_loss_cost`` of its state.  With any other loss it hands the
+    logits on.  Its state also carries, a routed layer, the assignments
+    that fell on held experts (``moe_held_assignments``), the fullest held
+    expert's load over the mean (``moe_load_max_over_mean``) and, summed,
+    the held assignments not multiplied (``moe_dropped_assignments``,
+    always 0), which the estimator publishes as gauges at the epoch's
+    closing sync.
+
+    Every layer application is one ``jax.checkpoint`` under the ``"attn"``
+    policy: kept are the attention's output with the two rows of softmax
+    statistics (all that the flash backward kernels read besides q, k and
+    v), the feed-forward's output and the route's integers (the sort's
+    permutation, its inverse and the group sizes: 0.4 MB a layer at 8,192
+    tokens and top-6, a top-k and two sorts to make again).
+    """
+
+    def __init__(self, vocab, n_block, n_head, hidden_size,
+                 intermediate_size, kv_latent_rank, qk_nope_dim, qk_rope_dim,
+                 v_head_dim, routed_experts, experts_per_token, expert_size,
+                 experts_held=None, experts_held_from=0, shared_experts=0,
+                 routed_scale=1.0, leading_dense=1, rotary_theta=1e6,
+                 norm_eps=1e-6, loss_block=2048, remat="attn", **kwargs):
+        super().__init__(
+            n_block=n_block, n_head=n_head, hidden_size=hidden_size,
+            intermediate_size=intermediate_size, hidden_drop=0.0,
+            attn_drop=0.0, activation="silu", remat=remat, norm="rms",
+            norm_placement="before", norm_eps=norm_eps,
+            rotary_theta=rotary_theta, gated_ffn=True, use_bias=False,
+            attention="latent", kv_latent_rank=kv_latent_rank,
+            qk_nope_dim=qk_nope_dim, qk_rope_dim=qk_rope_dim,
+            v_head_dim=v_head_dim, routed_experts=routed_experts,
+            experts_held=experts_held, experts_held_from=experts_held_from,
+            experts_per_token=experts_per_token, expert_size=expert_size,
+            shared_experts=shared_experts, routed_scale=routed_scale,
+            leading_dense=leading_dense, **kwargs)
+        self.vocab = int(vocab)
+        self.loss_block = int(loss_block)
+        self.n_routed = max(self.n_block - self.leading_dense, 0)
+
+    def build(self, input_shape):
+        pass  # params are nested; built in init_params
+
+    def init_params(self, rng):
+        std, d = self.initializer_range, self.hidden_size
+        ks = jax.random.split(rng, 2 + self.n_block)
+        return {
+            "tok_embed": _dense_init(ks[0], (self.vocab, d), std),
+            "blocks": [self._block_params(ks[2 + i], i)
+                       for i in range(self.n_block)],
+            "final_gamma": jnp.ones((d,)),
+            "head_kernel": _dense_init(ks[1], (d, self.vocab), std),
+        }
+
+    def param_count(self):
+        return (2 * self.vocab * self.hidden_size + self.hidden_size
+                + sum(self._per_block_param_count(i)
+                      for i in range(self.n_block)))
+
+    @property
+    def stateful(self):
+        return True
+
+    def init_state(self):
+        per_layer = jnp.zeros((self.n_routed,), jnp.float32)
+        return {"lm_loss_cost": jnp.zeros((), jnp.float32),
+                "moe_held_assignments": per_layer,
+                "moe_load_max_over_mean": per_layer,
+                "moe_dropped_assignments": jnp.zeros((), jnp.float32)}
+
+    def compute_output_shape(self, input_shape):
+        if isinstance(input_shape, list):
+            input_shape = input_shape[0]
+        return tuple(input_shape) + (self.vocab,)
+
+    _loss_blocks = LoopedDecoder._loss_blocks
+
+    def _mean_ce(self, params, s, targets):
+        """Mean next-token cross-entropy of the normed state ``s`` through
+        the head, in token blocks, gradient made with the logits."""
+        weights = jnp.full(targets.shape, 1.0 / targets.size, jnp.float32)
+        total, _ce, head_grad = _weighted_ce(
+            self._loss_blocks(*targets.shape), params["head_kernel"], s,
+            targets.astype(jnp.int32), weights,
+            jnp.zeros(params["head_kernel"].shape, jnp.float32))
+        return _deliver(total, params["head_kernel"].astype(jnp.float32),
+                        head_grad)
+
+    def call(self, params, inputs, state=None, training=False, rng=None):
+        from analytics_zoo_tpu.parallel.plan import (
+            REMAT_KEPT_NAMES,
+            apply_remat,
+            resolve_remat,
+        )
+
+        tokens = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        targets = current_targets() if training else None
+        policy = resolve_remat(self.name or "blocks", default=self.remat)
+        body = apply_remat(self._block_forward_aux, policy,
+                           static_argnums=(3,))
+        loss_blocks = self._loss_blocks(*tokens.shape) \
+            if targets is not None else 0
+        rank, nope, rope, vd = self.latent
+        decoder_records.append({
+            "layer": self.name, "training": bool(training),
+            "dense_layers": self.n_block - self.n_routed,
+            "routed_layers": self.n_routed,
+            "router_width": self.routed_experts,
+            "experts_held": self.experts_held,
+            "experts_held_from": self.experts_held_from,
+            "experts_per_token": self.experts_per_token,
+            "capacity_factor": None, "attention": self.attention,
+            "qk_width": nope + rope, "value_width": vd, "remat": policy,
+            "kept": list(REMAT_KEPT_NAMES.get(policy, ())),
+            "loss_blocks": loss_blocks})
+
+        h = jnp.take(params["tok_embed"], tokens.astype(jnp.int32), axis=0)
+        routes = []
+        for bp in params["blocks"]:
+            h, route, _ = body(bp, h, None, training, None)
+            if "router_kernel" in bp:   # static: the tree is traced once
+                routes.append(route)
+        s = apply_remat(lambda gamma, h: _rms_norm(h, gamma, self.norm_eps),
+                        "full")(params["final_gamma"], h)
+
+        def over_layers(key):
+            return jnp.stack([r[key] for r in routes]) if routes \
+                else jnp.zeros((0,), jnp.float32)
+
+        new_state = {
+            "lm_loss_cost": self._mean_ce(params, s, targets)
+            if targets is not None else jnp.zeros((), jnp.float32),
+            "moe_held_assignments": over_layers("held_assignments"),
+            "moe_load_max_over_mean": over_layers("load_max_over_mean"),
+            "moe_dropped_assignments": jnp.sum(
+                over_layers("dropped_assignments"))}
+        if not training and state is not None:
+            new_state = state
+        return s @ params["head_kernel"], new_state

@@ -276,6 +276,14 @@ def LoopedExitCrossEntropy():
                        "looped_exit_cross_entropy")
 
 
+def NextTokenCrossEntropy():
+    """Mean cross-entropy of a decoder's logits against the targets, taken
+    by the decoder itself where its logits are made
+    (``layers.LatentMoEDecoder``: in token blocks, none kept)."""
+    return InModelLoss(sparse_categorical_crossentropy_from_logits,
+                       "next_token_cross_entropy")
+
+
 class RankHinge(LossFunction):
     """Pairwise ranking hinge (reference RankHinge.scala)."""
 
@@ -297,6 +305,8 @@ def get_loss(identifier) -> LossFunction:
         key = identifier.lower()
         if key == "looped_exit_cross_entropy":
             return LoopedExitCrossEntropy()
+        if key == "next_token_cross_entropy":
+            return NextTokenCrossEntropy()
         if key in _LOSSES:
             return LossFunction(_LOSSES[key], key)
     raise ValueError(f"unknown loss {identifier!r}")

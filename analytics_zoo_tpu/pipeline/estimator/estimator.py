@@ -94,10 +94,14 @@ from analytics_zoo_tpu.ops.moe import collect_aux_cost as _collect_aux_cost
 from analytics_zoo_tpu.pipeline.api.keras.engine import training_targets
 
 
-def _publish_loop_gauges(state) -> None:
-    """A looped decoder's per-pass numbers of the epoch's last step, from
-    the layer-state channel into ``zoo_loop_exit_mass{label=<pass>}`` and
-    ``zoo_loop_pass_loss{label=<pass>}``.  Called after the epoch's closing
+def _publish_state_gauges(state) -> None:
+    """What layers report a step through the layer-state channel, of the
+    epoch's last step, as gauges: a looped decoder's per-pass numbers
+    (``zoo_loop_exit_mass{label=<pass>}``, ``zoo_loop_pass_loss{label=
+    <pass>}``) and a routed decoder's per-layer counts
+    (``zoo_moe_held_assignments{label=<routed layer>}``,
+    ``zoo_moe_load_max_over_mean{label=<routed layer>}``) with its
+    ``zoo_moe_dropped_assignments``.  Called after the epoch's closing
     sync and at no other time: the state is then computed, so the fetch
     waits for nothing."""
     if not isinstance(state, dict):
@@ -108,13 +112,25 @@ def _publish_loop_gauges(state) -> None:
              "last step's tokens"),
             ("loop_pass_loss", "zoo_loop_pass_loss",
              "mean cross-entropy of a looped decoder's pass over the last "
-             "step's tokens")):
+             "step's tokens"),
+            ("moe_held_assignments", "zoo_moe_held_assignments",
+             "assignments of the last step's tokens that fell on the "
+             "experts a routed layer holds here, and were multiplied"),
+            ("moe_load_max_over_mean", "zoo_moe_load_max_over_mean",
+             "rows of the fullest expert held over the mean of the held "
+             "experts, a routed layer, in the last step")):
         if key in state:
             gauge = get_registry().gauge(family, text, ("label",))
             for t, value in enumerate(np.asarray(state[key]), start=1):
                 gauge.labels(label=str(t)).set(float(value))
+    if "moe_dropped_assignments" in state:
+        get_registry().gauge(
+            "zoo_moe_dropped_assignments",
+            "assignments on held experts that the last step did not "
+            "multiply, over the routed layers: the routed layer has no "
+            "capacity, so 0").set(float(state["moe_dropped_assignments"]))
     for child in state.values():
-        _publish_loop_gauges(child)
+        _publish_state_gauges(child)
 
 
 def _normalize_grad_clip(grad_clip):
@@ -1761,7 +1777,7 @@ class Estimator:
                             "Throughput", throughput, self.global_step
                         )
                     step_metrics.record_epoch(epoch, throughput)
-                    _publish_loop_gauges(state)
+                    _publish_state_gauges(state)
                     record_device_memory()  # HBM gauges (no-op on CPU backends)
                     tstate.epoch_finished = True
                     epoch += 1

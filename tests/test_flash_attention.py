@@ -755,3 +755,76 @@ def test_mosaic_tpu_lowering_gpt2_cell():
                     lowering_platforms=("tpu",))
     finally:
         os.environ.pop("ZOO_FLASH_FORCE_PALLAS", None)
+
+
+# ---------------------------------------------------------------------------
+# A value width of its own (latent attention: q and k at 192, v at 128)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("route", ["fallback", "interpret"])
+@pytest.mark.parametrize("causal", [True, False])
+def test_value_width_unlike_query_width(route, causal, monkeypatch):
+    """q and k 24 wide, v and the output 16 wide, through the custom_vjp:
+    the forward and all three gradients against the dense reference, by
+    the blockwise fallback and by the three kernels in interpret mode; the
+    default scale is 1/sqrt of the query's width."""
+    if route == "interpret":
+        monkeypatch.setenv("ZOO_FLASH_INTERPRET", "1")
+    q, k = _rand((1, 2, 512, 24), 80), _rand((1, 2, 512, 24), 81)
+    v, g = _rand((1, 2, 512, 16), 82), _rand((1, 2, 512, 16), 83)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v) * g)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal, None, 128, 128)
+
+    def dense(q, k, v):
+        return _attention_reference(q, k, v, causal, 1.0 / np.sqrt(24))
+
+    schedules = _fresh_schedules()
+    got = [flash(q, k, v), *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)]
+    want = [dense(q, k, v), *jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)]
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+    assert got[0].shape == (1, 2, 512, 16) and got[3].shape == v.shape
+    if route == "interpret":
+        assert {r["kernel"] for r in schedules} == {"forward", "dq", "dkv"}
+        for record in schedules:
+            assert record["shape"] == (1, 2, 512, 512, 24)
+            assert record["value_width"] == 16
+
+
+def test_value_columns_are_independent_to_the_bit(monkeypatch):
+    """The value width only says how many columns P multiplies: the kernels'
+    output and dq, dk at equal widths are, bit for bit, what the two halves
+    of v give side by side (the softmax never sees v), and the record of an
+    equal-width call is the one it always was, with the width beside it."""
+    monkeypatch.setenv("ZOO_FLASH_INTERPRET", "1")
+    q, k, v, g = (_rand((1, 2, 512, 16), 90 + i) for i in range(4))
+
+    def run(v, g):
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, True, None, 128, 128) * g)
+        return (flash_attention(q, k, v, True, None, 128, 128),
+                *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+
+    schedules = _fresh_schedules()
+    whole = run(v, g)
+    equal = [dict(r) for r in schedules]
+    left, right = run(v[..., :8], g[..., :8]), run(v[..., 8:], g[..., 8:])
+    np.testing.assert_array_equal(
+        whole[0], jnp.concatenate([left[0], right[0]], axis=-1))
+    np.testing.assert_array_equal(
+        whole[3], jnp.concatenate([left[3], right[3]], axis=-1))
+    # dq and dk sum the halves' shares: equal to rounding, not to the bit
+    for i in (1, 2):
+        np.testing.assert_allclose(whole[i], left[i] + right[i],
+                                   rtol=1e-5, atol=1e-6)
+    for record in equal:
+        assert record["shape"] == (1, 2, 512, 512, 16)
+        assert record["value_width"] == 16
+        assert record["blocks"] == (128, 128)
+        assert (record["skipped"], record["plain"], record["masked"]) \
+            == (6, 6, 4)

@@ -1,0 +1,286 @@
+"""Latent attention, the routed feed-forward without dropped tokens and the
+decoder built from them (``keras/layers/self_attention.py``,
+``ops/moe.py``), at toy size on the CPU in float32, against the plain
+reference of the ``kanana-2-30b-a3b`` configuration
+(``benchmark/configs/kanana-2-30b-a3b/reference.py``, which imports nothing
+of the program)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from analytics_zoo_tpu.ops import moe
+from analytics_zoo_tpu.ops.pallas import grouped_matmul as grouped
+from analytics_zoo_tpu.pipeline.api.keras.engine import training_targets
+from analytics_zoo_tpu.pipeline.api.keras.layers import self_attention
+from benchmark import narrow
+from benchmark.manifest import Manifest
+
+#: hidden 64, 4 heads of 16 + 8 (values 16), latent 32, 1 dense + 2 routed
+#: layers, 16 routed experts of width 32 with 4 held, top-3, 2 shared,
+#: vocabulary 128 sliced to 32, 32 tokens
+TOY = {"hidden_size": 64, "num_attention_heads": 4, "qk_nope_head_dim": 16,
+       "qk_rope_head_dim": 8, "qk_head_dim": 24, "v_head_dim": 16,
+       "kv_lora_rank": 32, "num_hidden_layers": 3, "n_routed_experts": 4,
+       "router_width": 16, "moe_intermediate_size": 32,
+       "num_experts_per_tok": 3, "intermediate_size": 96, "vocab_size": 32,
+       "n_positions": 32}
+BATCH = 4
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return Manifest().configuration("kanana-2-30b-a3b", TOY)
+
+
+@pytest.fixture(scope="module")
+def reference(cfg):
+    return cfg.module("reference")
+
+
+@pytest.fixture(scope="module")
+def batch(cfg):
+    rng = np.random.default_rng(13)
+    shape = (BATCH, cfg.sizes["n_positions"])
+    return (jnp.asarray(rng.integers(0, 32, shape).astype(np.int32)),
+            jnp.asarray(rng.integers(0, 32, shape).astype(np.int32)))
+
+
+@pytest.fixture(scope="module")
+def weights(cfg, reference):
+    return reference.init_params(jax.random.PRNGKey(5), cfg.sizes)
+
+
+@pytest.fixture()
+def net(cfg):
+    from analytics_zoo_tpu import init_zoo_context
+
+    init_zoo_context("latent moe decoder", seed=3)
+    model = cfg.module("model").build(cfg.sizes)
+    _built, state = model.build_params()
+    return model, state
+
+
+def _program_loss(net, params, x, y):
+    model, state = net
+    with training_targets(y):
+        _, new_state = model.forward(params, x, state=state, training=True)
+    return moe.collect_aux_cost(new_state), new_state
+
+
+def test_logits_loss_and_every_gradient_against_the_reference(
+        net, cfg, reference, weights, batch):
+    model, state = net
+    built, _ = model.build_params()
+    assert jax.tree_util.tree_structure(built) \
+        == jax.tree_util.tree_structure(weights)
+    x, y = batch
+    logits, _ = model.forward(weights, x, state=state, training=False)
+    want = reference.logits(weights, x, cfg.sizes)
+    np.testing.assert_allclose(logits, want, atol=2e-6)
+
+    (loss, new_state), grads = jax.value_and_grad(
+        lambda p: _program_loss(net, p, x, y), has_aux=True)(weights)
+    ref_loss, ref_grads = jax.value_and_grad(reference.loss_fn)(
+        weights, x, y, cfg.sizes)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
+        name = jax.tree_util.keystr(path)
+        if "router_bias" in name:
+            # b picks, by integers: it is a leaf and takes no gradient
+            assert not np.any(got) and not np.any(ref), name
+            continue
+        assert float(jnp.abs(ref).max()) > 0, name
+        np.testing.assert_allclose(
+            got, ref, atol=2e-5 * float(jnp.abs(ref).max()), err_msg=name)
+    layer = new_state["kanana"]
+    assert layer["moe_held_assignments"].shape == (2,)
+    assert float(layer["moe_dropped_assignments"]) == 0.0
+    record = self_attention.decoder_records[-1]
+    assert (record["dense_layers"], record["routed_layers"]) == (1, 2)
+    assert (record["router_width"], record["experts_held"],
+            record["experts_held_from"], record["experts_per_token"],
+            record["capacity_factor"]) == (16, 4, 0, 3, None)
+    assert (record["qk_width"], record["value_width"]) == (24, 16)
+    assert "moe_route" in record["kept"] and record["loss_blocks"] == 1
+
+
+def _layer_inputs(cfg, reference, weights, seed=0):
+    """One routed layer's leaves and (T, d) tokens to feed it."""
+    bp = weights[reference.CORE]["blocks"][1]
+    u = jnp.asarray(np.random.default_rng(seed).normal(
+        size=(BATCH * 32, cfg.sizes["hidden_size"])).astype(np.float32))
+    return bp, u
+
+
+def _whole_layer(cfg, reference, key):
+    """A routed layer that holds all 16 experts: the uncut reference."""
+    sizes = {**cfg.sizes, "n_routed_experts": 16}
+    bp = reference.init_params(key, sizes)[reference.CORE]["blocks"][1]
+    return sizes, bp
+
+
+def test_the_shares_add_up_to_the_uncut_layer(cfg, reference):
+    """Four workers hold experts 0-3, 4-7, 8-11, 12-15.  The routed parts
+    that the four give, with the shared experts counted once, are what the
+    uncut reference gives for the whole layer."""
+    sizes, bp = _whole_layer(cfg, reference, jax.random.PRNGKey(9))
+    u = jnp.asarray(np.random.default_rng(1).normal(
+        size=(128, 64)).astype(np.float32))
+    qs = narrow.rounders(None)
+    whole = reference._feed_forward(qs, sizes, bp, u)
+    shared = (jax.nn.silu(u @ bp["shared_gate_kernel"])
+              * (u @ bp["shared_fc_kernel"])) @ bp["shared_out_kernel"]
+    total, held = shared, 0.0
+    for first in (0, 4, 8, 12):
+        part, stats = moe.held_experts_ffn(
+            u, bp["router_kernel"], bp["router_bias"],
+            *(bp[k][first:first + 4] for k in
+              ("experts_gate", "experts_up", "experts_down")),
+            first_held=first, top_k=3,
+            routed_scale=sizes["routed_scaling_factor"])
+        total = total + part
+        held += float(stats["held_assignments"])
+        assert float(stats["dropped_assignments"]) == 0.0
+    assert held == 3 * 128      # every assignment fell on one of the four
+    np.testing.assert_allclose(total, whole, atol=2e-6)
+    # the reference's own share is the same part
+    for first in (0, 8):
+        share = {**bp, **{k: bp[k][first:first + 4] for k in
+                          ("experts_gate", "experts_up", "experts_down")}}
+        got = reference._feed_forward(
+            qs, {**sizes, "experts_held_from": first}, share, u) - shared
+        want, _ = moe.held_experts_ffn(
+            u, bp["router_kernel"], bp["router_bias"],
+            share["experts_gate"], share["experts_up"],
+            share["experts_down"], first_held=first, top_k=3,
+            routed_scale=sizes["routed_scaling_factor"])
+        np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("favoured, rows", [((2, 9, 10), 128),
+                                            ((0, 1, 2), 384)],
+                         ids=["one_held_expert", "the_whole_buffer"])
+def test_a_skewed_router_drops_nothing(cfg, reference, weights, favoured,
+                                       rows):
+    """Every token picks the same three experts: expert 2 alone of the
+    held ones takes all 128 tokens, or the three held ones fill the buffer
+    of 3 x 128 rows.  Nothing is dropped and the result is the
+    reference's."""
+    bp, u = _layer_inputs(cfg, reference, weights)
+    bias = jnp.zeros((16,)).at[jnp.asarray(favoured)].set(4.0)
+    bp = {**bp, "router_bias": bias}
+    got, stats = moe.held_experts_ffn(
+        u, bp["router_kernel"], bias, bp["experts_gate"], bp["experts_up"],
+        bp["experts_down"], first_held=0, top_k=3,
+        routed_scale=cfg.sizes["routed_scaling_factor"])
+    assert float(stats["held_assignments"]) == rows
+    assert float(stats["dropped_assignments"]) == 0.0
+    held_favoured = sum(e < 4 for e in favoured)
+    assert float(stats["load_max_over_mean"]) == pytest.approx(
+        4 / held_favoured)
+    qs = narrow.rounders(None)
+    shared = (jax.nn.silu(u @ bp["shared_gate_kernel"])
+              * (u @ bp["shared_fc_kernel"])) @ bp["shared_out_kernel"]
+    want = reference._feed_forward(qs, cfg.sizes, bp, u) - shared
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    assert float(jnp.abs(want).max()) > 1e-3
+
+
+def test_the_bias_picks_and_does_not_weigh(cfg, reference, weights):
+    bp, u = _layer_inputs(cfg, reference, weights, seed=2)
+    scale = cfg.sizes["routed_scaling_factor"]
+    s = jax.nn.sigmoid(u @ bp["router_kernel"])
+    plain, _ = moe.sigmoid_route(u, bp["router_kernel"], jnp.zeros((16,)),
+                                 top_k=3, routed_scale=scale)
+    picked, w = moe.sigmoid_route(u, bp["router_kernel"], bp["router_bias"],
+                                  top_k=3, routed_scale=scale)
+    # the seeded b changes the selection of some tokens ...
+    changed = np.any(np.sort(np.asarray(plain), -1)
+                     != np.sort(np.asarray(picked), -1), axis=-1)
+    assert 0 < changed.sum() < len(changed)
+    # ... and the weights are the scores themselves at the picked experts
+    at = jnp.take_along_axis(s, picked, axis=-1)
+    np.testing.assert_allclose(
+        w, at / at.sum(-1, keepdims=True) * scale, rtol=1e-6)
+    np.testing.assert_allclose(w.sum(-1), scale, rtol=1e-6)
+    # the reference notices its absence
+    with_b = reference.route(cfg.sizes, bp, u)
+    without = reference.route(
+        cfg.sizes, {**bp, "router_bias": jnp.zeros((16,))}, u)
+    assert np.any(np.asarray(with_b) != np.asarray(without))
+
+
+def test_the_grouped_kernel_in_interpret_mode(cfg, reference, weights,
+                                              monkeypatch):
+    """The Pallas grouped product (interpret mode) gives the fallback's
+    result and gradients, rows past the held ones masked both ways."""
+    bp, u = _layer_inputs(cfg, reference, weights, seed=3)
+    g = jnp.asarray(np.random.default_rng(4).normal(
+        size=u.shape).astype(np.float32))
+
+    def run(u, gate, up, down):
+        y, _ = moe.held_experts_ffn(
+            u, bp["router_kernel"], bp["router_bias"], gate, up, down,
+            first_held=0, top_k=3, routed_scale=2.0)
+        return jnp.sum(y * g)
+
+    args = (u, bp["experts_gate"], bp["experts_up"], bp["experts_down"])
+    want = jax.value_and_grad(run, argnums=(0, 1, 2, 3))(*args)
+    before = dict(grouped.invocation_counts)
+    monkeypatch.setenv("ZOO_KERNEL_INTERPRET", "1")
+    got = jax.value_and_grad(run, argnums=(0, 1, 2, 3))(*args)
+    assert grouped.invocation_counts["pallas"] == before["pallas"] + 3
+    assert grouped.invocation_counts["fallback"] == before["fallback"]
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.all(np.isfinite(a))
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("policy, sorts", [("attn", 2), ("full", 4)])
+def test_the_route_is_kept_and_not_sorted_twice(net, weights, policy, sorts):
+    """Under the decoder's policy the backward pass of a routed layer
+    application reads the sort's permutation and its inverse from what was
+    kept: its gradient holds the two sorts once, under ``"full"`` twice."""
+    from analytics_zoo_tpu.parallel.plan import apply_remat
+
+    layer = net[0].layers[-1]
+    bp = weights["kanana"]["blocks"][1]
+    h = jnp.asarray(np.random.default_rng(6).normal(
+        size=(BATCH, 32, 64)).astype(np.float32))
+    body = apply_remat(layer._block_forward_aux, policy, static_argnums=(3,))
+    text = str(jax.make_jaxpr(jax.grad(lambda bp, h: jnp.sum(jnp.square(
+        body(bp, h, None, True, None)[0])), argnums=(0, 1)))(bp, h))
+    assert text.count(" sort[") == sorts
+
+
+def test_fit_with_the_models_own_loss_publishes_the_gauges(cfg, weights):
+    from analytics_zoo_tpu import init_zoo_context
+    from analytics_zoo_tpu.metrics import snapshot
+
+    init_zoo_context("latent moe decoder fit", seed=3)
+    model_py = cfg.module("model")
+    model = model_py.build(cfg.sizes)
+    model.build_params()
+    model.params = jax.tree_util.tree_map(jnp.array, weights)
+    rng = np.random.default_rng(5)
+    x = rng.integers(0, 32, (8, 32)).astype(np.int32)
+    y = rng.integers(0, 32, (8, 32)).astype(np.int32)
+    model.fit(model_py.feature_set(x, y, cfg.sizes), batch_size=8,
+              nb_epoch=2)
+    history = [h["loss"] for h in model._estimator.history]
+    assert history[1] < history[0] < np.log(32) + 0.2
+    assert model_py.routing_fault("cpu") is None
+    gauges = {(s["name"], (s.get("labels") or {}).get("label", "")):
+              s["value"] for s in snapshot()["samples"]
+              if s["name"].startswith("zoo_moe_")}
+    assert gauges[("zoo_moe_dropped_assignments", "")] == 0.0
+    for layer in ("1", "2"):
+        # 8 x 32 tokens x 3 picks over 16 experts, 4 held: 192 expected
+        assert 96 < gauges[("zoo_moe_held_assignments", layer)] < 288
+        assert 1.0 <= gauges[("zoo_moe_load_max_over_mean", layer)] <= 4.0
+    # evaluate and predict read the logits, as with any other loss
+    assert model.predict(x[:4]).shape == (4, 32, 32)

@@ -248,6 +248,14 @@ def _specs():
                                 hidden_size=8, intermediate_size=12,
                                 passes=2, input_shape=(6,)), (6,), ints=17)
 
+    seq("LatentMoEDecoder",
+        lambda: L.LatentMoEDecoder(
+            vocab=17, n_block=2, n_head=2, hidden_size=8,
+            intermediate_size=12, kv_latent_rank=4, qk_nope_dim=4,
+            qk_rope_dim=2, v_head_dim=4, routed_experts=4, experts_held=2,
+            experts_per_token=2, expert_size=6, shared_experts=1,
+            input_shape=(6,)), (6,), ints=17)
+
     # ---- multi-input / multi-output graphs -----------------------------
     def merge_spec():
         a, b = Input(shape=(4,)), Input(shape=(4,))

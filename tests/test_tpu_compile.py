@@ -53,12 +53,15 @@ def real_kernels(monkeypatch):
         compilation_cache.reset_cache()
 
 
-def _flash(shape, grad, padding_bias=False, segments=False, **kw):
-    """(fn, arg specs) for flash attention on (B, H, L, D) bf16."""
+def _flash(shape, grad, padding_bias=False, segments=False,
+           value_width=None, **kw):
+    """(fn, arg specs) for flash attention on (B, H, L, D) bf16, v
+    ``value_width`` wide where given."""
     from analytics_zoo_tpu.ops.pallas.flash_attention import flash_attention
 
     b, _h, l, _d = shape
-    args = [(shape, jnp.bfloat16)] * 3
+    args = [(shape, jnp.bfloat16)] * 2 \
+        + [(shape[:3] + (value_width or shape[3],), jnp.bfloat16)]
     if padding_bias:
         args.append(((b, 1, 1, l), jnp.float32))  # the BERT mask
     if segments:
@@ -78,6 +81,24 @@ def _flash(shape, grad, padding_bias=False, segments=False, **kw):
         return jnp.sum(fwd(q, k, v, *rest).astype(jnp.float32))
 
     return jax.grad(loss, argnums=(0, 1, 2)), args
+
+
+def _routed(tokens, width, router, held, expert, top_k):
+    """(fn, arg specs) for the gradient of the routed feed-forward without
+    dropped tokens: the sort and the grouped products of top_k x tokens
+    assignments over the experts held."""
+    from analytics_zoo_tpu.ops.moe import held_experts_ffn
+
+    def loss(u, route, bias, gate, up, down):
+        y, _ = held_experts_ffn(u, route, bias, gate, up, down, first_held=0,
+                                top_k=top_k, routed_scale=2.448)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    return jax.grad(loss, argnums=(0, 1, 3, 4, 5)), [
+        ((tokens, width), jnp.bfloat16), ((width, router), jnp.bfloat16),
+        ((router,), jnp.bfloat16), ((held, width, expert), jnp.bfloat16),
+        ((held, width, expert), jnp.bfloat16),
+        ((held, expert, width), jnp.bfloat16)]
 
 
 def _xent(shape):
@@ -122,6 +143,14 @@ CASES = {
     # `ouro-2.6b-fit`'s own call: heads of 128, four 1024-row chunks
     "flash_fwd_bwd_ouro_cell":
         lambda: _flash((2, 16, 4096, 128), True, causal=True),
+    # `kanana-2-30b-a3b-fit`'s own calls: latent attention's q and k at
+    # 192, v and the output at 128; 49,152 assignments over 16 of 128
+    # experts of width 768
+    "flash_fwd_bwd_kanana_cell":
+        lambda: _flash((2, 32, 4096, 192), True, causal=True,
+                       value_width=128),
+    "routed_experts_kanana_cell":
+        lambda: _routed(8192, 2048, 128, 16, 768, 6),
     # the shape `_resolve_blocks` sizes its VMEM caps against
     "flash_fwd_bwd_vmem_caps":
         lambda: _flash((1, 2, 4096, 128), True, causal=True,
@@ -241,3 +270,104 @@ def test_the_looped_head_makes_its_gradient_in_its_forward_loops(
         == [3] * passes
     assert text.count(" convolution(") == 3 * passes
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+def _kanana_layer():
+    from analytics_zoo_tpu.pipeline.api.keras.layers import LatentMoEDecoder
+
+    return LatentMoEDecoder(
+        vocab=16032, n_block=2, n_head=32, hidden_size=2048,
+        intermediate_size=6144, kv_latent_rank=512, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128, routed_experts=128, experts_held=16,
+        experts_per_token=6, expert_size=768, shared_experts=2,
+        routed_scale=2.448)
+
+
+def test_a_routed_latent_layer_application_by_its_kernels(one_chip,
+                                                          real_kernels):
+    """The gradient of one checkpointed routed layer application of
+    `kanana-2-30b-a3b-fit` (2 x 4,096 tokens, 32 heads of 192/128, 16 of
+    128 experts held, bf16) for a described v5e: each flash kernel once (the
+    forward kernel is not run again for what the policy kept), and twelve
+    grouped products, three of the forward pass made again in the backward
+    pass (the policy keeps the feed-forward's output, not its hidden
+    activations), three for the rows' gradient and three transposed ones for
+    the experts'.  Told apart by what the benchmark's readers read: the
+    instruction's name and the arrays returned."""
+    from benchmark import xplane
+
+    layer = _kanana_layer()
+
+    def loss(bp, h):
+        from analytics_zoo_tpu.parallel.plan import apply_remat
+
+        body = apply_remat(layer._block_forward_aux, layer.remat,
+                           static_argnums=(3,))
+        out = body(bp, h, None, True, None)[0]
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    bp = jax.eval_shape(
+        lambda: layer._block_params(jax.random.PRNGKey(0), 1))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        (bp, jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16)))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).compile()
+    marked = f"/{xplane.KERNEL_TARGET}/"
+    kernels = [name for name in map(xplane.short_name,
+                                    compiled.as_text().splitlines())
+               if marked in name]
+    flash = sorted(int(k.rpartition(marked)[2]) for k in kernels
+                   if "flash" in k)
+    assert flash == [1, 2, 3], kernels
+    grouped = [k for k in kernels if "flash" not in k]
+    assert len(grouped) == 12 and all(
+        k.endswith(marked + "1") for k in grouped), kernels
+    # the sorted rows and their hidden activations, 49,152 x (2048 + 3 x
+    # 768) in bf16 with their gradients, are the temporaries: under 2.5 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_the_kanana_step_compiles_whole_for_v5e(one_chip, real_kernels):
+    """`kanana-2-30b-a3b-fit`'s train step as ``fit`` builds it (1 dense +
+    4 routed layers at the published widths, 2 x 4,096 tokens, bf16 compute
+    over float32 state, Adam) for a described v5e: 15 flash calls and 48
+    grouped products, 6.9 GB of arguments (parameters and both moments, 12
+    bytes each of 576 M) and under 4 GB of temporaries, so that the
+    harness's check steps' second float32 copy of the parameters (2.3 GB)
+    fits beside the loaded step under the allocator's 16.9 GB."""
+    import numpy as np
+
+    from analytics_zoo_tpu import init_zoo_context
+    from benchmark import data
+    from benchmark.manifest import Manifest
+
+    manifest = Manifest()
+    cell = manifest.cell("kanana-2-30b-a3b-fit")
+    configuration = manifest.configuration(cell["config"])
+    sizes = configuration.sizes
+    batch = manifest.traffic(cell["traffic"])["batch"]
+    init_zoo_context("kanana step compile",
+                     compute_dtype=sizes["compute_dtype"])
+    model_py = configuration.module("model")
+    model = model_py.build(sizes)
+    est = model._make_estimator()
+    x, y = data.rows(0, 0, batch, sizes)
+    step = est._build_train_step(
+        getattr(model_py.feature_set(x, y, sizes), "device_transform", None),
+        1, est._resolved_plan())
+    reference = configuration.module("reference")
+    params = jax.eval_shape(lambda k: reference.init_params(k, sizes),
+                            jax.random.PRNGKey(0))
+    _, state = model.build_params()
+    opt_state = jax.eval_shape(est.optimizer.init, params)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, opt_state, state, np.int32(0), np.int32(0),
+         {"x": x, "y": y}))
+    compiled = step._jitted.lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 15 + 48
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(6.91e9, rel=0.01)
+    assert memory.temp_size_in_bytes < 4.0e9
+    assert model_py.routing_fault("cpu") is None
