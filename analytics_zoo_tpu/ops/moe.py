@@ -164,39 +164,10 @@ def routed_ffn(h, gate_w, w1, b1, w2, b2, *, top_k=2, capacity_factor=1.25,
 # ---------------------------------------------------------------------------
 
 #: the ``checkpoint_name`` of what the route decides by integers alone: the
-#: picked experts, the sort's permutation and its inverse, the group sizes.
-#: The ``"attn"`` recomputation policy keeps them (``parallel/plan.py``): a
-#: few hundred KB that cost a top-k and two sorts to make again.
+#: sort's permutation and the group sizes.  The ``"attn"`` recomputation
+#: policy keeps them (``parallel/plan.py``): a few hundred KB that cost a
+#: top-k and a sort to make again.
 ROUTE_NAME = "moe_route"
-
-
-# The two below are each other's transpose, and both are gathers: the
-# assignments are a permutation of (token, choice) pairs, so the scatter-add
-# that autodiff would make of either gather is the other gather through the
-# inverse permutation.
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def dispatch(k, x, perm, inv):
-    """``x`` (T, D) -> (k T, D): row a is the token of the a-th assignment
-    in sorted order (``perm`` (k T,) of indices into the (T, k) assignments
-    laid out row by row, ``inv`` its inverse)."""
-    return jnp.take(x, perm // k, axis=0)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def combine(k, y, perm, inv):
-    """``y`` (k T, D) in sorted order -> (T, D): the sum of a token's k
-    rows, in float32."""
-    rows = jnp.take(y, inv, axis=0).astype(jnp.float32)
-    return jnp.sum(rows.reshape(-1, k, y.shape[-1]), axis=1).astype(y.dtype)
-
-
-dispatch.defvjp(
-    lambda k, x, perm, inv: (dispatch(k, x, perm, inv), (perm, inv)),
-    lambda k, kept, g: (combine(k, g, *kept), None, None))
-combine.defvjp(
-    lambda k, y, perm, inv: (combine(k, y, perm, inv), (perm, inv)),
-    lambda k, kept, g: (dispatch(k, g, *kept), None, None))
 
 
 def sigmoid_route(u, router, score_bias, *, top_k, routed_scale):
@@ -215,6 +186,158 @@ def sigmoid_route(u, router, score_bias, *, top_k, routed_scale):
     return experts.astype(jnp.int32), weights * routed_scale
 
 
+def walk_bound(assignments, held, router_width):
+    """R, the sorted rows a window of the walk covers: four thirds of the
+    even share of the ``assignments`` that ``held`` of ``router_width``
+    experts take, up to the grouped product's row tile, and at most all of
+    them.  From the shapes alone; a step whose held rows pass R walks a
+    second window."""
+    from analytics_zoo_tpu.ops.pallas.grouped_matmul import ROWS
+
+    share = -(-4 * assignments * held // (3 * router_width))
+    return min(assignments, -(-share // ROWS) * ROWS)
+
+
+def _rows(x, at):
+    """Rows ``at`` of ``x``.  Every index here is one of a permutation's or
+    a token's, in bounds by construction, and says so: checked, the gather
+    is followed by a select over all it gathered that costs as much again
+    (0.61 ms after 0.31 at (49,152, 2048) bf16; PERF.md, PR 33)."""
+    return x.at[at].get(mode="promise_in_bounds")
+
+
+def _window(bound, top_k, w, u, weights, perm, group_sizes, w_gate, w_up):
+    """Window ``w`` of the walk, sorted rows w R .. w R + R - 1, up to the
+    hidden rows: which rows of it are held (``live``), its share of each
+    held expert's rows (they stay sorted by expert, so the grouped products
+    take them as they are), its tokens' rows ``x``, the two products ``a``
+    and ``b``, the rows' weights ``weight`` and, for the sum over a token's
+    rows, the window's rows in the tokens' order (``by_token``, whose they
+    are, and how many fall in each tile of tokens; one sort of R pairs: the
+    same order from a running count and a search took 1.49 ms on the chip;
+    PERF.md, PR 33).  Rows that are not live are never multiplied, never
+    written and never summed: the kernels walk the sizes."""
+    from analytics_zoo_tpu.ops.pallas.grouped_matmul import (
+        grouped_matmul,
+        token_tile,
+    )
+
+    t = u.shape[0]
+    first = w * bound
+    ends = jnp.cumsum(group_sizes)
+    sizes = jnp.clip(ends, first, first + bound) \
+        - jnp.clip(ends - group_sizes, first, first + bound)
+    rows = jnp.arange(bound, dtype=jnp.int32)
+    live = first + rows < ends[-1]
+    picks = jax.lax.dynamic_slice(perm, (first,), (bound,))
+    token = picks // top_k
+    x = _rows(u, token)
+    whose, order = jax.lax.sort((jnp.where(live, token, t), rows),
+                                num_keys=1)
+    tile_sizes = jnp.sum(
+        whose[:, None] // token_tile(t)
+        == jnp.arange(t // token_tile(t))[None, :], axis=0, dtype=jnp.int32)
+    return {"live": live, "sizes": sizes, "picks": picks, "token": token,
+            "x": x, "a": grouped_matmul(x, w_gate, sizes),
+            "b": grouped_matmul(x, w_up, sizes),
+            "weight": _rows(weights.reshape(-1), picks)[:, None],
+            "by_token": (order, whose, tile_sizes)}
+
+
+def _sum_by_token(rows, by_token, total):
+    """``total`` (T, D) float32 + the sum of a token's ``rows`` (R, D)."""
+    from analytics_zoo_tpu.ops.pallas.grouped_matmul import grouped_row_sum
+
+    order, token, tile_sizes = by_token
+    return grouped_row_sum(_rows(rows, order), token, tile_sizes, total)
+
+
+def _windows(bound, perm, group_sizes, body, start):
+    """``body(w, carry)`` over the windows that the step's held rows fill,
+    ceil(held / R) of them, counted on the device: one compiled body.  No
+    loop where R is all the rows."""
+    if bound == perm.shape[0]:
+        return body(0, start)
+    return jax.lax.fori_loop(
+        0, (jnp.sum(group_sizes) + bound - 1) // bound, body, start)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _walk(bound, top_k, activation, u, weights, perm, group_sizes,
+          w_gate, w_up, w_down):
+    """The held experts' part of (T, D) tokens ``u``, window by window:
+    ``weights`` (T, k) are the assignments' and ``perm`` their sorted
+    order, padded to whole windows.  The parts are summed in float32.  A
+    loop whose trip count the device reads is not reverse-differentiable,
+    so the backward rule is a loop too: it keeps the operands only, makes a
+    window's hidden rows again and pulls the cotangent through them (under
+    the ``"attn"`` policy the forward products are made again in the
+    backward pass anyway), adding a window's share to the running sums:
+    the tokens' in float32, the experts' in their own dtype, each summed in
+    float32 by the kernel that writes it."""
+    from analytics_zoo_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    operands = (u, weights, perm, group_sizes, w_gate, w_up, w_down)
+
+    def body(w, y):
+        at = _window(bound, top_k, w, *operands[:6])
+        f = (activation(at["a"]) * at["b"]).astype(u.dtype)
+        part = grouped_matmul(f, w_down, at["sizes"])
+        return _sum_by_token(part * at["weight"].astype(part.dtype),
+                             at["by_token"], y)
+
+    return _windows(bound, perm, group_sizes, body,
+                    jnp.zeros(u.shape, jnp.float32)).astype(u.dtype)
+
+
+def _walk_bwd(bound, top_k, activation, operands, g):
+    from analytics_zoo_tpu.ops.pallas.grouped_matmul import (
+        grouped_matmul,
+        grouped_matmul_transposed,
+    )
+
+    u, weights, perm, group_sizes, w_gate, w_up, w_down = operands
+
+    def body(w, sums):
+        du, d_weight, d_gate, d_up, d_down = sums
+        at = _window(bound, top_k, w, *operands[:6])
+        sizes, weight = at["sizes"], at["weight"]
+        f, pull = jax.vjp(
+            lambda a, b: (activation(a) * b).astype(u.dtype),
+            at["a"], at["b"])
+        # y = (f Wdown) weight: the cotangent of a row before its weight,
+        # through Wdown once, serves f's and the weight's gradient alike
+        g_rows = _rows(g, at["token"])
+        df = grouped_matmul(g_rows, w_down, sizes, transpose_rhs=True)
+        # (rows that are not live add nothing, the padding's to pick 0)
+        d_weight = d_weight.at[at["picks"]].add(
+            jnp.where(at["live"], jnp.sum(
+                f.astype(jnp.float32) * df.astype(jnp.float32), axis=-1), 0),
+            mode="promise_in_bounds")
+        d_down = grouped_matmul_transposed(
+            f * weight.astype(f.dtype), g_rows, sizes, d_down)
+        da, db = pull(df * weight.astype(df.dtype))
+        d_gate = grouped_matmul_transposed(at["x"], da, sizes, d_gate)
+        d_up = grouped_matmul_transposed(at["x"], db, sizes, d_up)
+        dx = grouped_matmul(da, w_gate, sizes, transpose_rhs=True) \
+            + grouped_matmul(db, w_up, sizes, transpose_rhs=True)
+        return (_sum_by_token(dx, at["by_token"], du), d_weight,
+                d_gate, d_up, d_down)
+
+    du, d_weight, *d_experts = _windows(bound, perm, group_sizes, body, (
+        jnp.zeros(u.shape, jnp.float32),
+        jnp.zeros(weights.size, weights.dtype),
+        *(jnp.zeros_like(x) for x in (w_gate, w_up, w_down))))
+    return (du.astype(u.dtype), d_weight.reshape(weights.shape), None, None,
+            *d_experts)
+
+
+_walk.defvjp(
+    lambda bound, top_k, activation, *operands: (
+        _walk(bound, top_k, activation, *operands), operands),
+    _walk_bwd)
+
+
 def held_experts_ffn(u, router, score_bias, w_gate, w_up, w_down, *,
                      first_held, top_k, routed_scale,
                      activation=jax.nn.silu):
@@ -229,18 +352,25 @@ def held_experts_ffn(u, router, score_bias, w_gate, w_up, w_down, *,
     all E experts (``sigmoid_route``); the k T assignments are sorted so
     that the held experts' rows come first, expert by expert, and only
     those rows are multiplied, in groups, by their expert
-    (``ops/pallas/grouped_matmul.py``).  There is no capacity: the buffer
-    has all k T rows, so no assignment is dropped at any skew.  What the
-    experts held elsewhere would add is left out, and no code stands in
-    for the exchange that would bring it.
+    (``ops/pallas/grouped_matmul.py``).  There is no capacity and no
+    assignment is dropped at any skew.  What the experts held elsewhere
+    would add is left out, and no code stands in for the exchange that
+    would bring it.
+
+    The held rows are walked in windows of R = ``walk_bound`` sorted rows
+    (from the shapes): the gathers in and out, the elementwise work and the
+    sum over a token's rows cost what the held rows cost, not what all k T
+    would.  One compiled walk runs ceil(held / R) times, counted on the
+    device: once in an ordinary step, again for a step whose held rows pass
+    R, not at all where nothing is held.  A worker that holds so many
+    experts that R = k T has one window and no loop.
 
     Returns ``(y (T, D), stats)`` with ``stats`` float32 scalars:
     ``held_assignments`` (rows multiplied), ``load_max_over_mean`` (the
-    fullest held expert's rows over the mean) and ``dropped_assignments``
-    (held assignments that were not multiplied: 0 by construction)."""
+    fullest held expert's rows over the mean), ``dropped_assignments``
+    (held assignments that were not multiplied: 0 by construction) and
+    ``walk_windows`` (the windows the held rows fill)."""
     from jax.ad_checkpoint import checkpoint_name
-
-    from analytics_zoo_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
     t, held = u.shape[0], w_gate.shape[0]
     experts, weights = sigmoid_route(u, router, score_bias, top_k=top_k,
@@ -250,28 +380,23 @@ def held_experts_ffn(u, router, score_bias, w_gate, w_up, w_down, *,
     local = experts.reshape(-1) - first_held
     key = jnp.where((local >= 0) & (local < held), local, held)
     perm = jnp.argsort(key, stable=True).astype(jnp.int32)
-    inv = jnp.argsort(perm).astype(jnp.int32)
     group_sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
                           axis=0, dtype=jnp.int32)
-    perm, inv, group_sizes = (checkpoint_name(x, ROUTE_NAME)
-                              for x in (perm, inv, group_sizes))
+    perm, group_sizes = (checkpoint_name(x, ROUTE_NAME)
+                         for x in (perm, group_sizes))
     n_held = jnp.sum(group_sizes)
-    live = (jnp.arange(top_k * t) < n_held)[:, None]
 
-    # rows past the held ones are never multiplied and never written: the
-    # select on the way in zeroes what the kernel leaves in their gradient,
-    # the one on the way out what it leaves in the result
-    x = jnp.where(live, dispatch(top_k, u, perm, inv), 0)
-    f = activation(grouped_matmul(x, w_gate, group_sizes)) \
-        * grouped_matmul(x, w_up, group_sizes)
-    y = grouped_matmul(f.astype(u.dtype), w_down, group_sizes)
-    w_sorted = jnp.take(weights.reshape(-1), perm)[:, None]
-    # masked before it meets its weight: the weight's gradient reads y
-    y = jnp.where(live, y, 0) * w_sorted.astype(y.dtype)
-    multiplied = jnp.minimum(n_held, top_k * t)
+    bound = walk_bound(top_k * t, held, router.shape[-1])
+    # whole windows: the rows past k T are no assignment's and never live
+    y = _walk(bound, top_k, activation, u, weights,
+              jnp.pad(perm, (0, -(top_k * t) % bound)), group_sizes,
+              w_gate, w_up, w_down)
+    windows = (n_held + bound - 1) // bound
+    multiplied = jnp.minimum(n_held, windows * bound)
     stats = {
         "held_assignments": multiplied.astype(jnp.float32),
         "load_max_over_mean": jnp.max(group_sizes).astype(jnp.float32)
         * held / jnp.maximum(n_held, 1).astype(jnp.float32),
-        "dropped_assignments": (n_held - multiplied).astype(jnp.float32)}
-    return combine(top_k, y, perm, inv), stats
+        "dropped_assignments": (n_held - multiplied).astype(jnp.float32),
+        "walk_windows": windows.astype(jnp.float32)}
+    return y, stats

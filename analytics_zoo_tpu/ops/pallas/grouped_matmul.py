@@ -1,16 +1,21 @@
 """Grouped matrix product: rows of ``lhs`` sorted by group, each group's
 rows multiplied by that group's matrix.  The product of routed experts
-that drop no token (``ops/moe.py::held_experts_ffn``): the rows are the
-assignments sorted by expert, the groups the experts held here.
+that drop no token (``ops/moe.py::held_experts_ffn``): the rows are a
+window of the assignments sorted by expert, the groups the experts held
+here.
 
     out[start_g : start_g + group_sizes[g]] = lhs[the same rows] @ rhs[g]
 
 On a TPU this is the Pallas kernel JAX ships as ``jax.experimental.pallas.
-ops.tpu.megablox`` (``gmm``, and ``tgmm`` for the weights' gradient): its
-grid runs over the row tiles that the group sizes fill, so rows past
-``sum(group_sizes)`` cost nothing and are NOT WRITTEN: the caller masks
-them.  Elsewhere ``jax.lax.ragged_dot`` is the plain fallback and the
-oracle (it writes zeros there).  ``ZOO_KERNEL_INTERPRET=1`` runs the kernel
+ops.tpu.megablox`` (``gmm``), with its transposed form (``tgmm``) for the
+groups' own gradient and for the sum of a token's rows: their grids run
+over the row tiles that the group sizes fill, so rows past
+``sum(group_sizes)`` cost nothing; ``gmm`` does NOT WRITE them and
+``tgmm`` does not read them.  Elsewhere ``jax.lax.ragged_dot`` and
+``ragged_dot_general`` are the plain fallback and the oracle (zeros
+there).  None of the three is differentiable: ``held_experts_ffn`` has the
+rule, whose sums over windows the transposed kernels add to in place
+(``existing_out``).  ``ZOO_KERNEL_INTERPRET=1`` runs the kernel
 in interpret mode, ``ZOO_KERNEL_FORCE_PALLAS=1`` routes to the real kernel
 on any backend for lowering-only checks, as for the other kernels of this
 package.
@@ -18,7 +23,6 @@ package.
 
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
@@ -34,6 +38,8 @@ invocation_counts = {"pallas": 0, "fallback": 0}
 #: v5e's 16 MB of scoped VMEM.  The weights' gradient accumulates a
 #: (contraction, columns) tile in float32, so its contraction tile is half.
 ROWS, CONTRACTION, COLUMNS = 256, 2048, 1024
+#: The tokens a group of ``grouped_row_sum`` covers: one pass of the MXU wide.
+TOKENS = 128
 
 
 def _env_flag(name: str) -> bool:
@@ -72,50 +78,72 @@ def _megablox():
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gmm(lhs, rhs, group_sizes, interpret):
-    return _gmm_fwd(lhs, rhs, group_sizes, interpret)[0]
+def _count(kernel: bool) -> bool:
+    invocation_counts["pallas" if kernel else "fallback"] += 1
+    return kernel
 
 
-def _gmm_fwd(lhs, rhs, group_sizes, interpret):
-    megablox = _megablox()
-    (m, k), n = lhs.shape, rhs.shape[2]
-    out = megablox.gmm(
+def grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False):
+    """``lhs`` (rows, k) sorted by group, ``rhs`` (groups, k, n), or
+    (groups, n, k) with ``transpose_rhs``, ``group_sizes`` (groups,) int32
+    -> (rows, n) in ``lhs``'s dtype.  Rows past ``sum(group_sizes)`` are
+    unspecified (the kernel leaves them unwritten)."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    if not _count(_pallas_available()):
+        return jax.lax.ragged_dot(
+            lhs, rhs.swapaxes(1, 2) if transpose_rhs else rhs, group_sizes)
+    (m, k), n = lhs.shape, rhs.shape[1 if transpose_rhs else 2]
+    return _megablox().gmm(
         lhs, rhs, group_sizes, lhs.dtype,
         (_rows_tile(m), _tile(k, CONTRACTION), _tile(n, COLUMNS)),
-        interpret=interpret)
-    return out, (lhs, rhs, group_sizes)
+        transpose_rhs=transpose_rhs, interpret=_interpret_forced())
 
 
-def _gmm_bwd(interpret, kept, g):
-    """d lhs = g @ rhs[group]^T, the same kernel with the transposed
-    matrices; d rhs[group] = lhs[rows]^T @ g[rows], its transposed form."""
-    megablox = _megablox()
-    lhs, rhs, group_sizes = kept
-    (m, k), n = lhs.shape, rhs.shape[2]
-    d_lhs = megablox.gmm(
-        g, rhs, group_sizes, lhs.dtype,
-        (_rows_tile(m), _tile(n, CONTRACTION), _tile(k, COLUMNS)),
-        transpose_rhs=True, interpret=interpret)
-    d_rhs = megablox.tgmm(
-        lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
-        (_rows_tile(m), _tile(k, CONTRACTION // 2), _tile(n, COLUMNS)),
-        num_actual_groups=rhs.shape[0], interpret=interpret)
-    return d_lhs, d_rhs, None
-
-
-_gmm.defvjp(_gmm_fwd, _gmm_bwd)
-
-
-def grouped_matmul(lhs, rhs, group_sizes):
-    """``lhs`` (rows, k) sorted by group, ``rhs`` (groups, k, n),
-    ``group_sizes`` (groups,) int32 -> (rows, n) in ``lhs``'s dtype.
-    Rows past ``sum(group_sizes)`` are unspecified (the kernel leaves them
-    unwritten, in the result and in ``lhs``'s gradient alike).
-    Differentiable in ``lhs`` and ``rhs``."""
+def grouped_matmul_transposed(lhs, g, group_sizes, existing_out):
+    """``existing_out[group] + lhs[rows]^T @ g[rows]`` over a group's rows:
+    ``lhs`` (rows, k), ``g`` (rows, n), both sorted by group ->
+    (groups, k, n) in ``existing_out``'s dtype, the sum made in float32.
+    The groups' own gradient of ``grouped_matmul``, added to a running
+    sum; every group is written, rows past ``sum(group_sizes)`` are not
+    read."""
     group_sizes = group_sizes.astype(jnp.int32)
-    if _pallas_available():
-        invocation_counts["pallas"] += 1
-        return _gmm(lhs, rhs, group_sizes, _interpret_forced())
-    invocation_counts["fallback"] += 1
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    if not _count(_pallas_available()):
+        return (existing_out.astype(jnp.float32) + jax.lax.ragged_dot_general(
+            lhs, g, group_sizes, jax.lax.RaggedDotDimensionNumbers(
+                (((0,), (0,)), ((), ())), [0], []),
+            preferred_element_type=jnp.float32)).astype(existing_out.dtype)
+    (m, k), n = lhs.shape, g.shape[1]
+    # the float32 accumulator's (contraction, columns) tile is the large one
+    return _megablox().tgmm(
+        lhs.swapaxes(0, 1), g, group_sizes, existing_out.dtype,
+        (_rows_tile(m), _tile(k, CONTRACTION // 2), _tile(n, COLUMNS)),
+        existing_out=existing_out, interpret=_interpret_forced())
+
+
+def token_tile(tokens: int) -> int:
+    """The tokens a group of ``grouped_row_sum`` covers, of ``tokens``."""
+    return _tile(tokens, TOKENS, 8)
+
+
+def grouped_row_sum(rows, token, tile_sizes, existing_out):
+    """``existing_out[t] + the sum of the rows whose token is t``:
+    ``rows`` (m, n) in the order of their ``token`` (m,) int32,
+    ``tile_sizes`` (tokens / token_tile(tokens),) int32 how many of them
+    fall to each tile of tokens, ``existing_out`` (tokens, n) float32.
+    Rows past ``sum(tile_sizes)`` are not read; their ``token`` is
+    ``tokens`` or more.  On a TPU the transposed grouped product of a row's
+    place in its tile, one-hot, with the rows: it walks the row tiles that
+    the sizes fill.  Elsewhere a ``segment_sum``."""
+    tokens = existing_out.shape[0]
+    if not _count(_pallas_available()):
+        return existing_out + jax.ops.segment_sum(
+            rows.astype(jnp.float32), token, num_segments=tokens,
+            indices_are_sorted=True)
+    tile = token_tile(tokens)
+    place = token[:, None] % tile == jnp.arange(tile)[None, :]
+    return _megablox().tgmm(
+        place.astype(rows.dtype).swapaxes(0, 1), rows,
+        tile_sizes.astype(jnp.int32), existing_out.dtype,
+        (_rows_tile(rows.shape[0]), tile, _tile(rows.shape[1], COLUMNS)),
+        existing_out=existing_out.reshape(-1, tile, rows.shape[1]),
+        interpret=_interpret_forced()).reshape(existing_out.shape)

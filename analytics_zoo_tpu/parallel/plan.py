@@ -100,7 +100,7 @@ REMAT_POLICIES = ("full", "dots", "attn")
 #: feed-forward output, which the norm after or around that branch reads.
 #: ``"moe_route"`` is what a routed feed-forward without dropped tokens
 #: decides by integers (``ops/moe.py::held_experts_ffn``: the sort's
-#: permutation, its inverse and the group sizes).
+#: permutation and the group sizes).
 #: So under ``"attn"`` the backward pass makes the norms, q, k, v and the
 #: other products of the scope again, but runs no attention forward, no
 #: down-projection and no sort a second time (measured: ``PERF.md``,

@@ -991,17 +991,18 @@ class LatentMoEDecoder(_TransformerCore):
     ``lm_loss_cost`` of its state.  With any other loss it hands the
     logits on.  Its state also carries, a routed layer, the assignments
     that fell on held experts (``moe_held_assignments``), the fullest held
-    expert's load over the mean (``moe_load_max_over_mean``) and, summed,
-    the held assignments not multiplied (``moe_dropped_assignments``,
-    always 0), which the estimator publishes as gauges at the epoch's
-    closing sync.
+    expert's load over the mean (``moe_load_max_over_mean``), the windows
+    its walk of the held rows ran (``moe_walk_windows``) and, summed, the
+    held assignments not multiplied (``moe_dropped_assignments``, always
+    0), which the estimator publishes as gauges at the epoch's closing
+    sync.
 
     Every layer application is one ``jax.checkpoint`` under the ``"attn"``
     policy: kept are the attention's output with the two rows of softmax
     statistics (all that the flash backward kernels read besides q, k and
     v), the feed-forward's output and the route's integers (the sort's
-    permutation, its inverse and the group sizes: 0.4 MB a layer at 8,192
-    tokens and top-6, a top-k and two sorts to make again).
+    permutation and the group sizes: 0.2 MB a layer at 8,192 tokens and
+    top-6, a top-k and a sort to make again).
     """
 
     def __init__(self, vocab, n_block, n_head, hidden_size,
@@ -1055,6 +1056,7 @@ class LatentMoEDecoder(_TransformerCore):
         return {"lm_loss_cost": jnp.zeros((), jnp.float32),
                 "moe_held_assignments": per_layer,
                 "moe_load_max_over_mean": per_layer,
+                "moe_walk_windows": per_layer,
                 "moe_dropped_assignments": jnp.zeros((), jnp.float32)}
 
     def compute_output_shape(self, input_shape):
@@ -1121,6 +1123,7 @@ class LatentMoEDecoder(_TransformerCore):
             if targets is not None else jnp.zeros((), jnp.float32),
             "moe_held_assignments": over_layers("held_assignments"),
             "moe_load_max_over_mean": over_layers("load_max_over_mean"),
+            "moe_walk_windows": over_layers("walk_windows"),
             "moe_dropped_assignments": jnp.sum(
                 over_layers("dropped_assignments"))}
         if not training and state is not None:

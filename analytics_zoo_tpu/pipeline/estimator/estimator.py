@@ -100,7 +100,8 @@ def _publish_state_gauges(state) -> None:
     (``zoo_loop_exit_mass{label=<pass>}``, ``zoo_loop_pass_loss{label=
     <pass>}``) and a routed decoder's per-layer counts
     (``zoo_moe_held_assignments{label=<routed layer>}``,
-    ``zoo_moe_load_max_over_mean{label=<routed layer>}``) with its
+    ``zoo_moe_load_max_over_mean{label=<routed layer>}``,
+    ``zoo_moe_walk_windows{label=<routed layer>}``) with its
     ``zoo_moe_dropped_assignments``.  Called after the epoch's closing
     sync and at no other time: the state is then computed, so the fetch
     waits for nothing."""
@@ -118,7 +119,11 @@ def _publish_state_gauges(state) -> None:
              "experts a routed layer holds here, and were multiplied"),
             ("moe_load_max_over_mean", "zoo_moe_load_max_over_mean",
              "rows of the fullest expert held over the mean of the held "
-             "experts, a routed layer, in the last step")):
+             "experts, a routed layer, in the last step"),
+            ("moe_walk_windows", "zoo_moe_walk_windows",
+             "windows of R sorted rows that a routed layer's walk of its "
+             "held rows ran in the last step: 1 in an ordinary step, 0 "
+             "with nothing held, 2 or more past R")):
         if key in state:
             gauge = get_registry().gauge(family, text, ("label",))
             for t, value in enumerate(np.asarray(state[key]), start=1):

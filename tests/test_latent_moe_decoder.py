@@ -181,10 +181,7 @@ def test_a_skewed_router_drops_nothing(cfg, reference, weights, favoured,
     held_favoured = sum(e < 4 for e in favoured)
     assert float(stats["load_max_over_mean"]) == pytest.approx(
         4 / held_favoured)
-    qs = narrow.rounders(None)
-    shared = (jax.nn.silu(u @ bp["shared_gate_kernel"])
-              * (u @ bp["shared_fc_kernel"])) @ bp["shared_out_kernel"]
-    want = reference._feed_forward(qs, cfg.sizes, bp, u) - shared
+    want = _routed_part(cfg.sizes, reference, bp, u)
     np.testing.assert_allclose(got, want, atol=2e-6)
     assert float(jnp.abs(want).max()) > 1e-3
 
@@ -232,7 +229,11 @@ def test_the_grouped_kernel_in_interpret_mode(cfg, reference, weights,
     before = dict(grouped.invocation_counts)
     monkeypatch.setenv("ZOO_KERNEL_INTERPRET", "1")
     got = jax.value_and_grad(run, argnums=(0, 1, 2, 3))(*args)
-    assert grouped.invocation_counts["pallas"] == before["pallas"] + 3
+    # the one walk as traced: three products and the sum of a token's rows
+    # in the forward rule; in the backward rule the gate and up products
+    # made again, the cotangent through the down product, the three
+    # experts' gradients, the rows' two and the sum of a token's rows
+    assert grouped.invocation_counts["pallas"] == before["pallas"] + 4 + 9
     assert grouped.invocation_counts["fallback"] == before["fallback"]
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
@@ -240,11 +241,185 @@ def test_the_grouped_kernel_in_interpret_mode(cfg, reference, weights,
         np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()))
 
 
-@pytest.mark.parametrize("policy, sorts", [("attn", 2), ("full", 4)])
+#: 384 tokens (three tiles of the row sum), top-3 of 16 experts, 4 held:
+#: 1,152 sorted rows, walked in windows of R = ``walk_bound`` of them
+WALK_TOKENS = 384
+
+
+def _bias_holding(s, rows):
+    """A ``score_bias`` under which exactly ``rows`` of the 3 x 384
+    assignments fall on held experts (0-3): ``rows // 384`` of a token's
+    picks are held experts for every token, the others experts 10 and 11
+    (held elsewhere), and the last is expert 9 (held elsewhere) but for
+    the ``rows % 384`` tokens whose score of expert 3 is furthest above
+    that of expert 9: they pick expert 3."""
+    sure, extra = divmod(rows, WALK_TOKENS)
+    if sure == 3:
+        return jnp.zeros((16,)).at[jnp.arange(3)].set(4.0)
+    lead = np.sort(np.asarray(s[:, 3] - s[:, 9]))[::-1]
+    margin = 1.0 if extra == 0 \
+        else float(lead[extra - 1] + lead[extra]) / 2
+    picked = list(range(sure)) + [10, 11][:2 - sure]
+    return jnp.zeros((16,)).at[jnp.asarray(picked)].set(4.0) \
+        .at[9].set(2.0).at[3].set(2.0 - margin)
+
+
+#: case -> (held rows as a function of R, or None for the seeded bias;
+#: windows as reckoned at R = 512)
+WALKS = {
+    "nothing_held": (lambda r: 0, 0),
+    "held_under_a_window": (None, 1),
+    "held_rows_fill_a_window": (lambda r: r, 1),
+    "one_row_past_a_window": (lambda r: r + 1, 2),
+    "every_pick_held": (lambda r: 3 * WALK_TOKENS, 3),
+}
+
+
+def _routed_part(cfg, reference, bp, u):
+    """The per-expert oracle: the reference's feed-forward less its shared
+    experts."""
+    shared = (jax.nn.silu(u @ bp["shared_gate_kernel"])
+              * (u @ bp["shared_fc_kernel"])) @ bp["shared_out_kernel"]
+    return reference._feed_forward(narrow.rounders(None), cfg, bp, u) \
+        - shared
+
+
+LEAVES = ("router_kernel", "experts_gate", "experts_up", "experts_down")
+
+
+def _against_the_oracle(sizes, reference, bp, u, windows, rows=None):
+    """Values and the five gradients (tokens, router, the three expert
+    matrices) of ``held_experts_ffn`` against the per-expert oracle."""
+    g = jnp.asarray(np.random.default_rng(8).normal(
+        size=u.shape).astype(np.float32))
+
+    def program(u, *leaves):
+        y, stats = moe.held_experts_ffn(
+            u, leaves[0], bp["router_bias"], *leaves[1:], first_held=0,
+            top_k=3, routed_scale=sizes["routed_scaling_factor"])
+        return jnp.sum(y * g), (y, stats)
+
+    def oracle(u, *leaves):
+        y = _routed_part(sizes, reference,
+                         {**bp, **dict(zip(LEAVES, leaves))}, u)
+        return jnp.sum(y * g), y
+
+    args = (u,) + tuple(bp[k] for k in LEAVES)
+    (_, (y, stats)), got = jax.value_and_grad(
+        program, argnums=range(5), has_aux=True)(*args)
+    (_, want_y), want = jax.value_and_grad(
+        oracle, argnums=range(5), has_aux=True)(*args)
+    assert float(stats["dropped_assignments"]) == 0.0
+    assert float(stats["walk_windows"]) == windows
+    if rows is not None:
+        assert float(stats["held_assignments"]) == rows
+    np.testing.assert_allclose(y, want_y, atol=2e-6)
+    for name, a, b in zip(("u",) + LEAVES, got, want):
+        assert np.all(np.isfinite(a)), name
+        if rows == 0:
+            # no window runs: zeros, where the oracle leaves rounding
+            assert not np.any(a) and float(jnp.abs(b).max()) < 1e-8, name
+            continue
+        assert float(jnp.abs(b).max()) > 0, name
+        np.testing.assert_allclose(
+            a, b, atol=2e-5 * float(jnp.abs(b).max()), err_msg=name)
+    if rows != 0:
+        assert float(jnp.abs(want_y).max()) > 1e-3
+
+
+@pytest.mark.parametrize("kernel", ["fallback", "interpret"])
+@pytest.mark.parametrize("case", list(WALKS) + ["every_expert_held"])
+def test_the_walk_runs_as_many_windows_as_the_held_rows_fill(
+        cfg, reference, weights, monkeypatch, case, kernel):
+    """``held_experts_ffn`` walks its 1,152 sorted rows in windows of R =
+    512, one compiled walk run ceil(held / R) times: equal to the
+    per-expert oracle in value and in all five gradients with nothing
+    held (no window), at seeded routing, with the held rows filling a
+    window and one past it (a second window for one row), and with every
+    pick on a held expert (three windows); nothing is dropped.  A worker
+    that holds all sixteen experts has R = 1,152, one window and no
+    loop."""
+    if kernel == "interpret":
+        monkeypatch.setenv("ZOO_KERNEL_INTERPRET", "1")
+    u = jnp.asarray(np.random.default_rng(7).normal(
+        size=(WALK_TOKENS, 64)).astype(np.float32))
+    if case == "every_expert_held":
+        sizes, bp = _whole_layer(cfg, reference, jax.random.PRNGKey(9))
+        assert moe.walk_bound(3 * WALK_TOKENS, 16, 16) == 3 * WALK_TOKENS
+        _against_the_oracle(sizes, reference, bp, u, 1, 3 * WALK_TOKENS)
+        return
+    bound = moe.walk_bound(3 * WALK_TOKENS, 4, 16)
+    assert bound == 512
+    bp = weights[reference.CORE]["blocks"][1]
+    held, windows = WALKS[case]
+    rows = None if held is None else held(bound)
+    if rows is not None:
+        bp = {**bp, "router_bias": _bias_holding(
+            jax.nn.sigmoid(u @ bp["router_kernel"]), rows)}
+    _against_the_oracle(cfg.sizes, reference, bp, u, windows, rows)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of what it calls, a kernel's own body
+    left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("held, loops", [(4, 2), (16, 0)])
+def test_the_traced_gradient_holds_one_walk(cfg, reference, weights, held,
+                                            loops):
+    """The gradient's trace of a routed layer, under a checkpoint that
+    keeps the route as the decoder's does: no ``cond``, one ``while`` in
+    the forward rule and one in the backward rule (none for a worker that
+    holds every expert), and no array of all 1,152 sorted rows by the
+    hidden (64) or the experts' (32) width: the walk's arrays have R = 512
+    rows."""
+    if held == 16:
+        _sizes, bp = _whole_layer(cfg, reference, jax.random.PRNGKey(9))
+    else:
+        bp = weights[reference.CORE]["blocks"][1]
+    u = jnp.zeros((WALK_TOKENS, 64))
+
+    def loss(u, *leaves):
+        run = jax.checkpoint(
+            lambda u, *leaves: moe.held_experts_ffn(
+                u, leaves[0], bp["router_bias"], *leaves[1:], first_held=0,
+                top_k=3, routed_scale=2.0)[0],
+            policy=jax.checkpoint_policies.save_only_these_names(
+                moe.ROUTE_NAME))
+        return jnp.sum(jnp.square(run(u, *leaves)))
+
+    eqns = list(_equations(jax.make_jaxpr(jax.grad(loss, argnums=range(5)))(
+        u, *(bp[k] for k in LEAVES)).jaxpr))
+    names = [eqn.primitive.name for eqn in eqns]
+    assert names.count("cond") == 0
+    assert names.count("while") == loops
+    shapes = {v.aval.shape for eqn in eqns for v in eqn.outvars}
+    wide = {(3 * WALK_TOKENS, 64), (3 * WALK_TOKENS, 32)}
+    if held == 16:
+        assert wide <= shapes
+    else:
+        assert not wide & shapes
+        assert {(512, 64), (512, 32)} <= shapes
+
+
+@pytest.mark.parametrize("policy, sorts", [("attn", 3), ("full", 4)])
 def test_the_route_is_kept_and_not_sorted_twice(net, weights, policy, sorts):
     """Under the decoder's policy the backward pass of a routed layer
-    application reads the sort's permutation and its inverse from what was
-    kept: its gradient holds the two sorts once, under ``"full"`` twice."""
+    application reads the sort's permutation from what was kept: its
+    gradient holds the route's sort once, under ``"full"`` twice.  Beside
+    it a window puts its R rows in their tokens' order, in the forward
+    rule's walk and in the backward rule's (0.02 ms on the chip; PERF.md,
+    PR 33); under ``"full"`` the forward rule's walk made again is dead
+    code and is not traced."""
     from analytics_zoo_tpu.parallel.plan import apply_remat
 
     layer = net[0].layers[-1]
@@ -257,7 +432,17 @@ def test_the_route_is_kept_and_not_sorted_twice(net, weights, policy, sorts):
     assert text.count(" sort[") == sorts
 
 
-def test_fit_with_the_models_own_loss_publishes_the_gauges(cfg, weights):
+@pytest.mark.parametrize("favoured", [(), (0, 1)],
+                         ids=["seeded", "past_a_window"])
+def test_fit_with_the_models_own_loss_publishes_the_gauges(cfg, weights,
+                                                           favoured):
+    """Two epochs of one batch through ``fit``: the loss falls and the
+    routed layers' counts of the last step are published.  8 x 32 tokens x
+    3 picks over 16 experts, 4 held: 192 rows expected under the seeded
+    bias, in one window of R = 256 (a layer that holds more, in two); with
+    a bias that sends every token to
+    held experts 0 and 1 a layer holds 512 rows and more and walks them in
+    two windows or three."""
     from analytics_zoo_tpu import init_zoo_context
     from analytics_zoo_tpu.metrics import snapshot
 
@@ -266,6 +451,9 @@ def test_fit_with_the_models_own_loss_publishes_the_gauges(cfg, weights):
     model = model_py.build(cfg.sizes)
     model.build_params()
     model.params = jax.tree_util.tree_map(jnp.array, weights)
+    for bp in model.params["kanana"]["blocks"][1:]:
+        bp["router_bias"] = bp["router_bias"].at[jnp.asarray(
+            favoured, jnp.int32)].add(4.0)
     rng = np.random.default_rng(5)
     x = rng.integers(0, 32, (8, 32)).astype(np.int32)
     y = rng.integers(0, 32, (8, 32)).astype(np.int32)
@@ -278,9 +466,16 @@ def test_fit_with_the_models_own_loss_publishes_the_gauges(cfg, weights):
               s["value"] for s in snapshot()["samples"]
               if s["name"].startswith("zoo_moe_")}
     assert gauges[("zoo_moe_dropped_assignments", "")] == 0.0
+    bound = moe.walk_bound(3 * 8 * 32, 4, 16)
+    assert bound == 256
     for layer in ("1", "2"):
-        # 8 x 32 tokens x 3 picks over 16 experts, 4 held: 192 expected
-        assert 96 < gauges[("zoo_moe_held_assignments", layer)] < 288
-        assert 1.0 <= gauges[("zoo_moe_load_max_over_mean", layer)] <= 4.0
+        held = gauges[("zoo_moe_held_assignments", layer)]
+        windows = gauges[("zoo_moe_walk_windows", layer)]
+        assert windows == -(-held // bound)
+        if favoured:
+            assert 512 <= held <= 768 and windows >= 2
+        else:
+            assert 96 < held < 288 and windows <= 2
+            assert 1.0 <= gauges[("zoo_moe_load_max_over_mean", layer)] <= 4.0
     # evaluate and predict read the logits, as with any other loss
     assert model.predict(x[:4]).shape == (4, 32, 32)

@@ -222,12 +222,19 @@ def test_a_layer_application_runs_the_forward_kernel_once(
     assert returned == [1, 2] + [3] * forward_calls, returned
 
 
-def _convolutions(computations, name):
-    """Convolutions in the HLO computation ``name`` and in what it calls."""
+def _computations(text):
+    """An HLO module's computations by name."""
+    return {block.split(" (", 1)[0].rpartition("%")[2]: block
+            for block in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)}
+
+
+def _convolutions(computations, name, what=" convolution("):
+    """Convolutions (or ``what``) in the HLO computation ``name`` and in
+    what it calls."""
     text = computations[name]
-    return text.count(" convolution(") + sum(
-        _convolutions(computations, called)
-        for called in re.findall(r"calls=%([\w.\-]+)", text))
+    return text.count(what) + sum(
+        _convolutions(computations, called, what)
+        for called in re.findall(r"(?:calls|body)=%([\w.\-]+)", text))
 
 
 def test_the_looped_head_makes_its_gradient_in_its_forward_loops(
@@ -261,15 +268,19 @@ def test_the_looped_head_makes_its_gradient_in_its_forward_loops(
         params, [described((2, 4096, 2048))] * passes,
         described((2, 4096), jnp.int32)).compile()
     text = compiled.as_text()
-    computations = {
-        block.split(" (", 1)[0].rpartition("%")[2]: block
-        for block in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)}
+    computations = _computations(text)
     loops = re.findall(r" while\(.*body=%([\w.\-]+)", text)
     assert len(loops) == passes, loops
     assert [_convolutions(computations, body) for body in loops] \
         == [3] * passes
     assert text.count(" convolution(") == 3 * passes
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+#: Mosaic calls of the one walk in a routed layer's gradient: 4 in the
+#: forward rule's loop, 9 in the backward rule's (the parent's whole-buffer
+#: walk held 12, PR 33's two walks 26)
+ROUTED_LAYER_KERNELS = 13
 
 
 def _kanana_layer():
@@ -288,12 +299,17 @@ def test_a_routed_latent_layer_application_by_its_kernels(one_chip,
     """The gradient of one checkpointed routed layer application of
     `kanana-2-30b-a3b-fit` (2 x 4,096 tokens, 32 heads of 192/128, 16 of
     128 experts held, bf16) for a described v5e: each flash kernel once (the
-    forward kernel is not run again for what the policy kept), and twelve
-    grouped products, three of the forward pass made again in the backward
-    pass (the policy keeps the feed-forward's output, not its hidden
-    activations), three for the rows' gradient and three transposed ones for
-    the experts'.  Told apart by what the benchmark's readers read: the
-    instruction's name and the arrays returned."""
+    forward kernel is not run again for what the policy kept), and the one
+    walk of the held rows (``ops/moe.py``: windows of 8,192 of the 49,152
+    sorted rows) in its two loops and in no ``conditional``: thirteen
+    Mosaic calls.  The forward rule's loop holds the three grouped products
+    and the sum of a token's rows; the backward rule's the gate and up
+    products made again (the policy keeps the feed-forward's output, not
+    its hidden rows), the cotangent through the down product, the three
+    experts' gradients (the transposed kernel, adding to the running sums
+    in place), the rows' two and the sum of a token's rows.  PR 33's two
+    whole walks held 26.  Told apart by what the benchmark's readers read:
+    the instruction's name and the arrays returned."""
     from benchmark import xplane
 
     layer = _kanana_layer()
@@ -313,26 +329,34 @@ def test_a_routed_latent_layer_application_by_its_kernels(one_chip,
                                        sharding=one_chip),
         (bp, jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16)))
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).compile()
+    text = compiled.as_text()
     marked = f"/{xplane.KERNEL_TARGET}/"
-    kernels = [name for name in map(xplane.short_name,
-                                    compiled.as_text().splitlines())
+    kernels = [name for name in map(xplane.short_name, text.splitlines())
                if marked in name]
     flash = sorted(int(k.rpartition(marked)[2]) for k in kernels
                    if "flash" in k)
     assert flash == [1, 2, 3], kernels
     grouped = [k for k in kernels if "flash" not in k]
-    assert len(grouped) == 12 and all(
+    assert len(grouped) == ROUTED_LAYER_KERNELS and all(
         k.endswith(marked + "1") for k in grouped), kernels
-    # the sorted rows and their hidden activations, 49,152 x (2048 + 3 x
-    # 768) in bf16 with their gradients, are the temporaries: under 2.5 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    assert text.count(" conditional(") == 0
+    # (XLA's own loops, a sort's or a gather's, hold no kernel)
+    loops = [_convolutions(_computations(text), body, "tpu_custom_call")
+             for body in re.findall(r" while\(.*body=%([\w.\-]+)", text)]
+    assert sorted(n for n in loops if n) == [4, 9], loops
+    # the attention's arrays and, of the routed path, a window's 8,192 rows
+    # with their hidden activations and the running sums: 1,102,237,184
+    # bytes, where the walk of all 49,152 rows wanted 1,236,356,096 (commit
+    # a76fa78, PR 32's tree, compiled for this described chip by PR 34)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
 
 
 def test_the_kanana_step_compiles_whole_for_v5e(one_chip, real_kernels):
     """`kanana-2-30b-a3b-fit`'s train step as ``fit`` builds it (1 dense +
     4 routed layers at the published widths, 2 x 4,096 tokens, bf16 compute
-    over float32 state, Adam) for a described v5e: 15 flash calls and 48
-    grouped products, 6.9 GB of arguments (parameters and both moments, 12
+    over float32 state, Adam) for a described v5e: 15 flash calls and, in
+    each of four routed layers, the thirteen kernels of the one walk, in
+    no ``conditional``; 6.9 GB of arguments (parameters and both moments, 12
     bytes each of 576 M) and under 4 GB of temporaries, so that the
     harness's check steps' second float32 copy of the parameters (2.3 GB)
     fits beside the loaded step under the allocator's 16.9 GB."""
@@ -366,7 +390,9 @@ def test_the_kanana_step_compiles_whole_for_v5e(one_chip, real_kernels):
         (params, opt_state, state, np.int32(0), np.int32(0),
          {"x": x, "y": y}))
     compiled = step._jitted.lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 15 + 48
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 15 + 4 * ROUTED_LAYER_KERNELS
+    assert text.count(" conditional(") == 0
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(6.91e9, rel=0.01)
     assert memory.temp_size_in_bytes < 4.0e9
