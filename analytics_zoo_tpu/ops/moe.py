@@ -189,12 +189,21 @@ def sigmoid_route(u, router, score_bias, *, top_k, routed_scale):
 def walk_bound(assignments, held, router_width):
     """R, the sorted rows a window of the walk covers: four thirds of the
     even share of the ``assignments`` that ``held`` of ``router_width``
-    experts take, up to the grouped product's row tile, and at most all of
-    them.  From the shapes alone; a step whose held rows pass R walks a
-    second window."""
+    experts take, and no fewer than an eighth of them all, up to the
+    grouped product's row tile, and at most all of them.  From the shapes
+    alone; a step whose held rows pass R walks a second window.
+
+    The eighth: a window costs the same whatever it covers (its sort, its
+    gathers and zero fills, thirteen kernel launches: what some 5,000 held
+    rows cost), and at seeded weights a routed layer holds several times
+    its even share through tens of steps.  A worker with a small share (8
+    of 256 experts: 2,816 rows by the four thirds) then walked 4.4 to 5.7
+    windows a step over four layers where 4 do at 8,192, and its step
+    followed the seed by as much (PERF.md, PR 35)."""
     from analytics_zoo_tpu.ops.pallas.grouped_matmul import ROWS
 
-    share = -(-4 * assignments * held // (3 * router_width))
+    share = max(-(-4 * assignments * held // (3 * router_width)),
+                -(-assignments // 8))
     return min(assignments, -(-share // ROWS) * ROWS)
 
 

@@ -36,6 +36,8 @@ _KERNEL_MODULES = {
         "analytics_zoo_tpu.ops.pallas.fused_softmax_xent",
     "int8_matmul": "analytics_zoo_tpu.ops.pallas.int8_matmul",
     "grouped_matmul": "analytics_zoo_tpu.ops.pallas.grouped_matmul",
+    # the walk's kernels are ``kda_scan.py``'s; the caller counts
+    "kda_scan": "analytics_zoo_tpu.ops.linear_attention",
 }
 
 _PLANNED_STEPS: dict = {}

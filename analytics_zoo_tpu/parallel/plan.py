@@ -100,12 +100,16 @@ REMAT_POLICIES = ("full", "dots", "attn")
 #: feed-forward output, which the norm after or around that branch reads.
 #: ``"moe_route"`` is what a routed feed-forward without dropped tokens
 #: decides by integers (``ops/moe.py::held_experts_ffn``: the sort's
-#: permutation and the group sizes).
+#: permutation and the group sizes).  ``"kda_state"`` is the chunks'
+#: incoming states of the chunked gated delta rule, which its backward
+#: walk reads (``ops/linear_attention.py``, named in the forward rule with
+#: the rule's output, which is an ``"attn_context"``).
 #: So under ``"attn"`` the backward pass makes the norms, q, k, v and the
 #: other products of the scope again, but runs no attention forward, no
-#: down-projection and no sort a second time (measured: ``PERF.md``,
-#: PR 29).
-REMAT_KEPT_NAMES = {"attn": ("attn_context", "ffn_out", "moe_route")}
+#: down-projection, no sort and no walk over a sequence's chunks a second
+#: time (measured: ``PERF.md``, PR 29).
+REMAT_KEPT_NAMES = {"attn": ("attn_context", "ffn_out", "moe_route",
+                             "kda_state")}
 
 #: dtype ROLES a plan's ``dtype_rules`` may map a path to.  A role is
 #: not a raw dtype: it names the leaf's job in the precision plane.

@@ -51,7 +51,8 @@ class _TransformerCore(Layer):
                  qk_nope_dim=None, qk_rope_dim=None, v_head_dim=None,
                  routed_experts=0, experts_held=None, experts_held_from=0,
                  experts_per_token=None, expert_size=None, shared_experts=0,
-                 routed_scale=1.0, leading_dense=0, input_shape=None,
+                 routed_scale=1.0, leading_dense=0, kda_heads=None,
+                 kda_head_dim=None, kda_conv_size=4, input_shape=None,
                  name=None, **kwargs):
         super().__init__(input_shape=input_shape, name=name, **kwargs)
         self.n_block = int(n_block)
@@ -82,7 +83,12 @@ class _TransformerCore(Layer):
         #     heads; [k_n, v] = RMSNorm(c) Wkvb in heads of qk_nope_dim +
         #     v_head_dim; rotary on q_r and k_r in adjacent pairs; scores
         #     over [q_n, q_r] . [k_n, k_r] / sqrt(qk_nope_dim + qk_rope_dim),
-        #     values and output v_head_dim a head);
+        #     values and output v_head_dim a head; with ``rotary_theta``
+        #     None the rotary step is left out and the model has no
+        #     positions), or "kda" (Kimi Delta Attention, a recurrence over
+        #     the sequence: ``_kda_mixer``), or the kind of every block in
+        #     turn, a sequence of ``n_block`` of those names: the mixer
+        #     differs by layer as the feed-forward does;
         #   routed_experts: E > 0 makes the feed-forward of every block from
         #     ``leading_dense`` on a routed one (ops.moe.held_experts_ffn):
         #     a router over all E experts, sigmoid scores, the bias-corrected
@@ -107,18 +113,37 @@ class _TransformerCore(Layer):
         if moe_experts and (self.gated_ffn or not self.use_bias):
             raise ValueError("the routed feed-forward is the plain one "
                              "with biases: no gated_ffn, no use_bias=False")
-        if attention not in ("full", "latent"):
-            raise ValueError("attention must be 'full' or 'latent'; got "
-                             f"{attention!r}")
-        self.attention = attention
+        by_layer = (attention,) * self.n_block \
+            if isinstance(attention, str) else tuple(attention)
+        if len(by_layer) != self.n_block or any(
+                kind not in ("full", "latent", "kda") for kind in by_layer):
+            raise ValueError(
+                "attention must be 'full', 'latent' or 'kda', or one of "
+                f"them for each of the {self.n_block} blocks; got "
+                f"{attention!r}")
+        #: the kind of every block, and the one name where all are alike
+        self.attention_by_layer = by_layer
+        self.attention = by_layer[0] if len(set(by_layer)) == 1 \
+            else "by_layer"
         self.latent = None
-        if attention == "latent":
+        if "latent" in by_layer:
             self.latent = tuple(int(x) for x in (
                 kv_latent_rank, qk_nope_dim, qk_rope_dim, v_head_dim))
-            if self.use_bias or self.rotary_theta is None \
-                    or self.latent[2] % 2:
-                raise ValueError("latent attention has no bias, rotary "
-                                 "positions and an even qk_rope_dim")
+            if self.use_bias:
+                raise ValueError("latent attention has no bias")
+            if self.rotary_theta is not None and self.latent[2] % 2:
+                raise ValueError(
+                    "latent attention under rotary positions turns pairs: "
+                    f"qk_rope_dim {self.latent[2]} is odd")
+        self.kda = None
+        if "kda" in by_layer:
+            if self.use_bias or self.bidirectional:
+                raise ValueError("kda is causal and has no bias")
+            width = int(kda_head_dim or self.hidden_size // self.n_head)
+            #: heads, a head's key and value width (the rank of the two
+            #: low-rank gates too), taps of the causal convolutions
+            self.kda = (int(kda_heads or self.n_head), width,
+                        int(kda_conv_size))
         self.routed_experts = int(routed_experts)
         self.leading_dense = int(leading_dense)
         if self.routed_experts:
@@ -186,6 +211,58 @@ class _TransformerCore(Layer):
     def _n_norms(self):
         return 4 if self.norm_placement == "around" else 2
 
+    def _attention_kind(self, index):
+        """The mixer of block ``index``; of a stack whose blocks are all
+        alike where the caller has no index."""
+        if index is None:
+            if self.attention == "by_layer":
+                raise ValueError("the attention kind differs by layer: "
+                                 "which block?")
+            return self.attention
+        return self.attention_by_layer[index]
+
+    def _kda_params(self, rng):
+        """A KDA mixer's leaves: three projections to heads x width, a
+        causal depthwise convolution on each (taps x channels, drawn
+        uniform in +-1/sqrt(taps), a depthwise convolution's usual draw:
+        at ``initializer_range`` the values would fall under the output
+        norm's eps), the decay's low-rank gate with ``dt_bias`` a channel
+        and ``A_log`` a head (A uniform in 1..16, the step dt log-uniform
+        in 0.001..0.1 behind a softplus: a token's log-decay
+        -A softplus(. + dt_bias) then lies between about -2 and -0.001),
+        beta's projection a head, the output's low-rank gate with its bias,
+        the output norm's gain over a head's width and the output
+        projection."""
+        d, std = self.hidden_size, self.initializer_range
+        heads, width, taps = self.kda
+        wide, rank = heads * width, width
+        ks = iter(jax.random.split(rng, 14))
+
+        def conv():
+            return jax.random.uniform(next(ks), (taps, wide), minval=-1.0,
+                                      maxval=1.0) / taps ** 0.5
+
+        dt = jnp.exp(jax.random.uniform(
+            next(ks), (wide,), minval=jnp.log(1e-3), maxval=jnp.log(0.1)))
+        return {
+            "kda_q_kernel": _dense_init(next(ks), (d, wide), std),
+            "kda_k_kernel": _dense_init(next(ks), (d, wide), std),
+            "kda_v_kernel": _dense_init(next(ks), (d, wide), std),
+            "kda_q_conv": conv(), "kda_k_conv": conv(), "kda_v_conv": conv(),
+            "kda_f_a_kernel": _dense_init(next(ks), (d, rank), std),
+            "kda_f_b_kernel": _dense_init(next(ks), (rank, wide), std),
+            # softplus^-1(dt)
+            "kda_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "kda_a_log": jnp.log(jax.random.uniform(
+                next(ks), (heads,), minval=1.0, maxval=16.0)),
+            "kda_b_kernel": _dense_init(next(ks), (d, heads), std),
+            "kda_g_a_kernel": _dense_init(next(ks), (d, rank), std),
+            "kda_g_b_kernel": _dense_init(next(ks), (rank, wide), std),
+            "kda_g_bias": jnp.zeros((wide,)),
+            "kda_o_norm": jnp.ones((width,)),
+            "kda_o_kernel": _dense_init(next(ks), (wide, d), std),
+        }
+
     def _is_routed(self, index):
         return bool(self.routed_experts) and index is not None \
             and index >= self.leading_dense
@@ -200,7 +277,10 @@ class _TransformerCore(Layer):
         # the older blocks draw from
         more = iter(jax.random.split(jax.random.fold_in(rng, 1), 11))
         bias = {}
-        if self.attention == "latent":
+        kind = self._attention_kind(index)
+        if kind == "kda":
+            p = self._kda_params(jax.random.fold_in(rng, 2))
+        elif kind == "latent":
             rank, nope, rope, vd = self.latent
             h = self.n_head
             p = {
@@ -290,7 +370,14 @@ class _TransformerCore(Layer):
         b = 1 if self.use_bias else 0
         norms = self._n_norms * d * (2 if self.norm == "layer" else 1)
         attn = 3 * d * d + d * d + b * 4 * d + norms    # qkv + proj + norms
-        if self.attention == "latent":
+        kind = self._attention_kind(index)
+        if kind == "kda":
+            heads, width, taps = self.kda
+            wide, rank = heads * width, width
+            attn = 4 * d * wide + 3 * taps * wide + 2 * d * rank \
+                + 2 * rank * wide + 2 * wide + heads + d * heads + width \
+                + norms
+        elif kind == "latent":
             rank, nope, rope, vd = self.latent
             h = self.n_head
             attn = d * h * (nope + rope) + d * (rank + rope) + rank \
@@ -378,7 +465,55 @@ class _TransformerCore(Layer):
             out = self._norm(out, bp, first + 1)
         return h + out
 
+    def _kda_mixer(self, bp, u):
+        """Kimi Delta Attention of the normed state ``u`` (B, L, D): q, k
+        and v each a projection, a causal depthwise convolution and SiLU;
+        q and k of unit length a head; the log-decay a key channel
+        g = -exp(A_log) softplus(u Wf1 Wf2 + dt_bias) and the step size a
+        head beta = sigmoid(u Wb), both float32; the gated delta rule over
+        the sequence (``ops.linear_attention.chunked_kda``); then a head's
+        RMSNorm times sigmoid(u Wg1 Wg2 + b_g), and the output projection.
+        Returns the branch (B, L, D) and the rule's two numbers."""
+        from analytics_zoo_tpu.ops.linear_attention import chunked_kda
+
+        heads, width, _taps = self.kda
+        b, l, _ = u.shape
+        f32 = jnp.float32
+
+        def conv_heads(name):
+            z = u @ bp[f"kda_{name}_kernel"]
+            taps = bp[f"kda_{name}_conv"]
+            n = taps.shape[0]
+            past = jnp.pad(z, ((0, 0), (n - 1, 0), (0, 0)))
+            # y_t = sum_i w_i z_{t - (n - 1) + i}, zeros before the start
+            y = sum(past[:, i:i + l] * taps[i] for i in range(n))
+            return split_heads(jax.nn.silu(y), heads)
+
+        def unit(x):    # x / sqrt(|x|^2 + 1e-6) a head, in float32
+            x32 = x.astype(f32)
+            return (x32 * jax.lax.rsqrt(
+                jnp.sum(jnp.square(x32), axis=-1, keepdims=True)
+                + 1e-6)).astype(x.dtype)
+
+        q, k, v = unit(conv_heads("q")), unit(conv_heads("k")), \
+            conv_heads("v")
+        decay = (u @ bp["kda_f_a_kernel"]) @ bp["kda_f_b_kernel"]
+        g = -jnp.exp(bp["kda_a_log"].astype(f32))[:, None, None] \
+            * split_heads(jax.nn.softplus(
+                decay.astype(f32) + bp["kda_dt_bias"].astype(f32)), heads)
+        beta = jax.nn.sigmoid((u @ bp["kda_b_kernel"]).astype(f32))
+        o, stats = chunked_kda(q, k, v, g, beta.transpose(0, 2, 1),
+                               scale=width ** -0.5)
+        gate = (u @ bp["kda_g_a_kernel"]) @ bp["kda_g_b_kernel"] \
+            + bp["kda_g_bias"]
+        o = _rms_norm(o, bp["kda_o_norm"], self.norm_eps) \
+            * split_heads(jax.nn.sigmoid(gate.astype(f32)),
+                          heads).astype(o.dtype)
+        return merge_heads(o) @ bp["kda_o_kernel"], stats
+
     def _block_forward_aux(self, bp, h, mask, training, brng):
+        aux = drop = jnp.zeros((), jnp.float32)
+
         def dense(x, name):
             y = x @ bp[name + "_kernel"]
             return y + bp[name + "_bias"] if self.use_bias else y
@@ -391,12 +526,16 @@ class _TransformerCore(Layer):
             kv = split_heads(
                 _rms_norm(c, bp["kv_a_norm"], self.norm_eps)
                 @ bp["kv_b_kernel"], self.n_head)
-            # adjacent pairs: the halves side by side (on q and k alike, so
-            # the scores are the pairs' own), then the rotate-half form
-            q_r, k_r = _rotary(_pairs_to_halves(q[..., nope:]),
-                               _pairs_to_halves(k_r[:, None]),
-                               self.rotary_theta)
-            q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+            if self.rotary_theta is None:
+                k_r = k_r[:, None]      # no positions: the slices as made
+            else:
+                # adjacent pairs: the halves side by side (on q and k
+                # alike, so the scores are the pairs' own), then the
+                # rotate-half form
+                q_r, k_r = _rotary(_pairs_to_halves(q[..., nope:]),
+                                   _pairs_to_halves(k_r[:, None]),
+                                   self.rotary_theta)
+                q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
             k = jnp.concatenate(
                 [kv[..., :nope],
                  jnp.broadcast_to(k_r, (b, self.n_head, l, rope))], axis=-1)
@@ -405,7 +544,14 @@ class _TransformerCore(Layer):
             return merge_heads(a) @ bp["o_kernel"]
 
         def attention(u):
-            if self.attention == "latent":
+            nonlocal aux
+            # static: the tree is traced once, and a block's leaves say
+            # which mixer it has
+            if "kda_q_kernel" in bp:
+                y, stats = self._kda_mixer(bp, u)
+                aux = {"kda_" + key: value for key, value in stats.items()}
+                return y
+            if "q_kernel" in bp:
                 return latent_attention(u)
             q, k, v = jnp.split(dense(u, "qkv"), 3, axis=-1)
             q = split_heads(q, self.n_head)
@@ -422,8 +568,6 @@ class _TransformerCore(Layer):
             )
             return dense(merge_heads(a), "proj")
 
-        aux = drop = jnp.zeros((), jnp.float32)
-
         def gated(u, prefix):
             f = self.act(u @ bp[prefix + "gate_kernel"]) \
                 * (u @ bp[prefix + "fc_kernel"])
@@ -435,13 +579,15 @@ class _TransformerCore(Layer):
                 from analytics_zoo_tpu.ops.moe import held_experts_ffn
 
                 b, l, d = u.shape
-                # ``aux`` of a routed block is the route's counts
-                f, aux = held_experts_ffn(
+                # ``aux`` of a routed block is the route's counts (beside a
+                # KDA mixer's numbers where the block has one)
+                f, route = held_experts_ffn(
                     u.reshape(b * l, d), bp["router_kernel"],
                     bp["router_bias"], bp["experts_gate"], bp["experts_up"],
                     bp["experts_down"], first_held=self.experts_held_from,
                     top_k=self.experts_per_token,
                     routed_scale=self.routed_scale, activation=self.act)
+                aux = {**aux, **route} if isinstance(aux, dict) else route
                 f = f.reshape(b, l, d)
                 if "shared_gate_kernel" in bp:
                     f = f + gated(u, "shared_")
@@ -965,10 +1111,15 @@ class LoopedDecoder(_TransformerCore):
 #: ``routed_layers``, ``router_width`` (experts the router scores),
 #: ``experts_held`` and ``experts_held_from``, ``experts_per_token``,
 #: ``capacity_factor`` (None: the routed layer has none and drops nothing),
-#: ``attention`` with ``qk_width`` and ``value_width`` of a head, ``remat``
-#: and ``kept`` (the policy of a layer application and the
-#: ``checkpoint_name``s it keeps), ``loss_blocks`` (token blocks the head's
-#: cross-entropy is taken in under the layer's own loss; 0 without it).
+#: ``attention`` (the mixer where every layer has the same, "by_layer"
+#: otherwise) with ``attention_by_layer`` (every layer's: "latent" or
+#: "kda"), ``rotary`` (whether latent attention turns its rope slices),
+#: ``kda`` (heads, a head's width and convolution taps; None without such a
+#: layer), ``qk_width`` and ``value_width`` of a latent head, ``remat`` and
+#: ``kept`` (the policy of a layer
+#: application and the ``checkpoint_name``s it keeps), ``loss_blocks``
+#: (token blocks the head's cross-entropy is taken in under the layer's own
+#: loss; 0 without it).
 decoder_records: collections.deque = collections.deque(maxlen=16)
 
 
@@ -983,6 +1134,16 @@ class LatentMoEDecoder(_TransformerCore):
     ``shared_experts`` beside them), a final RMSNorm and an untied head.
     Input (B, L) token ids, output logits (B, L, vocab).  ``vocab`` may be
     this worker's slice of the vocabulary: ids and targets then lie in it.
+
+    The mixer may differ by layer: ``attention`` is "latent", or a
+    sequence of ``n_block`` names, "latent" or "kda" (Kimi Delta
+    Attention, ``_TransformerCore._kda_mixer``, sized by ``kda_heads``,
+    ``kda_head_dim`` and ``kda_conv_size``; its two low-rank gates have a
+    head's width as their rank); ``rotary_theta=None`` leaves latent
+    attention without positions (a model whose order comes from KDA's
+    convolutions and recurrence).  With a KDA layer the state also carries, a KDA layer,
+    ``kda_chunk_log_decay_min``, ``kda_sub_block_log_decay_min`` and
+    ``kda_state_rms`` (``ops.linear_attention.chunked_kda``'s numbers).
 
     Trained with ``loss="next_token_cross_entropy"`` the layer takes the
     mean cross-entropy itself in blocks of ``loss_block`` tokens, the
@@ -1000,7 +1161,9 @@ class LatentMoEDecoder(_TransformerCore):
     Every layer application is one ``jax.checkpoint`` under the ``"attn"``
     policy: kept are the attention's output with the two rows of softmax
     statistics (all that the flash backward kernels read besides q, k and
-    v), the feed-forward's output and the route's integers (the sort's
+    v; of a KDA layer the rule's output and its chunks' incoming states,
+    67 + 134 MB at (2, 32, 4096, 128) in chunks of 128), the feed-forward's
+    output and the route's integers (the sort's
     permutation and the group sizes: 0.2 MB a layer at 8,192 tokens and
     top-6, a top-k and a sort to make again).
     """
@@ -1010,14 +1173,15 @@ class LatentMoEDecoder(_TransformerCore):
                  v_head_dim, routed_experts, experts_per_token, expert_size,
                  experts_held=None, experts_held_from=0, shared_experts=0,
                  routed_scale=1.0, leading_dense=1, rotary_theta=1e6,
-                 norm_eps=1e-6, loss_block=2048, remat="attn", **kwargs):
+                 norm_eps=1e-6, loss_block=2048, remat="attn",
+                 attention="latent", **kwargs):
         super().__init__(
             n_block=n_block, n_head=n_head, hidden_size=hidden_size,
             intermediate_size=intermediate_size, hidden_drop=0.0,
             attn_drop=0.0, activation="silu", remat=remat, norm="rms",
             norm_placement="before", norm_eps=norm_eps,
             rotary_theta=rotary_theta, gated_ffn=True, use_bias=False,
-            attention="latent", kv_latent_rank=kv_latent_rank,
+            attention=attention, kv_latent_rank=kv_latent_rank,
             qk_nope_dim=qk_nope_dim, qk_rope_dim=qk_rope_dim,
             v_head_dim=v_head_dim, routed_experts=routed_experts,
             experts_held=experts_held, experts_held_from=experts_held_from,
@@ -1027,6 +1191,11 @@ class LatentMoEDecoder(_TransformerCore):
         self.vocab = int(vocab)
         self.loss_block = int(loss_block)
         self.n_routed = max(self.n_block - self.leading_dense, 0)
+        self.n_kda = self.attention_by_layer.count("kda")
+        if "full" in self.attention_by_layer:
+            raise ValueError("LatentMoEDecoder mixes by latent attention "
+                             "or by KDA; got "
+                             f"{list(self.attention_by_layer)}")
 
     def build(self, input_shape):
         pass  # params are nested; built in init_params
@@ -1051,13 +1220,21 @@ class LatentMoEDecoder(_TransformerCore):
     def stateful(self):
         return True
 
+    #: what the state carries a KDA layer: ``chunked_kda``'s numbers
+    KDA_NUMBERS = ("kda_chunk_log_decay_min", "kda_sub_block_log_decay_min",
+                   "kda_state_rms")
+
     def init_state(self):
         per_layer = jnp.zeros((self.n_routed,), jnp.float32)
-        return {"lm_loss_cost": jnp.zeros((), jnp.float32),
-                "moe_held_assignments": per_layer,
-                "moe_load_max_over_mean": per_layer,
-                "moe_walk_windows": per_layer,
-                "moe_dropped_assignments": jnp.zeros((), jnp.float32)}
+        state = {"lm_loss_cost": jnp.zeros((), jnp.float32),
+                 "moe_held_assignments": per_layer,
+                 "moe_load_max_over_mean": per_layer,
+                 "moe_walk_windows": per_layer,
+                 "moe_dropped_assignments": jnp.zeros((), jnp.float32)}
+        if self.n_kda:
+            per_kda = jnp.zeros((self.n_kda,), jnp.float32)
+            state.update({key: per_kda for key in self.KDA_NUMBERS})
+        return state
 
     def compute_output_shape(self, input_shape):
         if isinstance(input_shape, list):
@@ -1091,7 +1268,7 @@ class LatentMoEDecoder(_TransformerCore):
                            static_argnums=(3,))
         loss_blocks = self._loss_blocks(*tokens.shape) \
             if targets is not None else 0
-        rank, nope, rope, vd = self.latent
+        rank, nope, rope, vd = self.latent or (None, 0, 0, None)
         decoder_records.append({
             "layer": self.name, "training": bool(training),
             "dense_layers": self.n_block - self.n_routed,
@@ -1101,16 +1278,20 @@ class LatentMoEDecoder(_TransformerCore):
             "experts_held_from": self.experts_held_from,
             "experts_per_token": self.experts_per_token,
             "capacity_factor": None, "attention": self.attention,
+            "attention_by_layer": list(self.attention_by_layer),
+            "rotary": self.rotary_theta is not None, "kda": self.kda,
             "qk_width": nope + rope, "value_width": vd, "remat": policy,
             "kept": list(REMAT_KEPT_NAMES.get(policy, ())),
             "loss_blocks": loss_blocks})
 
         h = jnp.take(params["tok_embed"], tokens.astype(jnp.int32), axis=0)
-        routes = []
+        routes, rules = [], []
         for bp in params["blocks"]:
-            h, route, _ = body(bp, h, None, training, None)
+            h, numbers, _ = body(bp, h, None, training, None)
             if "router_kernel" in bp:   # static: the tree is traced once
-                routes.append(route)
+                routes.append(numbers)
+            if "kda_q_kernel" in bp:
+                rules.append(numbers)
         s = apply_remat(lambda gamma, h: _rms_norm(h, gamma, self.norm_eps),
                         "full")(params["final_gamma"], h)
 
@@ -1126,6 +1307,10 @@ class LatentMoEDecoder(_TransformerCore):
             "moe_walk_windows": over_layers("walk_windows"),
             "moe_dropped_assignments": jnp.sum(
                 over_layers("dropped_assignments"))}
+        if rules:
+            new_state.update({
+                key: jnp.stack([r[key] for r in rules])
+                for key in self.KDA_NUMBERS})
         if not training and state is not None:
             new_state = state
         return s @ params["head_kernel"], new_state

@@ -102,9 +102,12 @@ def _publish_state_gauges(state) -> None:
     (``zoo_moe_held_assignments{label=<routed layer>}``,
     ``zoo_moe_load_max_over_mean{label=<routed layer>}``,
     ``zoo_moe_walk_windows{label=<routed layer>}``) with its
-    ``zoo_moe_dropped_assignments``.  Called after the epoch's closing
-    sync and at no other time: the state is then computed, so the fetch
-    waits for nothing."""
+    ``zoo_moe_dropped_assignments``, and a KDA layer's numbers
+    (``zoo_kda_chunk_log_decay_min{label=<KDA layer>}``,
+    ``zoo_kda_sub_block_log_decay_min{label=<KDA layer>}``,
+    ``zoo_kda_state_rms{label=<KDA layer>}``).  Called after the epoch's
+    closing sync and at no other time: the state is then computed, so the
+    fetch waits for nothing."""
     if not isinstance(state, dict):
         return
     for key, family, text in (
@@ -123,7 +126,20 @@ def _publish_state_gauges(state) -> None:
             ("moe_walk_windows", "zoo_moe_walk_windows",
              "windows of R sorted rows that a routed layer's walk of its "
              "held rows ran in the last step: 1 in an ordinary step, 0 "
-             "with nothing held, 2 or more past R")):
+             "with nothing held, 2 or more past R"),
+            ("kda_chunk_log_decay_min", "zoo_kda_chunk_log_decay_min",
+             "most negative log-decay that any key channel of a KDA "
+             "layer summed to over any chunk of the last step: what the "
+             "chunked form's exponentials have to survive"),
+            ("kda_sub_block_log_decay_min",
+             "zoo_kda_sub_block_log_decay_min",
+             "most negative log-decay that any key channel of a KDA "
+             "layer summed to from the first row of any sub-block to its "
+             "last in the last step: under -80 (ops.linear_attention."
+             "CLAMP) the chunked form is no longer exact"),
+            ("kda_state_rms", "zoo_kda_state_rms",
+             "root mean square of a KDA layer's recurrent states after "
+             "the last token of the last step's sequences")):
         if key in state:
             gauge = get_registry().gauge(family, text, ("label",))
             for t, value in enumerate(np.asarray(state[key]), start=1):

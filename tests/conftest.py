@@ -135,3 +135,26 @@ def rng():
     import jax
 
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture(autouse=True)
+def _kernel_counts_as_a_fresh_process_has_them(request):
+    """The benchmark's routing tests (``tests/bench_harness``) read the
+    kernels' trace-time routing counters after one toy run and expect what
+    a process that ran nothing else would hold; under xdist a worker has
+    run other files first (an interpret-mode flash test leaves ``pallas``
+    above 0 and ``fallback`` at 0, and ``routing_fault`` then names another
+    kernel than the test expects).  Zero the counters of the kernel modules
+    that are imported before each such test; tests elsewhere compare a
+    count with its value before, and are left alone."""
+    if "bench_harness" in str(request.node.fspath):
+        import sys
+
+        for name in ("analytics_zoo_tpu.ops.pallas.flash_attention",
+                     "analytics_zoo_tpu.ops.pallas.grouped_matmul",
+                     "analytics_zoo_tpu.ops.linear_attention"):
+            counts = getattr(sys.modules.get(name), "invocation_counts",
+                             None)
+            if counts is not None:
+                counts.update(dict.fromkeys(counts, 0))
+    yield

@@ -479,3 +479,264 @@ def test_fit_with_the_models_own_loss_publishes_the_gauges(cfg, weights,
             assert 1.0 <= gauges[("zoo_moe_load_max_over_mean", layer)] <= 4.0
     # evaluate and predict read the logits, as with any other loss
     assert model.predict(x[:4]).shape == (4, 32, 32)
+
+
+# -- the mixer by layer: Kimi Delta Attention and latent attention without
+# -- positions, against the ``kimi-linear-48b-a3b`` configuration's reference
+
+#: hidden 64, 2 KDA heads of 16 (4 taps), 2 latent heads of 16 + 8 (values
+#: 16), latent 32, five layers KDA, KDA, KDA, MLA, KDA (1 dense + 4 routed),
+#: 8 routed experts of width 32 with 2 held, top-2, 1 shared, vocabulary 32,
+#: 80 tokens: a chunk of 80 tokens in five sub-blocks
+KIMI_TOY = {
+    "hidden_size": 64, "num_attention_heads": 2, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "kv_lora_rank": 32,
+    "linear_attn_config": {"full_attn_layers": [4], "head_dim": 16,
+                           "kda_layers": [1, 2, 3, 5], "num_heads": 2,
+                           "short_conv_kernel_size": 4},
+    "num_experts": 2, "router_width": 8, "moe_intermediate_size": 32,
+    "num_experts_per_token": 2, "intermediate_size": 96, "vocab_size": 32,
+    "n_positions": 80}
+
+
+@pytest.fixture(scope="module")
+def kimi():
+    return Manifest().configuration("kimi-linear-48b-a3b", KIMI_TOY)
+
+
+@pytest.fixture(scope="module")
+def kimi_weights(kimi):
+    return kimi.module("reference").init_params(jax.random.PRNGKey(7),
+                                                kimi.sizes)
+
+
+@pytest.fixture()
+def kimi_net(kimi):
+    from analytics_zoo_tpu import init_zoo_context
+
+    init_zoo_context("kimi decoder", seed=3)
+    model = kimi.module("model").build(kimi.sizes)
+    _built, state = model.build_params()
+    return model, state
+
+
+def test_the_mixer_by_layer_against_the_reference(kimi_net, kimi,
+                                                  kimi_weights):
+    """Logits, loss and every leaf's gradient of the decoder whose layers
+    mix by KDA, KDA, KDA, latent attention without positions, KDA."""
+    model, state = kimi_net
+    reference = kimi.module("reference")
+    built, _ = model.build_params()
+    assert jax.tree_util.tree_structure(built) \
+        == jax.tree_util.tree_structure(kimi_weights)
+    assert all(a.shape == b.shape for a, b in zip(
+        jax.tree_util.tree_leaves(built),
+        jax.tree_util.tree_leaves(kimi_weights)))
+    layer = model.layers[-1]
+    assert layer.param_count() == sum(
+        int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(built))
+    rng = np.random.default_rng(17)
+    x, y = (jnp.asarray(rng.integers(0, 32, (BATCH, 80)).astype(np.int32))
+            for _ in range(2))
+    logits, _ = model.forward(kimi_weights, x, state=state, training=False)
+    np.testing.assert_allclose(
+        logits, reference.logits(kimi_weights, x, kimi.sizes), atol=3e-6)
+
+    (loss, new_state), grads = jax.value_and_grad(
+        lambda p: _program_loss(kimi_net, p, x, y), has_aux=True)(
+            kimi_weights)
+    ref_loss, ref_grads = jax.value_and_grad(reference.loss_fn)(
+        kimi_weights, x, y, kimi.sizes)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
+        name = jax.tree_util.keystr(path)
+        if "router_bias" in name:
+            assert not np.any(got) and not np.any(ref), name
+            continue
+        assert float(jnp.abs(ref).max()) > 0, name
+        np.testing.assert_allclose(
+            got, ref, atol=3e-5 * float(jnp.abs(ref).max()), err_msg=name)
+    numbers = new_state["kimi"]
+    assert numbers["moe_held_assignments"].shape == (4,)
+    assert numbers["kda_chunk_log_decay_min"].shape == (4,)
+    # a chunk of 80 tokens at the seeded decays: A up to 16, dt up to 0.1
+    assert np.all(np.asarray(numbers["kda_chunk_log_decay_min"]) < -20.0)
+    assert np.all(np.asarray(numbers["kda_chunk_log_decay_min"]) > -400.0)
+    # a sub-block of 16 tokens stays inside the form's exact range
+    sub = np.asarray(numbers["kda_sub_block_log_decay_min"])
+    assert sub.shape == (4,) and np.all(sub < -4.0) and np.all(sub > -80.0)
+    assert np.all(np.asarray(numbers["kda_state_rms"]) > 0.0)
+    record = self_attention.decoder_records[-1]
+    assert record["attention_by_layer"] == ["kda", "kda", "kda", "latent",
+                                            "kda"]
+    assert (record["attention"], record["rotary"]) == ("by_layer", False)
+    assert (record["dense_layers"], record["routed_layers"]) == (1, 4)
+
+
+def test_at_one_kind_of_attention_the_tree_and_the_outputs_are_the_same(
+        net, cfg, weights, batch):
+    """``attention="latent"`` with rotary positions is the decoder as it
+    was: the leaves it had, no KDA number in its state, and the same
+    outputs to the bit whether the kind is given once or a layer."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import LatentMoEDecoder
+
+    model, state = net
+    layer = model.layers[-1]
+    assert layer.attention == "latent" and layer.kda is None
+    assert sorted(weights["kanana"]["blocks"][1]) == [
+        "experts_down", "experts_gate", "experts_up", "kv_a_kernel",
+        "kv_a_norm", "kv_b_kernel", "ln1_gamma", "ln2_gamma", "o_kernel",
+        "q_kernel", "router_bias", "router_kernel", "shared_fc_kernel",
+        "shared_gate_kernel", "shared_out_kernel"]
+    assert sorted(state["kanana"]) == [
+        "lm_loss_cost", "moe_dropped_assignments", "moe_held_assignments",
+        "moe_load_max_over_mean", "moe_walk_windows"]
+    options = dict(
+        vocab=32, n_block=3, n_head=4, hidden_size=64, intermediate_size=96,
+        kv_latent_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+        routed_experts=16, experts_held=4, experts_per_token=3,
+        expert_size=32, shared_experts=2, routed_scale=2.448,
+        rotary_theta=1e6, name="kanana")
+    x, _ = batch
+    outputs = []
+    for kinds in ("latent", ["latent"] * 3):
+        one = LatentMoEDecoder(attention=kinds, **options)
+        params = one.init_params(jax.random.PRNGKey(2))
+        outputs.append((params, one.call(weights["kanana"], x)[0]))
+    for a, b in zip(jax.tree_util.tree_leaves(outputs[0]),
+                    jax.tree_util.tree_leaves(outputs[1])):
+        np.testing.assert_array_equal(a, b)
+    logits, _ = model.forward(weights, x, state=state, training=False)
+    np.testing.assert_array_equal(outputs[0][1], logits)
+
+
+def test_latent_attention_takes_rotary_or_none():
+    """Without rotary positions latent attention takes any ``qk_rope_dim``
+    (the slice is a width like another); with them an odd one has no
+    pairs to turn, and says so."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import LatentMoEDecoder
+
+    options = dict(
+        vocab=32, n_block=2, n_head=2, hidden_size=32, intermediate_size=48,
+        kv_latent_rank=16, qk_nope_dim=8, qk_rope_dim=5, v_head_dim=8,
+        routed_experts=4, experts_per_token=2, expert_size=16)
+    with pytest.raises(ValueError, match="qk_rope_dim 5 is odd"):
+        LatentMoEDecoder(rotary_theta=1e4, **options)
+    with pytest.raises(ValueError, match="for each of the 2 blocks"):
+        LatentMoEDecoder(attention=["kda"], rotary_theta=None, **options)
+    with pytest.raises(ValueError, match="latent attention or by KDA"):
+        LatentMoEDecoder(attention=["full", "kda"], rotary_theta=None,
+                         **options)
+    layer = LatentMoEDecoder(rotary_theta=None, **options)
+    params = layer.init_params(jax.random.PRNGKey(0))
+    x = jnp.asarray(np.random.default_rng(0).integers(0, 32, (2, 12)))
+    assert layer.call(params, x)[0].shape == (2, 12, 32)
+    # no positions anywhere: through one layer a sequence's last token
+    # sees a set of keys, whatever their order
+    one = LatentMoEDecoder(rotary_theta=None, **{**options, "n_block": 1})
+    params = one.init_params(jax.random.PRNGKey(0))
+    swapped = x.at[:, :11].set(x[:, 10::-1])
+    np.testing.assert_allclose(one.call(params, swapped)[0][:, -1],
+                               one.call(params, x)[0][:, -1], atol=1e-6)
+    turned = LatentMoEDecoder(rotary_theta=1e4, **{
+        **options, "n_block": 1, "qk_rope_dim": 4})
+    params = turned.init_params(jax.random.PRNGKey(0))
+    assert float(jnp.abs(turned.call(params, swapped)[0][:, -1]
+                         - turned.call(params, x)[0][:, -1]).max()) > 1e-4
+
+
+def test_a_window_is_four_thirds_of_the_even_share_or_an_eighth_of_all():
+    """R from the shapes: four thirds of the even share, no fewer than an
+    eighth of the assignments, up to the row tile, at most every
+    assignment."""
+    # kanana-2-30b-a3b-fit: 8,192 x 6 over 16 of 128, by its share
+    assert moe.walk_bound(49152, 16, 128) == 8192
+    # kimi-linear-48b-a3b-fit: 8,192 x 8 over 8 of 256: the share's 2,816
+    # is under the eighth
+    assert moe.walk_bound(65536, 8, 256) == 8192
+    assert moe.walk_bound(65536, 64, 256) == 22016
+    assert moe.walk_bound(768, 4, 16) == 256
+    assert moe.walk_bound(768, 1, 64) == 256
+    assert moe.walk_bound(768, 16, 16) == 768
+
+
+def test_the_thirty_two_shares_add_up_to_the_uncut_layer(kimi):
+    """This model's routing: 256 experts, the top 8 a token.  32 workers
+    hold experts 0-7, 8-15, ..., 248-255.  The routed parts that the 32
+    give, with the shared expert counted once, are what the uncut
+    reference gives for the whole layer."""
+    reference = kimi.module("reference")
+    sizes = {**kimi.sizes, "num_experts": 256, "router_width": 256,
+             "num_experts_per_token": 8}
+    bp = reference.init_params(jax.random.PRNGKey(9),
+                               sizes)[reference.CORE]["blocks"][1]
+    u = jnp.asarray(np.random.default_rng(1).normal(
+        size=(96, 64)).astype(np.float32))
+    qs = narrow.rounders(None)
+    whole = reference._feed_forward(qs, sizes, bp, u)
+    total = (jax.nn.silu(u @ bp["shared_gate_kernel"])
+             * (u @ bp["shared_fc_kernel"])) @ bp["shared_out_kernel"]
+    held = 0.0
+    for first in range(0, 256, 8):
+        part, stats = moe.held_experts_ffn(
+            u, bp["router_kernel"], bp["router_bias"],
+            *(bp[k][first:first + 8] for k in
+              ("experts_gate", "experts_up", "experts_down")),
+            first_held=first, top_k=8,
+            routed_scale=sizes["routed_scaling_factor"])
+        total = total + part
+        held += float(stats["held_assignments"])
+        assert float(stats["dropped_assignments"]) == 0.0
+    assert held == 8 * 96       # every assignment fell on one of the 32
+    np.testing.assert_allclose(total, whole, atol=2e-6)
+    # the reference's own share is the same part
+    share = {**bp, **{k: bp[k][40:48] for k in
+                      ("experts_gate", "experts_up", "experts_down")}}
+    got = reference._feed_forward(
+        qs, {**sizes, "num_experts": 8, "experts_held_from": 40}, share, u)
+    want, _ = moe.held_experts_ffn(
+        u, bp["router_kernel"], bp["router_bias"], share["experts_gate"],
+        share["experts_up"], share["experts_down"], first_held=40, top_k=8,
+        routed_scale=sizes["routed_scaling_factor"])
+    shared = total - sum(
+        moe.held_experts_ffn(
+            u, bp["router_kernel"], bp["router_bias"],
+            *(bp[k][first:first + 8] for k in
+              ("experts_gate", "experts_up", "experts_down")),
+            first_held=first, top_k=8,
+            routed_scale=sizes["routed_scaling_factor"])[0]
+        for first in range(0, 256, 8))
+    np.testing.assert_allclose(got - shared, want, atol=2e-6)
+
+
+def test_fit_publishes_a_kda_layers_numbers(kimi, kimi_weights):
+    """One epoch through ``fit``: the four KDA layers' two gauges of the
+    last step are published beside the routed layers' counts."""
+    from analytics_zoo_tpu import init_zoo_context
+    from analytics_zoo_tpu.metrics import snapshot
+
+    init_zoo_context("kimi decoder fit", seed=3)
+    model_py = kimi.module("model")
+    model = model_py.build(kimi.sizes)
+    model.build_params()
+    model.params = jax.tree_util.tree_map(jnp.array, kimi_weights)
+    rng = np.random.default_rng(5)
+    x = rng.integers(0, 32, (8, 80)).astype(np.int32)
+    y = rng.integers(0, 32, (8, 80)).astype(np.int32)
+    model.fit(model_py.feature_set(x, y, kimi.sizes), batch_size=8,
+              nb_epoch=2)
+    history = [h["loss"] for h in model._estimator.history]
+    assert history[1] < history[0] < np.log(32) + 0.2
+    assert model_py.routing_fault("cpu") is None
+    gauges = {(s["name"], (s.get("labels") or {}).get("label", "")):
+              s["value"] for s in snapshot()["samples"]
+              if s["name"].startswith(("zoo_kda_", "zoo_moe_"))}
+    for layer in ("1", "2", "3", "4"):
+        assert -400.0 < gauges[("zoo_kda_chunk_log_decay_min", layer)] < -20.0
+        assert -80.0 < gauges[("zoo_kda_sub_block_log_decay_min", layer)] \
+            < -4.0
+        assert 0.0 < gauges[("zoo_kda_state_rms", layer)] < 10.0
+        assert gauges[("zoo_moe_walk_windows", layer)] >= 0.0
+    assert ("zoo_kda_state_rms", "5") not in gauges
+    assert gauges[("zoo_moe_dropped_assignments", "")] == 0.0

@@ -101,6 +101,22 @@ def _routed(tokens, width, router, held, expert, top_k):
         ((held, expert, width), jnp.bfloat16)]
 
 
+def _kda(shape):
+    """(fn, arg specs) for the gradient of the chunked gated delta rule
+    with a decay a key channel on (B, H, L, D) bf16 heads: the two Pallas
+    kernels of the walk over a sequence's chunks, a chunk's local
+    arithmetic and its ``jax.vjp`` inside them."""
+    from analytics_zoo_tpu.ops.linear_attention import chunked_kda
+
+    def loss(q, k, v, g, beta):
+        o, _ = chunked_kda(q, k, v, g, beta, scale=shape[3] ** -0.5)
+        return jnp.sum(jnp.square(o.astype(jnp.float32)))
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), \
+        [(shape, jnp.bfloat16)] * 3 + [(shape, jnp.float32),
+                                       (shape[:3], jnp.float32)]
+
+
 def _xent(shape):
     from analytics_zoo_tpu.ops.pallas.fused_softmax_xent import softmax_xent
 
@@ -163,6 +179,8 @@ CASES = {
     "flash_fwd_bwd_segments_unaligned":
         lambda: _flash((2, 12, 1000, 64), True, causal=True,
                        segments=True),
+    # `kimi-linear-48b-a3b-fit`'s own call: 32 KDA heads of 128
+    "kda_walk_fwd_bwd_kimi_cell": lambda: _kda((2, 32, 4096, 128)),
     "softmax_xent_fwd_bwd": lambda: _xent((4096, 50304)),
     "int8_matmul": lambda: _int8(256, 2048, 1000),
     "fused_adam":
