@@ -34,8 +34,12 @@ stats-recompute pass; on TPU the backward runs as two Pallas kernels
 (``_flash_bwd_pallas``: a dq kernel walking K/V tiles past each q tile,
 and a dk/dv/dbias kernel walking q tiles past each K/V tile) whose
 rematerialized score tiles never leave VMEM.  Which tiles the three
-kernels visit and what a visited tile computes is the tile schedule
-below (``tile_schedules`` records it for each traced call).  Elsewhere —
+kernels visit, what a visited tile computes and how many independent
+tiles one loop body issues (every visit of a call that one grid step
+owns, spelled out; on a longer call two or four sub-tiles of the step's
+rows against one tile of the other side, which stays in VMEM whole, and
+the diagonal in sub-tiles) is the tile schedule below (``tile_schedules``
+records it for each traced call).  Elsewhere —
 CPU, or a full (Lq, Lk) bias that needs its own O(Lq·Lk) gradient — a
 blockwise lax.scan over key blocks serves as fallback and oracle
 (O(Lq·block_k) live memory).  Either way long-context training never
@@ -51,6 +55,7 @@ import collections
 import functools
 import math
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -193,8 +198,6 @@ def attention_stats(q, k, v, causal=False, scale=None, block_q=None,
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     if _pallas_available() and q.shape[-1] % 64 == 0 \
             and q.shape[2] >= 128 and k.shape[2] >= 128:
-        block_q, block_k = _resolve_blocks(block_q, block_k, q.shape[2],
-                                           k.shape[2], causal=causal)
         out = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
                                 block_k, interpret=_interpret_forced(),
                                 return_stats=True)
@@ -224,24 +227,73 @@ def attention_stats(q, k, v, causal=False, scale=None, block_q=None,
 # product) and accumulate in float32; softmax statistics, exp and D stay
 # float32.
 #
-# One grid step keeps up to _RESIDENT_ROWS rows of each side in VMEM and
-# walks its tiles in loops inside the kernel, bounded by the diagonal, so
-# a skipped tile costs neither a grid step (0.35 us, more than a
-# 256 x 256 tile's products) nor a DMA.  A step that owns the whole
-# sequence in at most _UNROLLED_TILES tiles knows every bound as a Python
-# number and spells the visits out, which lets the scheduler run one
-# tile's products under another's softmax.  Bias and segment ids stream
-# one tile a grid step.
+# One grid step owns up to _RESIDENT_ROWS rows of one side (q rows in the
+# forward and dq kernels, key rows in dk/dv) and walks the tiles of the
+# other side in loops inside the kernel, bounded by the diagonal, so a
+# skipped tile costs neither a grid step (0.35 us, more than a 256 x 256
+# tile's products) nor a DMA.  What one loop body, one basic block, issues
+# depends on the shape alone:
+#
+# * UNROLLED: a step that owns the whole sequence in at most
+#   _UNROLLED_TILES tiles knows every bound as a Python number and spells
+#   the visits out, which lets the scheduler run one tile's products under
+#   another's softmax (1,024 tokens: `gpt2-small-fit`).
+# * WALK: every longer call.  A tile is a chain (the score product, a
+#   column maximum over the whole tile, the exponentials, a product that
+#   contracts over the whole tile), so a body that holds one tile leaves
+#   the MXU waiting for the vector unit and the vector unit for the MXU:
+#   at 4,096 tokens a tile cost its products PLUS its vector arithmetic.
+#   The step's rows are therefore cut into up to _BODY_TILES sub-tiles,
+#   each with a carry of its own, and one body issues all of them against
+#   ONE tile of the other side, loaded once: chains that share nothing
+#   else.  The other side stays in VMEM whole up to _RESIDENT_BYTES
+#   (K and V of 4,096 x (192 + 128) bf16 are 2.5 MiB), in chunks past
+#   that.  The tiles that cross the group's diagonal stand at positions
+#   that are static relative to the group (its first row is a multiple of
+#   its rows and the offset is static), so that band is spelled out at
+#   trace time sub-tile by sub-tile: above the diagonal skipped, on it
+#   masked, below it plain (`_band`).
+# * STREAMED: bias and segment ids ride one tile a grid step, and a causal
+#   call whose explicit blocks do not divide a group keeps the loop of one
+#   tile a body (LOOPED).
 # ---------------------------------------------------------------------------
 
 _RESIDENT_ROWS = 1024
+
+#: the most independent tiles one loop body of the walk issues
+_BODY_TILES = 4
+
+#: bytes of the walked side (one buffer of the two) that a grid step of the
+#: walk keeps in VMEM whole
+_RESIDENT_BYTES = 4 << 20
+
+#: the walk's tile (block_q, block_k) by kernel where the caller names
+#: none, cut to the forward's caps (`_resolve_blocks`) and to the call: the
+#: walked side in tiles of 1,024 rows, the step's 1,024 rows in four
+#: sub-tiles of 256 (forward, dk/dv), which is then the diagonal's sub-tile
+#: too, or in two of 512 (dq, where four of 256 timed 19% slower than two
+#: of 512 with the diagonal cut no finer than the tile).  Set by timing the
+#: kernels alone at (2, 32, 4096, 192/128) and (2, 16, 4096, 128) on a v5e
+#: (PERF.md section 6, PR 36).
+_WALK_TILES = {"forward": (256, 1024), "dq": (512, 1024),
+               "dkv": (1024, 256)}
+
+#: scoped VMEM for a kernel on the walk: _BODY_TILES chains of float32
+#: score tiles beside the resident side pass the default 16 MiB
+_WALK_VMEM_BYTES = 64 << 20
 
 #: Trace-time record of each kernel's schedule, newest last: ``kernel``
 #: ("forward", "dq", "dkv"), ``shape`` (b, h, lq, lk, d) with d the width
 #: of q and k, ``value_width`` (that of v and of the output, which latent
 #: attention makes unlike d), ``blocks`` (block_q, block_k) as resolved, ``operand_dtype`` of the matrix
-#: products, and the tiles of one (batch, head) by class: ``skipped``,
-#: ``plain``, ``masked``.  Plain arithmetic on static shapes, like
+#: products, ``schedule`` ("unrolled", "walk", "looped" or "streamed"),
+#: ``tiles_per_body`` (the independent tiles one basic block issues: the
+#: visits of the step on the unrolled path, the sub-tiles of the step's
+#: rows on the walk, 1 on a loop of one tile a body), ``resident_rows``
+#: (rows of the walked side a grid step holds in VMEM), ``sub_blocks``
+#: (the diagonal's sub-tile, (q rows, key rows); the blocks off the walk)
+#: and the tiles of one (batch, head) by class, in units of ``sub_blocks``:
+#: ``skipped``, ``plain``, ``masked``.  Plain arithmetic on static shapes, like
 #: ``invocation_counts``: jit traces once, so it counts compilations.
 tile_schedules: collections.deque = collections.deque(maxlen=64)
 
@@ -321,21 +373,198 @@ def _tile_rows(j, lo, block, resident):
                  else pl.multiple_of(start, block), block)
 
 
-def _record_schedule(kernel, q, v, block_q, block_k, causal, all_masked):
+class _Plan(NamedTuple):
+    """How one of the three kernels visits its tiles (`_plan`).  The
+    GROUPED side is the one whose rows a grid step owns (q rows in the
+    forward and dq kernels, key rows in dk/dv), the WALKED side the one it
+    loops over."""
+
+    block_q: int
+    block_k: int
+    group: int       # tiles of the grouped side a grid step owns
+    resident: int    # tiles of the walked side it keeps in VMEM
+    schedule: str    # "unrolled", "walk", "looped" or "streamed"
+    sub: int         # rows of the walked side in a sub-tile of the diagonal
+    q_grouped: bool
+
+    @property
+    def whole(self):
+        return self.schedule == "unrolled"
+
+    @property
+    def walk(self):
+        return self.schedule == "walk"
+
+    @property
+    def tiles(self):
+        """(the grouped side's tile, the walked side's)."""
+        return (self.block_q, self.block_k) if self.q_grouped \
+            else (self.block_k, self.block_q)
+
+    @property
+    def sub_blocks(self):
+        own = self.tiles[0]
+        return (own, self.sub) if self.q_grouped else (self.sub, own)
+
+
+def _plan(kernel, block_q, block_k, lq, lk, row_bytes, *, causal, streamed,
+          full_bias=False, dropout=False) -> _Plan:
+    """The schedule of ``kernel`` ("forward", "dq", "dkv") for a call, from
+    its static arguments alone: ``block_q`` / ``block_k`` as the caller
+    gave them (None: the defaults), ``row_bytes`` of the walked side's
+    operands.  A call that one grid step owns in at most _UNROLLED_TILES
+    tiles is spelled out, bias and segment ids stream, every other call
+    walks (see the schedule comment above)."""
+    if kernel == "forward":
+        tq, tk = _resolve_blocks(block_q, block_k, lq, lk, causal=causal,
+                                 full_bias=full_bias, dropout=dropout)
+    else:
+        tq, tk = _resolve_bwd_blocks(block_q, block_k, lq, lk, causal=causal,
+                                     kernel=kernel)
+    tq, tk = _tiles(tq, tk, lq, lk)
+    q_grouped = kernel != "dkv"
+
+    def sides(tq, tk):
+        return ((lq, tq), (lk, tk)) if q_grouped else ((lk, tk), (lq, tq))
+
+    (l_own, t_own), (l_other, t_other) = sides(tq, tk)
+    group, resident, whole = _grouping(l_own, t_own, l_other, t_other,
+                                       streamed)
+    one_tile = _Plan(tq, tk, group, resident, "unrolled" if whole else
+                     "streamed" if streamed else "looped", t_other,
+                     q_grouped)
+    if whole or streamed:
+        return one_tile
+    cap_q, cap_k = _resolve_blocks(None, None, lq, lk, causal=causal,
+                                   dropout=dropout)
+    walk_q, walk_k = _WALK_TILES[kernel]
+    tq, tk = _tiles(tq if block_q else min(cap_q, walk_q),
+                    tk if block_k else min(cap_k, walk_k), lq, lk)
+    (l_own, t_own), (l_other, t_other) = sides(tq, tk)
+    n_own = -(-l_own // t_own)
+    for n in range(min(_BODY_TILES, n_own), 0, -1):
+        rows = n * t_own
+        # a group is a whole number of the walked side's tiles, or where
+        # the diagonal stands in it would not be static
+        if rows <= max(_RESIDENT_ROWS, t_own) \
+                and (l_own % t_own or n_own % n == 0) \
+                and not (causal and rows % t_other):
+            break
+    else:
+        return one_tile
+    sub = t_own if t_own < t_other and t_other % t_own == 0 else t_other
+    # the walked side whole where it fits, else in chunks of equal size
+    n_other = -(-l_other // t_other)
+    fits = max(1, _RESIDENT_BYTES // (t_other * row_bytes))
+    resident = -(-n_other // -(-n_other // fits))
+    return _Plan(tq, tk, n, resident, "walk", sub, q_grouped)
+
+
+def _band(plan: _Plan, offset, all_masked):
+    """Where a group of the walk meets the diagonal, relative to the
+    group: ``(c, classes)``.  The band's tiles of the walked side are
+    ``g * per + c + t`` for group ``g``, ``per`` the tiles that a group's
+    rows span and ``t`` an index of ``classes``; ``classes[t][u][r]`` says
+    what sub-tile ``u`` of that tile is to the group's sub-tile ``r``:
+    None (above the diagonal: skipped), True (masked) or False (plain).
+    The walked side's tiles before the band (q grouped) or after it (keys
+    grouped) are plain for the whole group, those on its other side
+    skipped."""
+    t_own, t_other = plan.tiles
+    rows = plan.group * t_own
+    if plan.q_grouped:
+        c, end = (offset + 1) // t_other, -(-(rows + offset) // t_other)
+    else:
+        c, end = -offset // t_other, -(-(rows - 1 - offset) // t_other)
+    classes = []
+    for t in range(c, end):
+        tile = []
+        for u in range(t_other // plan.sub):
+            first = t * t_other + u * plan.sub
+            last = first + plan.sub - 1
+            chains = []
+            for r in range(plan.group):
+                own0, own1 = r * t_own, (r + 1) * t_own - 1
+                if plan.q_grouped:   # own rows are queries, the others keys
+                    dead = own1 + offset < first
+                    clean = own0 + offset >= last
+                else:
+                    dead = last + offset < own0
+                    clean = first + offset >= own1
+                chains.append(None if dead else bool(all_masked or not clean))
+            tile.append(chains)
+        classes.append(tile)
+    return c, classes
+
+
+def _walk_visits(plan: _Plan, lq, lk, causal, all_masked):
+    """Every tile and sub-tile that the walk issues for one (batch, head):
+    ``(q0, q rows, k0, key rows, masked)``, by the bounds the kernels use
+    (`_walk`), in Python numbers."""
+    (l_own, l_other) = (lq, lk) if plan.q_grouped else (lk, lq)
+    t_own, t_other = plan.tiles
+    rows, n_other = plan.group * t_own, -(-l_other // t_other)
+    band = _band(plan, lk - lq, all_masked) if causal else None
+
+    def visit(own0, other0, other_rows, masked):
+        return (own0, t_own, other0, other_rows, masked) if plan.q_grouped \
+            else (other0, other_rows, own0, t_own, masked)
+
+    for g in range(-(-l_own // rows)):
+        loop, banded = range(n_other), []
+        if band is not None:
+            c, classes = band
+            start = g * (rows // t_other) + c
+            loop = range(min(start, n_other)) if plan.q_grouped \
+                else range(max(start + len(classes), 0), n_other)
+            banded = [(start + t, tile) for t, tile in enumerate(classes)
+                      if 0 <= start + t < n_other]
+        for j in loop:
+            for r in range(plan.group):
+                yield visit(g * rows + r * t_own, j * t_other, t_other,
+                            bool(all_masked))
+        for j, tile in banded:
+            for u, chains in enumerate(tile):
+                for r, masked in enumerate(chains):
+                    if masked is not None:
+                        yield visit(g * rows + r * t_own,
+                                    j * t_other + u * plan.sub, plan.sub,
+                                    masked)
+
+
+def _record_schedule(kernel, q, v, plan: _Plan, causal, all_masked):
     b, h, lq, d = q.shape
     lk = v.shape[2]
-    n_q, n_k = -(-lq // block_q), -(-lk // block_k)
+    block_q, block_k = plan.block_q, plan.block_k
     plain = masked = 0
-    for i in range(n_q):
-        p, need = _k_tile_range(i * block_q, block_q, block_k, lk - lq,
-                                n_k, causal, all_masked)
-        plain += int(p)
-        masked += int(need) - int(p)
+    if plan.walk:
+        t_own, t_other = plan.tiles
+        (l_own, l_other) = (lq, lk) if plan.q_grouped else (lk, lq)
+        total = -(-l_own // (plan.group * t_own)) * plan.group \
+            * -(-l_other // t_other) * (t_other // plan.sub)
+        for _, rows_q, _, rows_k, is_masked in _walk_visits(
+                plan, lq, lk, causal, all_masked):
+            units = (rows_k if plan.q_grouped else rows_q) // plan.sub
+            masked += units if is_masked else 0
+            plain += 0 if is_masked else units
+        per_body = plan.group
+    else:
+        n_q, n_k = -(-lq // block_q), -(-lk // block_k)
+        total = n_q * n_k
+        for i in range(n_q):
+            p, need = _k_tile_range(i * block_q, block_q, block_k, lk - lq,
+                                    n_k, causal, all_masked)
+            plain += int(p)
+            masked += int(need) - int(p)
+        per_body = plain + masked if plan.whole else 1
     tile_schedules.append({
         "kernel": kernel, "shape": (b, h, lq, lk, d),
         "value_width": v.shape[3], "blocks": (block_q, block_k), "operand_dtype": str(q.dtype),
-        "skipped": n_q * n_k - plain - masked, "plain": plain,
-        "masked": masked})
+        "skipped": total - plain - masked, "plain": plain,
+        "masked": masked, "schedule": plan.schedule,
+        "tiles_per_body": per_body,
+        "resident_rows": plan.resident * plan.tiles[1],
+        "sub_blocks": plan.sub_blocks})
 
 
 def _zero_rows(x, live):
@@ -412,6 +641,78 @@ def _walk_tiles(lo, resident, masked, plain, tile, carry):
     return span(masked, True, span(plain, False, carry))
 
 
+def _walk(plan: _Plan, band, g, ci, lq, lk, carries, load, tile, all_masked):
+    """The walk of group ``g`` over the share that the resident chunk
+    ``ci`` holds of the other side's tiles, in a call of ``lq`` queries
+    and ``lk`` keys.
+
+    ``carries``: one carry for each of the group's sub-tiles.
+    ``load(rows, first)``: the walked side's operands on ``rows`` of the
+    resident chunk, ``first`` their position in the sequence, loaded once
+    for all of the group's sub-tiles.  ``tile(r, operands, first, carry,
+    masked)``: sub-tile ``r``'s carry after that tile.  One body of the
+    loop issues all ``plan.group`` sub-tiles against one tile: chains that
+    share nothing but ``operands``.  ``band`` (`_band`; None in a call that
+    is not causal) is spelled out after the loop, a tile of it under a
+    ``cond`` only where the shapes cannot say that every group has it in
+    its chunk."""
+    from jax.experimental import pallas as pl
+
+    t_own, t_other = plan.tiles
+    l_own, l_other = (lq, lk) if plan.q_grouped else (lk, lq)
+    groups = -(-l_own // (plan.group * t_own))
+    n_other = -(-l_other // t_other)
+    if plan.resident >= n_other:   # one chunk: every bound but g's static
+        lo, hi = 0, n_other
+    else:
+        lo = ci * plan.resident
+        hi = jnp.minimum(lo + plan.resident, n_other)
+
+    def rows(j, u=0, size=t_other):
+        start = (j - lo) * t_other + u * plan.sub
+        return pl.ds(start if _static(start)
+                     else pl.multiple_of(start, size), size)
+
+    def body(j, carries):
+        operands = load(rows(j), j * t_other)
+        return tuple(tile(r, operands, j * t_other, carry, all_masked)
+                     for r, carry in enumerate(carries))
+
+    if band is None:
+        return jax.lax.fori_loop(lo, hi, body, tuple(carries))
+    c, classes = band
+    per = plan.group * t_own // t_other
+    start = g * per + c
+    carries = jax.lax.fori_loop(
+        *((lo, jnp.minimum(hi, start)) if plan.q_grouped
+          else (jnp.maximum(lo, start + len(classes)), hi)),
+        body, tuple(carries))
+    for t, subtiles in enumerate(classes):
+        j = start + t
+
+        def visit(carries, j=j, subtiles=subtiles):
+            carries = list(carries)
+            for u, chains in enumerate(subtiles):
+                if all(masked is None for masked in chains):
+                    continue
+                first = j * t_other + u * plan.sub
+                operands = load(rows(j, u, plan.sub), first)
+                for r, masked in enumerate(chains):
+                    if masked is not None:
+                        carries[r] = tile(r, operands, first, carries[r],
+                                          masked)
+            return tuple(carries)
+
+        # the tile's index in the first group and in the last
+        if _static(lo, hi) and lo <= c + t \
+                and (groups - 1) * per + c + t < hi:
+            carries = visit(carries)
+        else:
+            carries = jax.lax.cond((j >= lo) & (j < hi), visit,
+                                   lambda carries: carries, carries)
+    return carries
+
+
 # ---------------------------------------------------------------------------
 # Pallas forward
 # ---------------------------------------------------------------------------
@@ -433,13 +734,19 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                       dropout_p=0.0, seed=None, return_stats=False):
     """Forward: grid (b, h, q groups, k chunks), key chunks innermost.
 
-    A grid step owns ``group`` q tiles and sees one chunk of K and V
-    (the whole of them up to _RESIDENT_ROWS rows); for each q tile it
-    walks the chunk's key tiles up to the diagonal, the plain ones in one
-    loop and the masked ones in another.  Softmax running stats (m, l)
-    and the output accumulator, (dv, block_q) like the tile, persist
-    across chunks in VMEM scratch, so VMEM holds O(group·block_q·d +
-    chunk·d) and the sequence length is bounded by HBM, not VMEM.
+    A grid step owns ``group`` q tiles and sees one chunk of K and V.  On
+    the walk (`_plan`: a call that no single grid step owns) the chunk is
+    the whole of K and V up to _RESIDENT_BYTES, and one loop body issues
+    the step's q sub-tiles, each with its own (m, l, acc), against one key
+    tile loaded once; the diagonal's band is spelled out in sub-tiles
+    (`_walk`).  Otherwise (the sequence spelled out, or bias and segment
+    ids streamed) each q tile walks the chunk's key tiles up to the
+    diagonal, one tile a visit, the plain ones before the masked ones
+    (`_walk_tiles`).  Softmax running stats (m, l) and the output
+    accumulator, (dv, block_q) like the tile, persist across chunks in
+    VMEM scratch, so VMEM holds O(group·block_q·d + chunk·d) and the
+    sequence length is bounded by HBM, not VMEM.  ``block_q`` /
+    ``block_k``: the caller's, None for the defaults.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -447,19 +754,23 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     b, h, lq, d = q.shape
     lk, dv = k.shape[2], v.shape[3]   # v and the output have their own width
     offset = lk - lq  # end-aligned causal diagonal
-    block_q, block_k = _tiles(block_q, block_k, lq, lk)
-    n_k = pl.cdiv(lk, block_k)
     has_bias = bias is not None
     has_seg = q_seg is not None
     has_drop = dropout_p > 0.0
+    plan = _plan("forward", block_q, block_k, lq, lk,
+                 (d + dv) * q.dtype.itemsize, causal=causal,
+                 streamed=has_bias or has_seg,
+                 full_bias=has_bias and bias.shape[2] > 1, dropout=has_drop)
+    block_q, block_k = plan.block_q, plan.block_k
+    group, resident, whole = plan.group, plan.resident, plan.whole
+    n_k = pl.cdiv(lk, block_k)
     pad_k = lk % block_k != 0
     all_masked = (has_bias or has_seg or has_drop or pad_k
                   or lq % block_q != 0)
-    group, resident, whole = _grouping(lq, block_q, lk, block_k,
-                                       has_bias or has_seg)
     rows_q, chunk = group * block_q, resident * block_k
     n_c = pl.cdiv(n_k, resident)
-    _record_schedule("forward", q, v, block_q, block_k, causal, all_masked)
+    band = _band(plan, offset, all_masked) if plan.walk and causal else None
+    _record_schedule("forward", q, v, plan, causal, all_masked)
 
     def kernel(*refs):
         i = 3
@@ -495,16 +806,16 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        def tile(j, carry, qs, q0, masked):
+        def tile(kb, vb, k0, carry, qs, q0, masked):
+            """One tile: the k and v rows at ``k0`` against the scaled q
+            rows at ``q0``; the tile is as large as they are."""
             m, l, acc = carry  # (1, block_q) twice, (dv, block_q)
-            at = _tile_rows(j, ci * resident, block_k, resident)
-            kb, vb = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
             live = None
             if masked:
                 # a ragged q tile needs no mask here: its columns past lq
                 # are dropped on the way out
                 live, k_rows, q_pos, k_pos = _live(
-                    q0, j * block_k, block_q, block_k, lq, lk, offset,
+                    q0, k0, qs.shape[0], kb.shape[0], lq, lk, offset,
                     causal, False, pad_k, segs)
                 if pad_k:
                     kb, vb = _zero_rows(kb, k_rows), _zero_rows(vb, k_rows)
@@ -530,17 +841,36 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
             acc = acc * alpha + _dot(vb, p.astype(vb.dtype), (0, 0))
             return new_m, l, acc
 
-        lo = ci * resident
-        for r in range(group):
-            rows = pl.ds(r * block_q, block_q)
-            q0 = (gi * group + r) * block_q
-            qs = _scaled(q_ref[0, 0, rows, :], scale)
-            plain, need = _k_tile_range(q0, block_q, block_k, offset, n_k,
-                                        causal, all_masked)
-            m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows] = _walk_tiles(
-                lo, resident, (plain, need), (0, plain),
-                functools.partial(tile, qs=qs, q0=q0),
-                (m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows]))
+        def visit(j, carry, qs, q0, masked):
+            at = _tile_rows(j, ci * resident, block_k, resident)
+            return tile(k_ref[0, 0, at, :], v_ref[0, 0, at, :], j * block_k,
+                        carry, qs, q0, masked)
+
+        own = [pl.ds(r * block_q, block_q) for r in range(group)]
+        if plan.walk:
+            qs = [_scaled(q_ref[0, 0, rows, :], scale) for rows in own]
+            carries = _walk(
+                plan, band, gi, ci, lq, lk,
+                [(m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows])
+                 for rows in own],
+                lambda at, k0: (k_ref[0, 0, at, :], v_ref[0, 0, at, :]),
+                lambda r, kv, k0, carry, masked: tile(
+                    *kv, k0, carry, qs[r], gi * rows_q + r * block_q,
+                    masked),
+                all_masked)
+            for rows, carry in zip(own, carries):
+                m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows] = carry
+        else:
+            lo = ci * resident
+            for r, rows in enumerate(own):
+                q0 = (gi * group + r) * block_q
+                qs = _scaled(q_ref[0, 0, rows, :], scale)
+                plain, need = _k_tile_range(q0, block_q, block_k, offset, n_k,
+                                            causal, all_masked)
+                m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows] = _walk_tiles(
+                    lo, resident, (plain, need), (0, plain),
+                    functools.partial(visit, qs=qs, q0=q0),
+                    (m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows]))
 
         @pl.when(ci == n_c - 1)
         def _emit():
@@ -612,6 +942,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
+            vmem_limit_bytes=_WALK_VMEM_BYTES if plan.walk else None,
         ),
         interpret=interpret,
     )(*args)
@@ -645,7 +976,13 @@ def _resolve_blocks(block_q, block_k, lq, lk, *, causal=False,
     live), block_k 512 under dropout (its PRNG tile) and 512x512 with a
     full bias (~8 MB live).  A causal call takes `_causal_tile` of its
     shorter side where that is less: 512x512 at 1024 tokens, the caps
-    from 2048 on.  Explicit block_q/block_k arguments always win."""
+    from 2048 on.  Explicit block_q/block_k arguments always win.
+
+    These are the tile of a loop body that holds ONE tile (a sequence
+    spelled out, bias and segment ids streamed) and the caps of the walk,
+    whose body holds several: there `_plan` cuts the step's rows into
+    sub-tiles of `_WALK_TILES` (256 x 1024 in the forward at 4,096
+    tokens, four a body) under a scoped-VMEM limit of its own."""
     cap_q, cap_k = (512, 512) if full_bias else \
         (1024, 512 if dropout else 1024)
     if causal:
@@ -664,7 +1001,13 @@ def _resolve_bwd_blocks(block_q, block_k, lq, lk, *, causal=False,
     score/prob/grad tiles, the PRNG-bits tile, two (bk, d) accumulators),
     and 512x512 keeps both kernels ~7 MB at d=128 with dropout, well under
     the ~16 MB scoped budget.  A caller's SMALLER explicit blocks are
-    honored (the VMEM-pressure escape hatch)."""
+    honored (the VMEM-pressure escape hatch).
+
+    As in `_resolve_blocks`, this is the one-tile body's tile; on the walk
+    `_plan` gives dq two sub-tiles of 512 q rows and dk/dv four of 256 key
+    rows a body, each against 1,024 rows of the other side
+    (`_WALK_TILES`), and the caller's explicit blocks, cut as here, are
+    the sub-tile and the walked tile."""
     cap = 512
     if causal and kernel == "dkv":
         cap = min(cap, max(256, _causal_tile(min(lq, lk)) // 2))
@@ -683,7 +1026,9 @@ def _flash_bwd_pallas(q, k, v, g, out, m, l, causal, scale,
     dq across the key tiles of the chunks it sees.  dk/dv kernel: grid
     (b, h, k groups, q chunks) — each K/V tile accumulates dk/dv (and its
     bias-grad tile) across the q tiles.  Both walk their tiles in loops
-    inside the kernel, bounded by the diagonal, as the forward does.
+    inside the kernel, bounded by the diagonal, as the forward does, and
+    on a call that no grid step owns with several sub-tiles of the step's
+    rows a loop body, each with its own accumulator (`_walk`).
     Score tiles are rematerialized from q/k in VMEM (standard flash
     strategy) as ``exp(s - lse)`` from the forward's saved softmax stats,
     ``lse = m + log(l)`` made once in XLA beside ``D``, so no
@@ -736,21 +1081,24 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
     b, h, lq, d = q.shape
     lk, dv = k.shape[2], v.shape[3]   # v and dO have their own width
     offset = lk - lq
-    bq, bk = _tiles(*_resolve_bwd_blocks(block_q, block_k, lq, lk,
-                                         causal=causal, kernel=kernel),
-                    lq, lk)
-    n_q = pl.cdiv(lq, bq)
-    n_k = pl.cdiv(lk, bk)
     has_bias = bias is not None
     has_seg = q_seg is not None
     has_drop = dropout_p > 0.0
+    streamed = has_bias or has_seg
+    # dk/dv walks q, dO and the two float32 stats of a query
+    row_bytes = (d + dv) * q.dtype.itemsize + (8 if kernel == "dkv" else 0)
+    plan = _plan(kernel, block_q, block_k, lq, lk, row_bytes, causal=causal,
+                 streamed=streamed, dropout=has_drop)
+    bq, bk = plan.block_q, plan.block_k
+    n_q = pl.cdiv(lq, bq)
+    n_k = pl.cdiv(lk, bk)
     pad_q = lq % bq != 0
     pad_k = lk % bk != 0
     all_masked = has_bias or has_seg or has_drop or pad_q or pad_k
-    streamed = has_bias or has_seg
     if has_bias:
         bb, bh = bias.shape[:2]
-    _record_schedule(kernel, q, v, bq, bk, causal, all_masked)
+    band = _band(plan, offset, all_masked) if plan.walk and causal else None
+    _record_schedule(kernel, q, v, plan, causal, all_masked)
 
     thr = _drop_threshold(dropout_p) if has_drop else None
     inv_keep = 1.0 / (1.0 - dropout_p) if has_drop else None
@@ -758,15 +1106,17 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
     def recompute(qs, kb, vb, gb, lse, dd, q0, k0, masked, opt, bi, hi):
         """One tile's (p_t, ds), (bk, bq) in float32, from the scaled q
         tile, the k, v and dO tiles and the queries' stats, (1, bq) rows;
-        and the operands, with the rows of a ragged edge zeroed."""
+        and the operands, with the rows of a ragged edge zeroed.  The tile
+        is as large as its operands."""
         bias_ref, segs, seed_ref = opt
         live = None
         if masked:
             live, k_rows, q_pos, k_pos = _live(
-                q0, k0, bq, bk, lq, lk, offset, causal, pad_q, pad_k, segs)
+                q0, k0, qs.shape[0], kb.shape[0], lq, lk, offset, causal,
+                pad_q, pad_k, segs)
             if pad_q:
                 q_rows = q0 + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, 1), 0) < lq
+                    jnp.int32, (qs.shape[0], 1), 0) < lq
                 qs, gb = _zero_rows(qs, q_rows), _zero_rows(gb, q_rows)
             if pad_k:
                 kb, vb = _zero_rows(kb, k_rows), _zero_rows(vb, k_rows)
@@ -807,7 +1157,7 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
         return (bias_ref, segs, seed_ref), i
 
     # ---- dq kernel: grid (b, h, q groups, k chunks) --------------------
-    group_q, resident_k, whole_dq = _grouping(lq, bq, lk, bk, streamed)
+    group_q, resident_k, whole_dq = plan.group, plan.resident, plan.whole
 
     def dq_kernel(*refs):
         q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref = refs[:6]
@@ -822,26 +1172,46 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        lo = ci * resident_k
-        for r in range(group_q):
-            rows = pl.ds(r * bq, bq)
-            q0 = (gi * group_q + r) * bq
-            qs = _scaled(q_ref[0, 0, rows, :], scale)
-            gb = g_ref[0, 0, rows, :]
-            lse, dd = lse_ref[0, 0, :, rows], d_ref[0, 0, :, rows]
+        own = [pl.ds(r * bq, bq) for r in range(group_q)]
+        if plan.walk:
+            chains = [(_scaled(q_ref[0, 0, rows, :], scale),
+                       g_ref[0, 0, rows, :], lse_ref[0, 0, :, rows],
+                       d_ref[0, 0, :, rows]) for rows in own]
 
-            def tile(j, acc, masked, qs=qs, gb=gb, lse=lse, dd=dd, q0=q0):
-                at = _tile_rows(j, lo, bk, resident_k)
+            def chain(r, kv, k0, acc, masked):
+                qs, gb, lse, dd = chains[r]
                 _, ds, _, kb, _ = recompute(
-                    qs, k_ref[0, 0, at, :], v_ref[0, 0, at, :], gb, lse,
-                    dd, q0, j * bk, masked, opt, bi, hi)
+                    qs, *kv, gb, lse, dd, gi * group_q * bq + r * bq, k0,
+                    masked, opt, bi, hi)
                 return acc + _dot(kb, ds.astype(kb.dtype), (0, 0))
 
-            plain, need = _k_tile_range(q0, bq, bk, offset, n_k, causal,
-                                        all_masked)
-            acc_ref[:, rows] = _walk_tiles(
-                lo, resident_k, (plain, need), (0, plain), tile,
-                acc_ref[:, rows])
+            accs = _walk(
+                plan, band, gi, ci, lq, lk,
+                [acc_ref[:, rows] for rows in own],
+                lambda at, k0: (k_ref[0, 0, at, :], v_ref[0, 0, at, :]),
+                chain, all_masked)
+            for rows, acc in zip(own, accs):
+                acc_ref[:, rows] = acc
+        else:
+            lo = ci * resident_k
+            for r, rows in enumerate(own):
+                q0 = (gi * group_q + r) * bq
+                qs = _scaled(q_ref[0, 0, rows, :], scale)
+                gb = g_ref[0, 0, rows, :]
+                lse, dd = lse_ref[0, 0, :, rows], d_ref[0, 0, :, rows]
+
+                def tile(j, acc, masked, qs=qs, gb=gb, lse=lse, dd=dd, q0=q0):
+                    at = _tile_rows(j, lo, bk, resident_k)
+                    _, ds, _, kb, _ = recompute(
+                        qs, k_ref[0, 0, at, :], v_ref[0, 0, at, :], gb, lse,
+                        dd, q0, j * bk, masked, opt, bi, hi)
+                    return acc + _dot(kb, ds.astype(kb.dtype), (0, 0))
+
+                plain, need = _k_tile_range(q0, bq, bk, offset, n_k, causal,
+                                            all_masked)
+                acc_ref[:, rows] = _walk_tiles(
+                    lo, resident_k, (plain, need), (0, plain), tile,
+                    acc_ref[:, rows])
 
         @pl.when(ci == pl.cdiv(n_k, resident_k) - 1)
         def _emit():
@@ -849,7 +1219,7 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
             dq_ref[0, 0] = (acc_ref[...] * scale).T.astype(dq_ref.dtype)
 
     # ---- dk/dv kernel: grid (b, h, k groups, q chunks) -----------------
-    group_k, resident_q, whole_dkv = _grouping(lk, bk, lq, bq, streamed)
+    group_k, resident_q, whole_dkv = plan.group, plan.resident, plan.whole
 
     def dkv_kernel(*refs):
         q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref = refs[:6]
@@ -873,36 +1243,57 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
             if has_bias:
                 db_acc[...] = jnp.zeros_like(db_acc)
 
-        lo = ci * resident_q
-        for r in range(group_k):
-            rows = pl.ds(r * bk, bk)
-            k0 = (gi * group_k + r) * bk
-            kb, vb = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
+        own = [pl.ds(r * bk, bk) for r in range(group_k)]
+        if plan.walk:
+            chains = [(k_ref[0, 0, rows, :], v_ref[0, 0, rows, :])
+                      for rows in own]
 
-            def tile(j, carry, masked, kb=kb, vb=vb, k0=k0):
-                at = _tile_rows(j, lo, bq, resident_q)
+            def chain(r, queries, q0, carry, masked):
                 p_t, ds, qs, _, gb = recompute(
-                    _scaled(q_ref[0, 0, at, :], scale), kb, vb,
-                    g_ref[0, 0, at, :], lse_ref[0, 0, :, at],
-                    d_ref[0, 0, :, at], j * bq, k0, masked, opt, bi, hi)
-                # dk through the scaled q: the scale of s, for nothing
-                dk = carry[0] + _dot(ds.astype(qs.dtype), qs, (1, 0))
-                dv = carry[1] + _dot(p_t.astype(gb.dtype), gb, (1, 0))
-                if has_bias:
-                    return dk, dv, carry[2] + jnp.sum(ds, axis=1,
-                                                      keepdims=True)
-                return dk, dv
+                    queries[0], *chains[r], *queries[1:], q0,
+                    gi * group_k * bk + r * bk, masked, opt, bi, hi)
+                return (carry[0] + _dot(ds.astype(qs.dtype), qs, (1, 0)),
+                        carry[1] + _dot(p_t.astype(gb.dtype), gb, (1, 0)))
 
-            first, plain = _q_tile_range(k0, bq, bk, offset, n_q, causal,
-                                         all_masked)
-            carry = (dk_acc[rows, :], dv_acc[rows, :])
-            if has_bias:
-                carry += (db_acc[...],)
-            carry = _walk_tiles(lo, resident_q, (first, plain),
-                                (plain, n_q), tile, carry)
-            dk_acc[rows, :], dv_acc[rows, :] = carry[:2]
-            if has_bias:
-                db_acc[...] = carry[2]
+            sums = _walk(
+                plan, band, gi, ci, lq, lk,
+                [(dk_acc[rows, :], dv_acc[rows, :]) for rows in own],
+                lambda at, q0: (_scaled(q_ref[0, 0, at, :], scale),
+                                g_ref[0, 0, at, :], lse_ref[0, 0, :, at],
+                                d_ref[0, 0, :, at]),
+                chain, all_masked)
+            for rows, (dk, dv_) in zip(own, sums):
+                dk_acc[rows, :], dv_acc[rows, :] = dk, dv_
+        else:
+            lo = ci * resident_q
+            for r, rows in enumerate(own):
+                k0 = (gi * group_k + r) * bk
+                kb, vb = k_ref[0, 0, rows, :], v_ref[0, 0, rows, :]
+
+                def tile(j, carry, masked, kb=kb, vb=vb, k0=k0):
+                    at = _tile_rows(j, lo, bq, resident_q)
+                    p_t, ds, qs, _, gb = recompute(
+                        _scaled(q_ref[0, 0, at, :], scale), kb, vb,
+                        g_ref[0, 0, at, :], lse_ref[0, 0, :, at],
+                        d_ref[0, 0, :, at], j * bq, k0, masked, opt, bi, hi)
+                    # dk through the scaled q: the scale of s, for nothing
+                    dk = carry[0] + _dot(ds.astype(qs.dtype), qs, (1, 0))
+                    dv = carry[1] + _dot(p_t.astype(gb.dtype), gb, (1, 0))
+                    if has_bias:
+                        return dk, dv, carry[2] + jnp.sum(ds, axis=1,
+                                                          keepdims=True)
+                    return dk, dv
+
+                first, plain = _q_tile_range(k0, bq, bk, offset, n_q, causal,
+                                             all_masked)
+                carry = (dk_acc[rows, :], dv_acc[rows, :])
+                if has_bias:
+                    carry += (db_acc[...],)
+                carry = _walk_tiles(lo, resident_q, (first, plain),
+                                    (plain, n_q), tile, carry)
+                dk_acc[rows, :], dv_acc[rows, :] = carry[:2]
+                if has_bias:
+                    db_acc[...] = carry[2]
 
         @pl.when(ci == pl.cdiv(n_q, resident_q) - 1)
         def _emit():
@@ -962,7 +1353,8 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
 
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
+                             "arbitrary"),
+        vmem_limit_bytes=_WALK_VMEM_BYTES if plan.walk else None)
 
     if kernel == "dq":
         rows_q, rows_k = group_q * bq, resident_k * bk
@@ -1050,10 +1442,6 @@ def _flash_core(q, k, v, bias, q_seg, kv_seg, seed, causal, scale,
 def _forward_impl(q, k, v, bias, q_seg, kv_seg, seed, causal, scale,
                   dropout_p, block_q, block_k, return_stats=False):
     if _pallas_available():
-        block_q, block_k = _resolve_blocks(
-            block_q, block_k, q.shape[2], k.shape[2], causal=causal,
-            full_bias=bias is not None and bias.shape[2] > 1,
-            dropout=dropout_p > 0.0)
         # A kernel that fails to trace raises: on a TPU nothing degrades
         # to the O(L^2) reference behind the caller's back.
         res = _flash_fwd_pallas(
@@ -1270,7 +1658,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     budget, and for a causal call half of the sequence a side where that
     is less (a quarter in the dk/dv kernel), so that tiles above the
     diagonal go — see those functions' docstrings for the measured limits
-    that set them."""
+    that set them.  A call longer than one grid step owns (from 2,048
+    causal tokens on) walks: the step's 1,024 rows in two or four
+    sub-tiles a loop body against 1,024-row tiles of the other side
+    (``_WALK_TILES``), where explicit blocks name the sub-tile and the
+    walked tile."""
     b, h, lq, d = q.shape
     lk = k.shape[2]
     scale = 1.0 / math.sqrt(d) if scale is None else scale

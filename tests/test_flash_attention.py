@@ -601,17 +601,54 @@ def _rel(got, want):
             float(np.abs(diff).max() / np.abs(want).max()))
 
 
+def _classes_by_position(lq, lk, sub_q, sub_k, all_masked):
+    """(skipped, plain, masked) sub-tiles of a causal call by where their
+    positions stand to the end-aligned diagonal, over the tiles' whole
+    extent: a ragged edge is not the diagonal's business (a call that has
+    one masks every tile it visits)."""
+    n_q, n_k = -(-lq // sub_q), -(-lk // sub_k)
+    live = (np.arange(n_q * sub_q)[:, None] + (lk - lq)
+            >= np.arange(n_k * sub_k)[None, :])
+    counts = {"skipped": 0, "plain": 0, "masked": 0}
+    for i in range(n_q):
+        for j in range(n_k):
+            tile = live[i * sub_q:(i + 1) * sub_q, j * sub_k:(j + 1) * sub_k]
+            counts["skipped" if not tile.any() else "masked"
+                   if all_masked or not tile.all() else "plain"] += 1
+    return counts["skipped"], counts["plain"], counts["masked"]
+
+
+#: (lq, lk, block_q, block_k, d, dv, schedule, tiles a loop body issues)
+SCHEDULED = {
+    # 4 x 4 tiles that one grid step spells out
+    "unrolled": (512, 512, 128, 128, 16, 16, "unrolled", 10),
+    # 6 x 6 tiles: a step's three sub-tiles a body against one key tile
+    "walk_n3": (1536, 1536, 256, 256, 16, 16, "walk", 3),
+    "walk_n2": (1280, 1280, 128, 128, 16, 16, "walk", 2),
+    # the cells' form: four sub-tiles a body against a tile of twice their
+    # rows, the diagonal in sub-tiles of 128 x 128 (all three classes)
+    "walk_n4_sub_tiles": (1024, 1024, 128, 256, 16, 16, "walk", 4),
+    "walk_value_width": (1024, 1024, 128, 256, 24, 16, "walk", 4),
+    # the end-aligned diagonal: query i sees keys 0..512 + i
+    "walk_lk_longer": (512, 1024, 128, 128, 16, 16, "walk", 4),
+    # a ragged length masks every tile it visits
+    "walk_ragged": (1000, 1000, 128, 128, 16, 16, "walk", 4),
+}
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("length,block", [(512, 128), (1536, 256)],
-                         ids=["unrolled", "looped"])
-def test_tile_schedule_matches_reference(length, block, dtype, monkeypatch):
-    """Forward and all three gradients at a block-aligned causal shape
-    that has all three classes of tile, through the real custom_vjp route
-    in interpret mode: 4 x 4 tiles that one grid step spells out, and
-    6 x 6 that it walks in loops over two resident chunks."""
+@pytest.mark.parametrize("case", sorted(SCHEDULED))
+def test_tile_schedule_matches_reference(case, dtype, monkeypatch):
+    """Forward and all three gradients of a causal call against the dense
+    float32 oracle, through the real custom_vjp route in interpret mode, on
+    the spelled-out path and on the walk (`SCHEDULED`); and the trace-time
+    record: which schedule, how many tiles a body, and the sub-tiles by
+    class as the positions give them."""
     monkeypatch.setenv("ZOO_FLASH_INTERPRET", "1")
-    shape = (1, 2, length, 16)
-    q, k, v, g = (_rand(shape, 70 + i).astype(dtype) for i in range(4))
+    lq, lk, block_q, block_k, d, dv, schedule, per_body = SCHEDULED[case]
+    q, k, v, g = (_rand((1, 2, length, width), 70 + i).astype(dtype)
+                  for i, (length, width) in enumerate(
+                      [(lq, d), (lk, d), (lk, dv), (lq, dv)]))
     scale = 0.25
 
     def loss(attend):
@@ -619,7 +656,7 @@ def test_tile_schedule_matches_reference(length, block, dtype, monkeypatch):
             attend(q, k, v).astype(jnp.float32) * g.astype(jnp.float32))
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, True, scale, block, block)
+        return flash_attention(q, k, v, True, scale, block_q, block_k)
 
     def dense(q, k, v):
         return _attention_reference(q, k, v, True, scale)
@@ -630,16 +667,23 @@ def test_tile_schedule_matches_reference(length, block, dtype, monkeypatch):
     want = [dense(*wide), *jax.grad(loss(dense), argnums=(0, 1, 2))(*wide)]
     tol_l2, tol_max = SCHEDULE_TOL[dtype]
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
-        assert a.dtype == jnp.dtype(dtype), name
+        assert a.dtype == jnp.dtype(dtype) and a.shape == b.shape, name
         rel_l2, rel_max = _rel(a, b)
         assert rel_l2 <= tol_l2 and rel_max <= tol_max, (
             f"{name} {dtype}: rel_l2 {rel_l2:.3g} (<= {tol_l2}), "
             f"rel_max {rel_max:.3g} (<= {tol_max})")
-    n = length // block
+    ragged = lq % block_q != 0 or lk % block_k != 0
     for record in schedules:
         assert record["operand_dtype"] == dtype
-        assert (record["skipped"], record["plain"], record["masked"]) == (
-            n * (n - 1) // 2, n * (n - 1) // 2, n), record
+        assert record["shape"] == (1, 2, lq, lk, d)
+        assert record["value_width"] == dv
+        assert (record["schedule"], record["tiles_per_body"]) \
+            == (schedule, per_body), record
+        assert record["resident_rows"] >= (
+            lq if record["kernel"] == "dkv" else lk), record
+        assert (record["skipped"], record["plain"], record["masked"]) \
+            == _classes_by_position(lq, lk, *record["sub_blocks"], ragged), \
+            record
     assert {r["kernel"] for r in schedules} == {"forward", "dq", "dkv"}
 
 
@@ -665,6 +709,64 @@ def test_tile_schedule_record_at_the_cell_shape(dtype, monkeypatch):
         assert record["masked"] == diagonal
         assert record["skipped"] == diagonal * (diagonal - 1) // 2 > 0
         assert record["plain"] == record["skipped"]
+        # one grid step owns the sequence and spells its visits out: the
+        # walk (PR 36) is not this cell's path
+        assert record["schedule"] == "unrolled"
+        assert record["tiles_per_body"] == record["plain"] + record["masked"]
+        assert record["resident_rows"] == 1024
+        assert record["sub_blocks"] == record["blocks"]
+
+
+#: what the three long cells call: (shape of q and k, width of v)
+LONG_CELLS = {
+    # `kanana-2-30b-a3b-fit`, and `kimi-linear-48b-a3b-fit`'s one MLA layer
+    "kanana": ((2, 32, 4096, 192), 128),
+    "ouro": ((2, 16, 4096, 128), 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(LONG_CELLS))
+def test_tile_schedule_record_at_the_long_cells_shapes(cell, monkeypatch):
+    """The trace-time records of the 4,096-token cells' calls, causal bf16:
+    all three kernels walk, two or more independent sub-tiles a loop body,
+    with the whole other side of a head resident; the forward and dk/dv
+    cut the diagonal in 256 x 256 sub-tiles and compute 136 of them for the
+    128 the causal half counts; dq keeps two sub-tiles of 512 rows and the
+    diagonal at its tile, 36 of 512 x 512 for 32 (four of 256 timed
+    4.74 ms against 4.18 at kanana's shape on a v5e: PERF.md section 6,
+    PR 36).  Traced, not run."""
+    monkeypatch.setenv("ZOO_FLASH_FORCE_PALLAS", "1")
+    shape, value_width = LONG_CELLS[cell]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:3] + (value_width,), jnp.bfloat16)
+    schedules = _fresh_schedules()
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2)),
+        q, q, v)
+    records = {r["kernel"]: r for r in schedules}
+    assert set(records) == {"forward", "dq", "dkv"}
+    for kernel, record in records.items():
+        assert record["shape"] == shape[:3] + (4096, shape[3])
+        assert record["value_width"] == value_width
+        assert record["schedule"] == "walk"
+        assert record["tiles_per_body"] >= 2
+        assert record["resident_rows"] == 4096
+        sub_q, sub_k = record["sub_blocks"]
+        counted = (4096 // sub_q) * (4096 // sub_k) / 2  # the causal half
+        visited = record["plain"] + record["masked"]
+        assert record["skipped"] + visited == 2 * counted
+        assert (record["skipped"], record["plain"], record["masked"]) \
+            == _classes_by_position(4096, 4096, sub_q, sub_k, False)
+        if kernel == "dq":
+            assert (sub_q, sub_k) == (512, 512) and visited == 36
+        else:
+            assert (sub_q, sub_k) == (256, 256) and visited == 136
+            assert visited <= 1.07 * counted
+    assert records["forward"]["blocks"] == (256, 1024)
+    assert records["dq"]["blocks"] == (512, 1024)
+    assert records["dkv"]["blocks"] == (1024, 256)
+    assert [records[k]["tiles_per_body"] for k in ("forward", "dq", "dkv")] \
+        == [4, 2, 4]
 
 
 def test_tile_schedule_masks_every_tile_of_a_call_that_needs_it(monkeypatch):
@@ -699,16 +801,47 @@ def test_tile_schedule_masks_every_tile_of_a_call_that_needs_it(monkeypatch):
 @pytest.mark.parametrize("lq,lk,bq,bk", [
     (1024, 1024, 256, 256), (1024, 1024, 512, 256), (1024, 1024, 128, 512),
     (600, 700, 128, 256), (700, 600, 256, 128), (256, 1024, 128, 128),
+    # on the walk (PR 36): the cells' tiles, a sub-tile smaller than the
+    # walked tile on either side, both signs of the offset, ragged edges
+    (4096, 4096, 256, 1024), (4096, 4096, 512, 1024), (4096, 4096, 1024, 256),
+    (2048, 2048, 128, 512), (1536, 1536, 256, 256), (2048, 3072, 256, 512),
+    (3072, 2048, 256, 256), (1000, 1000, 128, 128), (1100, 1500, 128, 256),
 ])
 @pytest.mark.parametrize("all_masked", [False, True])
 def test_tile_ranges_agree_with_the_positions(lq, lk, bq, bk, all_masked):
     """`_k_tile_range` (forward, dq) and `_q_tile_range` (dk/dv) put every
     tile in the class that its positions give it, end-aligned diagonal
-    and ragged edges included."""
+    and ragged edges included; and where a kernel walks (`_plan`), the
+    tiles and the diagonal band's sub-tiles that it issues (`_walk_visits`)
+    cover every live position exactly once, none of them wholly dead, the
+    plain ones wholly live."""
     from analytics_zoo_tpu.ops.pallas.flash_attention import (
         _k_tile_range,
+        _plan,
         _q_tile_range,
+        _walk_visits,
     )
+
+    ragged = lq % bq != 0 or lk % bk != 0
+    for kernel in ("forward", "dq", "dkv"):
+        plan = _plan(kernel, bq, bk, lq, lk, 64, causal=True, streamed=False)
+        if not plan.walk or (ragged and not all_masked):
+            continue
+        edge = max(lq, lk) + 4096   # room for a ragged group's overhang
+        seen = np.zeros((edge, edge), np.int8)
+        alive = (np.arange(edge)[:, None] + (lk - lq)
+                 >= np.arange(edge)[None, :])
+        visits = list(_walk_visits(plan, lq, lk, True, all_masked))
+        for q0, rows_q, k0, rows_k, masked in visits:
+            at = (slice(q0, q0 + rows_q), slice(k0, k0 + rows_k))
+            seen[at] += 1
+            assert alive[at].any(), (kernel, q0, k0, "a dead sub-tile")
+            assert masked or alive[at].all(), (kernel, q0, k0, "not plain")
+            assert masked == (all_masked or not alive[at].all())
+        assert seen.max() == 1, (kernel, "a position visited twice")
+        assert seen[:lq, :lk][alive[:lq, :lk]].all(), (
+            kernel, "a live position not visited")
+        assert len(visits) > 0
 
     offset = lk - lq
     n_q, n_k = -(-lq // bq), -(-lk // bk)

@@ -179,6 +179,16 @@ CASES = {
     "flash_fwd_bwd_segments_unaligned":
         lambda: _flash((2, 12, 1000, 64), True, causal=True,
                        segments=True),
+    # the walk (PR 36) off the cells' shapes: a ragged length, whose last
+    # group overhangs the sequence; 16,384 tokens, whose K and V stay
+    # resident in two chunks; queries longer than keys, whose first groups
+    # see no key.  Each puts the diagonal's tiles under a `cond`.
+    "flash_fwd_bwd_walk_ragged":
+        lambda: _flash((2, 4, 3000, 128), True, causal=True),
+    "flash_fwd_bwd_walk_chunked":
+        lambda: _flash((1, 8, 16384, 128), True, causal=True),
+    "flash_fwd_bwd_walk_not_causal":
+        lambda: _flash((2, 4, 4096, 128), True),
     # `kimi-linear-48b-a3b-fit`'s own call: 32 KDA heads of 128
     "kda_walk_fwd_bwd_kimi_cell": lambda: _kda((2, 32, 4096, 128)),
     "softmax_xent_fwd_bwd": lambda: _xent((4096, 50304)),
