@@ -113,6 +113,44 @@ def clock_offset_ns(dispatch_ns: list[int], program_ns: list[float],
     return lower, "paired-sync", lower, upper
 
 
+def on_device_clock(run: dict, out=None) -> dict | None:
+    """{device plane: the window's main-thread spans as (start, end, name)
+    on the clock of that plane's events}: the k-th
+    ``zoo.train.step_dispatch`` span of the window against the k-th step
+    program of ``run["step_modules"]``, each ``zoo.train.epoch_sync``
+    against the last program dispatched before it (``clock_offset_ns``).
+    Nothing where there is no step program or no span, or where the spans
+    are not these programs'.  Writes to ``out`` a line a plane, ``clock
+    <anchor|paired|paired-sync> <offset ns> between <lower> <upper>``."""
+    step_modules = run["step_modules"]
+    if not step_modules or not all(step_modules.values()):
+        return None
+    main = [e for call in window_calls(run) for e in main_thread(call)]
+    if not main:
+        return None
+    clock = get_tracer().device_clock_ns
+    on_host = [(*clock(e), e["name"]) for e in main]
+    dispatch = sorted(s[0] for s in on_host if s[2] == DISPATCH)
+    # a sync waits for the last program dispatched before it
+    syncs = [(end, bisect.bisect_left(dispatch, start))
+             for start, end, name in on_host if name == SYNC]
+    shifted = {}
+    for plane, modules in step_modules.items():
+        if len(modules) != len(dispatch):
+            return None
+        found = clock_offset_ns(
+            dispatch, [m.start_ns for m in modules],
+            [(end, modules[k - 1].end_ns) for end, k in syncs if k])
+        if found is None:
+            return None
+        offset, how, lower, upper = found
+        if out is not None:
+            out.write(f"clock {how} {offset} between {lower} {upper}\n")
+        shifted[plane] = [(s + offset, e + offset, name)
+                          for s, e, name in on_host]
+    return shifted
+
+
 def idle_by_span(gaps: list[tuple[float, float]],
                  spans: list[tuple[float, float, str]]) -> dict[str, float]:
     """Seconds of ``gaps`` by the innermost of ``spans`` (start, end,

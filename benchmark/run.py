@@ -90,6 +90,57 @@ class CompileCounter:
             self.count += 1
 
 
+def read_layers(run: dict, result: dict, device_out: dict, job,
+                trace_dir: str) -> dict:
+    """The traced run's half: the capture into ``run``, the device's busy
+    seconds into ``device_out``, the breakdown and what the step's phases
+    left without a scope into ``result``; returns the per-layer metrics."""
+    from benchmark import xplane
+    from benchmark.manifest import load_module
+
+    manifest, window = run["manifest"], run["window"]
+    layer_metrics = os.path.join(manifest.home, "layer_metrics")
+    run["trace_dir"] = trace_dir
+    run["capture"] = capture = xplane.load(xplane.find_xplane(trace_dir))
+    run["step_modules"] = {
+        plane: [m for m in modules if m.name.startswith(job.STEP_PROGRAM)]
+        for plane, modules in capture.modules.items()}
+    if capture.device_ops:
+        busy = [xplane.busy_seconds(ops)
+                for ops in capture.device_ops.values()]
+        device_out["busy_s"] = sum(busy) / len(busy)
+        device_out["window_s"] = window["elapsed_s"]
+        plane = next(iter(capture.device_ops))
+        # a gap between programs by the host's span open then, where the
+        # two clocks can be paired
+        spans = load_module(os.path.join(layer_metrics, "_spans.py"))
+        shifted, by_span = spans.on_device_clock(run), None
+        if shifted is not None:
+            def by_span(gaps):
+                return spans.idle_by_span(gaps, shifted[plane])
+        result["breakdown"] = {
+            "device_ops": xplane.top(
+                xplane.op_seconds(capture.device_ops[plane])),
+            "idle_gaps": xplane.top(xplane.idle_by_place(
+                capture.device_ops[plane],
+                run["step_modules"].get(plane, []), job.steps_per_call,
+                window["elapsed_s"], by_span))}
+    metrics = {}
+    for metric in manifest.per_layer(run["cell"]["name"]):
+        value = manifest.reader(metric["name"])(run)
+        if value is not None:
+            metrics[metric["name"]] = {"value": float(value),
+                                       "unit": metric["unit"]}
+    phases = load_module(os.path.join(layer_metrics, "_phases.py"))
+    found = phases.of_run(run)
+    if found is not None:
+        # the breakdown's two lists are the contract's; the operations
+        # that carry no scope stand beside them, seconds of the window
+        result["no_scope_ops"] = xplane.top(
+            {name: ns / 1e9 for name, ns in found["unnamed"].items()})
+    return metrics
+
+
 def run_cell(manifest, workload: str, seed: int, seconds: float,
              trace: bool, *, require_tpu: bool = True,
              config_overrides: dict | None = None,
@@ -170,34 +221,12 @@ def run_cell(manifest, workload: str, seed: int, seconds: float,
     result = {"correct": correct, "attempted": window["attempted"],
               "failed": window["failed"]}
     if trace:
-        from benchmark import xplane
-
-        capture = xplane.load(xplane.find_xplane(trace_dir))
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        run["capture"] = capture
-        run["step_modules"] = {
-            plane: [m for m in modules
-                    if m.name.startswith(job.STEP_PROGRAM)]
-            for plane, modules in capture.modules.items()}
-        if capture.device_ops:
-            busy = [xplane.busy_seconds(ops)
-                    for ops in capture.device_ops.values()]
-            device_out["busy_s"] = sum(busy) / len(busy)
-            device_out["window_s"] = window["elapsed_s"]
-            plane = next(iter(capture.device_ops))
-            steps = run["step_modules"].get(plane, [])
-            result["breakdown"] = {
-                "device_ops": xplane.top(
-                    xplane.op_seconds(capture.device_ops[plane])),
-                "idle_gaps": xplane.top(xplane.idle_by_place(
-                    capture.device_ops[plane], steps,
-                    job.steps_per_call, window["elapsed_s"]))}
-        metrics = {}
-        for metric in manifest.per_layer(workload):
-            value = manifest.reader(metric["name"])(run)
-            if value is not None:
-                metrics[metric["name"]] = {"value": float(value),
-                                           "unit": metric["unit"]}
+        try:
+            metrics = read_layers(run, result, device_out, job, trace_dir)
+        finally:
+            # the capture's directory outlives the readers: one added as a
+            # file alone can reach it (``run["trace_dir"]``)
+            shutil.rmtree(trace_dir, ignore_errors=True)
     else:
         values = {"setup_s": setup_s, **window["end_to_end"]}
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}

@@ -74,12 +74,21 @@ def test_sound_run_is_correct(sound, cell):
 
 def test_traced_run_reports_the_layers_it_can_read(toy):
     result, _table = toy("gpt2-small-fit", trace=True)
-    # no device plane on the CPU: the trace's readers return nothing, and
-    # no share of a peak is printed for a CPU
-    assert set(result["metrics"]) == {
-        "data_wait_ms_per_step", "dispatch_ms_per_step", "compile_s",
-        "compiles_in_window"}
-    assert result["metrics"]["compiles_in_window"]["value"] == 0
+    # no device plane on the CPU: the trace's readers return nothing, no
+    # share of a peak is printed for a CPU, and the readers that time the
+    # host against a chip (``layer_metrics/_chip.py``) stay silent
+    printed = result["metrics"]
+    assert {"data_wait_ms_per_step", "dispatch_ms_per_step", "compile_s",
+            "compiles_in_window"} <= set(printed)
+    assert printed["step_lower_s"]["value"] > 0
+    entries = {m["name"]: m for m in Manifest().doc["per_layer"]}
+    home = os.path.join(ROOT, "benchmark", "layer_metrics")
+    for name, metric in printed.items():
+        assert metric["unit"] != "%", name
+        assert entries[name]["source"] != "device_trace", name
+        with open(os.path.join(home, name + ".py")) as fh:
+            assert '"_chip"' not in fh.read(), name
+    assert printed["compiles_in_window"]["value"] == 0
     assert result["correct"] is True
 
 

@@ -25,13 +25,20 @@ def test_every_named_thing_is_found():
             "loss_gap", "grad_gap", "grad_gap_median", "delta_gap",
             "delta_gap_median", "grad_diff", "grad_diff_median",
             "grad_diff_whole"}
-        names = [m["name"] for m in manifest.per_layer(cell["name"])]
-        assert ("flash_attention_roofline" in names) \
-            == (cell["name"] == "gpt2-small-fit")
         assert [m["name"] for m in manifest.end_to_end(cell["name"])] \
             == ["train_examples_per_s", "setup_s"]
+    cells = [cell["name"] for cell in doc["workloads"]]
     for metric in doc["per_layer"]:
         assert callable(manifest.reader(metric["name"]))
+        if "workloads" not in metric:
+            continue
+        # a list names cells, and a cell reports the entry if and only if
+        # the list names it
+        assert set(metric["workloads"]) <= set(cells), metric
+        for cell in cells:
+            reported = {m["name"] for m in manifest.per_layer(cell)}
+            assert (metric["name"] in reported) \
+                == (cell in metric["workloads"]), (metric["name"], cell)
     for entry in doc["configs"]:
         with open(os.path.join(ROOT, entry["file"])) as fh:
             sizes = json.load(fh)

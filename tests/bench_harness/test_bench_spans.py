@@ -341,9 +341,17 @@ def test_off_the_chip_the_new_readers_stay_silent(ring, manifest, metric):
     assert manifest.reader(metric)(run) is None
 
 
+def _holds_run(names, run):
+    """``run`` stands in ``names`` as it is, one name after the other."""
+    return any(names[i:i + len(run)] == run
+               for i in range(len(names) - len(run) + 1))
+
+
 def test_the_new_metrics_are_appended_and_report_in_both_cells(manifest):
-    names = [m["name"] for m in manifest.doc["per_layer"]]
-    assert names[-len(NEW):] == NEW
+    """PR 25's five stand together and in their order, in the list and in
+    every cell's; what a later PR appends comes after them."""
+    assert _holds_run([m["name"] for m in manifest.doc["per_layer"]], NEW)
     for cell in manifest.doc["workloads"]:
-        assert set(NEW) <= {m["name"]
-                            for m in manifest.per_layer(cell["name"])}
+        assert _holds_run([m["name"] for m
+                           in manifest.per_layer(cell["name"])], NEW)
+    assert not _holds_run(NEW[:2] + ["other"] + NEW[2:], NEW)
