@@ -40,7 +40,16 @@ import os
 import threading
 import time
 
-__all__ = ["Tracer", "span", "get_tracer", "set_tracer"]
+__all__ = ["Tracer", "span", "get_tracer", "set_tracer",
+           "MIXER_SCOPE", "FFN_SCOPE", "HEAD_SCOPE"]
+
+#: The device's parts of a step, beside the host's spans: the names of the
+#: ``jax.named_scope``s that the decoders open around a block's mixer
+#: branch, its feed-forward branch and the head with its loss.  They are
+#: metadata only (an operation's ``op_name`` in the compiled program, which
+#: a profiler capture keeps), never an operation; the benchmark reads them
+#: as ``step_mixer_ms``, ``step_ffn_ms`` and ``step_head_ms``.
+MIXER_SCOPE, FFN_SCOPE, HEAD_SCOPE = "zoo.mixer", "zoo.ffn", "zoo.head"
 
 # Innermost open span as (name, id), per execution context / thread.
 _current_span: contextvars.ContextVar = contextvars.ContextVar(

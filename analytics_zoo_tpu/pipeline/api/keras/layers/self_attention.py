@@ -20,8 +20,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import named_scope
 from jax.ad_checkpoint import checkpoint_name
 
+from analytics_zoo_tpu.metrics.tracing import (
+    FFN_SCOPE,
+    HEAD_SCOPE,
+    MIXER_SCOPE,
+)
 from analytics_zoo_tpu.ops.attention import (
     dot_product_attention,
     merge_heads,
@@ -613,8 +619,12 @@ class _TransformerCore(Layer):
             return checkpoint_name(dense(f, "out"), "ffn_out")
 
         n = self._n_norms // 2
-        h = self._branch(attention, h, bp, 1, training, brng, 1)
-        h = self._branch(feed_forward, h, bp, 1 + n, training, brng, 2)
+        # the device's parts of a block, by name in every operation's
+        # ``op_name`` (metadata only: ``metrics/tracing.py``)
+        with named_scope(MIXER_SCOPE):
+            h = self._branch(attention, h, bp, 1, training, brng, 1)
+        with named_scope(FFN_SCOPE):
+            h = self._branch(feed_forward, h, bp, 1 + n, training, brng, 2)
         return h, aux, drop
 
 
@@ -1037,16 +1047,21 @@ class LoopedDecoder(_TransformerCore):
             params["head_kernel"].shape, jnp.float32)}
         costs, mass, pass_loss = [], [], []
         for t, s in enumerate(states):
-            cost, p_mean, ce_mean, log_survive = self._exit_tail(
-                tail_params, s, targets, log_survive, t == self.passes - 1)
+            # the head's scope around the tail alone: the pass's blocks are
+            # traced when ``states`` is asked for ``s``, outside it
+            with named_scope(HEAD_SCOPE):
+                cost, p_mean, ce_mean, log_survive = self._exit_tail(
+                    tail_params, s, targets, log_survive,
+                    t == self.passes - 1)
             costs.append(cost)
             mass.append(p_mean)
             pass_loss.append(ce_mean)
         # the costs are added here with weight one, which is what makes the
         # sum over the passes the head's gradient (``_weighted_ce``)
-        total = _deliver(
-            sum(costs), params["head_kernel"].astype(jnp.float32),
-            tail_params["head_grad"])
+        with named_scope(HEAD_SCOPE):
+            total = _deliver(
+                sum(costs), params["head_kernel"].astype(jnp.float32),
+                tail_params["head_grad"])
         return {"loop_exit_cost": total, "loop_exit_mass": jnp.stack(mass),
                 "loop_pass_loss": jnp.stack(pass_loss)}
 
@@ -1072,7 +1087,8 @@ class LoopedDecoder(_TransformerCore):
 
         def one_pass(h):
             h = self._run_blocks(params["blocks"], h, None, training, None)
-            return final(params["final_gamma"], h)
+            with named_scope(HEAD_SCOPE):
+                return final(params["final_gamma"], h)
 
         takes_loss = targets is not None
         policy = resolve_remat(self.name or "blocks", default=self.remat)
@@ -1092,8 +1108,10 @@ class LoopedDecoder(_TransformerCore):
         if not takes_loss:
             for _ in range(self.passes):
                 h = one_pass(h)
+            with named_scope(HEAD_SCOPE):
+                logits = h @ params["head_kernel"]
             # training under another loss: nothing of the gate's to report
-            return h @ params["head_kernel"], \
+            return logits, \
                 self.init_state() if training or state is None else state
 
         def states():   # a pass is made when its tail asks for its state
@@ -1103,7 +1121,9 @@ class LoopedDecoder(_TransformerCore):
                 yield h
 
         loss_state = self._exit_loss(params, states(), targets)
-        return h @ params["head_kernel"], loss_state
+        with named_scope(HEAD_SCOPE):
+            logits = h @ params["head_kernel"]
+        return logits, loss_state
 
 
 #: Trace-time record of each ``LatentMoEDecoder`` traced, newest last (as
@@ -1292,16 +1312,19 @@ class LatentMoEDecoder(_TransformerCore):
                 routes.append(numbers)
             if "kda_q_kernel" in bp:
                 rules.append(numbers)
-        s = apply_remat(lambda gamma, h: _rms_norm(h, gamma, self.norm_eps),
-                        "full")(params["final_gamma"], h)
+        with named_scope(HEAD_SCOPE):
+            s = apply_remat(
+                lambda gamma, h: _rms_norm(h, gamma, self.norm_eps),
+                "full")(params["final_gamma"], h)
+            cost = self._mean_ce(params, s, targets) \
+                if targets is not None else jnp.zeros((), jnp.float32)
 
         def over_layers(key):
             return jnp.stack([r[key] for r in routes]) if routes \
                 else jnp.zeros((0,), jnp.float32)
 
         new_state = {
-            "lm_loss_cost": self._mean_ce(params, s, targets)
-            if targets is not None else jnp.zeros((), jnp.float32),
+            "lm_loss_cost": cost,
             "moe_held_assignments": over_layers("held_assignments"),
             "moe_load_max_over_mean": over_layers("load_max_over_mean"),
             "moe_walk_windows": over_layers("walk_windows"),
@@ -1313,4 +1336,6 @@ class LatentMoEDecoder(_TransformerCore):
                 for key in self.KDA_NUMBERS})
         if not training and state is not None:
             new_state = state
-        return s @ params["head_kernel"], new_state
+        with named_scope(HEAD_SCOPE):
+            logits = s @ params["head_kernel"]
+        return logits, new_state

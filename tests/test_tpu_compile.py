@@ -425,3 +425,97 @@ def test_the_kanana_step_compiles_whole_for_v5e(one_chip, real_kernels):
     assert memory.argument_size_in_bytes == pytest.approx(6.91e9, rel=0.01)
     assert memory.temp_size_in_bytes < 4.0e9
     assert model_py.routing_fault("cpu") is None
+
+
+def _kimi_step_text(one_chip) -> str:
+    """`kimi-linear-48b-a3b-fit`'s train step as ``fit`` builds it, at a
+    size that compiles in seconds (a KDA layer and a routed latent one,
+    heads of 128 and 192/128, 2 x 512 tokens), compiled for a v5e."""
+    import numpy as np
+
+    from analytics_zoo_tpu import init_zoo_context
+    from benchmark import data
+    from benchmark.manifest import Manifest
+
+    configuration = Manifest().configuration("kimi-linear-48b-a3b", {
+        "hidden_size": 256, "num_attention_heads": 2, "kv_lora_rank": 128,
+        "num_hidden_layers": 2, "linear_attn_config": {
+            "full_attn_layers": [2], "head_dim": 128, "kda_layers": [1],
+            "num_heads": 2, "short_conv_kernel_size": 4},
+        "num_experts": 2, "router_width": 8, "moe_intermediate_size": 256,
+        "num_experts_per_token": 2, "intermediate_size": 512,
+        "vocab_size": 1024, "n_positions": 512})
+    sizes = configuration.sizes
+    init_zoo_context("kimi step scopes", compute_dtype=sizes["compute_dtype"])
+    model_py = configuration.module("model")
+    model = model_py.build(sizes)
+    est = model._make_estimator()
+    x, y = data.rows(0, 0, 2, sizes)
+    step = est._build_train_step(
+        getattr(model_py.feature_set(x, y, sizes), "device_transform", None),
+        1, est._resolved_plan())
+    params = jax.eval_shape(
+        lambda k: configuration.module("reference").init_params(k, sizes),
+        jax.random.PRNGKey(0))
+    _, state = model.build_params()
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, jax.eval_shape(est.optimizer.init, params), state,
+         np.int32(0), np.int32(0), {"x": x, "y": y}))
+    return step._jitted.lower(*args).compile().as_text()
+
+
+def test_the_step_s_parts_rename_only_pallas_calls_on_the_chip(
+        one_chip, real_kernels, monkeypatch):
+    """The three scopes of ``metrics/tracing.py`` are metadata, and on the
+    chip's compiler they also rename the Pallas calls whose ``op_name``
+    they split (``jvp_jit__kda_walk_forward__.1`` ->
+    ``_kda_walk_forward.1``, the name the backward calls already had): the
+    compiled step without metadata and stack frames, with its instructions
+    numbered in order of their first appearance, is the step compiled with
+    the scopes patched to ``nullcontext``; every name that differs is a
+    ``tpu_custom_call``'s or one of its results'; every kernel carries its
+    part (PERF.md, PR 38)."""
+    import contextlib
+
+    from analytics_zoo_tpu.pipeline.api.keras.layers import self_attention
+    from benchmark import xplane
+
+    scoped = _kimi_step_text(one_chip)
+    monkeypatch.setattr(self_attention, "named_scope",
+                        lambda _name: contextlib.nullcontext())
+    plain = _kimi_step_text(one_chip)
+    line = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$", re.M)
+    metadata = re.compile(r", metadata=\{[^}]*\}")
+
+    def instructions(text):
+        return [(name, metadata.sub("", rest))
+                for name, rest in line.findall(text)]
+
+    def numbered(rows):
+        seen = {}
+        for name, _rest in rows:
+            seen.setdefault(name, str(len(seen)))
+        return [re.sub(r"%([\w.\-]+)", lambda m: "%" + seen.get(
+            m.group(1), m.group(1)), rest) for _name, rest in rows]
+
+    a, b = instructions(scoped), instructions(plain)
+    assert numbered(a) == numbered(b)
+    kernels = {name for name, rest in a if xplane.KERNEL_TARGET in rest}
+    renamed = [(x, y) for (x, rx), (y, _ry) in zip(a, b) if x != y]
+    assert renamed
+    for x, _y in renamed:
+        rest = dict(a)[x]
+        assert x in kernels or any(
+            f"get-tuple-element(%{k})" in rest for k in kernels), x
+    parts = {}
+    for name, op_name in re.findall(
+            r'^\s*(?:ROOT )?%([\w.\-]+) = .*?custom_call_target='
+            r'"tpu_custom_call".*?op_name="([^"]*)"', scoped, re.M):
+        found = [p for p in ("zoo.mixer", "zoo.ffn", "zoo.head")
+                 if p in op_name]
+        assert len(found) == 1, (name, op_name)
+        parts.setdefault(found[0], set()).add(
+            re.sub(r"[^a-z]", "", name.rsplit(".", 1)[0]))
+    assert {"kdawalkforward", "flashfwdpallas"} <= parts["zoo.mixer"]
+    assert {"gmm", "tgmm"} <= parts["zoo.ffn"]
