@@ -129,6 +129,21 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, rng=None,
                            "attn_context")
 
 
+def project_heads(x, kernel, heads, parts=1, bias=None):
+    """``x @ kernel`` for x (B, L, D) and kernel (D, parts * heads * d),
+    written head-major: ``parts`` arrays (B, heads, L, d), or the one.  The
+    product's own output layout takes the place of :func:`split_heads`'
+    transpose, and the gradient's products read the heads where they lie,
+    so neither direction has a pass that only moves the heads."""
+    d_in, width = kernel.shape
+    d = width // (parts * heads)
+    out = jnp.einsum("bld,dphe->pbhle", x,
+                     kernel.reshape(d_in, parts, heads, d))
+    if bias is not None:
+        out = out + bias.reshape(parts, 1, heads, 1, d)
+    return tuple(out) if parts > 1 else out[0]
+
+
 def split_heads(x, n_heads):
     """(B, L, H*D) -> (B, H, L, D)."""
     b, l, hd = x.shape

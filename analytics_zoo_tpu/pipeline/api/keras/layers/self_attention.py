@@ -20,6 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import named_scope
 from jax.ad_checkpoint import checkpoint_name
 
@@ -31,6 +32,7 @@ from analytics_zoo_tpu.metrics.tracing import (
 from analytics_zoo_tpu.ops.attention import (
     dot_product_attention,
     merge_heads,
+    project_heads,
     split_heads,
 )
 from analytics_zoo_tpu.pipeline.api.keras.engine import (
@@ -527,21 +529,27 @@ class _TransformerCore(Layer):
         def latent_attention(u):
             rank, nope, rope, vd = self.latent
             b, l, _ = u.shape
-            q = split_heads(u @ bp["q_kernel"], self.n_head)
-            c, k_r = jnp.split(u @ bp["kv_a_kernel"], [rank], axis=-1)
-            kv = split_heads(
-                _rms_norm(c, bp["kv_a_norm"], self.norm_eps)
-                @ bp["kv_b_kernel"], self.n_head)
             if self.rotary_theta is None:
-                k_r = k_r[:, None]      # no positions: the slices as made
+                # no positions: the slices as made
+                q = split_heads(u @ bp["q_kernel"], self.n_head)
+                c, k_r = jnp.split(u @ bp["kv_a_kernel"], [rank], axis=-1)
+                k_r = k_r[:, None]
             else:
                 # adjacent pairs: the halves side by side (on q and k
                 # alike, so the scores are the pairs' own), then the
-                # rotate-half form
-                q_r, k_r = _rotary(_pairs_to_halves(q[..., nope:]),
-                                   _pairs_to_halves(k_r[:, None]),
-                                   self.rotary_theta)
-                q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+                # rotate-half form; the kernels' columns hold that order,
+                # so the products write it, q's heads as they lie, and
+                # one pass turns the rope lanes and leaves q's nope lanes
+                q = rotary(project_heads(
+                    u, _rope_halves(bp["q_kernel"], self.n_head, rope),
+                    self.n_head), self.rotary_theta, rope)
+                c, k_r = jnp.split(
+                    u @ _rope_halves(bp["kv_a_kernel"], 1, rope), [rank],
+                    axis=-1)
+                k_r = rotary(k_r[:, None], self.rotary_theta)
+            kv = split_heads(
+                _rms_norm(c, bp["kv_a_norm"], self.norm_eps)
+                @ bp["kv_b_kernel"], self.n_head)
             k = jnp.concatenate(
                 [kv[..., :nope],
                  jnp.broadcast_to(k_r, (b, self.n_head, l, rope))], axis=-1)
@@ -559,12 +567,17 @@ class _TransformerCore(Layer):
                 return y
             if "q_kernel" in bp:
                 return latent_attention(u)
-            q, k, v = jnp.split(dense(u, "qkv"), 3, axis=-1)
-            q = split_heads(q, self.n_head)
-            k = split_heads(k, self.n_head)
-            v = split_heads(v, self.n_head)
-            if self.rotary_theta is not None:
-                q, k = _rotary(q, k, self.rotary_theta)
+            if self.rotary_theta is None:
+                q, k, v = (split_heads(x, self.n_head) for x in
+                           jnp.split(dense(u, "qkv"), 3, axis=-1))
+            else:
+                # the heads as the product writes them, and the rotation
+                # one pass over each of q and k (``rotary``)
+                q, k, v = project_heads(
+                    u, bp["qkv_kernel"], self.n_head, parts=3,
+                    bias=bp["qkv_bias"] if self.use_bias else None)
+                q = rotary(q, self.rotary_theta)
+                k = rotary(k, self.rotary_theta)
             a = dot_product_attention(
                 q, k, v, mask=mask,
                 dropout_p=self.attn_drop if training else 0.0,
@@ -638,31 +651,91 @@ def _rms_norm(x, gamma, eps):
     return (x32 * scale * gamma.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rotary(q, k, theta):
-    """Rotary positions 0..L-1 on (B, H, L, hd) queries and keys, the
-    rotate-half form over the whole head: x cos + rotate_half(x) sin with
-    the angle of pair i at position p being p * theta^(-2i/hd).  The
-    tables are float32; the result keeps the inputs' dtype."""
-    l, hd = q.shape[-2], q.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    angles = jnp.arange(l, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+#: Trace-time record of each rotary rotation traced, newest last (as
+#: ``decoder_records``): ``form`` ("fused": the one pass of ``rotary``, from
+#: the projection's head-major output to the turned heads and back, with no
+#: float32 copy and no transpose of its own; "fallback" would name a
+#: rotation taken in passes of its own, which no path takes), ``width`` (the
+#: lanes turned a head) and ``heads``.
+rotary_records: collections.deque = collections.deque(maxlen=256)
+
+
+def rotary(x, theta, turned=None):
+    """Rotary positions 0..L-1 on the head-major x (B, H, L, w), in the
+    rotate-half form over a head's last ``turned`` lanes (all of them by
+    default; the others go through unturned): x cos + rotate_half(x) sin,
+    the angle of pair i at position p being p * theta^(-2i/turned).  One
+    pass each way: the float32 arithmetic stays in the pass and is rounded
+    once to x's dtype, rotate_half is a product with a signed permutation
+    (each output is +-one input, so the product is exact), and the
+    backward is dy cos - rotate_half(dy sin) in the same form."""
+    turned = x.shape[-1] if turned is None else int(turned)
+    rotary_records.append({"form": "fused", "width": turned,
+                           "heads": x.shape[1]})
+    return _rotary(x, float(theta), turned)
+
+
+def _rotary_tables(length, width, theta, turned):
+    """cos and sin (length, width), float32: the angles of the last
+    ``turned`` lanes as the rotate-half form pairs them, 1 and 0 on the
+    lanes before them."""
+    inv_freq = theta ** (-jnp.arange(0, turned, 2, dtype=jnp.float32)
+                         / turned)
+    angles = jnp.arange(length, dtype=jnp.float32)[:, None] \
+        * inv_freq[None, :]
     angles = jnp.concatenate([angles, angles], axis=-1)
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    kept = (length, width - turned)
+    return (jnp.concatenate([jnp.ones(kept), jnp.cos(angles)], axis=-1),
+            jnp.concatenate([jnp.zeros(kept), jnp.sin(angles)], axis=-1))
 
-    def turn(x):
-        x32 = x.astype(jnp.float32)
-        x1, x2 = jnp.split(x32, 2, axis=-1)
-        half = jnp.concatenate([-x2, x1], axis=-1)
-        return (x32 * cos + half * sin).astype(x.dtype)
 
-    return turn(q), turn(k)
+def _rotate_half(width, turned, dtype):
+    """(width, width) signed permutation: x @ it is rotate_half of x's last
+    ``turned`` lanes, (-x2, x1), and 0 on the lanes before them."""
+    half, first = turned // 2, width - turned
+    lanes = np.arange(half) + first
+    m = np.zeros((width, width), np.float32)
+    m[lanes + half, lanes] = -1.0
+    m[lanes, lanes + half] = 1.0
+    return jnp.asarray(m, dtype)
+
+
+def _turn(x, theta, turned, backward):
+    length, width = x.shape[-2:]
+    cos, sin = _rotary_tables(length, width, theta, turned)
+    half = jnp.einsum("...ld,ed->...le" if backward else "...ld,de->...le",
+                      x, _rotate_half(width, turned, x.dtype),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + half * sin).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _rotary(x, theta, turned):
+    return _turn(x, theta, turned, backward=False)
+
+
+# the transpose of rotate_half is -rotate_half: dx = dy cos + (dy2, -dy1)
+# sin, the halves' angles being equal, read from dy as it lies
+_rotary.defvjp(lambda x, theta, turned: (_rotary(x, theta, turned), None),
+               lambda theta, turned, _, dy: (_turn(dy, theta, turned, True),))
 
 
 def _pairs_to_halves(x):
     """(..., 2n) adjacent pairs (x0, x1), (x2, x3), ... -> the pairs' first
-    members, then their second: ``_rotary``'s rotate-half form on the
+    members, then their second: ``rotary``'s rotate-half form on the
     result turns the published pairs (``rope_interleave``)."""
     return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _rope_halves(kernel, heads, rope):
+    """A projection's kernel (D, heads * w) with the last ``rope`` columns
+    of each head in ``_pairs_to_halves``' order: its product is the
+    projection's with those lanes so ordered, column for column."""
+    d, width = kernel.shape
+    k = kernel.reshape(d, heads, width // heads)
+    return jnp.concatenate([k[..., :-rope], _pairs_to_halves(k[..., -rope:])],
+                           axis=-1).reshape(d, width)
 
 
 class TransformerLayer(_TransformerCore):

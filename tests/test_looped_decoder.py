@@ -396,8 +396,10 @@ def test_an_application_keeps_its_context_and_feed_forward_output(cfg, capsys):
             lambda bp: jnp.sum(fn(bp, h) ** 2)))(bp)).count("dot_general")
 
     assert products("full") - products("attn") == 2
-    # made again: qkv, q k^T, the attention's projection, gate, up
-    assert products("attn") - products(None) == 5
+    # made again: qkv, q k^T, the attention's projection, gate, up, and
+    # the rotate-half products of q and k (signed permutations: data
+    # movement on the MXU, no arithmetic)
+    assert products("attn") - products(None) == 7
 
 
 def test_a_plan_rule_overrides_the_layers_policy(cfg, reference, weights,
@@ -544,7 +546,7 @@ def test_the_block_is_assembled_from_its_options(options):
 
 def test_rotary_turns_pairs_by_position():
     q = jnp.ones((1, 1, 4, 8))
-    turned, _ = self_attention._rotary(q, q, 100.0)
+    turned = self_attention.rotary(q, 100.0)
     np.testing.assert_allclose(turned[0, 0, 0], 1.0)        # position 0
     angle = 3 * 100.0 ** (-2 / 8)                           # pair 1 at 3
     np.testing.assert_allclose(

@@ -8,6 +8,7 @@ their references on the chip.
 Keep these in ONE file: only one process may hold the TPU library, and the
 worker that gets this file is the one that loads it."""
 
+import math
 import re
 
 import jax
@@ -211,19 +212,10 @@ def test_kernel_compiles_for_v5e(case, one_chip, real_kernels):
     assert memory.temp_size_in_bytes < 16 << 30  # the chip's memory
 
 
-@pytest.mark.parametrize("policy, forward_calls",
-                         [({}, 1), ({"remat": "full"}, 2)],
-                         ids=["the_layers_own", "full"])
-def test_a_layer_application_runs_the_forward_kernel_once(
-        policy, forward_calls, one_chip, real_kernels):
-    """The gradient of one checkpointed layer application of
-    `ouro-2.6b-fit` (2 x 4,096 tokens, 16 heads of 128, bf16): under the
-    looped decoder's own policy the compiled program calls the Mosaic
-    forward kernel, the one that returns three arrays, once; under
-    ``"full"`` the backward pass calls it again.  dq and dk/dv once each.
-    The kernels are told apart as the benchmark's readers tell them."""
+def _ouro_application_gradient(one_chip, **policy):
+    """The compiled gradient of one checkpointed layer application of
+    `ouro-2.6b-fit` (2 x 4,096 tokens, 16 heads of 128, bf16), as text."""
     from analytics_zoo_tpu.pipeline.api.keras.layers import LoopedDecoder
-    from benchmark import xplane
 
     layer = LoopedDecoder(vocab=49152, n_block=1, n_head=16,
                           hidden_size=2048, intermediate_size=5632, **policy)
@@ -240,14 +232,56 @@ def test_a_layer_application_runs_the_forward_kernel_once(
         lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
                                        sharding=one_chip),
         (blocks, jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16)))
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         *args).compile().as_text()
+
+
+@pytest.mark.parametrize("policy, forward_calls",
+                         [({}, 1), ({"remat": "full"}, 2)],
+                         ids=["the_layers_own", "full"])
+def test_a_layer_application_runs_the_forward_kernel_once(
+        policy, forward_calls, one_chip, real_kernels):
+    """The gradient of one checkpointed layer application of
+    `ouro-2.6b-fit` (2 x 4,096 tokens, 16 heads of 128, bf16): under the
+    looped decoder's own policy the compiled program calls the Mosaic
+    forward kernel, the one that returns three arrays, once; under
+    ``"full"`` the backward pass calls it again.  dq and dk/dv once each.
+    The kernels are told apart as the benchmark's readers tell them."""
+    from benchmark import xplane
+
+    text = _ouro_application_gradient(one_chip, **policy)
     marked = f"/{xplane.KERNEL_TARGET}/"
     returned = sorted(
         int(name.rpartition(marked)[2])
         for name in map(xplane.short_name, text.splitlines())
         if marked in name)
     assert returned == [1, 2] + [3] * forward_calls, returned
+
+
+def test_a_layer_application_writes_no_float32_copy_of_q_or_k(
+        one_chip, real_kernels):
+    """Rotary positions and the head split are one fusion a tensor and
+    direction (``self_attention.rotary`` on ``project_heads``' output):
+    the float32 arithmetic stays inside it, so no instruction outside a
+    fusion of the compiled gradient of one `ouro-2.6b-fit` layer
+    application writes a float32 array of q's size (2 x 4,096 x 16 x 128
+    values, in any shape).  PR 38's program wrote such copies."""
+    text = _ouro_application_gradient(one_chip)
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    size = 2 * 4096 * 16 * 128
+    written = []
+    for name, block in _computations(text).items():
+        if name in fused:
+            continue
+        for line in block.splitlines()[1:]:
+            shape = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) [a-z][\w-]*\(",
+                             line)
+            if shape and any(
+                    math.prod(map(int, dims.split(","))) == size
+                    for dims in re.findall(r"f32\[([\d,]+)\]",
+                                           shape.group(1))):
+                written.append(line.strip()[:160])
+    assert not written, written
 
 
 def _computations(text):
