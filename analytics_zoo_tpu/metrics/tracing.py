@@ -41,7 +41,7 @@ import threading
 import time
 
 __all__ = ["Tracer", "span", "get_tracer", "set_tracer",
-           "MIXER_SCOPE", "FFN_SCOPE", "HEAD_SCOPE"]
+           "MIXER_SCOPE", "FFN_SCOPE", "HEAD_SCOPE", "SSD_SCOPE"]
 
 #: The device's parts of a step, beside the host's spans: the names of the
 #: ``jax.named_scope``s that the decoders open around a block's mixer
@@ -50,6 +50,9 @@ __all__ = ["Tracer", "span", "get_tracer", "set_tracer",
 #: a profiler capture keeps), never an operation; the benchmark reads them
 #: as ``step_mixer_ms``, ``step_ffn_ms`` and ``step_head_ms``.
 MIXER_SCOPE, FFN_SCOPE, HEAD_SCOPE = "zoo.mixer", "zoo.ffn", "zoo.head"
+#: Inside ``zoo.mixer``, around a Mamba-2 mixer's body (in-projection,
+#: convolution, scan, gated norm, out-projection): ``step_ssd_ms``.
+SSD_SCOPE = "zoo.ssd"
 
 # Innermost open span as (name, id), per execution context / thread.
 _current_span: contextvars.ContextVar = contextvars.ContextVar(

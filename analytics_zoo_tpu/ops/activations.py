@@ -23,6 +23,8 @@ _ACTIVATIONS = {
     "gelu": jax.nn.gelu,
     "swish": jax.nn.swish,
     "silu": jax.nn.silu,
+    # relu(x)^2 (Primer, arXiv:2109.08668): the experts' of Nemotron-H
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
     "linear": lambda x: x,
     None: lambda x: x,
 }

@@ -63,7 +63,9 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, rng=None,
     """Batched multi-head attention.
 
     Args:
-      q, k, v: (B, H, L, D) arrays.
+      q, k, v: (B, H, L, D) arrays; k and v may have H_kv heads, H_kv
+        dividing H (grouped-query heads: query head h reads key-value head
+        h // (H / H_kv)).
       mask: optional additive mask broadcastable to (B, H, Lq, Lk) — 0 for
         keep, large-negative for drop (reference BERT attention_mask
         convention) — or a boolean mask (True = keep).
@@ -109,7 +111,19 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, rng=None,
             q, k, v, causal, scale, bias=bias,
             dropout_p=float(dropout_p),
             dropout_seed=rng if dropout_p > 0.0 else None)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if q.ndim == 4 and k.shape[1] != q.shape[1]:
+        # grouped-query heads: query head h reads key-value head
+        # h // (H / H_kv), without a copy of k and v a query head
+        b, h, lq, _ = q.shape
+        g = k.shape[-3]
+        if mask is not None and mask.ndim == 4:
+            mask = mask.reshape(mask.shape[0], -1, h // g if mask.shape[1]
+                                > 1 else 1, *mask.shape[2:])
+        out = dot_product_attention(
+            q.reshape(b, g, h // g, lq, d), k[:, :, None], v[:, :, None],
+            mask, dropout_p, rng, causal, scale, use_flash=False)
+        return out.reshape(b, h, lq, v.shape[-1])
+    scores = jnp.einsum("...qd,...kd->...qk", q, k) * scale
     if causal:
         lq, lk = scores.shape[-2], scores.shape[-1]
         causal_mask = jnp.tril(jnp.ones((lq, lk), bool), lk - lq)
@@ -125,7 +139,7 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, rng=None,
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
     # the context under the name the flash path gives its own (the "attn"
     # recomputation policy of parallel/plan.py keeps it; inert elsewhere)
-    return checkpoint_name(jnp.einsum("bhqk,bhkd->bhqd", probs, v),
+    return checkpoint_name(jnp.einsum("...qk,...kd->...qd", probs, v),
                            "attn_context")
 
 

@@ -67,9 +67,9 @@ from jax.ad_checkpoint import checkpoint_name
 # Trace-time routing counters, as ``flash_attention.invocation_counts``.
 invocation_counts = {"pallas": 0, "fallback": 0}
 
-#: Trace-time record of each chunked call traced, newest last: ``shape``
-#: (B, H, L, d_k, d_v), ``chunk`` C, ``chunks`` a sequence, ``sub_blocks`` a
-#: chunk, ``kernel`` (the walk through the Pallas kernels, or the scan).
+#: Trace-time record of each chunked call traced, newest last: ``rule`` "kda"
+#: (``ops/ssd.py`` keeps "ssd"), ``shape`` (B, H, L, d_k, d_v), ``chunk`` C,
+#: ``chunks``, ``sub_blocks`` a chunk, ``kernel`` (the Pallas walk, or scan).
 chunk_schedules: collections.deque = collections.deque(maxlen=64)
 
 #: tokens a chunk (timed at (2, 32, 4096, 128) on a v5e: forward and
@@ -362,7 +362,7 @@ def chunked_kda(q, k, v, g, beta, *, scale, chunk=None):
         chunks((bf * k.astype(_F32)).astype(cd)),
         chunks((bf * v.astype(_F32)).astype(cd)), gsum)
     chunk_schedules.append({
-        "shape": (b, h, l, dk, dv), "chunk": c, "chunks": n,
+        "rule": "kda", "shape": (b, h, l, dk, dv), "chunk": c, "chunks": n,
         "sub_blocks": c // sub, "kernel": _kernels(count=False)})
     edges = gsum.reshape(b * h, n, c // sub, sub, dk)
     stats = jax.lax.stop_gradient({

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -219,13 +220,14 @@ def _window(bound, top_k, w, u, weights, perm, group_sizes, w_gate, w_up):
     """Window ``w`` of the walk, sorted rows w R .. w R + R - 1, up to the
     hidden rows: which rows of it are held (``live``), its share of each
     held expert's rows (they stay sorted by expert, so the grouped products
-    take them as they are), its tokens' rows ``x``, the two products ``a``
-    and ``b``, the rows' weights ``weight`` and, for the sum over a token's
-    rows, the window's rows in the tokens' order (``by_token``, whose they
-    are, and how many fall in each tile of tokens; one sort of R pairs: the
-    same order from a running count and a search took 1.49 ms on the chip;
-    PERF.md, PR 33).  Rows that are not live are never multiplied, never
-    written and never summed: the kernels walk the sizes."""
+    take them as they are), its tokens' rows ``x``, the products ``a`` (by
+    the gate; None where the experts have none) and ``b``, the rows' weights
+    ``weight`` and, for the sum over a token's rows, the window's rows in
+    the tokens' order (``by_token``, whose they are, and how many fall in
+    each tile of tokens; one sort of R pairs: the same order from a running
+    count and a search took 1.49 ms on the chip; PERF.md). Rows that
+    are not live are never multiplied, never written and never summed: the
+    kernels walk the sizes."""
     from analytics_zoo_tpu.ops.pallas.grouped_matmul import (
         grouped_matmul,
         token_tile,
@@ -247,10 +249,23 @@ def _window(bound, top_k, w, u, weights, perm, group_sizes, w_gate, w_up):
         whose[:, None] // token_tile(t)
         == jnp.arange(t // token_tile(t))[None, :], axis=0, dtype=jnp.int32)
     return {"live": live, "sizes": sizes, "picks": picks, "token": token,
-            "x": x, "a": grouped_matmul(x, w_gate, sizes),
+            "x": x,
+            "a": None if w_gate is None else grouped_matmul(x, w_gate, sizes),
             "b": grouped_matmul(x, w_up, sizes),
             "weight": _rows(weights.reshape(-1), picks)[:, None],
             "by_token": (order, whose, tile_sizes)}
+
+
+def _hidden(activation, *rows):
+    """An expert's hidden rows from its input products: act(a) * b where
+    the expert is gated, act(b) where it is not."""
+    return activation(rows[0]) * rows[1] if len(rows) == 2 \
+        else activation(rows[0])
+
+
+def _ins(w_gate, w_up):
+    """The experts' input matrices: (gate, up), or (up,) without a gate."""
+    return (w_up,) if w_gate is None else (w_gate, w_up)
 
 
 def _sum_by_token(rows, by_token, total):
@@ -276,7 +291,8 @@ def _walk(bound, top_k, activation, u, weights, perm, group_sizes,
           w_gate, w_up, w_down):
     """The held experts' part of (T, D) tokens ``u``, window by window:
     ``weights`` (T, k) are the assignments' and ``perm`` their sorted
-    order, padded to whole windows.  The parts are summed in float32.  A
+    order, padded to whole windows; ``w_gate`` None for experts without a
+    gate.  The parts are summed in float32.  A
     loop whose trip count the device reads is not reverse-differentiable,
     so the backward rule is a loop too: it keeps the operands only, makes a
     window's hidden rows again and pulls the cotangent through them (under
@@ -290,7 +306,8 @@ def _walk(bound, top_k, activation, u, weights, perm, group_sizes,
 
     def body(w, y):
         at = _window(bound, top_k, w, *operands[:6])
-        f = (activation(at["a"]) * at["b"]).astype(u.dtype)
+        rows = (at["b"],) if w_gate is None else (at["a"], at["b"])
+        f = _hidden(activation, *rows).astype(u.dtype)
         part = grouped_matmul(f, w_down, at["sizes"])
         return _sum_by_token(part * at["weight"].astype(part.dtype),
                              at["by_token"], y)
@@ -307,13 +324,15 @@ def _walk_bwd(bound, top_k, activation, operands, g):
 
     u, weights, perm, group_sizes, w_gate, w_up, w_down = operands
 
+    ins = _ins(w_gate, w_up)
+
     def body(w, sums):
-        du, d_weight, d_gate, d_up, d_down = sums
+        du, d_weight, *d_ins, d_down = sums
         at = _window(bound, top_k, w, *operands[:6])
         sizes, weight = at["sizes"], at["weight"]
         f, pull = jax.vjp(
-            lambda a, b: (activation(a) * b).astype(u.dtype),
-            at["a"], at["b"])
+            lambda *rows: _hidden(activation, *rows).astype(u.dtype),
+            *((at["b"],) if w_gate is None else (at["a"], at["b"])))
         # y = (f Wdown) weight: the cotangent of a row before its weight,
         # through Wdown once, serves f's and the weight's gradient alike
         g_rows = _rows(g, at["token"])
@@ -325,18 +344,21 @@ def _walk_bwd(bound, top_k, activation, operands, g):
             mode="promise_in_bounds")
         d_down = grouped_matmul_transposed(
             f * weight.astype(f.dtype), g_rows, sizes, d_down)
-        da, db = pull(df * weight.astype(df.dtype))
-        d_gate = grouped_matmul_transposed(at["x"], da, sizes, d_gate)
-        d_up = grouped_matmul_transposed(at["x"], db, sizes, d_up)
-        dx = grouped_matmul(da, w_gate, sizes, transpose_rhs=True) \
-            + grouped_matmul(db, w_up, sizes, transpose_rhs=True)
-        return (_sum_by_token(dx, at["by_token"], du), d_weight,
-                d_gate, d_up, d_down)
+        d_rows = pull(df * weight.astype(df.dtype))
+        d_ins = [grouped_matmul_transposed(at["x"], d, sizes, total)
+                 for d, total in zip(d_rows, d_ins)]
+        dx = functools.reduce(operator.add, (
+            grouped_matmul(d, w_in, sizes, transpose_rhs=True)
+            for d, w_in in zip(d_rows, ins)))
+        return (_sum_by_token(dx, at["by_token"], du), d_weight, *d_ins,
+                d_down)
 
     du, d_weight, *d_experts = _windows(bound, perm, group_sizes, body, (
         jnp.zeros(u.shape, jnp.float32),
         jnp.zeros(weights.size, weights.dtype),
-        *(jnp.zeros_like(x) for x in (w_gate, w_up, w_down))))
+        *(jnp.zeros_like(x) for x in (*ins, w_down))))
+    if w_gate is None:
+        d_experts = [None, *d_experts]
     return (du.astype(u.dtype), d_weight.reshape(weights.shape), None, None,
             *d_experts)
 
@@ -352,7 +374,9 @@ def held_experts_ffn(u, router, score_bias, w_gate, w_up, w_down, *,
                      activation=jax.nn.silu):
     """The part that the experts held here give of a routed feed-forward,
     for (T, D) tokens: sum over a token's top-k experts e that are held of
-    w_e (act(u Wgate_e) * (u Wup_e)) Wdown_e.
+    w_e (act(u Wgate_e) * (u Wup_e)) Wdown_e, or of w_e act(u Wup_e)
+    Wdown_e where ``w_gate`` is None (experts without a gate: two grouped
+    products a window where gated experts take three).
 
     One expert-parallel worker's share: ``router`` (D, E) and
     ``score_bias`` (E,) are the whole router's, ``w_gate``/``w_up``
@@ -381,7 +405,7 @@ def held_experts_ffn(u, router, score_bias, w_gate, w_up, w_down, *,
     ``walk_windows`` (the windows the held rows fill)."""
     from jax.ad_checkpoint import checkpoint_name
 
-    t, held = u.shape[0], w_gate.shape[0]
+    t, held = u.shape[0], w_up.shape[0]
     experts, weights = sigmoid_route(u, router, score_bias, top_k=top_k,
                                      routed_scale=routed_scale)
     # sorted by (held expert, then the rest), stably: rows of one expert

@@ -125,8 +125,28 @@ def _normalize_seed(seed):
 # ---------------------------------------------------------------------------
 
 
+def _per_query_head(x, heads):
+    """(B, H_kv, L, d) -> (B, ``heads``, L, d), query head h reading
+    key-value head h // (heads / H_kv): the plain form of grouped-query
+    heads, for the fallback and the oracle (the kernels read a shared head
+    where it lies)."""
+    share = heads // x.shape[1]
+    return x if share == 1 else jnp.repeat(x, share, axis=1)
+
+
+def _per_kv_head(dx, kv_heads):
+    """The transpose of ``_per_query_head``: a key-value head's cotangent
+    is the sum of its query heads', in float32."""
+    b, heads = dx.shape[:2]
+    if heads == kv_heads:
+        return dx
+    return jnp.sum(dx.astype(jnp.float32).reshape(
+        (b, kv_heads, heads // kv_heads) + dx.shape[2:]), axis=2)
+
+
 def _attention_reference(q, k, v, causal, scale, bias=None, q_seg=None,
                          kv_seg=None, dropout_p=0.0, seed=None):
+    k, v = (_per_query_head(x, q.shape[1]) for x in (k, v))
     scores = jnp.einsum("bhqd,bhkd->bhqk",
                         q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     lq, lk = scores.shape[-2], scores.shape[-1]
@@ -284,9 +304,10 @@ _WALK_VMEM_BYTES = 64 << 20
 
 #: Trace-time record of each kernel's schedule, newest last: ``kernel``
 #: ("forward", "dq", "dkv"), ``shape`` (b, h, lq, lk, d) with d the width
-#: of q and k, ``value_width`` (that of v and of the output, which latent
-#: attention makes unlike d), ``blocks`` (block_q, block_k) as resolved, ``operand_dtype`` of the matrix
-#: products, ``schedule`` ("unrolled", "walk", "looped" or "streamed"),
+#: of q and k, ``kv_heads`` (the heads of k and v: h, or fewer where query
+#: heads share them), ``value_width`` (that of v and of the output, which
+#: latent attention makes unlike d), ``blocks`` (block_q, block_k) as
+#: resolved, ``operand_dtype`` of the matrix products, ``schedule`` ("unrolled", "walk", "looped" or "streamed"),
 #: ``tiles_per_body`` (the independent tiles one basic block issues: the
 #: visits of the step on the unrolled path, the sub-tiles of the step's
 #: rows on the walk, 1 on a loop of one tile a body), ``resident_rows``
@@ -559,7 +580,8 @@ def _record_schedule(kernel, q, v, plan: _Plan, causal, all_masked):
         per_body = plain + masked if plan.whole else 1
     tile_schedules.append({
         "kernel": kernel, "shape": (b, h, lq, lk, d),
-        "value_width": v.shape[3], "blocks": (block_q, block_k), "operand_dtype": str(q.dtype),
+        "kv_heads": v.shape[1], "value_width": v.shape[3],
+        "blocks": (block_q, block_k), "operand_dtype": str(q.dtype),
         "skipped": total - plain - masked, "plain": plain,
         "masked": masked, "schedule": plan.schedule,
         "tiles_per_body": per_body,
@@ -753,6 +775,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
 
     b, h, lq, d = q.shape
     lk, dv = k.shape[2], v.shape[3]   # v and the output have their own width
+    share = h // k.shape[1]   # query heads a key-value head serves
     offset = lk - lq  # end-aligned causal diagonal
     has_bias = bias is not None
     has_seg = q_seg is not None
@@ -885,7 +908,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         return (bi, hi, gi, 0)
 
     def k_side(bi, hi, gi, ci):
-        return (bi, hi, ci, 0)
+        return (bi, hi // share if share > 1 else hi, ci, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, rows_q, d), q_side, memory_space=pltpu.VMEM),
@@ -1057,6 +1080,9 @@ def _flash_bwd_pallas(q, k, v, g, out, m, l, causal, scale,
             block_k, interpret, bias, q_seg, kv_seg, dropout_p, seed)
     (dq,) = _flash_bwd_kernel("dq", *rest)
     dk, dv, *db = _flash_bwd_kernel("dkv", *rest)
+    if k.shape[1] != q.shape[1]:
+        dk, dv = (_per_kv_head(dx, k.shape[1]).astype(x.dtype)
+                  for dx, x in ((dk, k), (dv, v)))
     if bias is None:
         return dq, dk, dv, None
     # (b, h, 1, lk) per-(b, h) partials
@@ -1080,6 +1106,7 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
     q, k, v = operands[:3]
     b, h, lq, d = q.shape
     lk, dv = k.shape[2], v.shape[3]   # v and dO have their own width
+    share = h // k.shape[1]   # query heads a key-value head serves
     offset = lk - lq
     has_bias = bias is not None
     has_seg = q_seg is not None
@@ -1309,7 +1336,8 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
             return (bi, hi, order(g2, g3)[0], 0)
 
         def im_k(bi, hi, g2, g3):
-            return (bi, hi, order(g2, g3)[1], 0)
+            return (bi, hi // share if share > 1 else hi,
+                    order(g2, g3)[1], 0)
 
         def im_stat(bi, hi, g2, g3):
             return (bi, hi, 0, order(g2, g3)[0])
@@ -1383,8 +1411,11 @@ def _flash_bwd_kernel(kernel, operands, causal, scale, block_q, block_k,
                      lambda bi, hi, gi, ci: (bi, hi, gi, 0),
                      memory_space=pltpu.VMEM),
     ]
-    kv_out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
-                    jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    # a query head's share of its key-value head's gradient, in float32
+    # where several heads share one (summed by `_flash_bwd_pallas`)
+    kv_out_shape = [jax.ShapeDtypeStruct(
+        (b, h) + x.shape[2:], x.dtype if share == 1 else jnp.float32)
+        for x in (k, v)]
     kv_scratch = [pltpu.VMEM((rows_k, d), jnp.float32),
                   pltpu.VMEM((rows_k, dv), jnp.float32)]
     if has_bias:
@@ -1486,6 +1517,7 @@ def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
     q, k, v, bias, q_seg, kv_seg, seed, out, m_s, l_s = res
     b, h, lq, d = q.shape
     lk, dv = k.shape[2], v.shape[3]
+    kv_heads, kv_dtype = k.shape[1], k.dtype
     scale_v = 1.0 / math.sqrt(d) if scale is None else scale
     offset = lk - lq
     has_bias = bias is not None
@@ -1514,6 +1546,7 @@ def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
     bk = min(block_k or 256, 256, lk)
     n_k = -(-lk // bk)
     pad = n_k * bk - lk
+    k, v = (_per_query_head(x, h) for x in (k, v))
 
     qf = q.astype(jnp.float32)
     gf = g.astype(jnp.float32)
@@ -1626,8 +1659,9 @@ def _bwd(causal, scale, dropout_p, block_q, block_k, res, g):
             bb, bh, bq, n_k * bk)[..., :lk].astype(bias.dtype)
     else:
         dbias = None
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
-            dbias, dseg_q, dseg_kv, dseed)
+    return (dq.astype(q.dtype), _per_kv_head(dk, kv_heads).astype(kv_dtype),
+            _per_kv_head(dv, kv_heads).astype(kv_dtype), dbias, dseg_q,
+            dseg_kv, dseed)
 
 
 _flash_core.defvjp(_fwd, _bwd)
@@ -1642,7 +1676,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
       q, k, v: (B, H, L, D); v may have a last dimension of its own, Dv
         (latent attention: q and k at 192, v at 128).  The output is then
         (B, H, Lq, Dv), the default scale stays 1/sqrt(D), and all three
-        kernels read both widths from the operands' shapes.
+        kernels read both widths from the operands' shapes.  k and v may
+        have fewer heads than q, H_kv dividing H (grouped-query heads):
+        query head h reads key-value head h // (H / H_kv) where it lies,
+        and a key-value head's gradient is the sum of its query heads'.
       bias: optional additive f32 mask/bias, shape (B|1, H|1, Lq|1, Lk) —
         the BERT (B, 1, 1, L) padding mask streams as (1, block_k) tiles.
       q_segment_ids / kv_segment_ids: optional (B, Lq)/(B, Lk) int arrays;
@@ -1666,6 +1703,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     b, h, lq, d = q.shape
     lk = k.shape[2]
     scale = 1.0 / math.sqrt(d) if scale is None else scale
+    if h % k.shape[1] or v.shape[1] != k.shape[1]:
+        raise ValueError(f"{h} query heads over {k.shape[1]} key and "
+                         f"{v.shape[1]} value heads")
     if bias is not None:
         bias = jnp.asarray(bias)
         if bias.ndim != 4 or bias.shape[3] != lk or \

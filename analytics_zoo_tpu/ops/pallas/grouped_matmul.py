@@ -40,6 +40,12 @@ invocation_counts = {"pallas": 0, "fallback": 0}
 ROWS, CONTRACTION, COLUMNS = 256, 2048, 1024
 #: The tokens a group of ``grouped_row_sum`` covers: one pass of the MXU wide.
 TOKENS = 128
+#: Bytes of the float32 (contraction, columns) accumulator of the weights'
+#: gradient: what (CONTRACTION / 2, COLUMNS) holds.  A width that no
+#: multiple of 128 divides is a tile whole (an expert of 1,856), and the
+#: other side's tile is then cut to keep the sum at this size: at (896,
+#: 1856) the kernel asked for 20.6 MB of the 16 MB of scoped VMEM.
+ACCUMULATOR = 4 * (CONTRACTION // 2) * COLUMNS
 
 
 def _env_flag(name: str) -> bool:
@@ -116,8 +122,20 @@ def grouped_matmul_transposed(lhs, g, group_sizes, existing_out):
     # the float32 accumulator's (contraction, columns) tile is the large one
     return _megablox().tgmm(
         lhs.swapaxes(0, 1), g, group_sizes, existing_out.dtype,
-        (_rows_tile(m), _tile(k, CONTRACTION // 2), _tile(n, COLUMNS)),
+        (_rows_tile(m), *_accumulator_tiles(k, n)),
         existing_out=existing_out, interpret=_interpret_forced())
+
+
+def _accumulator_tiles(k: int, n: int) -> tuple[int, int]:
+    """(contraction, columns) tiles of the weights' gradient whose float32
+    sum stays within ``ACCUMULATOR``: the contraction's cut first, then
+    the columns'."""
+    tk, tn = _tile(k, CONTRACTION // 2), _tile(n, COLUMNS)
+    if 4 * tk * tn > ACCUMULATOR:
+        tk = _tile(k, max(ACCUMULATOR // (4 * tn), 128))
+    if 4 * tk * tn > ACCUMULATOR:
+        tn = _tile(n, max(ACCUMULATOR // (4 * tk), 128))
+    return tk, tn
 
 
 def token_tile(tokens: int) -> int:

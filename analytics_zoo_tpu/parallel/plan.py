@@ -103,13 +103,14 @@ REMAT_POLICIES = ("full", "dots", "attn")
 #: permutation and the group sizes).  ``"kda_state"`` is the chunks'
 #: incoming states of the chunked gated delta rule, which its backward
 #: walk reads (``ops/linear_attention.py``, named in the forward rule with
-#: the rule's output, which is an ``"attn_context"``).
+#: the rule's output, which is an ``"attn_context"``); ``"ssd_state"``
+#: the same of Mamba-2's chunked rule (``ops/ssd.py``).
 #: So under ``"attn"`` the backward pass makes the norms, q, k, v and the
 #: other products of the scope again, but runs no attention forward, no
 #: down-projection, no sort and no walk over a sequence's chunks a second
 #: time (measured: ``PERF.md``, PR 29).
 REMAT_KEPT_NAMES = {"attn": ("attn_context", "ffn_out", "moe_route",
-                             "kda_state")}
+                             "kda_state", "ssd_state")}
 
 #: dtype ROLES a plan's ``dtype_rules`` may map a path to.  A role is
 #: not a raw dtype: it names the leaf's job in the precision plane.

@@ -16,6 +16,7 @@ sharded) variants swap in without touching this layer.  Long-context support
 from __future__ import annotations
 
 import collections
+import dataclasses
 import functools
 
 import jax
@@ -40,10 +41,24 @@ from analytics_zoo_tpu.pipeline.api.keras.engine import (
     current_targets,
     get_initializer,
 )
+from analytics_zoo_tpu.pipeline.api.keras.layers.ssd_mixer import SSDMixer
 
 
 def _dense_init(rng, shape, std):
     return std * jax.random.normal(rng, shape)
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """The record of a block's routed feed-forward (``_TransformerCore``'s
+    ``layout``): ``gated`` experts are (act(u Wgate) * (u Wup)) Wdown, the
+    others act(u Wup) Wdown; ``activation`` by name.  The sizes are the
+    core's routed arguments."""
+
+    gated: bool = True
+    activation: str = "silu"
+
+    kind = "routed"
 
 
 class _TransformerCore(Layer):
@@ -60,8 +75,8 @@ class _TransformerCore(Layer):
                  routed_experts=0, experts_held=None, experts_held_from=0,
                  experts_per_token=None, expert_size=None, shared_experts=0,
                  routed_scale=1.0, leading_dense=0, kda_heads=None,
-                 kda_head_dim=None, kda_conv_size=4, input_shape=None,
-                 name=None, **kwargs):
+                 kda_head_dim=None, kda_conv_size=4, layout=None,
+                 input_shape=None, name=None, **kwargs):
         super().__init__(input_shape=input_shape, name=name, **kwargs)
         self.n_block = int(n_block)
         self.n_head = int(n_head)
@@ -106,6 +121,14 @@ class _TransformerCore(Layer):
         #     ``expert_size`` wide; beside them ``shared_experts`` x
         #     ``expert_size`` of gated feed-forward on every token.  The
         #     blocks before ``leading_dense`` keep the dense feed-forward.
+        #   layout: the blocks as records in place of ``attention`` and
+        #     ``leading_dense``, a (mixer, feed-forward) pair a block: the
+        #     mixer an ``ssd_mixer.SSDMixer`` ("ssd", Mamba-2) or a
+        #     ``grouped_attention.GroupedQueryAttention`` ("full" with
+        #     key-value heads of its own and no positions), the
+        #     feed-forward an ``Experts`` record (routed, gated or not, as
+        #     the routed arguments size it) or None (a block without one).
+        #     The mixers of one kind share one record.
         if norm not in ("layer", "rms"):
             raise ValueError(f"norm must be 'layer' or 'rms'; got {norm!r}")
         if norm_placement not in ("after", "before", "around"):
@@ -121,12 +144,29 @@ class _TransformerCore(Layer):
         if moe_experts and (self.gated_ffn or not self.use_bias):
             raise ValueError("the routed feed-forward is the plain one "
                              "with biases: no gated_ffn, no use_bias=False")
+        self.layout = None if layout is None else tuple(
+            (mixer, ffn) for mixer, ffn in layout)
+        #: the records of the layout's mixers and feed-forwards by kind
+        self.records = {}
+        if self.layout is not None:
+            if len(self.layout) != self.n_block:
+                raise ValueError(f"a layout of {len(self.layout)} blocks "
+                                 f"for {self.n_block}")
+            for record in (x for pair in self.layout for x in pair
+                           if x is not None):
+                if self.records.setdefault(record.kind, record) != record:
+                    raise ValueError(
+                        f"the {record.kind!r} blocks differ: "
+                        f"{self.records[record.kind]} and {record}")
+            attention = [mixer.kind for mixer, _ in self.layout]
         by_layer = (attention,) * self.n_block \
             if isinstance(attention, str) else tuple(attention)
+        kinds = ("full", "latent", "kda") + (
+            ("ssd",) if self.layout is not None else ())
         if len(by_layer) != self.n_block or any(
-                kind not in ("full", "latent", "kda") for kind in by_layer):
+                kind not in kinds for kind in by_layer):
             raise ValueError(
-                "attention must be 'full', 'latent' or 'kda', or one of "
+                f"attention must be one of {kinds}, or one of "
                 f"them for each of the {self.n_block} blocks; got "
                 f"{attention!r}")
         #: the kind of every block, and the one name where all are alike
@@ -152,12 +192,27 @@ class _TransformerCore(Layer):
             #: low-rank gates too), taps of the causal convolutions
             self.kda = (int(kda_heads or self.n_head), width,
                         int(kda_conv_size))
+        #: the SSD mixers' and the grouped-query attention's records
+        self.ssd = self.records.get("ssd")
+        self.grouped = self.records.get("full")
         self.routed_experts = int(routed_experts)
         self.leading_dense = int(leading_dense)
+        #: the routed experts' record: the layout's, or the block's own
+        #: feed-forward's form
+        self.experts = self.records.get("routed") or Experts(
+            gated=self.gated_ffn)
+        if "routed" in self.records and not self.routed_experts:
+            raise ValueError("a layout's Experts record needs the routed "
+                             "arguments: routed_experts is 0")
         if self.routed_experts:
-            if moe_experts or not self.gated_ffn or self.use_bias:
-                raise ValueError("routed_experts are gated feed-forwards "
-                                 "without bias, and not moe_experts'")
+            if moe_experts or self.use_bias or not (
+                    self.experts.gated or self.layout is not None):
+                raise ValueError(
+                    "routed_experts are feed-forwards without bias, gated "
+                    "(act(u Wgate) * (u Wup)) Wdown as gated_ffn says, or "
+                    "ungated act(u Wup) Wdown by a layout's Experts record; "
+                    f"not moe_experts' (got {self.experts}, use_bias="
+                    f"{self.use_bias}, moe_experts={moe_experts})")
             self.experts_held = int(experts_held or routed_experts)
             self.experts_held_from = int(experts_held_from)
             self.experts_per_token = int(experts_per_token)
@@ -213,6 +268,8 @@ class _TransformerCore(Layer):
         from analytics_zoo_tpu.ops.activations import get_activation
 
         self.act = get_activation(activation)
+        self.expert_act = self.act if "routed" not in self.records \
+            else get_activation(self.experts.activation)
 
     # -- param construction (nested; overrides the flat-spec default) ------
     @property
@@ -272,8 +329,18 @@ class _TransformerCore(Layer):
         }
 
     def _is_routed(self, index):
+        if self.layout is not None:
+            return index is not None and self.layout[index][1] is not None
         return bool(self.routed_experts) and index is not None \
             and index >= self.leading_dense
+
+    def _has_ffn(self, index):
+        """Whether block ``index`` has a feed-forward branch: a layout's
+        block without a feed-forward record has none."""
+        return self.layout is None or self._is_routed(index)
+
+    def _norm_count(self, index):
+        return self._n_norms if self._has_ffn(index) else self._n_norms // 2
 
     def _block_params(self, rng, index=None):
         """One block's leaves; ``index`` tells a routed block from the
@@ -288,6 +355,8 @@ class _TransformerCore(Layer):
         kind = self._attention_kind(index)
         if kind == "kda":
             p = self._kda_params(jax.random.fold_in(rng, 2))
+        elif kind in self.records:
+            p = self.records[kind].params(jax.random.fold_in(rng, 3), d, std)
         elif kind == "latent":
             rank, nope, rope, vd = self.latent
             h = self.n_head
@@ -306,28 +375,32 @@ class _TransformerCore(Layer):
                 "proj_kernel": _dense_init(ks[1], (d, d), std),
             }
             bias = {"qkv_bias": 3 * d, "proj_bias": d}
-        for i in range(1, self._n_norms + 1):
+        for i in range(1, self._norm_count(index) + 1):
             p[f"ln{i}_gamma"] = jnp.ones((d,))
             if self.norm == "layer":
                 p[f"ln{i}_beta"] = jnp.zeros((d,))
+        if not self._has_ffn(index):
+            return p
         if self._is_routed(index):
             e, held, f = self.routed_experts, self.experts_held, \
                 self.expert_size
+            gated = self.experts.gated
             p.update({
                 "router_kernel": _dense_init(next(more), (d, e), std),
                 # DeepSeek-V3's e_score_correction_bias: it picks, takes no
                 # gradient, and is a load balancer's to set; 0 picks by
                 # the scores alone
                 "router_bias": jnp.zeros((e,)),
-                "experts_gate": _dense_init(next(more), (held, d, f), std),
+                **({"experts_gate": _dense_init(next(more), (held, d, f),
+                                                std)} if gated else {}),
                 "experts_up": _dense_init(next(more), (held, d, f), std),
                 "experts_down": _dense_init(next(more), (held, f, d), std),
             })
             if self.shared_experts:
                 sf = self.shared_experts * f
                 p.update({
-                    "shared_gate_kernel": _dense_init(next(more), (d, sf),
-                                                      std),
+                    **({"shared_gate_kernel": _dense_init(
+                        next(more), (d, sf), std)} if gated else {}),
                     "shared_fc_kernel": _dense_init(next(more), (d, sf), std),
                     "shared_out_kernel": _dense_init(next(more), (sf, d),
                                                      std),
@@ -376,10 +449,13 @@ class _TransformerCore(Layer):
     def _per_block_param_count(self, index=None):
         d, m = self.hidden_size, self.intermediate_size
         b = 1 if self.use_bias else 0
-        norms = self._n_norms * d * (2 if self.norm == "layer" else 1)
+        norms = self._norm_count(index) * d * (2 if self.norm == "layer"
+                                               else 1)
         attn = 3 * d * d + d * d + b * 4 * d + norms    # qkv + proj + norms
         kind = self._attention_kind(index)
-        if kind == "kda":
+        if kind in self.records:
+            attn = self.records[kind].param_count(d) + norms
+        elif kind == "kda":
             heads, width, taps = self.kda
             wide, rank = heads * width, width
             attn = 4 * d * wide + 3 * taps * wide + 2 * d * rank \
@@ -390,10 +466,13 @@ class _TransformerCore(Layer):
             h = self.n_head
             attn = d * h * (nope + rope) + d * (rank + rope) + rank \
                 + rank * h * (nope + vd) + h * vd * d + norms
+        if not self._has_ffn(index):
+            return attn
         if self._is_routed(index):
             f = self.expert_size
             return attn + d * self.routed_experts + self.routed_experts \
-                + 3 * d * f * (self.experts_held + self.shared_experts)
+                + (2 + self.experts.gated) * d * f \
+                * (self.experts_held + self.shared_experts)
         if self.moe_experts:
             e = self.moe_experts
             return attn + d * e + e * (2 * d * m + m) + d
@@ -565,6 +644,11 @@ class _TransformerCore(Layer):
                 y, stats = self._kda_mixer(bp, u)
                 aux = {"kda_" + key: value for key, value in stats.items()}
                 return y
+            if "ssd_in_kernel" in bp:
+                y, aux = self.ssd(bp, u, self.norm_eps)
+                return y
+            if "attn_q_kernel" in bp:
+                return self.grouped(bp, u, causal=not self.bidirectional)
             if "q_kernel" in bp:
                 return latent_attention(u)
             if self.rotary_theta is None:
@@ -592,6 +676,12 @@ class _TransformerCore(Layer):
                 * (u @ bp[prefix + "fc_kernel"])
             return f @ bp[prefix + "out_kernel"]
 
+        def shared(u):
+            if "shared_gate_kernel" in bp:
+                return gated(u, "shared_")
+            return self.expert_act(u @ bp["shared_fc_kernel"]) \
+                @ bp["shared_out_kernel"]
+
         def feed_forward(u):
             nonlocal aux, drop
             if "router_kernel" in bp:
@@ -602,14 +692,16 @@ class _TransformerCore(Layer):
                 # KDA mixer's numbers where the block has one)
                 f, route = held_experts_ffn(
                     u.reshape(b * l, d), bp["router_kernel"],
-                    bp["router_bias"], bp["experts_gate"], bp["experts_up"],
-                    bp["experts_down"], first_held=self.experts_held_from,
+                    bp["router_bias"], bp.get("experts_gate"),
+                    bp["experts_up"], bp["experts_down"],
+                    first_held=self.experts_held_from,
                     top_k=self.experts_per_token,
-                    routed_scale=self.routed_scale, activation=self.act)
+                    routed_scale=self.routed_scale,
+                    activation=self.expert_act)
                 aux = {**aux, **route} if isinstance(aux, dict) else route
                 f = f.reshape(b, l, d)
-                if "shared_gate_kernel" in bp:
-                    f = f + gated(u, "shared_")
+                if "shared_fc_kernel" in bp:
+                    f = f + shared(u)
                 return checkpoint_name(f, "ffn_out")
             if "moe_gate" in bp:
                 from analytics_zoo_tpu.ops.moe import routed_ffn
@@ -636,8 +728,11 @@ class _TransformerCore(Layer):
         # ``op_name`` (metadata only: ``metrics/tracing.py``)
         with named_scope(MIXER_SCOPE):
             h = self._branch(attention, h, bp, 1, training, brng, 1)
-        with named_scope(FFN_SCOPE):
-            h = self._branch(feed_forward, h, bp, 1 + n, training, brng, 2)
+        # static: a block without a feed-forward has no norm for one
+        if f"ln{1 + n}_gamma" in bp:
+            with named_scope(FFN_SCOPE):
+                h = self._branch(feed_forward, h, bp, 1 + n, training, brng,
+                                 2)
         return h, aux, drop
 
 
@@ -1206,7 +1301,11 @@ class LoopedDecoder(_TransformerCore):
 #: ``capacity_factor`` (None: the routed layer has none and drops nothing),
 #: ``attention`` (the mixer where every layer has the same, "by_layer"
 #: otherwise) with ``attention_by_layer`` (every layer's: "latent" or
-#: "kda"), ``rotary`` (whether latent attention turns its rope slices),
+#: "kda", or a layout's "ssd" or "full"), ``ffn_by_layer`` (every block's
+#: feed-forward: "dense", "routed" or None), ``experts_gated``,
+#: ``kv_heads`` (the grouped-query attention's; None without it), ``ssd``
+#: (heads, a head's width, state, groups, taps; None without an SSD layer),
+#: ``rotary`` (whether latent attention turns its rope slices),
 #: ``kda`` (heads, a head's width and convolution taps; None without such a
 #: layer), ``qk_width`` and ``value_width`` of a latent head, ``remat`` and
 #: ``kept`` (the policy of a layer
@@ -1237,6 +1336,13 @@ class LatentMoEDecoder(_TransformerCore):
     convolutions and recurrence).  With a KDA layer the state also carries, a KDA layer,
     ``kda_chunk_log_decay_min``, ``kda_sub_block_log_decay_min`` and
     ``kda_state_rms`` (``ops.linear_attention.chunked_kda``'s numbers).
+    ``layout`` gives the blocks as records in place of ``attention`` and
+    ``leading_dense`` (``_TransformerCore``): Mamba-2 mixers
+    (``ssd_mixer.SSDMixer``, whose layers' state carries
+    ``SSD_NUMBERS``), grouped-query attention without positions
+    (``grouped_attention.GroupedQueryAttention``), routed experts gated or
+    not (``Experts``), and blocks without a feed-forward; the latent
+    attention's sizes are then not asked for.
 
     Trained with ``loss="next_token_cross_entropy"`` the layer takes the
     mean cross-entropy itself in blocks of ``loss_block`` tokens, the
@@ -1262,8 +1368,9 @@ class LatentMoEDecoder(_TransformerCore):
     """
 
     def __init__(self, vocab, n_block, n_head, hidden_size,
-                 intermediate_size, kv_latent_rank, qk_nope_dim, qk_rope_dim,
-                 v_head_dim, routed_experts, experts_per_token, expert_size,
+                 intermediate_size, kv_latent_rank=None, qk_nope_dim=None,
+                 qk_rope_dim=None, v_head_dim=None, routed_experts=0,
+                 experts_per_token=None, expert_size=None,
                  experts_held=None, experts_held_from=0, shared_experts=0,
                  routed_scale=1.0, leading_dense=1, rotary_theta=1e6,
                  norm_eps=1e-6, loss_block=2048, remat="attn",
@@ -1283,11 +1390,17 @@ class LatentMoEDecoder(_TransformerCore):
             leading_dense=leading_dense, **kwargs)
         self.vocab = int(vocab)
         self.loss_block = int(loss_block)
-        self.n_routed = max(self.n_block - self.leading_dense, 0)
+        #: every block's feed-forward: "dense", "routed" or None (none)
+        self.ffn_by_layer = tuple(
+            "routed" if self._is_routed(i)
+            else "dense" if self._has_ffn(i) else None
+            for i in range(self.n_block))
+        self.n_routed = self.ffn_by_layer.count("routed")
         self.n_kda = self.attention_by_layer.count("kda")
-        if "full" in self.attention_by_layer:
+        self.n_ssd = self.attention_by_layer.count("ssd")
+        if "full" in self.attention_by_layer and self.grouped is None:
             raise ValueError("LatentMoEDecoder mixes by latent attention "
-                             "or by KDA; got "
+                             "or by KDA, or by a layout's records; got "
                              f"{list(self.attention_by_layer)}")
 
     def build(self, input_shape):
@@ -1316,6 +1429,8 @@ class LatentMoEDecoder(_TransformerCore):
     #: what the state carries a KDA layer: ``chunked_kda``'s numbers
     KDA_NUMBERS = ("kda_chunk_log_decay_min", "kda_sub_block_log_decay_min",
                    "kda_state_rms")
+    #: ... and an SSD layer: ``chunked_ssd``'s
+    SSD_NUMBERS = SSDMixer.NUMBERS
 
     def init_state(self):
         per_layer = jnp.zeros((self.n_routed,), jnp.float32)
@@ -1327,6 +1442,9 @@ class LatentMoEDecoder(_TransformerCore):
         if self.n_kda:
             per_kda = jnp.zeros((self.n_kda,), jnp.float32)
             state.update({key: per_kda for key in self.KDA_NUMBERS})
+        if self.n_ssd:
+            per_ssd = jnp.zeros((self.n_ssd,), jnp.float32)
+            state.update({key: per_ssd for key in self.SSD_NUMBERS})
         return state
 
     def compute_output_shape(self, input_shape):
@@ -1364,8 +1482,12 @@ class LatentMoEDecoder(_TransformerCore):
         rank, nope, rope, vd = self.latent or (None, 0, 0, None)
         decoder_records.append({
             "layer": self.name, "training": bool(training),
-            "dense_layers": self.n_block - self.n_routed,
+            "dense_layers": self.ffn_by_layer.count("dense"),
             "routed_layers": self.n_routed,
+            "ffn_by_layer": list(self.ffn_by_layer),
+            "experts_gated": self.experts.gated,
+            "kv_heads": self.grouped.kv_heads if self.grouped else None,
+            "ssd": self.ssd.record() if self.ssd else None,
             "router_width": self.routed_experts,
             "experts_held": self.experts_held,
             "experts_held_from": self.experts_held_from,
@@ -1378,13 +1500,15 @@ class LatentMoEDecoder(_TransformerCore):
             "loss_blocks": loss_blocks})
 
         h = jnp.take(params["tok_embed"], tokens.astype(jnp.int32), axis=0)
-        routes, rules = [], []
+        routes, rules, scans = [], [], []
         for bp in params["blocks"]:
             h, numbers, _ = body(bp, h, None, training, None)
             if "router_kernel" in bp:   # static: the tree is traced once
                 routes.append(numbers)
             if "kda_q_kernel" in bp:
                 rules.append(numbers)
+            if "ssd_in_kernel" in bp:
+                scans.append(numbers)
         with named_scope(HEAD_SCOPE):
             s = apply_remat(
                 lambda gamma, h: _rms_norm(h, gamma, self.norm_eps),
@@ -1403,10 +1527,11 @@ class LatentMoEDecoder(_TransformerCore):
             "moe_walk_windows": over_layers("walk_windows"),
             "moe_dropped_assignments": jnp.sum(
                 over_layers("dropped_assignments"))}
-        if rules:
-            new_state.update({
-                key: jnp.stack([r[key] for r in rules])
-                for key in self.KDA_NUMBERS})
+        for found, keys in ((rules, self.KDA_NUMBERS),
+                            (scans, self.SSD_NUMBERS)):
+            if found:
+                new_state.update({key: jnp.stack([r[key] for r in found])
+                                  for key in keys})
         if not training and state is not None:
             new_state = state
         with named_scope(HEAD_SCOPE):

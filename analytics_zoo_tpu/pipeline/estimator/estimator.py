@@ -105,7 +105,10 @@ def _publish_state_gauges(state) -> None:
     ``zoo_moe_dropped_assignments``, and a KDA layer's numbers
     (``zoo_kda_chunk_log_decay_min{label=<KDA layer>}``,
     ``zoo_kda_sub_block_log_decay_min{label=<KDA layer>}``,
-    ``zoo_kda_state_rms{label=<KDA layer>}``).  Called after the epoch's
+    ``zoo_kda_state_rms{label=<KDA layer>}``) and an SSD layer's
+    (``zoo_ssd_chunk_log_decay_min{label=<SSD layer>}``,
+    ``zoo_ssd_dt_max{label=<SSD layer>}``,
+    ``zoo_ssd_state_rms{label=<SSD layer>}``).  Called after the epoch's
     closing sync and at no other time: the state is then computed, so the
     fetch waits for nothing."""
     if not isinstance(state, dict):
@@ -139,7 +142,17 @@ def _publish_state_gauges(state) -> None:
              "CLAMP) the chunked form is no longer exact"),
             ("kda_state_rms", "zoo_kda_state_rms",
              "root mean square of a KDA layer's recurrent states after "
-             "the last token of the last step's sequences")):
+             "the last token of the last step's sequences"),
+            ("ssd_chunk_log_decay_min", "zoo_ssd_chunk_log_decay_min",
+             "most negative sum of dt A that any head of an SSD (Mamba-2) "
+             "layer reached over any chunk of the last step: how far a "
+             "head forgets inside one chunk"),
+            ("ssd_dt_max", "zoo_ssd_dt_max",
+             "largest step dt of any head and token of an SSD layer in "
+             "the last step"),
+            ("ssd_state_rms", "zoo_ssd_state_rms",
+             "root mean square of an SSD layer's states after the last "
+             "token of the last step's sequences")):
         if key in state:
             gauge = get_registry().gauge(family, text, ("label",))
             for t, value in enumerate(np.asarray(state[key]), start=1):

@@ -961,3 +961,110 @@ def test_value_columns_are_independent_to_the_bit(monkeypatch):
         assert record["blocks"] == (128, 128)
         assert (record["skipped"], record["plain"], record["masked"]) \
             == (6, 6, 4)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query heads: k and v with fewer heads than q
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("route", ["fallback", "interpret"])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("kv_heads", [1, 2])
+def test_grouped_query_heads_against_repeated_ones(route, causal, kv_heads,
+                                                   monkeypatch):
+    """Four query heads over one or two key-value heads: the forward and
+    all three gradients are those of the dense reference with k and v
+    repeated to every query head (a key-value head's gradient the sum of
+    its query heads'), by the blockwise fallback and by the three kernels
+    in interpret mode, which record the key-value heads they read."""
+    if route == "interpret":
+        monkeypatch.setenv("ZOO_FLASH_INTERPRET", "1")
+    q, g = _rand((2, 4, 384, 64), 100), _rand((2, 4, 384, 64), 101)
+    k, v = _rand((2, kv_heads, 384, 64), 102), _rand((2, kv_heads, 384, 64),
+                                                     103)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v) * g)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal, None, 128, 128)
+
+    def dense(q, k, v):
+        share = 4 // kv_heads
+        return _attention_reference(q, jnp.repeat(k, share, axis=1),
+                                    jnp.repeat(v, share, axis=1), causal,
+                                    1.0 / 8.0)
+
+    schedules = _fresh_schedules()
+    got = [flash(q, k, v), *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)]
+    want = [dense(q, k, v), *jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)]
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+    assert got[2].shape == k.shape and got[3].shape == v.shape
+    if route == "interpret":
+        assert {r["kernel"] for r in schedules} == {"forward", "dq", "dkv"}
+        assert {(r["shape"][1], r["kv_heads"]) for r in schedules} \
+            == {(4, kv_heads)}
+
+
+def test_grouped_query_heads_through_the_attention_op():
+    """``dot_product_attention`` takes k and v of fewer heads on its plain
+    path too (no copy a query head), as it does with them repeated."""
+    from analytics_zoo_tpu.ops.attention import dot_product_attention
+
+    q = _rand((2, 8, 64, 16), 110)
+    k, v = _rand((2, 2, 64, 16), 111), _rand((2, 2, 64, 16), 112)
+    mask = _rand((2, 8, 64, 64), 113)
+    for kwargs in ({"causal": True}, {"mask": mask}):
+        got = dot_product_attention(q, k, v, use_flash=False, **kwargs)
+        want = dot_product_attention(q, jnp.repeat(k, 4, axis=1),
+                                     jnp.repeat(v, 4, axis=1),
+                                     use_flash=False, **kwargs)
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    with pytest.raises(ValueError, match="4 query heads over 3 key"):
+        flash_attention(q[:, :4], _rand((2, 3, 64, 16), 114),
+                        _rand((2, 3, 64, 16), 115))
+
+
+#: sha256 of the forward output and the three gradients (float32 bytes) of
+#: the kernels in interpret mode at equal heads, (1, 2, 512, 64), as the
+#: kernels gave them before grouped-query heads were added
+EQUAL_HEADS = {
+    ("float32", True, (128, 128)):
+        "d2d7dd875c5bd35f374ec45513f08e87c6b01824402ed1fe728a56bf62941082",
+    ("float32", False, (None, None)):
+        "9ca549b51b38345a47a3023a7158aa58f370a8a0e60e94dae3712513ec9d9c40",
+    ("float32", True, (None, None)):
+        "cb354391aed94f572c192f6140422455bbde8385d3f64198725acb877ebc1d5c",
+    ("bfloat16", True, (128, 128)):
+        "112f42d1a13db18806080808160cf4fc1f45674d7e3f1a918e68a7dd98e305f8",
+    ("bfloat16", False, (None, None)):
+        "edba84444a66409afc442a288d924c1c08d5e2a2ddb3f25dceec729582fdd0f9",
+    ("bfloat16", True, (None, None)):
+        "e6d9a9664ddd1f418073a432ad276ec2f70f556e67c220398026c0fed41dd1da",
+}
+
+
+@pytest.mark.parametrize("case", sorted(EQUAL_HEADS, key=str))
+def test_at_equal_heads_the_kernels_are_the_earlier_ones(case, monkeypatch):
+    """Where k and v have as many heads as q, the three kernels read a
+    head's k and v as they always did: outputs and gradients equal, to the
+    bit, what the kernels gave before grouped-query heads existed."""
+    import hashlib
+
+    monkeypatch.setenv("ZOO_FLASH_INTERPRET", "1")
+    dtype, causal, blocks = case
+    q, k, v, g = (jax.random.normal(jax.random.PRNGKey(40 + i),
+                                    (1, 2, 512, 64)).astype(dtype)
+                  for i in range(4))
+
+    def loss(q, k, v):
+        return jnp.sum((flash_attention(q, k, v, causal, None, *blocks)
+                        * g).astype(jnp.float32))
+
+    outs = [flash_attention(q, k, v, causal, None, *blocks),
+            *jax.grad(loss, argnums=(0, 1, 2))(q, k, v)]
+    digest = hashlib.sha256(b"".join(
+        np.asarray(o.astype(jnp.float32)).tobytes() for o in outs)).hexdigest()
+    assert digest == EQUAL_HEADS[case]

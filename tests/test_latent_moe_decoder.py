@@ -740,3 +740,220 @@ def test_fit_publishes_a_kda_layers_numbers(kimi, kimi_weights):
         assert gauges[("zoo_moe_walk_windows", layer)] >= 0.0
     assert ("zoo_kda_state_rms", "5") not in gauges
     assert gauges[("zoo_moe_dropped_assignments", "")] == 0.0
+
+
+# -- blocks as records: Mamba-2 mixers, grouped-query attention without
+# -- positions, relu² experts without a gate and a block without a
+# -- feed-forward, against the ``nemotron-3-nano-30b-a3b`` reference
+
+#: hidden 64, 4 SSD heads of 8 (state 16, 2 groups, 4 taps, chunks of 16),
+#: 4 attention heads over 2 key-value heads of 16, the pattern's 7 layers
+#: MEMEM*E as blocks (M, E) (M, E) (M, -) (*, E), 8 routed experts of width
+#: 32 with 2 held, top-2, a shared expert of 64, vocabulary 32, 72 tokens
+NEMOTRON_TOY = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 16, "mamba_num_heads": 4, "mamba_head_dim": 8,
+    "ssm_state_size": 16, "n_groups": 2, "chunk_size": 16,
+    "n_routed_experts": 2, "router_width": 8, "moe_intermediate_size": 32,
+    "moe_shared_expert_intermediate_size": 64, "num_experts_per_tok": 2,
+    "vocab_size": 32, "n_positions": 72}
+
+
+@pytest.fixture(scope="module")
+def nemotron():
+    return Manifest().configuration("nemotron-3-nano-30b-a3b", NEMOTRON_TOY)
+
+
+@pytest.fixture(scope="module")
+def nemotron_weights(nemotron):
+    return nemotron.module("reference").init_params(jax.random.PRNGKey(11),
+                                                    nemotron.sizes)
+
+
+@pytest.fixture()
+def nemotron_net(nemotron):
+    from analytics_zoo_tpu import init_zoo_context
+
+    init_zoo_context("nemotron decoder", seed=3)
+    model = nemotron.module("model").build(nemotron.sizes)
+    _built, state = model.build_params()
+    return model, state
+
+
+def test_blocks_as_records_against_the_reference(nemotron_net, nemotron,
+                                                 nemotron_weights):
+    """Logits, loss and every leaf's gradient of the decoder whose blocks
+    are (Mamba-2, relu² experts), twice, (Mamba-2, none) and (grouped-query
+    attention, relu² experts), off the chunk grid (72 tokens in chunks of
+    16); the tree, the parameter count, the state and the records."""
+    from analytics_zoo_tpu.ops import ssd
+
+    model, state = nemotron_net
+    reference = nemotron.module("reference")
+    built, _ = model.build_params()
+    assert jax.tree_util.tree_structure(built) \
+        == jax.tree_util.tree_structure(nemotron_weights)
+    assert all(a.shape == b.shape for a, b in zip(
+        jax.tree_util.tree_leaves(built),
+        jax.tree_util.tree_leaves(nemotron_weights)))
+    blocks = nemotron_weights["nemotron"]["blocks"]
+    assert ["ssd_in_kernel" in bp for bp in blocks] == [True] * 3 + [False]
+    assert "attn_q_kernel" in blocks[3]
+    assert "ln2_gamma" not in blocks[2] and "router_kernel" not in blocks[2]
+    assert "experts_gate" not in blocks[0] \
+        and "shared_gate_kernel" not in blocks[0]
+    layer = model.layers[-1]
+    assert layer.param_count() == sum(
+        int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(built))
+    rng = np.random.default_rng(19)
+    x, y = (jnp.asarray(rng.integers(0, 32, (BATCH, 72)).astype(np.int32))
+            for _ in range(2))
+    logits, _ = model.forward(nemotron_weights, x, state=state,
+                              training=False)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            logits, reference.logits(nemotron_weights, x, nemotron.sizes),
+            atol=3e-6)
+        (loss, new_state), grads = jax.value_and_grad(
+            lambda p: _program_loss(nemotron_net, p, x, y), has_aux=True)(
+                nemotron_weights)
+        ref_loss, ref_grads = jax.value_and_grad(reference.loss_fn)(
+            nemotron_weights, x, y, nemotron.sizes)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
+        name = jax.tree_util.keystr(path)
+        if "router_bias" in name:
+            assert not np.any(got) and not np.any(ref), name
+            continue
+        assert float(jnp.abs(ref).max()) > 0, name
+        np.testing.assert_allclose(
+            got, ref, atol=3e-5 * float(jnp.abs(ref).max()), err_msg=name)
+    numbers = new_state["nemotron"]
+    assert numbers["moe_held_assignments"].shape == (3,)
+    assert numbers["ssd_chunk_log_decay_min"].shape == (3,)
+    assert np.all(np.asarray(numbers["ssd_chunk_log_decay_min"]) < 0.0)
+    assert np.all(np.asarray(numbers["ssd_dt_max"]) > 0.0)
+    assert np.all(np.asarray(numbers["ssd_state_rms"]) > 0.0)
+    record = self_attention.decoder_records[-1]
+    assert record["attention_by_layer"] == ["ssd", "ssd", "ssd", "full"]
+    assert record["ffn_by_layer"] == ["routed", "routed", None, "routed"]
+    assert (record["dense_layers"], record["routed_layers"]) == (0, 3)
+    assert (record["kv_heads"], record["ssd"], record["experts_gated"]) \
+        == (2, (4, 8, 16, 2, 4), False)
+    assert "ssd_state" in record["kept"] and record["rotary"] is False
+    assert {(r["rule"], r["groups"], r["chunk"], r["chunks"])
+            for r in ssd.chunk_schedules} == {("ssd", 2, 16, 5)}
+
+
+def test_the_pattern_reads_as_blocks(nemotron):
+    """Two layers in a row, a mixer and the "E" after it, are one block;
+    a mixer with no "E" after it a block without a feed-forward."""
+    model_py = nemotron.module("model")
+    assert model_py.blocks("MEMEM*E") == [("M", True), ("M", True),
+                                          ("M", False), ("*", True)]
+    assert model_py.blocks("MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEM"
+                           "EME")[-2:] == [("M", True), ("M", True)]
+    with pytest.raises(ValueError, match="a mixer"):
+        model_py.blocks("EM")
+    assert nemotron.module("reference").blocks(
+        {"hybrid_override_pattern": "MEMEM*E", "num_hidden_layers": 7}) \
+        == model_py.blocks("MEMEM*E")
+
+
+def test_a_layout_whose_records_of_a_kind_differ_is_refused():
+    from analytics_zoo_tpu.pipeline.api.keras.layers import LatentMoEDecoder
+    from analytics_zoo_tpu.pipeline.api.keras.layers.self_attention import (
+        Experts,
+    )
+    from analytics_zoo_tpu.pipeline.api.keras.layers.ssd_mixer import (
+        SSDMixer,
+    )
+
+    options = dict(vocab=32, n_block=2, n_head=2, hidden_size=32,
+                   intermediate_size=48, routed_experts=4,
+                   experts_per_token=2, expert_size=16)
+    with pytest.raises(ValueError, match="the 'ssd' blocks differ"):
+        LatentMoEDecoder(layout=[(SSDMixer(2, 8, 16, 1), None),
+                                 (SSDMixer(2, 8, 16, 2), None)], **options)
+    with pytest.raises(ValueError, match="a layout of 1 blocks for 2"):
+        LatentMoEDecoder(layout=[(SSDMixer(2, 8, 16, 1), Experts())],
+                         **options)
+    # without a layout, routed experts are gated as gated_ffn says
+    with pytest.raises(ValueError, match="ungated act"):
+        self_attention.TransformerLayer(
+            vocab=32, seq_len=8, n_block=1, n_head=2, hidden_size=32,
+            routed_experts=4, experts_per_token=2, expert_size=16,
+            use_bias=False)
+
+
+def test_fit_publishes_an_ssd_layers_numbers(nemotron, nemotron_weights):
+    """One epoch through ``fit``: the three SSD layers' gauges of the last
+    step are published beside the routed layers' counts."""
+    from analytics_zoo_tpu import init_zoo_context
+    from analytics_zoo_tpu.metrics import snapshot
+
+    init_zoo_context("nemotron decoder fit", seed=3)
+    model_py = nemotron.module("model")
+    model = model_py.build(nemotron.sizes)
+    model.build_params()
+    model.params = jax.tree_util.tree_map(jnp.array, nemotron_weights)
+    rng = np.random.default_rng(5)
+    x = rng.integers(0, 32, (8, 72)).astype(np.int32)
+    y = rng.integers(0, 32, (8, 72)).astype(np.int32)
+    model.fit(model_py.feature_set(x, y, nemotron.sizes), batch_size=8,
+              nb_epoch=2)
+    history = [h["loss"] for h in model._estimator.history]
+    assert history[1] < history[0] < np.log(32) + 0.2
+    assert model_py.routing_fault("cpu") is None
+    gauges = {(s["name"], (s.get("labels") or {}).get("label", "")):
+              s["value"] for s in snapshot()["samples"]
+              if s["name"].startswith(("zoo_ssd_", "zoo_moe_"))}
+    for layer in ("1", "2", "3"):
+        assert gauges[("zoo_ssd_chunk_log_decay_min", layer)] < 0.0
+        assert 0.0 < gauges[("zoo_ssd_dt_max", layer)] < 10.0
+        assert 0.0 < gauges[("zoo_ssd_state_rms", layer)] < 100.0
+        assert gauges[("zoo_moe_walk_windows", layer)] >= 0.0
+    assert ("zoo_ssd_state_rms", "4") not in gauges
+    assert gauges[("zoo_moe_dropped_assignments", "")] == 0.0
+
+
+#: sha256 of the lowered text (CPU) of a toy decoder's logits, loss and
+#: gradient at ``kanana-2-30b-a3b``'s and at ``kimi-linear-48b-a3b``'s
+#: options, as the decoder lowered them before blocks could be records
+WITHOUT_RECORDS = {
+    "kanana": "f1840b58bdd872e100d98754f0ccd68e24a31edc1b2cd6fc70c1f559e05e170c",
+    "kimi": "07a7ea7c021e93a7f412b745869f18232b41554983a26a0ec5559fdddbc01778"}
+
+
+@pytest.mark.parametrize("which", sorted(WITHOUT_RECORDS))
+def test_without_records_the_trees_and_programs_are_the_earlier_ones(
+        which, cfg, kimi):
+    """At the two earlier routed configurations' options (no layout) the
+    decoder's tree is the reference's and its program, logits, loss and
+    gradient, lowers to the text it lowered to before a block could be
+    described by records."""
+    import hashlib
+
+    from analytics_zoo_tpu import init_zoo_context
+
+    sizes = (cfg if which == "kanana" else kimi)
+    init_zoo_context("pin", seed=3)
+    model = sizes.module("model").build(sizes.sizes)
+    built, state = model.build_params()
+    w = sizes.module("reference").init_params(jax.random.PRNGKey(5),
+                                              sizes.sizes)
+    assert jax.tree_util.tree_structure(built) \
+        == jax.tree_util.tree_structure(w)
+    n = sizes.sizes["n_positions"]
+    rng = np.random.default_rng(13)
+    x, y = (jnp.asarray(rng.integers(0, 32, (4, n)).astype(np.int32))
+            for _ in range(2))
+
+    def step(p):
+        logits, _ = model.forward(p, x, state=state, training=False)
+        return logits, jax.value_and_grad(
+            lambda p: _program_loss((model, state), p, x, y)[0])(p)
+
+    text = jax.jit(step).lower(w).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == WITHOUT_RECORDS[which]

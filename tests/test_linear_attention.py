@@ -78,7 +78,8 @@ def test_chunked_form_against_the_recurrence(recurrence, length, chunk,
         np.testing.assert_allclose(
             got, ref, atol=5e-6 * float(jnp.abs(ref).max()), err_msg=name)
     record = linear.chunk_schedules[-1]
-    assert record == {"shape": (2, 2, length, WIDTH, WIDTH), "chunk": c,
+    assert record == {"rule": "kda",
+                      "shape": (2, 2, length, WIDTH, WIDTH), "chunk": c,
                       "chunks": -(-length // c), "sub_blocks": c // sub,
                       "kernel": False}
 

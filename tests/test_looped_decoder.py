@@ -297,7 +297,7 @@ def test_no_array_of_passes_x_tokens_x_vocabulary_in_the_step(cfg, weights,
     # the policy's names, wherever they occur: the looped stack has no
     # routed layer, so nothing of it carries the third
     assert record["kept"] == ["attn_context", "ffn_out", "moe_route",
-                              "kda_state"]
+                              "kda_state", "ssd_state"]
 
 
 def _products_over(jaxpr, size, in_scan=False):

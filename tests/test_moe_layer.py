@@ -312,3 +312,114 @@ class TestMoETransformerLayer:
         st = m.state
         (tl_state,) = [v for k, v in st.items() if "moe_aux_loss" in v]
         assert float(tl_state["moe_aux_loss"]) > 0.0
+
+
+# -- experts without a gate (relu² experts of nemotron-3-nano-30b-a3b) ------
+
+NEMOTRON_TOY = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 16, "mamba_num_heads": 4, "mamba_head_dim": 8,
+    "ssm_state_size": 16, "n_groups": 2, "chunk_size": 16,
+    "n_routed_experts": 128, "router_width": 128,
+    "moe_intermediate_size": 32, "moe_shared_expert_intermediate_size": 64,
+    "num_experts_per_tok": 6, "vocab_size": 32, "n_positions": 32}
+
+
+@pytest.fixture(scope="module")
+def nemotron():
+    from benchmark.manifest import Manifest
+
+    cfg = Manifest().configuration("nemotron-3-nano-30b-a3b", NEMOTRON_TOY)
+    reference = cfg.module("reference")
+    # the first block, (M, E): a routed layer that holds all 128 experts
+    bp = reference.init_params(jax.random.PRNGKey(9),
+                               cfg.sizes)[reference.CORE]["blocks"][0]
+    u = jnp.asarray(np.random.default_rng(1).normal(
+        size=(96, 64)).astype(np.float32))
+    return cfg.sizes, reference, bp, u
+
+
+def _relu2_shared(bp, u):
+    return jnp.square(jax.nn.relu(u @ bp["shared_fc_kernel"])) \
+        @ bp["shared_out_kernel"]
+
+
+def test_the_sixteen_shares_add_up_to_the_uncut_layer(nemotron):
+    """Nemotron's routing: 128 relu² experts without a gate, the top 6 a
+    token.  16 workers hold experts 0-7, 8-15, ..., 120-127.  The routed
+    parts that the 16 give, with the shared expert counted once, are what
+    the uncut reference gives for the whole layer; and the reference's own
+    share of 8 is the program's."""
+    from analytics_zoo_tpu.ops.activations import get_activation
+    from analytics_zoo_tpu.ops.moe import held_experts_ffn
+    from benchmark import narrow
+
+    sizes, reference, bp, u = nemotron
+    assert "experts_gate" not in bp
+    qs = narrow.rounders(None)
+    whole = reference._routed(qs, sizes, bp, u)
+    total, held = _relu2_shared(bp, u), 0.0
+    relu2 = get_activation("relu2")
+    parts = {}
+    for first in range(0, 128, 8):
+        part, stats = held_experts_ffn(
+            u, bp["router_kernel"], bp["router_bias"], None,
+            bp["experts_up"][first:first + 8],
+            bp["experts_down"][first:first + 8], first_held=first, top_k=6,
+            routed_scale=sizes["routed_scaling_factor"], activation=relu2)
+        parts[first] = part
+        total = total + part
+        held += float(stats["held_assignments"])
+        assert float(stats["dropped_assignments"]) == 0.0
+    assert held == 6 * 96       # every assignment fell on one of the 16
+    np.testing.assert_allclose(total, whole, atol=2e-6)
+    share = {**bp, "experts_up": bp["experts_up"][40:48],
+             "experts_down": bp["experts_down"][40:48]}
+    got = reference._routed(
+        qs, {**sizes, "n_routed_experts": 8, "experts_held_from": 40},
+        share, u) - _relu2_shared(bp, u)
+    np.testing.assert_allclose(got, parts[40], atol=2e-6)
+
+
+@pytest.mark.parametrize("route", ["fallback", "interpret"])
+def test_experts_without_a_gate_and_their_gradients(nemotron, route,
+                                                    monkeypatch):
+    """act(u Wup) Wdown a held expert, through the walk and its backward
+    rule (two grouped products a window forward): the part and the
+    gradients of the tokens, the router and both matrices against the
+    plain masked sum over the held experts; in interpret mode through the
+    grouped kernels, rows they must not write left unread."""
+    from analytics_zoo_tpu.ops.activations import get_activation
+    from analytics_zoo_tpu.ops.moe import held_experts_ffn, sigmoid_route
+
+    if route == "interpret":
+        monkeypatch.setenv("ZOO_KERNEL_INTERPRET", "1")
+    sizes, _, bp, u = nemotron
+    relu2 = get_activation("relu2")
+    up, down = bp["experts_up"][:8], bp["experts_down"][:8]
+
+    def program(u, router, up, down):
+        return held_experts_ffn(u, router, bp["router_bias"], None, up, down,
+                                first_held=0, top_k=6, routed_scale=2.5,
+                                activation=relu2)[0]
+
+    def plain(u, router, up, down):
+        experts, weights = sigmoid_route(u, router, bp["router_bias"],
+                                         top_k=6, routed_scale=2.5)
+        out = jnp.zeros_like(u)
+        for e in range(8):
+            w = jnp.sum(jnp.where(experts == e, weights, 0.0), axis=-1)
+            out = out + w[:, None] * (relu2(u @ up[e]) @ down[e])
+        return out
+
+    g = jnp.asarray(np.random.default_rng(3).normal(
+        size=u.shape).astype(np.float32))
+    args = (u, bp["router_kernel"], up, down)
+    np.testing.assert_allclose(program(*args), plain(*args), atol=2e-6)
+    got = jax.grad(lambda *a: jnp.sum(program(*a) * g), argnums=(0, 1, 2, 3))(
+        *args)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * g), argnums=(0, 1, 2, 3))(
+        *args)
+    for name, a, b in zip(("u", "router", "up", "down"), got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.abs(b).max()),
+                                   err_msg=name)

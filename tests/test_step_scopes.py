@@ -1,9 +1,11 @@
 """The device's parts of a step (``metrics/tracing.py``: ``zoo.mixer``,
 ``zoo.ffn``, ``zoo.head``), read where the benchmark reads them: in the
 ``op_name`` of the train step as ``fit`` compiles it, here on the CPU, for
-three toy models (a ``LatentMoEDecoder`` with a KDA, a latent and a routed
-layer; a ``LoopedDecoder`` of two passes; a ``TransformerLayer`` under a
-``Dense`` head).  Every product, loop and custom call of the blocks carries
+four toy models (a ``LatentMoEDecoder`` with a KDA, a latent and a routed
+layer; one with its blocks as records, Mamba-2 mixers under their own
+``zoo.ssd`` inside ``zoo.mixer``, grouped-query attention, relu² experts
+and a block without a feed-forward; a ``LoopedDecoder`` of two passes; a
+``TransformerLayer`` under a ``Dense`` head).  Every product, loop and custom call of the blocks carries
 one block name, in the forward pass, under ``transpose(`` and under
 ``rematted_computation``; the head's carry ``zoo.head``; no path holds two;
 and the scopes are metadata: with them patched to ``nullcontext`` the
@@ -22,7 +24,7 @@ import numpy as np
 import pytest
 
 from analytics_zoo_tpu.metrics.tracing import FFN_SCOPE, HEAD_SCOPE, \
-    MIXER_SCOPE
+    MIXER_SCOPE, SSD_SCOPE
 from analytics_zoo_tpu.pipeline.api.keras.layers import self_attention
 
 PARTS = (MIXER_SCOPE, FFN_SCOPE, HEAD_SCOPE)
@@ -76,8 +78,33 @@ def _dense_head():
         "sparse_categorical_crossentropy", True
 
 
+def _hybrid():
+    from analytics_zoo_tpu.pipeline.api.keras import Input, Model
+    from analytics_zoo_tpu.pipeline.api.keras.layers import LatentMoEDecoder
+    from analytics_zoo_tpu.pipeline.api.keras.layers.grouped_attention import (  # noqa: E501
+        GroupedQueryAttention,
+    )
+    from analytics_zoo_tpu.pipeline.api.keras.layers.ssd_mixer import (
+        SSDMixer,
+    )
+
+    Experts = self_attention.Experts
+
+    ssd = SSDMixer(heads=4, head_dim=8, state=16, groups=2, chunk=16)
+    routed = Experts(gated=False, activation="relu2")
+    tokens = Input(shape=(L,), name="tokens")
+    out = LatentMoEDecoder(
+        vocab=V, n_block=3, n_head=4, hidden_size=64, intermediate_size=96,
+        layout=[(ssd, routed), (ssd, None),
+                (GroupedQueryAttention(4, 2, 16), routed)],
+        routed_experts=8, experts_held=2, experts_per_token=2,
+        expert_size=32, shared_experts=1, rotary_theta=None,
+        name="hybrid")(tokens)
+    return Model(tokens, out), "next_token_cross_entropy", False
+
+
 MODELS = {"latent_moe_decoder": _latent, "looped_decoder": _looped,
-          "transformer_under_dense": _dense_head}
+          "transformer_under_dense": _dense_head, "hybrid_ssd_decoder": _hybrid}
 
 
 def _compiled_step(build) -> str:
@@ -159,7 +186,14 @@ def test_every_product_loop_and_call_of_the_model_names_its_part(steps):
         # again carries its part too
         for part in (MIXER_SCOPE, FFN_SCOPE):
             assert ("dot", "made again") in found[part], found[part]
-    if model == "latent_moe_decoder":
+    if model == "hybrid_ssd_decoder":
+        # the Mamba-2 mixers' own scope lies inside the mixer's, never
+        # beside the feed-forward's or the head's
+        ssd = [scope for _, scope in INSTRUCTION.findall(scoped)
+               if SSD_SCOPE in scope]
+        assert ssd and all(MIXER_SCOPE in scope for scope in ssd)
+        assert not any(FFN_SCOPE in s or HEAD_SCOPE in s for s in ssd)
+    if model in ("latent_moe_decoder", "hybrid_ssd_decoder"):
         # the routed walk: a loop and the grouped products' calls
         assert {("while", "forward"), ("while", "backward")} \
             <= found[FFN_SCOPE]

@@ -55,14 +55,16 @@ def real_kernels(monkeypatch):
 
 
 def _flash(shape, grad, padding_bias=False, segments=False,
-           value_width=None, **kw):
+           value_width=None, kv_heads=None, **kw):
     """(fn, arg specs) for flash attention on (B, H, L, D) bf16, v
-    ``value_width`` wide where given."""
+    ``value_width`` wide where given, k and v of ``kv_heads`` heads where
+    given."""
     from analytics_zoo_tpu.ops.pallas.flash_attention import flash_attention
 
-    b, _h, l, _d = shape
-    args = [(shape, jnp.bfloat16)] * 2 \
-        + [(shape[:3] + (value_width or shape[3],), jnp.bfloat16)]
+    b, h, l, d = shape
+    kv = (b, kv_heads or h, l)
+    args = [(shape, jnp.bfloat16), (kv + (d,), jnp.bfloat16),
+            (kv + (value_width or d,), jnp.bfloat16)]
     if padding_bias:
         args.append(((b, 1, 1, l), jnp.float32))  # the BERT mask
     if segments:
@@ -84,22 +86,27 @@ def _flash(shape, grad, padding_bias=False, segments=False,
     return jax.grad(loss, argnums=(0, 1, 2)), args
 
 
-def _routed(tokens, width, router, held, expert, top_k):
+def _routed(tokens, width, router, held, expert, top_k, gated=True):
     """(fn, arg specs) for the gradient of the routed feed-forward without
     dropped tokens: the sort and the grouped products of top_k x tokens
-    assignments over the experts held."""
+    assignments over the experts held (relu² experts without a gate where
+    not ``gated``)."""
+    from analytics_zoo_tpu.ops.activations import get_activation
     from analytics_zoo_tpu.ops.moe import held_experts_ffn
 
-    def loss(u, route, bias, gate, up, down):
-        y, _ = held_experts_ffn(u, route, bias, gate, up, down, first_held=0,
-                                top_k=top_k, routed_scale=2.448)
+    def loss(u, route, bias, *experts):
+        gate, up, down = experts if gated else (None, *experts)
+        y, _ = held_experts_ffn(
+            u, route, bias, gate, up, down, first_held=0, top_k=top_k,
+            routed_scale=2.448,
+            activation=get_activation("silu" if gated else "relu2"))
         return jnp.sum(jnp.square(y.astype(jnp.float32)))
 
-    return jax.grad(loss, argnums=(0, 1, 3, 4, 5)), [
+    return jax.grad(loss, argnums=(0, 1, 3, 4) + ((5,) if gated else ())), [
         ((tokens, width), jnp.bfloat16), ((width, router), jnp.bfloat16),
-        ((router,), jnp.bfloat16), ((held, width, expert), jnp.bfloat16),
-        ((held, width, expert), jnp.bfloat16),
-        ((held, expert, width), jnp.bfloat16)]
+        ((router,), jnp.bfloat16)] \
+        + [((held, width, expert), jnp.bfloat16)] * (1 + gated) \
+        + [((held, expert, width), jnp.bfloat16)]
 
 
 def _kda(shape):
@@ -116,6 +123,23 @@ def _kda(shape):
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), \
         [(shape, jnp.bfloat16)] * 3 + [(shape, jnp.float32),
                                        (shape[:3], jnp.float32)]
+
+
+def _ssd(batch, heads, length, p, groups, n):
+    """(fn, arg specs) for the gradient of Mamba-2's chunked rule: the two
+    Pallas kernels of the walk over a sequence's chunks, a group's B and C
+    read once for its heads."""
+    from analytics_zoo_tpu.ops.ssd import chunked_ssd
+
+    def loss(x, dt, a, b, c):
+        y, _ = chunked_ssd(x, dt, a, b, c)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), [
+        ((batch, heads, length, p), jnp.bfloat16),
+        ((batch, heads, length), jnp.float32), ((heads,), jnp.float32),
+        ((batch, groups, length, n), jnp.bfloat16),
+        ((batch, groups, length, n), jnp.bfloat16)]
 
 
 def _xent(shape):
@@ -192,6 +216,14 @@ CASES = {
         lambda: _flash((2, 4, 4096, 128), True),
     # `kimi-linear-48b-a3b-fit`'s own call: 32 KDA heads of 128
     "kda_walk_fwd_bwd_kimi_cell": lambda: _kda((2, 32, 4096, 128)),
+    # `nemotron-3-nano-30b-a3b-fit`'s own calls: 64 SSD heads of 64 with a
+    # state of 128 in 8 groups; 32 query heads of 128 over 2 key-value
+    # heads; 49,152 assignments over 8 of 128 relu² experts of 1,856
+    "ssd_walk_fwd_bwd_nemotron_cell": lambda: _ssd(2, 64, 4096, 64, 8, 128),
+    "flash_fwd_bwd_nemotron_cell":
+        lambda: _flash((2, 32, 4096, 128), True, causal=True, kv_heads=2),
+    "routed_experts_nemotron_cell":
+        lambda: _routed(8192, 2688, 128, 8, 1856, 6, gated=False),
     "softmax_xent_fwd_bwd": lambda: _xent((4096, 50304)),
     "int8_matmul": lambda: _int8(256, 2048, 1000),
     "fused_adam":
