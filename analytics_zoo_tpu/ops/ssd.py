@@ -27,9 +27,14 @@ elsewhere, which is also the kernels' oracle.  The
 cumulative decay and the state are float32; the products' operands are in
 x's dtype (bfloat16 on the chip) with float32 sums.
 
-B and C belong to a group of heads.  The walks take the heads as (groups,
-heads a group, ...) and B and C as (groups, ...): a group's B and C are read
-once for all its heads, never copied to every head.
+B and C belong to a group of heads: a group's B and C are read once for
+all its heads, never copied to every head.  ``chunked_ssd`` and the kernels
+take every array in the projections' token-major layout, (B, L, heads x P)
+and (B, L, groups x N), so that the mixer hands its channels over as they
+come out of the convolution and takes y back where its gate reads it; the
+scans, the kernels' oracle, walk the heads as (groups, heads a group, ...)
+and B and C as (groups, ...), and ``heads_of`` / ``tokens_of`` convert
+around them.
 
 The backward rule (``jax.custom_vjp``) keeps the inputs and the chunks'
 incoming states, so the forward walk is not run again for it.
@@ -47,9 +52,10 @@ from jax.ad_checkpoint import checkpoint_name
 invocation_counts = {"pallas": 0, "fallback": 0}
 
 #: Trace-time record of each chunked call traced, newest last: ``rule``
-#: ("ssd"), ``shape`` (B, heads, L, P, N), ``groups``, ``chunk`` Q,
-#: ``chunks`` a sequence and ``kernel`` (the walk through the Pallas
-#: kernels, or the scan).
+#: ("ssd"), ``shape`` (B, heads, L, P, N: the rule's sizes), ``groups``,
+#: ``chunk`` Q, ``chunks`` a sequence, ``kernel`` (the walk through the
+#: Pallas kernels, or the scan) and ``layout`` ("tokens": the walk read and
+#: wrote the projections' token-major arrays, no heads split out).
 chunk_schedules: collections.deque = collections.deque(maxlen=64)
 
 #: tokens a chunk (the published ``chunk_size``)
@@ -62,6 +68,33 @@ CHUNK = 128
 STATE_NAME = "ssd_state"
 
 _F32 = jnp.float32
+
+
+def _runs(k, width):
+    """(k, k x width) float32: row i is 1 over the i-th run of ``width``."""
+    return (jnp.arange(k * width) // width
+            == jnp.arange(k)[:, None]).astype(_F32)
+
+
+def spread(t, width):
+    """(..., k) -> (..., k x width) float32: each of ``t``'s k values over
+    ``width`` channels in a row, as a product with a 0/1 matrix.  On the TPU
+    a reshape that splits or merges the lane dimension of a (B, L, k x
+    width) array is a copy of it, and XLA writes such a broadcast out in
+    float32 before it copies it; a product is one fused pass of the matrix
+    unit.  At ``HIGH`` precision t's bfloat16 high and low parts each meet
+    an exact 0 or 1: 16 bits of t's mantissa reach the result (all 24 off
+    the TPU)."""
+    return jnp.matmul(t.astype(_F32), _runs(t.shape[-1], width),
+                      precision=jax.lax.Precision.HIGH)
+
+
+def run_sums(t, width):
+    """(..., k x width) -> (..., k) float32: the sum of each run of
+    ``width`` channels, ``spread``'s transpose, by the same product."""
+    k = t.shape[-1] // width
+    return jnp.matmul(t.astype(_F32), _runs(k, width).T,
+                      precision=jax.lax.Precision.HIGH)
 
 
 def _dot(a, b, contract):
@@ -211,6 +244,56 @@ def walk_backward_scan(xdt, a, b, c, states, dy):
             jnp.moveaxis(dc, 0, 1).astype(c.dtype))
 
 
+# -- the walks' token-major layout, and the scans' head-major one ----------
+
+def heads_of(t, a, heads=True):
+    """A token-major array of the walks, (B, L, G x R x W) (``heads``) or
+    (B, L, G x W), in the scans' layout: (B G, R, n, Q, W) or (B G, n, Q,
+    W); the walks' decay ``a`` (B, G, n, R, Q) gives the sizes."""
+    bt, g, n, r, q = a.shape
+    r = r if heads else 1
+    t = t.reshape(bt, n, q, g, r, -1).transpose(0, 3, 4, 1, 2, 5)
+    t = t.reshape(bt * g, r, n, q, t.shape[-1])
+    return t if heads else t[:, 0]
+
+
+def tokens_of(t, sequences):
+    """The inverse of ``heads_of``, for ``sequences`` sequences."""
+    t = t if t.ndim == 5 else t[:, None]
+    bg, r, n, q, w = t.shape
+    t = t.reshape(sequences, bg // sequences, r, n, q, w)
+    return t.transpose(0, 3, 4, 1, 2, 5).reshape(sequences, n * q, -1)
+
+
+def _decay_heads(a):
+    """The walks' decay (B, G, n, R, Q) as the scans' (B G, R, n, 1, Q)."""
+    bt, g, n, r, q = a.shape
+    return a.transpose(0, 1, 3, 2, 4).reshape(bt * g, r, n, 1, q)
+
+
+def walk_forward_by_scan(xdt, a, b, c):
+    """``walk_forward_scan`` in the kernels' layout (``ssd_scan.walk_forward``
+    takes and gives the same): ``xdt`` (B, L, H P), ``a`` (B, G, n, R, Q),
+    ``b`` and ``c`` (B, L, G N) -> (Y (B, L, H P), the states (B G, R, n,
+    P, N), the last chunk's outgoing state (B G, R, P, N))."""
+    y, states, final = walk_forward_scan(
+        heads_of(xdt, a), _decay_heads(a), heads_of(b, a, False),
+        heads_of(c, a, False))
+    return tokens_of(y, xdt.shape[0]), states, final
+
+
+def walk_backward_by_scan(xdt, a, b, c, states, dy):
+    """``walk_backward_scan`` in the kernels' layout: (dX~, da, dB, dC) as
+    ``xdt``, ``a``, ``b`` and ``c``."""
+    dx, da, db, dc = walk_backward_scan(
+        heads_of(xdt, a), _decay_heads(a), heads_of(b, a, False),
+        heads_of(c, a, False), states, heads_of(dy, a))
+    bt, g, n, r, q = a.shape
+    return (tokens_of(dx, bt),
+            da.reshape(bt, g, r, n, q).transpose(0, 1, 3, 2, 4),
+            tokens_of(db, bt), tokens_of(dc, bt))
+
+
 def _kernels(count: bool = True) -> bool:
     """Whether a walk goes through its Pallas kernel (on a TPU, or where
     the package's switches say so); counted at trace time."""
@@ -227,7 +310,7 @@ def _walk_forward(*chunks):
         from analytics_zoo_tpu.ops.pallas import ssd_scan
 
         return ssd_scan.walk_forward(*chunks)
-    return walk_forward_scan(*chunks)
+    return walk_forward_by_scan(*chunks)
 
 
 @jax.custom_vjp
@@ -254,7 +337,7 @@ def _chunked_bwd(kept, cotangents):
         from analytics_zoo_tpu.ops.pallas import ssd_scan
 
         return ssd_scan.walk_backward(*chunks, states, dy)
-    return walk_backward_scan(*chunks, states, dy)
+    return walk_backward_by_scan(*chunks, states, dy)
 
 
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
@@ -267,18 +350,19 @@ def chunk_of(length: int, chunk: int | None = None) -> int:
 
 
 def chunked_ssd(x, dt, a, b, c, *, chunk=None):
-    """Mamba-2's rule over whole sequences from a zero state: ``x`` (B, H,
-    L, P), ``dt`` (B, H, L) steps > 0, ``a`` (H,) the heads' A < 0, ``b``
-    and ``c`` (B, G, L, N) the groups', head h reading group h // (H / G)
-    -> ``(y (B, H, L, P) in x's dtype, without the skip D x, stats)`` with
-    ``stats`` float32 scalars that take no gradient:
+    """Mamba-2's rule over whole sequences from a zero state, token-major:
+    ``x`` (B, L, H, P), ``dt`` (B, L, H) steps > 0, ``a`` (H,) the heads'
+    A < 0, ``b`` and ``c`` (B, L, G, N) the groups', head h reading group
+    h // (H / G) -> ``(y (B, L, H, P) in x's dtype, without the skip D x,
+    stats)`` with ``stats`` float32 scalars that take no gradient:
     ``chunk_log_decay_min`` (the most negative sum of dt A that any head
     reached over any chunk: how far a head forgets inside one),
     ``dt_max`` and ``state_rms`` (the root mean square of the states after
     the last token).  A length that is no multiple of the chunk is padded
-    with tokens that decay nothing and write nothing."""
-    bt, h, l, p = x.shape
-    g, n_state = b.shape[1], b.shape[-1]
+    with tokens that decay nothing and write nothing.  The heads are never
+    split out: X~, B, C and y reach the walk as (B, L, channels)."""
+    bt, l, h, p = x.shape
+    g, n_state = b.shape[2:]
     if h % g:
         raise ValueError(f"{h} heads do not part into {g} groups")
     r = h // g
@@ -286,25 +370,29 @@ def chunked_ssd(x, dt, a, b, c, *, chunk=None):
     n = -(-l // q)
     cd = x.dtype
 
-    def padded(t):
-        return jnp.pad(t, ((0, 0), (0, 0), (0, n * q - l))
-                       + ((0, 0),) * (t.ndim - 3))
-
-    def by_head(t):     # (B, H, L, ...) -> (B G, R, n, Q, ...)
-        return padded(t).reshape((bt * g, r, n, q) + t.shape[3:])
+    def tokens(t):      # (B, L, ...) -> (B, n Q, the rest as one)
+        t = t.reshape(bt, l, -1)
+        return t if n * q == l else jnp.pad(t, ((0, 0), (0, n * q - l),
+                                                (0, 0)))
 
     dt32 = dt.astype(_F32)
-    xdt = by_head((x.astype(_F32) * dt32[..., None]).astype(cd))
-    a_sum = jnp.cumsum(by_head(dt32 * a.astype(_F32)[:, None]), axis=3)
-    y, final = _chunked(
-        xdt, a_sum[:, :, :, None, :],
-        padded(b.astype(cd)).reshape(bt * g, n, q, n_state),
-        padded(c.astype(cd)).reshape(bt * g, n, q, n_state))
+    xdt = tokens((x.reshape(bt, l, h * p).astype(_F32)
+                  * spread(dt32, p)).astype(cd))
+    # a head's sum of dt A from its chunk's start: (B, G, n, R, Q), as a
+    # product with a triangle of ones at float32's precision (a ``cumsum``
+    # over this layout is a slow ``reduce_window`` on the TPU)
+    steps = tokens(dt32 * a.astype(_F32)).reshape(bt, n, q, g, r)
+    upto = (_iota((q, q), 0) <= _iota((q, q), 1)).astype(_F32)
+    a_sum = jnp.matmul(steps.transpose(0, 3, 1, 4, 2), upto,
+                       precision=jax.lax.Precision.HIGHEST)
+    y, final = _chunked(xdt, a_sum, tokens(b.astype(cd)),
+                        tokens(c.astype(cd)))
     chunk_schedules.append({
         "rule": "ssd", "shape": (bt, h, l, p, n_state), "groups": g,
-        "chunk": q, "chunks": n, "kernel": _kernels(count=False)})
+        "chunk": q, "chunks": n, "kernel": _kernels(count=False),
+        "layout": "tokens"})
     stats = jax.lax.stop_gradient({
         "chunk_log_decay_min": jnp.min(a_sum[..., -1]),
         "dt_max": jnp.max(dt32),
         "state_rms": jnp.sqrt(jnp.mean(jnp.square(final)))})
-    return y.reshape(bt, h, n * q, p)[:, :, :l], stats
+    return y[:, :l].reshape(bt, l, h, p), stats

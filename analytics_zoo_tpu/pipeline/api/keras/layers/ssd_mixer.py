@@ -14,7 +14,12 @@ state of N, groups of heads that share B and C:
     out = RMSNorm_groups(y o SiLU(z)) W_out
 
 the rule by ``ops.ssd.chunked_ssd``, the norm over G groups of I / G
-channels with one gain over all I, the gate before it.
+channels with one gain over all I, the gate before it.  From the
+in-projection to the out-projection every array keeps the projections'
+token-major layout (B, L, channels) in u's dtype: the rule reads x, B and C
+where the convolution wrote them and writes y where the skip, the gate and
+the norm read it, and those three are one expression, float32 only inside
+it.  No head is split out or merged back.
 """
 
 from __future__ import annotations
@@ -26,7 +31,7 @@ import jax.numpy as jnp
 from jax import named_scope
 
 from analytics_zoo_tpu.metrics.tracing import SSD_SCOPE
-from analytics_zoo_tpu.ops.attention import merge_heads, split_heads
+from analytics_zoo_tpu.ops import ssd
 
 #: A = exp(A_log) a head is drawn uniform in this range; the step dt a head
 #: log-uniform in that one, floored at ``DT_FLOOR`` (Mamba's own draws)
@@ -97,11 +102,11 @@ class SSDMixer:
 
     def __call__(self, bp, u, eps):
         """The mixer of the normed state ``u`` (B, L, D): the branch (B, L,
-        D) and the rule's numbers."""
-        from analytics_zoo_tpu.ops.ssd import chunked_ssd
-
+        D) and the rule's numbers (the rule called through the module, as
+        it stands when the mixer runs)."""
         f32 = jnp.float32
-        l = u.shape[1]
+        tokens = u.shape[:2]
+        l = tokens[1]
         with named_scope(SSD_SCOPE):
             zxbcdt = u @ bp["ssd_in_kernel"]
             z = zxbcdt[..., :self.inner]
@@ -115,29 +120,37 @@ class SSDMixer:
                 sum(past[:, i:i + l] * taps[i] for i in range(n))
                 + bp["ssd_conv_bias"])
             groups = self.groups * self.state
-            x = split_heads(xbc[..., :self.inner], self.heads)
-            bmat = split_heads(xbc[..., self.inner:self.inner + groups],
-                               self.groups)
-            cmat = split_heads(xbc[..., self.inner + groups:], self.groups)
+            x = xbc[..., :self.inner]
+            bmat, cmat = (
+                xbc[..., self.inner + at:self.inner + at + groups].reshape(
+                    tokens + (self.groups, self.state))
+                for at in (0, groups))
             dt = jax.nn.softplus(dt.astype(f32)
                                  + bp["ssd_dt_bias"].astype(f32))
             a = -jnp.exp(bp["ssd_a_log"].astype(f32))
-            y, stats = chunked_ssd(x, dt.transpose(0, 2, 1), a, bmat, cmat,
-                                   chunk=self.chunk)
-            y = merge_heads(
-                (y.astype(f32) + bp["ssd_d"].astype(f32)[:, None, None]
-                 * x.astype(f32)).astype(x.dtype))
-            out = _gated_group_norm(y, z, bp["ssd_norm"], self.groups, eps) \
-                @ bp["ssd_out_kernel"]
+            y, stats = ssd.chunked_ssd(
+                x.reshape(tokens + (self.heads, self.head_dim)), dt, a,
+                bmat, cmat, chunk=self.chunk)
+            g = _skip_gate_norm(y.reshape(x.shape), x, z, bp["ssd_d"],
+                                bp["ssd_norm"], self.groups, eps)
+            out = g @ bp["ssd_out_kernel"]
         return out, {"ssd_" + key: value for key, value in stats.items()}
 
 
-def _gated_group_norm(y, z, gamma, groups, eps):
-    """RMSNorm over ``groups`` groups of channels of y o SiLU(z), with one
-    gain over all of them, in float32, handed on in y's dtype."""
+def _skip_gate_norm(y, x, z, d, gamma, groups, eps):
+    """RMSNorm over ``groups`` groups of channels of (y + D x) o SiLU(z),
+    with one gain over all of them: y, x and z (B, L, I) in their dtype, D
+    one a head, float32 inside, handed on in y's dtype.  The groups' mean
+    squares are taken, and their scales spread back over the channels, as
+    products (``ssd.spread``): the (B, L, I) arrays are never reshaped.
+    The result is written once, behind an optimization barrier: fused into
+    the out-projection's weight gradient instead, the whole float32 norm
+    was made again inside that product."""
     f32 = jnp.float32
-    g = (y.astype(f32) * jax.nn.silu(z.astype(f32)))
-    g = g.reshape(g.shape[:-1] + (groups, -1))
-    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-                          + eps)
-    return (g.reshape(y.shape) * gamma.astype(f32)).astype(y.dtype)
+    width = y.shape[-1] // groups
+    g = (y.astype(f32)
+         + jnp.repeat(d.astype(f32), y.shape[-1] // d.shape[0])
+         * x.astype(f32)) * jax.nn.silu(z.astype(f32))
+    scale = jax.lax.rsqrt(ssd.run_sums(jnp.square(g), width) / width + eps)
+    return jax.lax.optimization_barrier(
+        (g * ssd.spread(scale, width) * gamma.astype(f32)).astype(y.dtype))

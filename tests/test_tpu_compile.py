@@ -128,18 +128,21 @@ def _kda(shape):
 def _ssd(batch, heads, length, p, groups, n):
     """(fn, arg specs) for the gradient of Mamba-2's chunked rule: the two
     Pallas kernels of the walk over a sequence's chunks, a group's B and C
-    read once for its heads."""
+    read once for its heads; x, B and C token-major, as the mixer's
+    projections give them, (B, L, heads x P) and (B, L, groups x N)."""
     from analytics_zoo_tpu.ops.ssd import chunked_ssd
 
     def loss(x, dt, a, b, c):
-        y, _ = chunked_ssd(x, dt, a, b, c)
+        y, _ = chunked_ssd(x.reshape(batch, length, heads, p), dt, a,
+                           b.reshape(batch, length, groups, n),
+                           c.reshape(batch, length, groups, n))
         return jnp.sum(jnp.square(y.astype(jnp.float32)))
 
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), [
-        ((batch, heads, length, p), jnp.bfloat16),
-        ((batch, heads, length), jnp.float32), ((heads,), jnp.float32),
-        ((batch, groups, length, n), jnp.bfloat16),
-        ((batch, groups, length, n), jnp.bfloat16)]
+        ((batch, length, heads * p), jnp.bfloat16),
+        ((batch, length, heads), jnp.float32), ((heads,), jnp.float32),
+        ((batch, length, groups * n), jnp.bfloat16),
+        ((batch, length, groups * n), jnp.bfloat16)]
 
 
 def _xent(shape):
@@ -314,6 +317,56 @@ def test_a_layer_application_writes_no_float32_copy_of_q_or_k(
                                            shape.group(1))):
                 written.append(line.strip()[:160])
     assert not written, written
+
+
+def test_the_mamba2_mixer_copies_no_head_out_of_its_lanes(one_chip,
+                                                         real_kernels):
+    """One `nemotron-3-nano-30b-a3b-fit` Mamba-2 mixer's compiled gradient
+    under the decoder's ``"attn"`` checkpoint (2 x 4,096 tokens, 64 heads
+    of 64, a state of 128 in 8 groups, bf16): no instruction outside a
+    fusion copies or transposes an array of x's or y's size (2 x 4,096 x
+    4,096 values, in any shape or type), and both walk kernels read and
+    write the projections' token-major (2, 4096, 4096) arrays.  A mixer
+    that splits the heads out and merges them back does it by such copies,
+    lane-padded at a head of 64."""
+    from analytics_zoo_tpu.parallel.plan import apply_remat
+    from analytics_zoo_tpu.pipeline.api.keras.layers.ssd_mixer import (
+        SSDMixer,
+    )
+
+    mixer = SSDMixer(heads=64, head_dim=64, state=128, groups=8)
+
+    def loss(bp, u):
+        out = apply_remat(lambda bp, u: mixer(bp, u, 1e-5)[0], "attn")(bp, u)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    bp = jax.eval_shape(lambda: mixer.params(jax.random.PRNGKey(0), 2688,
+                                             0.02))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        (bp, jax.ShapeDtypeStruct((2, 4096, 2688), jnp.bfloat16)))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *args).compile().as_text()
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    size = 2 * 4096 * 4096
+    moved = []
+    for name, block in _computations(text).items():
+        if name in fused:
+            continue
+        for line in block.splitlines()[1:]:
+            found = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) "
+                             r"(copy|copy-start|transpose)\(", line)
+            if found and any(
+                    math.prod(map(int, dims.split(","))) == size
+                    for dims in re.findall(r"\w+\[([\d,]+)\]",
+                                           found.group(1))):
+                moved.append(line.strip()[:160])
+    assert not moved, moved
+    kernels = re.findall(r"= \((.*?)\) custom-call\(.*?"
+                         r'custom_call_target="tpu_custom_call"', text)
+    assert len(kernels) == 2, kernels
+    assert all("bf16[2,4096,4096]" in k for k in kernels), kernels
 
 
 def _computations(text):
