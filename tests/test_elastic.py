@@ -460,7 +460,34 @@ def test_chaos_acceptance_kill9_and_sigterm_unattended(tmp_path):
         lease_ms=800, min_workers=1, interval=0.1,
         chaos=ChaosSchedule.parse("kill@12:w1,term@24:w2"),
         worker_env={"ZOO_FLIGHT_DIR": str(tmp_path / "flight")})
-    res = sup.run(timeout_s=420)
+
+    def logged(action, reason):
+        return sum(1 for d in sup.decision_log()
+                   if d["action"] == action and d["reason"] == reason)
+
+    # A respawn takes seconds to import and join, the 24 steps after the
+    # SIGTERM about two.  From the SIGTERM until the second respawn has
+    # joined, the chief finds a chaos stall of 2 s armed at each step
+    # barrier (its control hash, consumed as it fires), so that the round
+    # cannot end before the join it is checked for.
+    finished = threading.Event()
+
+    def stall_the_chief():
+        broker = sup.ledger.broker
+        while not finished.is_set() and logged("rejoin", "join") < 3:
+            if logged("chaos", "term"):
+                ctl = sup.ledger.ctl_key(sup.current()["chief"])
+                if not fget(broker.hgetall(ctl), "stall_s"):
+                    broker.hset(ctl, {"stall_s": "2.0"})
+            time.sleep(0.02)
+
+    staller = threading.Thread(target=stall_the_chief, daemon=True)
+    staller.start()
+    try:
+        res = sup.run(timeout_s=420)
+    finally:
+        finished.set()
+        staller.join()
 
     # unattended completion: full target reached, result posted
     assert res is not None and res["done"] == 1, sup.decision_log()

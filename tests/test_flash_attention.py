@@ -45,8 +45,8 @@ def test_blockwise_backward_matches_reference(causal, lq, lk):
         return jnp.sum(
             _attention_reference(q, k, v, causal, 1.0 / np.sqrt(8)) ** 2)
 
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
@@ -97,8 +97,8 @@ def test_pallas_kernel_interpret_matches_reference(causal, lq, lk):
 
 def _grad_check(loss_flash, loss_ref, args, rtol=1e-4, atol=1e-4):
     n = len(args)
-    g1 = jax.grad(loss_flash, argnums=tuple(range(n)))(*args)
-    g2 = jax.grad(loss_ref, argnums=tuple(range(n)))(*args)
+    g1 = jax.jit(jax.grad(loss_flash, argnums=tuple(range(n))))(*args)
+    g2 = jax.jit(jax.grad(loss_ref, argnums=tuple(range(n))))(*args)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, rtol=rtol, atol=atol)
 
@@ -663,8 +663,10 @@ def test_tile_schedule_matches_reference(case, dtype, monkeypatch):
 
     wide = [x.astype(jnp.float32) for x in (q, k, v)]
     schedules = _fresh_schedules()
-    got = [flash(q, k, v), *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)]
-    want = [dense(*wide), *jax.grad(loss(dense), argnums=(0, 1, 2))(*wide)]
+    got, want = jax.jit(lambda q, k, v, wide: (
+        [flash(q, k, v), *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)],
+        [dense(*wide), *jax.grad(loss(dense), argnums=(0, 1, 2))(*wide)]))(
+            q, k, v, wide)
     tol_l2, tol_max = SCHEDULE_TOL[dtype]
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
         assert a.dtype == jnp.dtype(dtype) and a.shape == b.shape, name
@@ -916,8 +918,9 @@ def test_value_width_unlike_query_width(route, causal, monkeypatch):
         return _attention_reference(q, k, v, causal, 1.0 / np.sqrt(24))
 
     schedules = _fresh_schedules()
-    got = [flash(q, k, v), *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)]
-    want = [dense(q, k, v), *jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)]
+    got, want = jax.jit(lambda q, k, v: tuple(
+        [attend(q, k, v), *jax.grad(loss(attend), argnums=(0, 1, 2))(q, k, v)]
+        for attend in (flash, dense)))(q, k, v)
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
         assert a.shape == b.shape, name
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
@@ -996,8 +999,9 @@ def test_grouped_query_heads_against_repeated_ones(route, causal, kv_heads,
                                     1.0 / 8.0)
 
     schedules = _fresh_schedules()
-    got = [flash(q, k, v), *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)]
-    want = [dense(q, k, v), *jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)]
+    got, want = jax.jit(lambda q, k, v: tuple(
+        [attend(q, k, v), *jax.grad(loss(attend), argnums=(0, 1, 2))(q, k, v)]
+        for attend in (flash, dense)))(q, k, v)
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
         assert a.shape == b.shape, name
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)

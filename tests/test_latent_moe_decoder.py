@@ -52,7 +52,7 @@ def weights(cfg, reference):
     return reference.init_params(jax.random.PRNGKey(5), cfg.sizes)
 
 
-@pytest.fixture()
+@pytest.fixture(scope="module")
 def net(cfg):
     from analytics_zoo_tpu import init_zoo_context
 
@@ -69,6 +69,23 @@ def _program_loss(net, params, x, y):
     return moe.collect_aux_cost(new_state), new_state
 
 
+def _against_the_reference(net, reference, sizes, weights, x, y):
+    """The program's and the reference's logits, then each one's loss and
+    gradient of every leaf, traced and compiled as one program:
+    ``((logits, reference logits), ((loss, new state), grads), (reference
+    loss, reference grads))``."""
+    model, state = net
+
+    def both(p):
+        logits, _ = model.forward(p, x, state=state, training=False)
+        return (logits, reference.logits(p, x, sizes)), \
+            jax.value_and_grad(lambda p: _program_loss(net, p, x, y),
+                               has_aux=True)(p), \
+            jax.value_and_grad(reference.loss_fn)(p, x, y, sizes)
+
+    return jax.jit(both)(weights)
+
+
 def test_logits_loss_and_every_gradient_against_the_reference(
         net, cfg, reference, weights, batch):
     model, state = net
@@ -76,14 +93,9 @@ def test_logits_loss_and_every_gradient_against_the_reference(
     assert jax.tree_util.tree_structure(built) \
         == jax.tree_util.tree_structure(weights)
     x, y = batch
-    logits, _ = model.forward(weights, x, state=state, training=False)
-    want = reference.logits(weights, x, cfg.sizes)
+    (logits, want), ((loss, new_state), grads), (ref_loss, ref_grads) = \
+        _against_the_reference(net, reference, cfg.sizes, weights, x, y)
     np.testing.assert_allclose(logits, want, atol=2e-6)
-
-    (loss, new_state), grads = jax.value_and_grad(
-        lambda p: _program_loss(net, p, x, y), has_aux=True)(weights)
-    ref_loss, ref_grads = jax.value_and_grad(reference.loss_fn)(
-        weights, x, y, cfg.sizes)
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
     for (path, got), ref in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
@@ -538,15 +550,10 @@ def test_the_mixer_by_layer_against_the_reference(kimi_net, kimi,
     rng = np.random.default_rng(17)
     x, y = (jnp.asarray(rng.integers(0, 32, (BATCH, 80)).astype(np.int32))
             for _ in range(2))
-    logits, _ = model.forward(kimi_weights, x, state=state, training=False)
-    np.testing.assert_allclose(
-        logits, reference.logits(kimi_weights, x, kimi.sizes), atol=3e-6)
-
-    (loss, new_state), grads = jax.value_and_grad(
-        lambda p: _program_loss(kimi_net, p, x, y), has_aux=True)(
-            kimi_weights)
-    ref_loss, ref_grads = jax.value_and_grad(reference.loss_fn)(
-        kimi_weights, x, y, kimi.sizes)
+    (logits, want), ((loss, new_state), grads), (ref_loss, ref_grads) = \
+        _against_the_reference(kimi_net, reference, kimi.sizes,
+                               kimi_weights, x, y)
+    np.testing.assert_allclose(logits, want, atol=3e-6)
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
     for (path, got), ref in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
@@ -629,21 +636,23 @@ def test_latent_attention_takes_rotary_or_none():
         LatentMoEDecoder(attention=["full", "kda"], rotary_theta=None,
                          **options)
     layer = LatentMoEDecoder(rotary_theta=None, **options)
-    params = layer.init_params(jax.random.PRNGKey(0))
+    params = jax.eval_shape(layer.init_params, jax.random.PRNGKey(0))
     x = jnp.asarray(np.random.default_rng(0).integers(0, 32, (2, 12)))
-    assert layer.call(params, x)[0].shape == (2, 12, 32)
+    assert jax.eval_shape(layer.call, params, x)[0].shape == (2, 12, 32)
     # no positions anywhere: through one layer a sequence's last token
     # sees a set of keys, whatever their order
     one = LatentMoEDecoder(rotary_theta=None, **{**options, "n_block": 1})
-    params = one.init_params(jax.random.PRNGKey(0))
+    params = jax.jit(one.init_params)(jax.random.PRNGKey(0))
+    call = jax.jit(one.call)
     swapped = x.at[:, :11].set(x[:, 10::-1])
-    np.testing.assert_allclose(one.call(params, swapped)[0][:, -1],
-                               one.call(params, x)[0][:, -1], atol=1e-6)
+    np.testing.assert_allclose(call(params, swapped)[0][:, -1],
+                               call(params, x)[0][:, -1], atol=1e-6)
     turned = LatentMoEDecoder(rotary_theta=1e4, **{
         **options, "n_block": 1, "qk_rope_dim": 4})
-    params = turned.init_params(jax.random.PRNGKey(0))
-    assert float(jnp.abs(turned.call(params, swapped)[0][:, -1]
-                         - turned.call(params, x)[0][:, -1]).max()) > 1e-4
+    params = jax.jit(turned.init_params)(jax.random.PRNGKey(0))
+    call = jax.jit(turned.call)
+    assert float(jnp.abs(call(params, swapped)[0][:, -1]
+                         - call(params, x)[0][:, -1]).max()) > 1e-4
 
 
 def test_a_window_is_four_thirds_of_the_even_share_or_an_eighth_of_all():
@@ -677,14 +686,19 @@ def test_the_thirty_two_shares_add_up_to_the_uncut_layer(kimi):
     whole = reference._feed_forward(qs, sizes, bp, u)
     total = (jax.nn.silu(u @ bp["shared_gate_kernel"])
              * (u @ bp["shared_fc_kernel"])) @ bp["shared_out_kernel"]
-    held = 0.0
-    for first in range(0, 256, 8):
-        part, stats = moe.held_experts_ffn(
+
+    @jax.jit
+    def held_part(first):
+        return moe.held_experts_ffn(
             u, bp["router_kernel"], bp["router_bias"],
-            *(bp[k][first:first + 8] for k in
+            *(jax.lax.dynamic_slice_in_dim(bp[k], first, 8) for k in
               ("experts_gate", "experts_up", "experts_down")),
             first_held=first, top_k=8,
             routed_scale=sizes["routed_scaling_factor"])
+
+    parts = [held_part(first) for first in range(0, 256, 8)]
+    held = 0.0
+    for part, stats in parts:
         total = total + part
         held += float(stats["held_assignments"])
         assert float(stats["dropped_assignments"]) == 0.0
@@ -695,18 +709,8 @@ def test_the_thirty_two_shares_add_up_to_the_uncut_layer(kimi):
                       ("experts_gate", "experts_up", "experts_down")}}
     got = reference._feed_forward(
         qs, {**sizes, "num_experts": 8, "experts_held_from": 40}, share, u)
-    want, _ = moe.held_experts_ffn(
-        u, bp["router_kernel"], bp["router_bias"], share["experts_gate"],
-        share["experts_up"], share["experts_down"], first_held=40, top_k=8,
-        routed_scale=sizes["routed_scaling_factor"])
-    shared = total - sum(
-        moe.held_experts_ffn(
-            u, bp["router_kernel"], bp["router_bias"],
-            *(bp[k][first:first + 8] for k in
-              ("experts_gate", "experts_up", "experts_down")),
-            first_held=first, top_k=8,
-            routed_scale=sizes["routed_scaling_factor"])[0]
-        for first in range(0, 256, 8))
+    want = parts[40 // 8][0]
+    shared = total - sum(part for part, _ in parts)
     np.testing.assert_allclose(got - shared, want, atol=2e-6)
 
 
@@ -808,17 +812,12 @@ def test_blocks_as_records_against_the_reference(nemotron_net, nemotron,
     rng = np.random.default_rng(19)
     x, y = (jnp.asarray(rng.integers(0, 32, (BATCH, 72)).astype(np.int32))
             for _ in range(2))
-    logits, _ = model.forward(nemotron_weights, x, state=state,
-                              training=False)
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(
-            logits, reference.logits(nemotron_weights, x, nemotron.sizes),
-            atol=3e-6)
-        (loss, new_state), grads = jax.value_and_grad(
-            lambda p: _program_loss(nemotron_net, p, x, y), has_aux=True)(
-                nemotron_weights)
-        ref_loss, ref_grads = jax.value_and_grad(reference.loss_fn)(
-            nemotron_weights, x, y, nemotron.sizes)
+        (logits, want), ((loss, new_state), grads), \
+            (ref_loss, ref_grads) = _against_the_reference(
+                nemotron_net, reference, nemotron.sizes, nemotron_weights,
+                x, y)
+    np.testing.assert_allclose(logits, want, atol=3e-6)
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
     for (path, got), ref in zip(flat, jax.tree_util.tree_leaves(ref_grads)):

@@ -57,8 +57,19 @@ GRIDS = [(64, 16), (50, 16), (64, 32), (100, 64), (24, 64)]
 def test_chunked_form_against_the_recurrence(recurrence, length, chunk,
                                              hard):
     args, weight = _inputs(length, hard)
-    want = recurrence(*args)
-    got, stats = linear.chunked_kda(*args, scale=SCALE, chunk=chunk)
+
+    @jax.jit
+    def both(args):
+        # each traced and compiled once, not run op by op
+        return recurrence(*args), \
+            linear.chunked_kda(*args, scale=SCALE, chunk=chunk), \
+            jax.grad(lambda *a: jnp.sum(linear.chunked_kda(
+                *a, scale=SCALE, chunk=chunk)[0] * weight),
+                argnums=range(5))(*args), \
+            jax.grad(lambda *a: jnp.sum(recurrence(*a) * weight),
+                     argnums=range(5))(*args)
+
+    want, (got, stats), grads, ref_grads = both(args)
     np.testing.assert_allclose(got, want, atol=2e-6)
     c, sub = linear.chunk_of(length, chunk)
     if hard:
@@ -70,10 +81,6 @@ def test_chunked_form_against_the_recurrence(recurrence, length, chunk,
         assert -linear.CLAMP < float(stats["sub_block_log_decay_min"]) \
             < -4.0 * (min(sub, length) - 1)
     assert 0.05 < float(stats["state_rms"]) < 1.0
-    grads = jax.grad(lambda *a: jnp.sum(linear.chunked_kda(
-        *a, scale=SCALE, chunk=chunk)[0] * weight), argnums=range(5))(*args)
-    ref_grads = jax.grad(lambda *a: jnp.sum(recurrence(*a) * weight),
-                         argnums=range(5))(*args)
     for name, got, ref in zip("qkvgb", grads, ref_grads):
         np.testing.assert_allclose(
             got, ref, atol=5e-6 * float(jnp.abs(ref).max()), err_msg=name)

@@ -213,16 +213,18 @@ class TestInceptionV1:
     def test_shapes_and_param_count(self):
         from analytics_zoo_tpu.models.inception import Inception
 
-        net = Inception.v1(classes=1000)
-        net.build_params()
         import jax
 
+        net = Inception.v1(classes=1000)
+        # shapes alone: traced, neither drawn nor run
+        params = jax.eval_shape(net.init_params, jax.random.PRNGKey(0))
         n_params = sum(int(np.prod(p.shape))
-                       for p in jax.tree_util.tree_leaves(net.params))
+                       for p in jax.tree_util.tree_leaves(params))
         # GoogLeNet no-aux has ~7.0M params (6.99M conv/fc + biases)
         assert 6.5e6 < n_params < 7.5e6, n_params
-        x = np.zeros((2, 224, 224, 3), np.float32)
-        out, _ = net.forward(net.params, x, state=net.state)
+        x = jax.ShapeDtypeStruct((2, 224, 224, 3), np.float32)
+        out, _ = jax.eval_shape(
+            lambda p, x: net.forward(p, x, state=net.init_state()), params, x)
         assert out.shape == (2, 1000)
 
     def test_trains_on_tiny_task(self):

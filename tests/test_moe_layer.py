@@ -360,13 +360,20 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(nemotron):
     whole = reference._routed(qs, sizes, bp, u)
     total, held = _relu2_shared(bp, u), 0.0
     relu2 = get_activation("relu2")
+
+    @jax.jit
+    def held_part(first):
+        # one compiled part, the share it holds an argument
+        return held_experts_ffn(
+            u, bp["router_kernel"], bp["router_bias"], None,
+            *(jax.lax.dynamic_slice_in_dim(bp[k], first, 8)
+              for k in ("experts_up", "experts_down")), first_held=first,
+            top_k=6, routed_scale=sizes["routed_scaling_factor"],
+            activation=relu2)
+
     parts = {}
     for first in range(0, 128, 8):
-        part, stats = held_experts_ffn(
-            u, bp["router_kernel"], bp["router_bias"], None,
-            bp["experts_up"][first:first + 8],
-            bp["experts_down"][first:first + 8], first_held=first, top_k=6,
-            routed_scale=sizes["routed_scaling_factor"], activation=relu2)
+        part, stats = held_part(first)
         parts[first] = part
         total = total + part
         held += float(stats["held_assignments"])

@@ -45,10 +45,10 @@ class TestRingAttention:
             jnp.asarray(rng.normal(size=(1, 2, 16, 4)).astype(np.float32))
             for _ in range(3)
         )
-        g = jax.grad(lambda q: jnp.sum(
-            ring_attention(q, k, v, causal=True) ** 2))(q)
-        gr = jax.grad(lambda q: jnp.sum(dot_product_attention(
-            q, k, v, causal=True, use_flash=False) ** 2))(q)
+        g = jax.jit(jax.grad(lambda q: jnp.sum(
+            ring_attention(q, k, v, causal=True) ** 2)))(q)
+        gr = jax.jit(jax.grad(lambda q: jnp.sum(dot_product_attention(
+            q, k, v, causal=True, use_flash=False) ** 2)))(q)
         np.testing.assert_allclose(np.asarray(g), np.asarray(gr), atol=1e-4)
 
     def test_sharded_inputs_under_jit(self, seq_ctx):
@@ -480,12 +480,12 @@ class TestRingAttentionPallasInner:
         monkeypatch.setenv("ZOO_FLASH_INTERPRET", "1")
         q, k, v = self._data(seed=1)
 
-        g = jax.grad(lambda q, k, v: jnp.sum(
+        g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
             ring_attention(q, k, v, causal=True) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
+            argnums=(0, 1, 2)))(q, k, v)
+        gr = jax.jit(jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
             q, k, v, causal=True, use_flash=False) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
+            argnums=(0, 1, 2)))(q, k, v)
         for a, b, name in zip(g, gr, "qkv"):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-3, err_msg=name)
@@ -497,10 +497,10 @@ class TestRingAttentionPallasInner:
         from analytics_zoo_tpu.parallel import ring_attention
 
         q, k, v = self._data(L=32, d=8, seed=2)
-        g = jax.grad(lambda k: jnp.sum(
-            ring_attention(q, k, v, causal=False) ** 2))(k)
-        gr = jax.grad(lambda k: jnp.sum(dot_product_attention(
-            q, k, v, causal=False, use_flash=False) ** 2))(k)
+        g = jax.jit(jax.grad(lambda k: jnp.sum(
+            ring_attention(q, k, v, causal=False) ** 2)))(k)
+        gr = jax.jit(jax.grad(lambda k: jnp.sum(dot_product_attention(
+            q, k, v, causal=False, use_flash=False) ** 2)))(k)
         np.testing.assert_allclose(np.asarray(g), np.asarray(gr),
                                    atol=1e-4)
 
@@ -514,11 +514,11 @@ def test_ring_backward_chunk_padding(seq_ctx):
     rng = np.random.default_rng(5)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 1, 1280, 8))
                            .astype(np.float32) * 0.5) for _ in range(3))
-    g = jax.grad(lambda q, k, v: jnp.sum(
-        ring_attention(q, k, v, causal=True) ** 2), argnums=(0, 1, 2))(
+    g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        ring_attention(q, k, v, causal=True) ** 2), argnums=(0, 1, 2)))(
             q, k, v)
-    gr = jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
-        q, k, v, causal=True, use_flash=False) ** 2), argnums=(0, 1, 2))(
+    gr = jax.jit(jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
+        q, k, v, causal=True, use_flash=False) ** 2), argnums=(0, 1, 2)))(
             q, k, v)
     for a, b, name in zip(g, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -559,12 +559,12 @@ class TestZigzagRingAttention:
                         .astype(np.float32) * 0.5)
             for _ in range(3)
         )
-        g = jax.grad(lambda q, k, v: jnp.sum(
+        g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
             zigzag_ring_attention(q, k, v, causal=True) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
+            argnums=(0, 1, 2)))(q, k, v)
+        gr = jax.jit(jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
             q, k, v, causal=True, use_flash=False) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
+            argnums=(0, 1, 2)))(q, k, v)
         for a, b, name in zip(g, gr, "qkv"):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-3, err_msg=name)
@@ -618,16 +618,16 @@ def test_ring_pallas_backward_fires_and_matches(seq_ctx, monkeypatch):
     q, k, v = (jnp.asarray(rng.normal(size=(1, 2, 512, 64))
                            .astype(np.float32) * 0.5) for _ in range(3))
     before = fa.invocation_counts["pallas"]
-    g = jax.grad(lambda q, k, v: jnp.sum(
-        ring_attention(q, k, v, causal=True) ** 2), argnums=(0, 1, 2))(
+    g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        ring_attention(q, k, v, causal=True) ** 2), argnums=(0, 1, 2)))(
             q, k, v)
     # fwd hops + bwd hop kernels all counted at trace time; the bwd
     # contributes at least one pallas invocation beyond the forward's 2
     assert fa.invocation_counts["pallas"] >= before + 3, (
         "ring backward did not route through the Pallas kernels")
-    gr = jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
+    gr = jax.jit(jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
         q, k, v, causal=True, use_flash=False) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-3, err_msg=name)
@@ -643,12 +643,12 @@ def test_zigzag_pallas_backward_matches(seq_ctx, monkeypatch):
     rng = np.random.default_rng(12)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 1, 1024, 64))
                            .astype(np.float32) * 0.5) for _ in range(3))
-    g = jax.grad(lambda q, k, v: jnp.sum(
+    g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
         zigzag_ring_attention(q, k, v, causal=True) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
+        argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(lambda q, k, v: jnp.sum(dot_product_attention(
         q, k, v, causal=True, use_flash=False) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-3, err_msg=name)

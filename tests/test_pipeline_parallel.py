@@ -228,11 +228,6 @@ class TestTransformerGPipe:
         rng = np.random.default_rng(0)
         h = jnp.asarray(rng.normal(size=(8, 8, 16)).astype(np.float32))
 
-        ref = layer._run_blocks(params["blocks"], h, None, False, None)
-        out = transformer_gpipe(layer, params, h, n_microbatch=4)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5)
-
         def piped(params, h):
             return jnp.mean(
                 transformer_gpipe(layer, params, h, n_microbatch=4) ** 2)
@@ -242,8 +237,16 @@ class TestTransformerGPipe:
                 layer._run_blocks(params["blocks"], h, None, False,
                                   None) ** 2)
 
-        gp = jax.grad(piped)(params, h)
-        gs = jax.grad(seq)(params, h)
+        @jax.jit
+        def both(params, h):
+            # each traced and compiled once, not run op by op
+            return (layer._run_blocks(params["blocks"], h, None, False, None),
+                    transformer_gpipe(layer, params, h, n_microbatch=4),
+                    jax.grad(piped)(params, h), jax.grad(seq)(params, h))
+
+        ref, out, gp, gs = both(params, h)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
         jax.tree_util.tree_map(
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=2e-5), gp, gs)
@@ -268,15 +271,22 @@ class TestTransformerGPipe:
         mask = jnp.broadcast_to(
             jnp.where(jnp.arange(8) < 6, 0.0, -1e9), (8, 8))[None, None]
 
-        ref = layer._run_blocks(params["blocks"], h, mask, False, None)
-        out = transformer_gpipe(layer, params, h, n_microbatch=4,
-                                mask=mask)
+        @jax.jit
+        def both(params):
+            # each traced and compiled once, not run op by op
+            return (layer._run_blocks(params["blocks"], h, mask, False,
+                                      None),
+                    transformer_gpipe(layer, params, h, n_microbatch=4,
+                                      mask=mask),
+                    jax.grad(lambda p: jnp.mean(transformer_gpipe(
+                        layer, p, h, n_microbatch=4, mask=mask) ** 2))(
+                            params),
+                    jax.grad(lambda p: jnp.mean(layer._run_blocks(
+                        p["blocks"], h, mask, False, None) ** 2))(params))
+
+        ref, out, g, gr = both(params)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
-        g = jax.grad(lambda p: jnp.mean(transformer_gpipe(
-            layer, p, h, n_microbatch=4, mask=mask) ** 2))(params)
-        gr = jax.grad(lambda p: jnp.mean(layer._run_blocks(
-            p["blocks"], h, mask, False, None) ** 2))(params)
         jax.tree_util.tree_map(
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=2e-5), g, gr)
@@ -365,8 +375,8 @@ class TestGPipeHetero:
                 a = jnp.tanh(a @ e["w"])
             return jnp.mean(a ** 2)
 
-        gp, gx = jax.grad(piped, argnums=(0, 1))(tuple(edge), x)
-        rp, rx = jax.grad(seq, argnums=(0, 1))(tuple(edge), x)
+        gp, gx = jax.jit(jax.grad(piped, argnums=(0, 1)))(tuple(edge), x)
+        rp, rx = jax.jit(jax.grad(seq, argnums=(0, 1)))(tuple(edge), x)
         np.testing.assert_allclose(np.asarray(gx), np.asarray(rx),
                                    atol=1e-5)
         jax.tree_util.tree_map(
@@ -399,17 +409,19 @@ class TestGPipeHetero:
             return transformer_gpipe_lm(layer, params, head_w, head_b,
                                         toks, n_microbatch=4)
 
-        ref = seq(params, head_w)
-        out = piped(params, head_w)
+        @jax.jit
+        def both(params, head_w):
+            # each traced and compiled once, not run op by op
+            return seq(params, head_w), piped(params, head_w), jax.grad(
+                lambda p, w: jnp.mean(piped(p, w) ** 2), argnums=(0, 1))(
+                    params, head_w), jax.grad(
+                lambda p, w: jnp.mean(seq(p, w) ** 2), argnums=(0, 1))(
+                    params, head_w)
+
+        ref, out, (gp, gh), (rp, rh) = both(params, head_w)
         assert out.shape == (8, 8, 32)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
-        gp, gh = jax.grad(
-            lambda p, w: jnp.mean(piped(p, w) ** 2), argnums=(0, 1))(
-                params, head_w)
-        rp, rh = jax.grad(
-            lambda p, w: jnp.mean(seq(p, w) ** 2), argnums=(0, 1))(
-                params, head_w)
         np.testing.assert_allclose(np.asarray(gh), np.asarray(rh),
                                    atol=2e-5)
         jax.tree_util.tree_map(
@@ -477,8 +489,8 @@ class TestGPipeCircular:
                 x = jnp.tanh(x @ p["w"][i] + p["b"][i])
             return jnp.mean(x ** 2)
 
-        gp, gx = jax.grad(piped, argnums=(0, 1))(params, x)
-        rp, rx = jax.grad(seq, argnums=(0, 1))(params, x)
+        gp, gx = jax.jit(jax.grad(piped, argnums=(0, 1)))(params, x)
+        rp, rx = jax.jit(jax.grad(seq, argnums=(0, 1)))(params, x)
         np.testing.assert_allclose(np.asarray(gx), np.asarray(rx),
                                    atol=1e-5)
         for k in gp:

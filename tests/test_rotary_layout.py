@@ -165,14 +165,14 @@ def test_gradient_through_an_ouro_block_is_the_old_forms(monkeypatch):
         out = layer._block_forward_aux(bp, h, None, True, None)[0]
         return jnp.sum(out ** 2)
 
-    got = jax.grad(loss, argnums=(0, 1))(bp, h)
+    got = jax.jit(jax.grad(loss, argnums=(0, 1)))(bp, h)
     monkeypatch.setattr(
         self_attention, "project_heads",
         lambda u, kernel, heads, parts, bias: tuple(
             split_heads(p, heads) for p in jnp.split(u @ kernel, parts, -1)))
     monkeypatch.setattr(self_attention, "rotary",
                         lambda x, theta: _oracle_rotary(x, theta))
-    want = jax.grad(loss, argnums=(0, 1))(bp, h)
+    want = jax.jit(jax.grad(loss, argnums=(0, 1)))(bp, h)
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
                             jax.tree_util.tree_leaves(want)):
         scale = float(jnp.max(jnp.abs(w))) or 1.0

@@ -61,15 +61,18 @@ GRIDS = [(64, 16), (50, 16), (64, 32), (100, 64), (24, 64)]
 def _against_the_recurrence(recurrence, args, weight, chunk):
     """The rule's output and its five gradients against the recurrence's,
     in float32 at the highest precision."""
+    def both(args):
+        return recurrence(*args), ssd.chunked_ssd(*args, chunk=chunk), \
+            jax.grad(lambda *a: jnp.sum(ssd.chunked_ssd(
+                *a, chunk=chunk)[0] * weight), argnums=range(5))(*args), \
+            jax.grad(lambda *a: jnp.sum(recurrence(*a) * weight),
+                     argnums=range(5))(*args)
+
     with jax.default_matmul_precision("highest"):
-        want = recurrence(*args)
-        got, stats = ssd.chunked_ssd(*args, chunk=chunk)
-        np.testing.assert_allclose(got, want,
-                                   atol=2e-6 * float(jnp.abs(want).max()))
-        grads = jax.grad(lambda *a: jnp.sum(ssd.chunked_ssd(
-            *a, chunk=chunk)[0] * weight), argnums=range(5))(*args)
-        ref_grads = jax.grad(lambda *a: jnp.sum(recurrence(*a) * weight),
-                             argnums=range(5))(*args)
+        # each traced and compiled once, not run op by op
+        want, (got, stats), grads, ref_grads = jax.jit(both)(args)
+    np.testing.assert_allclose(got, want,
+                               atol=2e-6 * float(jnp.abs(want).max()))
     for name, g, ref in zip(("x", "dt", "A", "B", "C"), grads, ref_grads):
         np.testing.assert_allclose(
             g, ref, atol=5e-6 * float(jnp.abs(ref).max()), err_msg=name)
@@ -121,17 +124,21 @@ def test_bfloat16_operands_against_the_float32_recurrence(recurrence):
     args, weight = _inputs(96, hard=True)
     bf = tuple(t.astype(jnp.bfloat16) if i in (0, 3, 4) else t
                for i, t in enumerate(args))
-    want = recurrence(*args)
-    got, _ = ssd.chunked_ssd(*bf, chunk=32)
+
+    @jax.jit
+    def both(args, bf):
+        return recurrence(*args), ssd.chunked_ssd(*bf, chunk=32)[0], \
+            jax.grad(lambda *a: jnp.sum(ssd.chunked_ssd(
+                *a, chunk=32)[0].astype(jnp.float32) * weight),
+                argnums=range(5))(*bf), \
+            jax.grad(lambda *a: jnp.sum(recurrence(*a) * weight),
+                     argnums=range(5))(*args)
+
+    want, got, grads, ref_grads = both(args, bf)
     assert got.dtype == jnp.bfloat16
     gap = float(jnp.linalg.norm(got.astype(jnp.float32) - want)
                 / jnp.linalg.norm(want))
     assert gap < 0.01, gap
-    grads = jax.grad(lambda *a: jnp.sum(ssd.chunked_ssd(
-        *a, chunk=32)[0].astype(jnp.float32) * weight),
-        argnums=range(5))(*bf)
-    ref_grads = jax.grad(lambda *a: jnp.sum(recurrence(*a) * weight),
-                         argnums=range(5))(*args)
     for name, g, ref in zip(("x", "dt", "A", "B", "C"), grads, ref_grads):
         gap = float(jnp.linalg.norm(g.astype(jnp.float32) - ref)
                     / jnp.linalg.norm(ref))

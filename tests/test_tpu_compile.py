@@ -8,6 +8,7 @@ their references on the chip.
 Keep these in ONE file: only one process may hold the TPU library, and the
 worker that gets this file is the one that loads it."""
 
+import functools
 import math
 import re
 
@@ -247,9 +248,11 @@ def test_kernel_compiles_for_v5e(case, one_chip, real_kernels):
     assert memory.temp_size_in_bytes < 16 << 30  # the chip's memory
 
 
+@functools.lru_cache(maxsize=None)
 def _ouro_application_gradient(one_chip, **policy):
     """The compiled gradient of one checkpointed layer application of
-    `ouro-2.6b-fit` (2 x 4,096 tokens, 16 heads of 128, bf16), as text."""
+    `ouro-2.6b-fit` (2 x 4,096 tokens, 16 heads of 128, bf16), as text;
+    compiled once a policy for the tests that read it."""
     from analytics_zoo_tpu.pipeline.api.keras.layers import LoopedDecoder
 
     layer = LoopedDecoder(vocab=49152, n_block=1, n_head=16,
@@ -530,7 +533,7 @@ def test_the_kanana_step_compiles_whole_for_v5e(one_chip, real_kernels):
     reference = configuration.module("reference")
     params = jax.eval_shape(lambda k: reference.init_params(k, sizes),
                             jax.random.PRNGKey(0))
-    _, state = model.build_params()
+    state = jax.eval_shape(model.init_state)
     opt_state = jax.eval_shape(est.optimizer.init, params)
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
@@ -576,7 +579,7 @@ def _kimi_step_text(one_chip) -> str:
     params = jax.eval_shape(
         lambda k: configuration.module("reference").init_params(k, sizes),
         jax.random.PRNGKey(0))
-    _, state = model.build_params()
+    state = jax.eval_shape(model.init_state)
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         (params, jax.eval_shape(est.optimizer.init, params), state,
